@@ -3,7 +3,8 @@
 # pybitmessage_tpu/pow/native.py when missing or stale.
 
 .PHONY: all native test bench bench-smoke chaos perfguard lint \
-	roles-smoke clients-smoke profile-smoke device-smoke doctor clean
+	roles-smoke clients-smoke profile-smoke device-smoke doctor \
+	chip-smoke clean
 
 all: native
 
@@ -48,9 +49,8 @@ bench-smoke:
 
 # perf guard (docs/observability.md): run bench-smoke and diff the
 # guarded metrics against the committed baseline with per-metric
-# tolerance bands — exits non-zero on regression, keeping the
-# BENCH_r01->r05 gains from silently eroding.  Re-baseline after an
-# intentional perf change with:
+# tolerance bands — exits non-zero on regression.  Re-baseline after
+# an intentional perf change with:
 #   python tools/bench_compare.py --run --update
 perfguard:
 	python tools/bench_compare.py --run
@@ -77,12 +77,19 @@ device-smoke:
 # TPU preflight doctor (docs/observability.md): fingerprint the
 # jax/jaxlib/libtpu stack, enumerate devices, compile-probe every
 # program in the device-telemetry catalog, and map known failure
-# signatures (libtpu version mismatch, device busy, OOM) to named
-# diagnoses.  Nonzero exit blocks a multi-chip rendezvous (ROADMAP
-# item 3); classify a recorded failure tail with:
-#   python tools/tpu_doctor.py --diagnose MULTICHIP_r01.json
+# signatures (no TPU, device busy, OOM) to named diagnoses.  Nonzero
+# exit blocks a multi-chip rendezvous (ROADMAP item 3).  Off a TPU the
+# probes run in interpret mode and the report says "interpret": true.
 doctor:
 	python tools/tpu_doctor.py
+
+# the quickest proof that the node still starts on the chip: two nodes
+# send and receive at network difficulty on ONE directly attached TPU
+# chip, with every hidden fallback turned into a failure.  Fails at
+# its first check without a TPU; one process per chip (docs/roles.md).
+# `python chip_smoke.py --chips 4` runs the pod path on four chips.
+chip-smoke:
+	python chip_smoke.py
 
 # role-split smoke (docs/roles.md): spawn edge+relay as REAL daemon
 # subprocesses, deliver one message end to end over TCP through the

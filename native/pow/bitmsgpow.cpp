@@ -123,6 +123,7 @@ static void search_thread(int tid, int nthreads, const u64* ih, u64 target,
       if (stop_flag && *stop_flag) break;
     }
     if (trial(nonce, ih) <= target) {
+      ++local;  // the winning trial is a trial: a solve never reports 0
       // first hit wins; record the smallest winning nonce seen
       u64 prev = sh->winner.load();
       while (nonce < prev &&

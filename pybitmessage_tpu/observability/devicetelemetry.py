@@ -40,9 +40,11 @@ utilization"): one double-SHA512 PoW trial executes
 :data:`POW_FLOPS_PER_HASH` = 21152 vector u32 ops (counted from the
 jaxpr of the unrolled schedule); one ECDSA verify is ~3.6e6 u32 ops
 (Strauss-Shamir 256-step double ladder over 20x13-bit limbs), one
-ECDH ~2.4e6 (single 256-step Montgomery-style ladder).  Peak is
-:data:`DEVICE_PEAK_OPS` = 6.1e12 u32/s per v5e chip (8x128 lanes x 4
-ALUs x ~1.5 GHz) — on a CPU backend the MFU gauge is honest but tiny.
+ECDH ~2.4e6 (single 256-step Montgomery-style ladder).  Peak comes
+from :data:`DEVICE_PEAK_OPS`, keyed by the ``device_kind`` JAX
+reports; a device that is not in the table (the CPU backend, an
+unlisted chip) reports NO MFU rather than one against another
+device's peak.
 
 Program catalog (lockstep with the ``devicelaunch`` checker: every
 row below must be ``register_program()``-ed by a launch module, and
@@ -105,9 +107,11 @@ POW_FLOPS_PER_HASH = 21152.0
 SECP_VERIFY_FLOPS = 3.6e6
 #: one 256-step scalar-mult ladder (ECDH / fixed-base)
 SECP_ECDH_FLOPS = 2.4e6
-#: v5e VPU peak u32 issue rate per chip (8x128 lanes x 4 ALUs x
-#: ~1.5 GHz) — the documented denominator of every MFU figure
-DEVICE_PEAK_OPS = 6.1e12
+#: VPU peak u32 issue rate per chip — the denominator of every MFU
+#: figure — keyed by ``jax.devices()[0].device_kind``.  Source of the
+#: one row: an ESTIMATE, 8x128 lanes x 4 ALUs x ~1.5 GHz (BASELINE.md
+#: "Arithmetic utilization"), not a published figure.
+DEVICE_PEAK_OPS: dict[str, float] = {"TPU v5 lite": 6.1e12}
 
 #: bound on remembered (program, static-key) compile-cache entries —
 #: a runaway dynamic key degrades to counting everything as a compile
@@ -318,10 +322,16 @@ class DeviceTelemetry:
                 flops = self._programs.get(program, {}).get(
                     "flops_per_item")
             HASHRATE.labels(program=program).set(rate)
-            if flops:
+            peak = device_peak_ops()
+            if flops and peak:
                 MFU.labels(program=program).set(
-                    min(rate * flops / (DEVICE_PEAK_OPS * devices),
-                        1.0))
+                    min(rate * flops / (peak * devices), 1.0))
+
+    def compiled_keys(self) -> list[tuple]:
+        """Every (program, static-shape key) launched so far — one
+        entry per expected compile (chip_smoke.py prints the table)."""
+        with self._lock:
+            return sorted(self._seen_keys, key=repr)
 
     def reset(self) -> None:
         """Drop compile-cache/busy state (tests; counters stay
@@ -355,6 +365,15 @@ def _live_jax():
     """The jax module IF some subsystem already imported it — this
     plane must never be the reason a backend initializes."""
     return sys.modules.get("jax")
+
+
+def device_peak_ops() -> float | None:
+    """Peak u32 ops/s of the live device's kind, or None when JAX is
+    not loaded or the kind has no row in :data:`DEVICE_PEAK_OPS`."""
+    jax = _live_jax()
+    if jax is None:
+        return None
+    return DEVICE_PEAK_OPS.get(str(jax.devices()[0].device_kind))
 
 
 def update_device_gauges() -> list[dict]:

@@ -46,7 +46,7 @@ Overhead is self-measured (``profile_sampler_overhead_ratio``): the
 walk costs tens of microseconds per tick, so the default rate stays
 far below the <2% budget ``make profile-smoke`` asserts.
 
-See docs/observability.md ("Continuous profiling") for the taxonomy,
+See docs/observability.md ("Continuous profiling") for the thread classes,
 the dump formats, and the fleet-merge workflow
 (``tools/profile_merge.py``).
 """

@@ -139,6 +139,21 @@ def _carry2(d: jnp.ndarray) -> jnp.ndarray:
     return d
 
 
+def _place(x: jnp.ndarray, start: int, rows: int) -> jnp.ndarray:
+    """``x`` at rows [start, start + len(x)) of a zero ``rows``-row
+    stack.  Limb stacks are only ever shifted by concatenation on the
+    leading axis: Mosaic has no scatter, and leading-axis concatenation
+    of whole (8, 128) tiles moves no data."""
+    parts = []
+    if start:
+        parts.append(jnp.zeros((start,) + x.shape[1:], dtype=U32))
+    parts.append(x)
+    tail = rows - start - x.shape[0]
+    if tail:
+        parts.append(jnp.zeros((tail,) + x.shape[1:], dtype=U32))
+    return jnp.concatenate(parts) if len(parts) > 1 else x
+
+
 def f_reduce(d: jnp.ndarray) -> jnp.ndarray:
     """Arbitrary limb stack (rows <= 2*LIMBS, limbs < 2^31) -> R*."""
     d = _carry2(d)
@@ -146,11 +161,9 @@ def f_reduce(d: jnp.ndarray) -> jnp.ndarray:
         for _ in range(2):
             # fold rows >= 20 down: h*L^(20+k) == h*(FOLD0 + FOLD2*L^2)*L^k
             hi = d[LIMBS:]
-            r = jnp.concatenate(
-                [d[:LIMBS],
-                 jnp.zeros((2,) + d.shape[1:], dtype=U32)])
-            r = r.at[:hi.shape[0]].add(hi * FOLD0)
-            r = r.at[2:2 + hi.shape[0]].add(hi * FOLD2)
+            r = (_place(d[:LIMBS], 0, LIMBS + 2)
+                 + _place(hi * FOLD0, 0, LIMBS + 2)
+                 + _place(hi * FOLD2, 2, LIMBS + 2))
             d = _carry2(r)
         # two passes leave value < 2^260 + 2^66: rows > 20 are
         # structurally zero (a nonzero row 21 implies >= 2^273)
@@ -159,17 +172,17 @@ def f_reduce(d: jnp.ndarray) -> jnp.ndarray:
         d = d[:21]
     # fold bits >= 2^256 (rows 19..20): t = value div 2^256 bits
     t = (d[20] << 4) + (d[19] >> 9)
-    r = d.at[19].set(d[19] & 511)[:LIMBS]
-    r = r.at[0].add(t * TOP0)
-    r = r.at[2].add(t * TOP2)
+    r = jnp.concatenate([(d[0] + t * TOP0)[None], d[1:2],
+                         (d[2] + t * TOP2)[None], d[3:19],
+                         (d[19] & 511)[None]])
     return _carry2(r)[:LIMBS]
 
 
 def f_mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Schoolbook 20x20 with u32-native partial products (R* inputs)."""
-    d = jnp.zeros((2 * LIMBS - 1,) + a.shape[1:], dtype=U32)
-    for i in range(LIMBS):
-        d = d.at[i:i + LIMBS].add(a[i] * b)
+    d = _place(a[0] * b, 0, 2 * LIMBS - 1)
+    for i in range(1, LIMBS):
+        d = d + _place(a[i] * b, i, 2 * LIMBS - 1)
     return f_reduce(d)
 
 
@@ -236,16 +249,16 @@ def f_inv(a: jnp.ndarray, *, unrolled: bool = False) -> jnp.ndarray:
     Two spellings of the same exponentiation: the default ROLLED
     square-and-multiply (``fori_loop`` over a constant bits array —
     an unrolled chain measured 90 s of XLA compile per lane bucket)
-    for the XLA path, and the UNROLLED standard secp256k1 addition
-    chain (258 squarings + 14 multiplies, no captured constant array)
-    for Pallas kernel bodies, which may not close over array
-    constants and pay per-op dispatch in interpret mode.
+    for the XLA path, and the standard secp256k1 addition chain (258
+    squarings in ``fori_loop`` runs + 14 inline multiplies, no captured
+    constant array) for Pallas kernel bodies, which may not close over
+    array constants.
     """
     if unrolled:
         def sqn(x, n):
-            for _ in range(n):
-                x = f_sqr(x)
-            return x
+            # rolled: 258 unrolled squarings were most of the kernel's
+            # Mosaic compile time and code size
+            return jax.lax.fori_loop(0, n, lambda _, v: f_sqr(v), x)
 
         x2 = f_mul(f_sqr(a), a)
         x3 = f_mul(f_sqr(x2), a)
@@ -279,11 +292,17 @@ def f_inv(a: jnp.ndarray, *, unrolled: bool = False) -> jnp.ndarray:
 # to (x, y, 1, False).  No on-curve point has Y == 0 (odd prime group
 # order), so doubling needs no special case beyond infinity.
 
+def _bool_where(mask, a, b):
+    """``jnp.where`` over lane BOOLS as plain logic: Mosaic has no
+    select between i1 vectors."""
+    return (mask & a) | (~mask & b)
+
+
 def _pt_where(mask, a, b):
     """Lane-select between two (X, Y, Z, inf) points."""
     m = mask[None]
     return (jnp.where(m, a[0], b[0]), jnp.where(m, a[1], b[1]),
-            jnp.where(m, a[2], b[2]), jnp.where(mask, a[3], b[3]))
+            jnp.where(m, a[2], b[2]), _bool_where(mask, a[3], b[3]))
 
 
 def jac_double(pt):
@@ -344,14 +363,32 @@ def jac_to_affine(pt, *, unrolled_inv: bool = False):
 
 def _scalar_bit(words: jnp.ndarray, i) -> jnp.ndarray:
     """Bit ``i`` (0 = MSB) of each lane's 256-bit scalar, given as a
-    (8, *lanes) stack of big-endian u32 words.  ``i`` may be traced."""
-    w = jax.lax.dynamic_index_in_dim(words, i >> 5, axis=0,
-                                     keepdims=False)
+    (8, *lanes) stack of big-endian u32 words.  ``i`` may be traced:
+    the word is a select over all eight (Mosaic cannot index a value
+    by a loop counter)."""
+    idx = i >> 5
+    w = words[0]
+    for j in range(1, 8):
+        w = jnp.where(idx == j, words[j], w)
     sh = (31 - (i & 31)).astype(U32)
     return (w >> sh) & 1
 
 
 # --- ladders -----------------------------------------------------------------
+
+def _ladder_loop(nbits: int, step, lane_shape):
+    """``fori_loop`` of ``step(k, point) -> point`` from infinity.  The
+    infinity flag rides the loop as u32: Mosaic cannot carry an i1
+    vector through ``scf.for``."""
+    def body(k, carry):
+        x, y, z, inf = step(k, carry[:3] + (carry[3] != 0,))
+        return (x, y, z, inf.astype(U32))
+
+    x, y, z, inf = jac_infinity(lane_shape)
+    x, y, z, inf = jax.lax.fori_loop(0, nbits, body,
+                                     (x, y, z, inf.astype(U32)))
+    return (x, y, z, inf != 0)
+
 
 def shamir_ladder(u1w, u2w, q, nbits: int = 256,
                   unrolled_inv: bool = False):
@@ -380,10 +417,10 @@ def shamir_ladder(u1w, u2w, q, nbits: int = 256,
                        jnp.where(b2[None] == 1, gq_x, gx), qx)
         ay = jnp.where(b1[None] == 1,
                        jnp.where(b2[None] == 1, gq_y, gy), qy)
-        a_inf = jnp.where(b1 == 1, (b2 == 1) & gq_inf, b2 == 0)
+        a_inf = _bool_where(b1 == 1, (b2 == 1) & gq_inf, b2 == 0)
         return jac_add(acc, (ax, ay, one, a_inf))
 
-    return jax.lax.fori_loop(0, nbits, body, jac_infinity(lane_shape))
+    return _ladder_loop(nbits, body, lane_shape)
 
 
 def point_ladder(kw, p, p_inf=None, nbits: int = 256):
@@ -400,7 +437,7 @@ def point_ladder(kw, p, p_inf=None, nbits: int = 256):
         bit = _scalar_bit(kw, i)
         return jac_add(acc, (px, py, one, p_inf | (bit == 0)))
 
-    return jax.lax.fori_loop(0, nbits, body, jac_infinity(lane_shape))
+    return _ladder_loop(nbits, body, lane_shape)
 
 
 # --- core drain programs (shared by the XLA and Pallas paths) ---------------

@@ -19,7 +19,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from ._compat import shard_map
+from jax import shard_map
 
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              record_launch,

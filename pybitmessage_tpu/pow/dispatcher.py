@@ -40,8 +40,12 @@ from .native import NativeSolver
 
 logger = logging.getLogger("pybitmessage_tpu.pow")
 
-#: slab-stall deadline handed to the pipeline (seconds per harvest,
-#: generous enough for a cold Mosaic compile); 0 disables the watchdog
+#: slab-stall deadline handed to the pipeline: seconds ONE harvest (the
+#: blocking device->host fetch of a dispatched slab) may take; 0
+#: disables the watchdog.  A cold Mosaic compile (minutes) is not inside
+#: it: jit compiles synchronously in the dispatch call, on the solving
+#: thread, before the guarded fetch starts — chip_smoke.py's compile
+#: table shows it as first-launch DISPATCH seconds.
 DEFAULT_STALL_TIMEOUT = 120.0
 
 SOLVE_SECONDS = REGISTRY.histogram(
@@ -174,13 +178,26 @@ class PowDispatcher:
     # -- device topology -----------------------------------------------------
 
     def _device_count(self) -> int:
+        """Raises when JAX cannot initialise: callers probe inside a
+        tier handler, so that counts as a device-tier failure."""
+        import jax
+        return len(jax.devices())
+
+    def _batch_topology(self):
+        """``(device count, on accelerator)`` for the batch paths.  A
+        failed probe is counted and logged as a failure of the ``tpu``
+        tier (never read in silence as "no accelerator") and answers
+        ``(0, False)``, which no device branch below takes."""
         try:
-            import jax
-            return len(jax.devices())
-        except Exception as exc:
-            ERRORS.labels(site="pow.device_probe").inc()
-            logger.debug("device probe failed: %r", exc)
-            return 0
+            return self._device_count(), self._on_accelerator()
+        except Exception:
+            self.breakers["tpu"].record_failure()
+            ERRORS.labels(site="pow.tier.tpu").inc()
+            logger.exception(
+                "JAX device probe failed; batch goes to the per-object "
+                "ladder")
+            _note_fallback("tpu", "ladder")
+            return 0, False
 
     def _record_recovery(self) -> None:
         """A solve completed after a slab stall: export how long the
@@ -327,10 +344,12 @@ class PowDispatcher:
             # the farm rung leads the ladder; a farm failure falls
             # through to the local tiers below with nothing lost
             results = self._try_farm(items, should_stop, starts)
-            if results is None and self._tpu_enabled and len(items) > 1:
-                ndev = self._device_count()
+            ndev, on_accel = (
+                self._batch_topology()
+                if results is None and self._tpu_enabled else (0, False))
+            if len(items) > 1:
                 if ndev > 1:
-                    if self._on_accelerator() and pb.allow():
+                    if on_accel and pb.allow():
                         try:
                             inject("pow.device_launch")
                             from ..parallel import pallas_sharded_solve_batch
@@ -372,7 +391,7 @@ class PowDispatcher:
                                 "batched TPU PoW failed; falling back to "
                                 "per-object solves")
                             _note_fallback("tpu-batch", "ladder")
-                elif self._on_accelerator() and pb.allow():
+                elif on_accel and pb.allow():
                     # single chip: the async double-buffered pipeline
                     # plans the launch shape (multi-object slab packing
                     # for storms, the per-object (objects x chunks)
@@ -400,9 +419,8 @@ class PowDispatcher:
                             "batched Pallas PoW failed; falling back to "
                             "per-object solves")
                         self._pallas_failed(exc, "ladder")
-            if (results is None and len(items) == 1 and self._tpu_enabled
-                    and self._on_accelerator()
-                    and self._device_count() <= 1 and pb.allow()):
+            if (results is None and len(items) == 1 and on_accel
+                    and ndev <= 1 and pb.allow()):
                 # degenerate case: ONE object.  If it is tiny (expected
                 # to finish inside the first small launch) the pipeline
                 # takes its latency-optimal synchronous path instead of
@@ -453,11 +471,8 @@ class PowDispatcher:
         return results
 
     def _on_accelerator(self) -> bool:
-        try:
-            import jax
-            return jax.default_backend() != "cpu"
-        except Exception:
-            return False
+        import jax
+        return jax.default_backend() != "cpu"
 
     def _xla_kwargs(self) -> dict:
         """Slab sizing for the XLA tier: the TPU sweet spot (2^19 x 64)

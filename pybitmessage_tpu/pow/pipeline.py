@@ -112,7 +112,7 @@ class SlabAutotuner:
     ``target_seconds`` — the hit-poll / shutdown-poll granularity.
     Power-of-two quantization bounds the number of distinct compiled
     shapes; the EWMA plus a 10x outlier clamp make one slow
-    observation (a fresh jit compile, a relay stall) decay instead of
+    observation (a fresh jit compile, a host stall) decay instead of
     permanently shrinking slabs.  Thread-safe: the dispatcher's
     executor and the asyncio service may solve concurrently.
     """
@@ -139,7 +139,7 @@ class SlabAutotuner:
         with self._lock:
             prev = self._per_chunk.get(kind)
             if prev is not None and per > 10 * prev:
-                # compile / relay-stall outlier: cap its influence so
+                # compile / stall outlier: cap its influence so
                 # one bad slab cannot crater the suggestion
                 per = 10 * prev
             self._per_chunk[kind] = per if prev is None else (
@@ -179,11 +179,9 @@ AUTOTUNER = SlabAutotuner()
 
 
 def default_impl() -> str:
-    """"pallas" on an accelerator backend, "xla" on host CPU."""
-    try:
-        return "pallas" if jax.default_backend() != "cpu" else "xla"
-    except Exception:  # pragma: no cover - backend probe failure
-        return "xla"
+    """"pallas" on an accelerator backend, "xla" on host CPU.  A JAX
+    that fails to initialise raises into the caller's tier handler."""
+    return "pallas" if jax.default_backend() != "cpu" else "xla"
 
 
 def expected_trials(target: int) -> float:

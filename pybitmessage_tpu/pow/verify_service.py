@@ -49,16 +49,10 @@ def _accelerator_backend() -> bool:
     CPU backend the XLA 'device' batch pays ~100 ms of dispatch per
     drain while two host SHA-512s cost ~2 µs — routing batches to the
     device there CAPPED the whole ingest path at ~25 obj/s (measured,
-    ISSUE 14).  Mirrors the ``cryptotpu=auto`` probe semantics."""
-    try:
-        import jax
-        return jax.default_backend() != "cpu"
-    except Exception:  # pragma: no cover — jax absent/broken
-        from ..resilience.policy import ERRORS
-        ERRORS.labels(site="pow.verify_probe").inc()
-        logger.info("JAX backend probe failed; PoW verification stays "
-                    "on the host path", exc_info=True)
-        return False
+    ISSUE 14).  Mirrors the ``cryptotpu=auto`` probe semantics.
+    Raises when JAX cannot initialise — the caller counts that."""
+    import jax
+    return jax.default_backend() != "cpu"
 
 
 class BatchVerifier:
@@ -99,7 +93,17 @@ class BatchVerifier:
             import threading
 
             def probe() -> None:
-                self._device_ok = _accelerator_backend()
+                try:
+                    self._device_ok = _accelerator_backend()
+                except Exception:
+                    # a broken JAX is a counted failure, not "no
+                    # accelerator here"
+                    from ..resilience.policy import ERRORS
+                    ERRORS.labels(site="pow.verify_probe").inc()
+                    logger.exception(
+                        "JAX backend probe failed; PoW verification "
+                        "stays on the host path")
+                    self._device_ok = False
             threading.Thread(target=probe, daemon=True,
                              name="bmtpu-pow-verify-probe").start()
         self._task = asyncio.create_task(self._run())

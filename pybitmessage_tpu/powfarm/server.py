@@ -195,13 +195,16 @@ class FarmServer:
                 pass
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
+        # writers first: on Python 3.12 wait_closed() waits for every
+        # accepted connection, so a connected tenant would block it
         for writer in list(self._writers.values()):
             try:
                 writer.close()
             except Exception as exc:
                 logger.debug("farm writer close failed: %r", exc)
         self._writers.clear()
+        if self._server is not None:
+            await self._server.wait_closed()
         self._solve_exec.shutdown(wait=False)
         CONNECTIONS.set(0)
 

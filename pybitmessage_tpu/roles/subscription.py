@@ -713,10 +713,14 @@ class ClientPlane:
                                  return_exceptions=True)
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
+        # sessions first: on Python 3.12 wait_closed() waits for every
+        # accepted connection, so a connected client would block it
         for session in list(self.sessions.values()):
             await session.stop_writer()
+            session.writer.close()
         self.sessions.clear()
+        if self._server is not None:
+            await self._server.wait_closed()
         SESSIONS.set(0)
         for farm in self._farms.values():
             farm.close()

@@ -111,3 +111,53 @@ def test_solve_only_timing_recorded_separately():
     assert d.last_solve_seconds > 0
     assert d.last_verify_seconds >= 0
     assert d.last_solve_rate >= d.last_rate > 0
+
+
+def _errors(site):
+    from pybitmessage_tpu.observability import REGISTRY
+    return REGISTRY.sample("resilience_errors_total", {"site": site})
+
+
+@pytest.mark.parametrize("n_items", [1, 3])
+def test_failed_jax_probe_is_a_counted_tier_failure(monkeypatch, n_items):
+    """A JAX that cannot initialise is not read as "no accelerator":
+    the probe raises into the tpu tier's handler (counted, logged,
+    breaker opened) and the ladder's documented fall to C++ follows."""
+    d = PowDispatcher()
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(d, "_device_count", broken)
+    before = _errors("pow.tier.tpu")
+    items = [(hashlib.sha512(b"probe %d" % i).digest(), EASY)
+             for i in range(n_items)]
+    results = d.solve_batch(items)
+    for (ih, target), (nonce, _) in zip(items, results):
+        assert _host_trial(nonce, ih) <= target
+    assert d.last_backend == "cpp"
+    assert _errors("pow.tier.tpu") == before + 1
+    assert d.breakers["tpu"].state == "open"
+
+
+@pytest.mark.asyncio
+async def test_failed_verify_probe_is_counted_and_stays_on_the_host(
+        monkeypatch):
+    import asyncio
+
+    from pybitmessage_tpu.pow import verify_service
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(verify_service, "_accelerator_backend", broken)
+    before = _errors("pow.verify_probe")
+    verifier = verify_service.BatchVerifier()
+    verifier.start()
+    try:
+        for _ in range(100):
+            if verifier._device_ok is not None:
+                break
+            await asyncio.sleep(0.02)
+        assert verifier._device_ok is False
+        assert _errors("pow.verify_probe") == before + 1
+    finally:
+        await verifier.stop()

@@ -126,3 +126,39 @@ def test_pallas_sharded_single_reports_progress():
     assert nonce >= 512
     assert all(nxt > 512 for nxt in seen)
     assert seen == sorted(seen)
+
+
+def test_pallas_sharded_batch_spreads_a_small_batch_over_the_obj_axis(
+        monkeypatch):
+    """A batch smaller than the pod's capacity is dealt round-robin over
+    the obj-axis devices' slot blocks — not packed into the first
+    device's block with pads on the others (ISSUE 22: what the code did
+    while it had only met virtual devices)."""
+    import numpy as np
+
+    from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
+    monkeypatch.setattr(pod, "POD_BATCH_PER_DEVICE", 2)
+    seen = []
+    real_get_fn = pod._get_fn
+
+    def recording_get_fn(*args):
+        fn = real_get_fn(*args)
+
+        def call(ih_words, bases, targets):
+            seen.append(np.asarray(targets))
+            return fn(ih_words, bases, targets)
+        return call
+    monkeypatch.setattr(pod, "_get_fn", recording_get_fn)
+
+    mesh = make_mesh(4, obj_axis="obj", obj_size=4)
+    items = [(hashlib.sha512(b"spread %d" % i).digest(), 1 << 58)
+             for i in range(5)]
+    results = pallas_sharded_solve_batch(items, mesh, rows=1,
+                                         chunks_per_call=8, impl="xla")
+    for (ih, target), (nonce, _) in zip(items, results):
+        assert _host_trial(nonce, ih) <= target
+    live = [slot for slot, t in enumerate(seen[0])
+            if tuple(t) != (0xFFFFFFFF, 0xFFFFFFFF)]
+    # 4 devices x 2 slots: items 0-3 open one block each, item 4 joins
+    # device 0's block
+    assert live == [0, 1, 2, 4, 6]

@@ -7,24 +7,25 @@ device kind, count, topology), then compile-probes every program in
 the device-telemetry catalog with a 1-lane / always-hit-target shape —
 the cheapest input that still walks each kernel through trace +
 compile + one launch + readback on the live backend.  A probe failure
-is matched against a table of known failure signatures (starting with
-the MULTICHIP_r01 ``convert_element_type`` tail: a libtpu version
-mismatch between client and terminal) and turned into a NAMED
-diagnosis with a remediation hint instead of a 40-frame traceback.
+is matched against a table of known failure signatures (no TPU, a
+chip another process holds, out of memory, a rendezvous deadline) and
+turned into a NAMED diagnosis with a remediation hint instead of a
+40-frame traceback.
 
 Exit status: 0 when every probe passes, 1 otherwise — the multi-chip
 driver (ROADMAP item 3) gates the expensive pod rendezvous on it.
 Output is one JSON report on stdout (humans and CI both parse it).
 
 ``--diagnose FILE`` skips the live probes and instead classifies a
-recorded failure tail — either a ``MULTICHIP_r*.json`` document (its
-``tail`` field) or a raw text log.  A recognized signature prints the
+recorded failure tail — either a JSON document with a ``tail`` field
+or a raw text log.  A recognized signature prints the
 diagnosis and exits 1; an unrecognized tail exits 0 with
 ``diagnosis: null`` (nothing actionable to report).
 
 Probes run with ``interpret=True`` Pallas on non-TPU backends, so the
 doctor is CI-runnable on the CPU mesh — the same parity contract the
-rest of the test suite uses.
+rest of the test suite uses.  The report says so at top level
+(``"interpret": true``): such a run proves the plumbing, not the chip.
 """
 
 from __future__ import annotations
@@ -43,27 +44,20 @@ if _ROOT not in sys.path:
     sys.path.insert(0, _ROOT)
 
 #: known failure signatures, checked in order: (regex over the failure
-#: text, diagnosis name, remediation hint).  The first entry is the
-#: recorded MULTICHIP_r01 tail — a pod job that died in
-#: ``_convert_element_type_bind_with_trace`` with FAILED_PRECONDITION
-#: because client and terminal ran different libtpu builds.
+#: text, diagnosis name, remediation hint).
 SIGNATURES: list[tuple[str, str, str]] = [
-    (r"libtpu version mismatch",
-     "libtpu-version-mismatch",
-     "client and terminal run different libtpu builds (different "
-     "monorepo commits or a rolling upgrade mid-flight); re-sync the "
-     "environments so jax/jaxlib/libtpu versions match on every host, "
-     "then re-run `make doctor` on each"),
     (r"Unable to initialize backend '?tpu'?|No visible TPU|"
      r"failed to open libtpu|libtpu\.so.*(not found|no such file)",
      "no-tpu-found",
      "no TPU runtime is reachable: check the host actually has "
      "accelerators attached and libtpu is installed; on CPU hosts run "
      "with JAX_PLATFORMS=cpu instead"),
-    (r"already in use|libtpu.*in use|Device or resource busy",
+    (r"already in use|libtpu.*in use|Device or resource busy|"
+     r"libtpu multi-process lockfile",
      "tpu-device-busy",
-     "another process holds the TPU (libtpu is single-tenant): stop "
-     "the other client or point this one at a free chip"),
+     "another process holds the TPU (one process per chip): stop the "
+     "other client, or start this one with JAX_PLATFORMS=cpu; do not "
+     "remove libtpu's lock file"),
     (r"RESOURCE_EXHAUSTED|out of memory|OOM",
      "device-out-of-memory",
      "the probe shape exceeded device memory: another tenant may be "
@@ -242,9 +236,12 @@ def run_preflight(only=None, skip_probes: bool = False) -> dict:
     from pybitmessage_tpu.observability.devicetelemetry import \
         DEVICE_TELEMETRY
 
+    from pybitmessage_tpu.core.jaxsetup import setup_jax
+    setup_jax()
     report: dict = {"env": env_fingerprint()}
     try:
         import jax
+        report["interpret"] = _interpret()
         report["devices"] = _device_table()
         report["topology"] = {
             "deviceCount": jax.device_count(),
@@ -294,7 +291,7 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--diagnose", metavar="FILE",
                     help="classify a recorded failure tail "
-                         "(MULTICHIP_r*.json or raw text) instead of "
+                         "(JSON with a 'tail' field, or raw text) instead of "
                          "running live probes")
     ap.add_argument("--only", action="append", default=None,
                     help="probe only this program (repeatable)")
