@@ -32,9 +32,8 @@ import sys
 import time
 from contextlib import contextmanager
 
-from pybitmessage_tpu.observability import (REGISTRY, enable_jax_annotations,
-                                            env_fingerprint, snapshot,
-                                            trace)
+from pybitmessage_tpu.observability import (REGISTRY, env_fingerprint,
+                                            snapshot, trace)
 
 LANES = 1 << 19
 CHUNKS = 64
@@ -232,15 +231,11 @@ def _measure_mfu(initial_hash: bytes) -> dict:
         np.asarray(found)
     launch(0)                                      # already-warm no-op
     tmp = tempfile.mkdtemp(prefix="bm_mfu_trace_")
-    # mirror spans into TraceAnnotations while the profiler runs so
-    # slab launches are named in the XLA trace
-    enable_jax_annotations(True)
     try:
         with jax.profiler.trace(tmp):
             for i in range(3):
-                # the span mirrors into a TraceAnnotation (bridge
-                # enabled above) so the slab launch is named in the
-                # XLA trace
+                # the span mirrors into a TraceAnnotation, so the slab
+                # launch is named in the XLA trace
                 with trace("bench.slab", slab=i):
                     launch((i + 7) * trials)
         latest = max(glob.glob(tmp + "/plugins/profile/*"))
@@ -248,7 +243,6 @@ def _measure_mfu(initial_hash: bytes) -> dict:
         with gzip.open(trace_file) as f:
             trace_json = json.load(f)
     finally:
-        enable_jax_annotations(False)
         import shutil
         shutil.rmtree(tmp, ignore_errors=True)
     events = trace_json["traceEvents"]

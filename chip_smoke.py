@@ -57,8 +57,8 @@ TTL = 4 * 24 * 3600
 NETWORK_NTPB = NETWORK_EXTRA = 1000
 SEED = 22
 
-#: most first launches each Mosaic program may show: one per shape it
-#: was meant to have (network difficulty never plans a packed launch)
+#: most shapes each Mosaic program may compile or load: one per shape
+#: it was meant to have (network difficulty never plans a packed launch)
 EXPECTED_COMPILES = {"pallas_slab": 1, "batch_search": 1,
                      "packed_search": 0, "secp_verify": 1, "secp_ecdh": 1}
 
@@ -442,30 +442,33 @@ def check_no_hidden_fallback(rep: Report, pair) -> None:
         rep.check(all(programs.get(p, {}).get("launches", 0) > 0
                       for p in ("secp_verify", "secp_ecdh")),
                   "secp_verify/secp_ecdh launched on the device")
-    interp = [(p, k) for p, k in DEVICE_TELEMETRY.compiled_keys()
+    interp = [(p, k) for p, k in DEVICE_TELEMETRY.launched_keys()
               if p in _INTERPRET_AT and k[_INTERPRET_AT[p]]]
     rep.check(not interp, "no program ran with interpret=True %s"
               % (interp or ""))
     for prog, most in EXPECTED_COMPILES.items():
-        seen = programs.get(prog, {}).get("compiles", 0)
+        row = programs.get(prog, {})
+        seen = row.get("compiles", 0) + row.get("cacheHits", 0)
         rep.check(seen <= most,
                   "%s compiled once per shape it was meant to have "
-                  "(%d first launches, at most %d)" % (prog, seen, most))
+                  "(%d compiled or loaded from the cache, at most %d)"
+                  % (prog, seen, most))
 
 
 def print_compile_table(rep: Report, cache_events: dict) -> None:
     from pybitmessage_tpu.observability.devicetelemetry import (
         DEVICE_TELEMETRY, device_status)
-    rep.say("program            launches  first-launches  "
-            "first-launch-s  busy-s")
+    rep.say("program            launches  compiles  cache-hits  "
+            "compile-s  busy-s")
     for name, row in device_status()["programs"].items():
         if row["launches"]:
-            rep.say("%-18s %8d  %14d  %14.1f  %6.1f"
+            rep.say("%-18s %8d  %8d  %10d  %9.1f  %6.1f"
                     % (name, row["launches"], row["compiles"],
-                       row["compileSeconds"], row["busySeconds"]))
-    rep.say("(first-launch seconds = trace + compile, or the load from "
-            "the persistent cache)")
-    for prog, key in DEVICE_TELEMETRY.compiled_keys():
+                       row["cacheHits"], row["compileSeconds"],
+                       row["busySeconds"]))
+    rep.say("(compile seconds = trace + lowering + backend compile, or "
+            "the load from the persistent cache; from JAX's events)")
+    for prog, key in DEVICE_TELEMETRY.launched_keys():
         rep.say("  shape key: %s %r" % (prog, key))
     rep.say("persistent compile cache: %s"
             % (dict(sorted(cache_events.items())) or "no events"))

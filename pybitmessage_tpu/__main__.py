@@ -456,14 +456,15 @@ def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     _setup_logging(args)
     logging.getLogger("jax").setLevel(logging.INFO)
-    from .core.jaxsetup import setup_jax
-    setup_jax()
     from .core.appenv import (SingleInstance, SingleInstanceError,
                               appdata_dir, daemonize)
     if args.appdata and not args.data_dir:
         args.data_dir = str(appdata_dir())
     if args.daemon:  # pragma: no cover - forks away from test runners
         daemonize()
+    # after the fork: setup_jax initialises the backend
+    from .core.jaxsetup import setup_jax
+    setup_jax()
     lock = None
     if args.data_dir:
         lock = SingleInstance(args.data_dir)

@@ -10,24 +10,58 @@ Placement rule: when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
 it itself and nothing is set in code; otherwise the cache lives at the
 fixed path ``<checkout>/.jax_cache`` (git-ignored).  The path is part
 of the cache key, so it is never a temp name, a pid or a time.
+
+It is also where the program first touches the backend, so that the
+wall of that initialisation is the program's own number
+(``jax_backend_init_seconds``), and where JAX's compile events are
+wired to the registry (``jax_compile_*``, docs/observability.md).
 """
 
 from __future__ import annotations
 
+import logging
 import os
+import time
 from pathlib import Path
+
+logger = logging.getLogger("pybitmessage_tpu.core")
 
 CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
 #: <checkout>/.jax_cache — two levels above this package
 DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 
+_backend_timed = False
+
 
 def setup_jax() -> str:
-    """Place the persistent compile cache; returns the directory in
-    effect.  Imports JAX but initializes no backend."""
-    cache_dir = os.environ.get(CACHE_ENV)
-    if cache_dir:
-        return cache_dir
+    """Place the persistent compile cache, listen to JAX's compile
+    events and initialise the backend; returns the cache directory in
+    effect.  Safe to call again: the listener is installed and the
+    backend timed once a process.  A backend that fails to initialise
+    is logged and left to the caller's first device use, which meets
+    the same error where it is handled."""
     import jax
-    jax.config.update("jax_compilation_cache_dir", str(DEFAULT_CACHE_DIR))
-    return str(DEFAULT_CACHE_DIR)
+
+    from ..observability.devicetelemetry import install_compile_listener
+    install_compile_listener()
+    cache_dir = os.environ.get(CACHE_ENV)
+    if not cache_dir:
+        cache_dir = str(DEFAULT_CACHE_DIR)
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    _time_backend_init(jax)
+    return cache_dir
+
+
+def _time_backend_init(jax) -> None:
+    global _backend_timed
+    if _backend_timed:
+        return
+    _backend_timed = True
+    from ..observability.devicetelemetry import BACKEND_INIT_SECONDS
+    t0 = time.monotonic()
+    try:
+        jax.devices()
+    except Exception:
+        logger.exception("JAX backend failed to initialise")
+        return
+    BACKEND_INIT_SECONDS.set(time.monotonic() - t0)

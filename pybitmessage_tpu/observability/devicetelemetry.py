@@ -8,12 +8,16 @@ that every launch site in ops/, parallel/, crypto/ and pow/ routes
 through (the bmlint ``devicelaunch`` checker enforces the routing).
 Per named program it attributes:
 
-- **compiles vs cache hits** — the first launch of a (program,
-  static-shape key) traces + compiles synchronously inside the
-  dispatch call, so its dispatch wall clock IS the compile time;
-  subsequent same-key launches are cache hits.  The split makes a
-  recompile storm (an unstable static argument) visible as a counter
-  instead of a mystery slowdown.
+- **compiles vs persistent-cache hits** — from JAX's own monitoring
+  events (:func:`install_compile_listener`, installed by
+  ``core/jaxsetup.setup_jax``), never guessed from launches: every
+  trace, lowering and backend compile is counted and timed by phase
+  (``jax_compile_events_total`` / ``jax_compile_seconds_total``), and
+  where an event's ``fun_name`` is a cataloged program's jitted
+  function it also feeds that program's compile count, compile
+  seconds and persistent-cache hits.  A recompile storm (an unstable
+  static argument) or a shape first wanted mid-run is a counter that
+  moves, with the seconds it cost.
 - **dispatch vs execute wait** — host seconds spent issuing the
   launch vs blocking on the device->host fetch
   (``block_until_ready``/``np.asarray`` bracketing).
@@ -113,10 +117,8 @@ SECP_ECDH_FLOPS = 2.4e6
 #: "Arithmetic utilization"), not a published figure.
 DEVICE_PEAK_OPS: dict[str, float] = {"TPU v5 lite": 6.1e12}
 
-#: bound on remembered (program, static-key) compile-cache entries —
-#: a runaway dynamic key degrades to counting everything as a compile
-#: rather than growing without bound
-MAX_COMPILE_KEYS = 4096
+#: bound on remembered (program, static-key) launch shapes
+MAX_LAUNCH_KEYS = 4096
 #: EWMA smoothing for the derived hashrate gauge
 RATE_ALPHA = 0.3
 
@@ -134,16 +136,36 @@ def _device_label(index: int) -> str:
 
 COMPILES = REGISTRY.counter(
     "device_program_compiles_total",
-    "First-call traces+compiles per named device program (a new "
-    "(program, static-shape key) pairing)", ("program",))
+    "Backend compiles of a named device program that the persistent "
+    "cache did not serve (JAX's backend_compile event)", ("program",))
 CACHE_HITS = REGISTRY.counter(
     "device_program_cache_hits_total",
-    "Launches that reused an already-compiled executable",
+    "Backend compiles of a named device program served from the "
+    "persistent compile cache (still traced and lowered)",
     ("program",))
 COMPILE_SECONDS = REGISTRY.histogram(
     "device_program_compile_seconds",
-    "Dispatch wall seconds of first-key launches (trace+compile "
-    "happens synchronously inside that dispatch)", ("program",))
+    "Trace + lowering + backend-compile (or cache load) seconds of "
+    "one new shape of a named device program, from JAX's events",
+    ("program",))
+JAX_COMPILE_EVENTS = REGISTRY.counter(
+    "jax_compile_events_total",
+    "JAX compile-pipeline events of the whole process by phase "
+    "(trace | lower | backend_compile); one lower per program shape, "
+    "cached executable or not", ("phase",))
+JAX_COMPILE_SECONDS = REGISTRY.counter(
+    "jax_compile_seconds_total",
+    "Seconds JAX spent in each compile-pipeline phase, less the events "
+    "nested inside one (a traced program's callees): the phases add up "
+    "to the wall spent compiling", ("phase",))
+JAX_COMPILE_CACHE = REGISTRY.counter(
+    "jax_compile_cache_total",
+    "Persistent compile cache lookups by result (hit | miss)",
+    ("result",))
+BACKEND_INIT_SECONDS = REGISTRY.gauge(
+    "jax_backend_init_seconds",
+    "Wall seconds of the process's first JAX backend initialisation "
+    "(core/jaxsetup.setup_jax's device enumeration)")
 LAUNCHES = REGISTRY.counter(
     "device_launches_total",
     "Device program launches by program name", ("program",))
@@ -211,6 +233,8 @@ class DeviceTelemetry:
     def __init__(self):
         self._lock = threading.Lock()
         self._programs: dict[str, dict] = {}
+        #: jitted function name -> program, for JAX's compile events
+        self._jit_names: dict[str, str] = {}
         self._seen_keys: set[tuple] = set()
         #: per-program busy-span watermark (monotonic end time of the
         #: union of all credited spans) — spans complete in fetch
@@ -222,11 +246,14 @@ class DeviceTelemetry:
 
     def register_program(self, name: str, *,
                          flops_per_item: float | None = None,
-                         module: str = "") -> None:
+                         module: str = "",
+                         jit_names: tuple[str, ...] = ()) -> None:
         """Declare a named device program (idempotent).
 
         ``flops_per_item`` feeds the MFU model; ``module`` is the
-        defining module for the deviceStatus table."""
+        defining module for the deviceStatus table; ``jit_names`` are
+        the names of its jitted functions, by which JAX's compile
+        events find the program."""
         with self._lock:
             spec = self._programs.setdefault(
                 name, {"flops_per_item": None, "module": ""})
@@ -234,6 +261,15 @@ class DeviceTelemetry:
                 spec["flops_per_item"] = float(flops_per_item)
             if module:
                 spec["module"] = module
+            for jit_name in jit_names:
+                self._jit_names[jit_name] = name
+
+    def program_of(self, fun_name: str) -> str | None:
+        """The cataloged program a compile event's ``fun_name``
+        (``my_prog`` or ``jit(my_prog)``) belongs to."""
+        if fun_name.startswith("jit(") and fun_name.endswith(")"):
+            fun_name = fun_name[4:-1]
+        return self._jit_names.get(fun_name)
 
     def programs(self) -> dict[str, dict]:
         with self._lock:
@@ -250,10 +286,9 @@ class DeviceTelemetry:
                       devices: int = 1) -> None:
         """Attribute one finished launch.  Never raises.
 
-        ``key`` is the program's static-shape tuple: its first
-        sighting is counted as a compile (with ``dispatch_seconds``
-        as the compile wall), later sightings as cache hits.
-        ``span`` is (dispatch_start, fetch_end) in ``time.monotonic``
+        ``key`` is the program's static-shape tuple, remembered for
+        :meth:`launched_keys` (compiles are counted from JAX's own
+        events, not from here).  ``span`` is (dispatch_start, fetch_end) in ``time.monotonic``
         terms; overlap with the previous span is credited once.
         """
         try:
@@ -289,17 +324,9 @@ class DeviceTelemetry:
             WORK_ITEMS.labels(program=program).inc(items)
 
         if key is not None:
-            compile_key = (program, key)
             with self._lock:
-                new = compile_key not in self._seen_keys
-                if new and len(self._seen_keys) < MAX_COMPILE_KEYS:
-                    self._seen_keys.add(compile_key)
-            if new:
-                COMPILES.labels(program=program).inc()
-                COMPILE_SECONDS.labels(program=program).observe(
-                    dispatch_seconds)
-            else:
-                CACHE_HITS.labels(program=program).inc()
+                if len(self._seen_keys) < MAX_LAUNCH_KEYS:
+                    self._seen_keys.add((program, key))
 
         if span is None:
             busy = dispatch_seconds + wait_seconds
@@ -327,14 +354,15 @@ class DeviceTelemetry:
                 MFU.labels(program=program).set(
                     min(rate * flops / (peak * devices), 1.0))
 
-    def compiled_keys(self) -> list[tuple]:
-        """Every (program, static-shape key) launched so far — one
-        entry per expected compile (chip_smoke.py prints the table)."""
+    def launched_keys(self) -> list[tuple]:
+        """Every (program, static-shape key) launched so far
+        (chip_smoke.py prints the table and looks for interpret
+        mode in it)."""
         with self._lock:
             return sorted(self._seen_keys, key=repr)
 
     def reset(self) -> None:
-        """Drop compile-cache/busy state (tests; counters stay
+        """Drop launched-shape/busy state (tests; counters stay
         monotonic as the registry requires)."""
         with self._lock:
             self._seen_keys.clear()
@@ -347,13 +375,113 @@ DEVICE_TELEMETRY = DeviceTelemetry()
 
 
 def register_program(name: str, *, flops_per_item: float | None = None,
-                     module: str = "") -> None:
+                     module: str = "",
+                     jit_names: tuple[str, ...] = ()) -> None:
     DEVICE_TELEMETRY.register_program(
-        name, flops_per_item=flops_per_item, module=module)
+        name, flops_per_item=flops_per_item, module=module,
+        jit_names=jit_names)
 
 
 def record_launch(program: str, **kwargs) -> None:
     DEVICE_TELEMETRY.record_launch(program, **kwargs)
+
+
+# ---------------------------------------------------------------------------
+# JAX's compile events -> counters
+# ---------------------------------------------------------------------------
+
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+_CACHE_RESULTS = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+#: a program's trace, lowering, cache lookup and backend compile run
+#: one after another on the thread that launches it: what the earlier
+#: phases took, and how the lookup went, wait here for the last one
+_compiling = threading.local()
+#: bound on the top-level compile intervals a thread remembers
+_MAX_INTERVALS = 1024
+_listener_installed = False
+_listener_lock = threading.Lock()
+
+
+def _own_seconds(seconds: float) -> float:
+    """``seconds`` of the event that has just ended on this thread,
+    less the events that ran inside it: tracing a program traces every
+    jitted function it calls, and each reports its own duration, so
+    the plain sum would count those seconds twice.  With this the
+    phases add up to the wall the thread spent compiling."""
+    end = time.monotonic()
+    start = end - seconds
+    stack = getattr(_compiling, "intervals", None)
+    if stack is None:
+        stack = _compiling.intervals = []
+    inside = 0.0
+    # an event that began after this one began ran inside it (the
+    # phases of one program follow each other and do not qualify)
+    while stack and stack[-1][0] >= start:
+        inside += stack.pop()[1]
+    stack.append((start, seconds))
+    if len(stack) > _MAX_INTERVALS:
+        del stack[:_MAX_INTERVALS // 2]
+    return max(seconds - inside, 0.0)
+
+
+def _on_compile_duration(event: str, seconds: float, **kw) -> None:
+    phase = _COMPILE_PHASES.get(event)
+    if phase is None:
+        return
+    try:
+        JAX_COMPILE_EVENTS.labels(phase=phase).inc()
+        JAX_COMPILE_SECONDS.labels(phase=phase).inc(_own_seconds(seconds))
+        program = DEVICE_TELEMETRY.program_of(kw.get("fun_name") or "")
+        pending = getattr(_compiling, "seconds", None)
+        if pending is None:
+            pending = _compiling.seconds = {}
+        if phase != "backend_compile":
+            if program is not None:
+                pending[program] = pending.get(program, 0.0) + seconds
+            return
+        result, _compiling.cache = getattr(_compiling, "cache", None), None
+        if program is None:
+            return
+        COMPILE_SECONDS.labels(program=program).observe(
+            seconds + pending.pop(program, 0.0))
+        (CACHE_HITS if result == "hit" else COMPILES).labels(
+            program=program).inc()
+    except Exception:
+        # a listener that raises would fail the compile it watches
+        logger.debug("compile event dropped", exc_info=True)
+
+
+def _on_compile_event(event: str, **_kw) -> None:
+    result = _CACHE_RESULTS.get(event)
+    if result is None:
+        return
+    try:
+        JAX_COMPILE_CACHE.labels(result=result).inc()
+        _compiling.cache = result
+    except Exception:
+        logger.debug("compile cache event dropped", exc_info=True)
+
+
+def install_compile_listener() -> None:
+    """Feed the ``jax_compile_*`` and per-program compile series from
+    JAX's monitoring events.  Once a process however often it is
+    called: JAX cannot drop a listener.  Imports JAX."""
+    global _listener_installed
+    with _listener_lock:
+        if _listener_installed:
+            return
+        _listener_installed = True
+    import jax
+    jax.monitoring.register_event_duration_secs_listener(
+        _on_compile_duration)
+    jax.monitoring.register_event_listener(_on_compile_event)
 
 
 # ---------------------------------------------------------------------------

@@ -1,26 +1,41 @@
-"""Structured span tracer with an optional JAX-profiler bridge.
+"""Structured span tracer that follows the JAX profiler.
 
 ``trace("pow.solve", backend="tpu-pallas")`` works as a context
 manager or a decorator.  Each span records a monotonic start, its
-duration, free-form attributes, and its parent span (linked through a
-``contextvars.ContextVar`` so nesting survives ``await`` boundaries
-and executor hops started from instrumented code).  Finished spans
-land in a fixed-size ring buffer for post-hoc inspection (clientStatus
-debugging, tests) — there is no background exporter to pay for.
+duration, free-form attributes, and its parent span, linked through a
+``contextvars.ContextVar``.  Nesting therefore survives ``await``
+boundaries and ``asyncio`` tasks (a task copies its creator's
+context); it does NOT survive ``loop.run_in_executor`` by itself,
+which runs the callable in the worker thread's own context.  The two
+executor hops of the send path (``workers/sender.py`` ``_run_crypto``,
+``pow/service.py`` ``_run``) carry it explicitly with
+``contextvars.copy_context().run``.  Finished spans land in a
+fixed-size ring buffer for post-hoc inspection (tests, debugging) —
+there is no background exporter to pay for.
 
-When the JAX bridge is enabled (``enable_jax_annotations(True)``,
-done by bench.py before profiling runs), every span additionally
-enters a ``jax.profiler.TraceAnnotation`` so PoW slab launches show up
-named inside XLA profiler traces; the device-side kernel time is then
-read back per slab by bench.py and fed to the
-``pow_slab_device_seconds`` histogram.  The bridge is off by default:
-the hot path must not pay a jax import or annotation cost unless a
-profile is actually being taken.
+Every span is mirrored into a ``jax.profiler.TraceAnnotation`` once
+``jax`` has been imported by something else (a span never imports it;
+the class is looked up once).  With no profiler session open that
+costs well under a microsecond; with one open — the benchmark's
+``--trace 1``, an operator's ``profileDevice`` /
+``GET /debug/device?seconds=N`` — the program's spans are in the
+trace, on the same clock as the device's operations, with nobody
+switching anything on.  The profiler records whole intervals, so a
+span that crosses an ``await`` and leaves out of order on its thread
+(``worker.pow``, hundreds open at once on the loop thread) is sound
+for a reader that treats host events as intervals and never as a
+stack (``benchmarks/spanreduce.py``).
+
+One ``PowService`` batch is tied together by an identifier, not by
+parentage (the service's loop is a task of its own):
+:func:`set_batch` puts a sequence number into the context and every
+span entered under it carries it as the attribute ``batch``.
 
 A span may be given ``histogram=<Histogram child or family>`` — its
 duration is observed on exit, which is how the solve-latency
 histograms are fed without a second ``time.monotonic()`` pair at the
-call sites.
+call sites.  Call sites that need the pair themselves read it from
+the span (``span.start``, ``span.end``) instead of taking it again.
 """
 
 from __future__ import annotations
@@ -29,6 +44,7 @@ import contextvars
 import functools
 import itertools
 import logging
+import sys
 import threading
 import time
 from collections import deque
@@ -41,18 +57,28 @@ _current_span: contextvars.ContextVar["Span | None"] = \
 
 _span_ids = itertools.count(1)
 
-#: module switch for the jax.profiler.TraceAnnotation bridge
-_jax_annotations_enabled = False
+#: sequence number of the PowService batch this context works for
+_batch: contextvars.ContextVar["int | None"] = \
+    contextvars.ContextVar("pybitmessage_tpu_span_batch", default=None)
+
+#: jax.profiler.TraceAnnotation, once jax has been imported
+_annotation = None
 
 
-def enable_jax_annotations(on: bool = True) -> None:
-    """Toggle mirroring spans into jax.profiler.TraceAnnotation."""
-    global _jax_annotations_enabled
-    _jax_annotations_enabled = bool(on)
+def _find_annotation():
+    """The profiler's annotation class if ``jax.profiler`` is already
+    imported, else None.  Never imports JAX for a span."""
+    global _annotation
+    profiler = sys.modules.get("jax.profiler")
+    # a module still being imported by another thread has no class yet
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
 
 
-def jax_annotations_enabled() -> bool:
-    return _jax_annotations_enabled
+def set_batch(seq: "int | None") -> None:
+    """Every span entered in this context from now on carries
+    ``batch=seq`` (``None`` stops that)."""
+    _batch.set(seq)
 
 
 @dataclass
@@ -63,6 +89,11 @@ class Span:
     start: float                      # time.monotonic()
     attrs: dict = field(default_factory=dict)
     duration: float | None = None     # filled on exit
+
+    @property
+    def end(self) -> float:
+        """``time.monotonic()`` at exit (of a finished span)."""
+        return self.start + self.duration
 
     def as_dict(self) -> dict:
         return {"name": self.name, "span_id": self.span_id,
@@ -107,7 +138,7 @@ class trace:
     """
 
     __slots__ = ("name", "attrs", "histogram", "tracer", "span",
-                 "_token", "_jax_ctx", "_t0")
+                 "_token", "_jax_ctx")
 
     def __init__(self, name: str, *, histogram=None, tracer: Tracer = None,
                  **attrs):
@@ -118,27 +149,29 @@ class trace:
         self.span = None
         self._token = None
         self._jax_ctx = None
-        self._t0 = 0.0
 
     def __enter__(self) -> Span:
         parent = _current_span.get()
+        batch = _batch.get()
+        if batch is not None:
+            self.attrs.setdefault("batch", batch)
+        annotation = _annotation or _find_annotation()
+        if annotation is not None:
+            # the interval starts at construction; attributes known
+            # now ride along as the event's stats
+            try:
+                self._jax_ctx = annotation(self.name, **self.attrs)
+            except Exception:
+                self._jax_ctx = None
         self.span = Span(
             name=self.name, span_id=next(_span_ids),
             parent_id=parent.span_id if parent is not None else None,
             start=time.monotonic(), attrs=self.attrs)
         self._token = _current_span.set(self.span)
-        if _jax_annotations_enabled:
-            try:
-                from jax.profiler import TraceAnnotation
-                self._jax_ctx = TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
-        self._t0 = time.monotonic()
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        duration = time.monotonic() - self._t0
+        duration = time.monotonic() - self.span.start
         if self._jax_ctx is not None:
             try:
                 self._jax_ctx.__exit__(exc_type, exc, tb)

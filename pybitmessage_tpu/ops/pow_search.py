@@ -250,6 +250,7 @@ def verify(items: Sequence[tuple[int, bytes, int]]) -> list[bool]:
 
 
 register_program("pow_slab", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="ops/pow_search.py")
+                 module="ops/pow_search.py", jit_names=("pow_search_jit",))
 register_program("pow_verify", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="ops/pow_search.py")
+                 module="ops/pow_search.py",
+                 jit_names=("pow_verify_batch",))

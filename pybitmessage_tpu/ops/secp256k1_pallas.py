@@ -648,6 +648,8 @@ from ..observability.devicetelemetry import (SECP_ECDH_FLOPS,
                                              register_program)
 
 register_program("secp_verify", flops_per_item=SECP_VERIFY_FLOPS,
-                 module="ops/secp256k1_pallas.py")
+                 module="ops/secp256k1_pallas.py",
+                 jit_names=("pallas_verify", "xla_verify"))
 register_program("secp_ecdh", flops_per_item=SECP_ECDH_FLOPS,
-                 module="ops/secp256k1_pallas.py")
+                 module="ops/secp256k1_pallas.py",
+                 jit_names=("pallas_ecdh", "xla_ecdh"))

@@ -25,6 +25,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              record_launch,
                                              register_program)
+from ..observability.tracing import trace
 from .sha512_jax import _H0, _K
 from .u64 import U32
 
@@ -715,6 +716,10 @@ def solve(initial_hash: bytes, target: int, *,
     """
     import numpy as np
 
+    # the host loops' counters live with the pipeline, which imports
+    # this module at its top
+    from ..pow.pipeline import (ABANDONED_LAUNCHES, EXECUTED_TRIALS,
+                                LAUNCHES)
     from ..utils.hashes import double_sha512
     from .pow_search import PowInterrupted
 
@@ -742,22 +747,29 @@ def solve(initial_hash: bytes, target: int, *,
         # a separate explicit device-put
         base = np.array([(base_int >> 32) & 0xFFFFFFFF,
                          base_int & 0xFFFFFFFF], dtype=np.uint32)
-        return pallas_search(ih_words, base, target_arr, rows=rows,
-                             chunks=chunks, unroll=unroll,
-                             interpret=interpret)
+        with trace("pow.launch", program="pallas_slab", chunks=chunks,
+                   live=1) as span:
+            out = pallas_search(ih_words, base, target_arr, rows=rows,
+                                chunks=chunks, unroll=unroll,
+                                interpret=interpret)
+        LAUNCHES.labels(kind="slab").inc()
+        return out, span.start, span.end
 
     def harvest(found_dev, nonce_dev, t_disp, t_disp_end):
         """Sync one slab's results; returns the winning nonce or None."""
-        t_f = _time.monotonic()
-        f = np.asarray(found_dev)
-        t_done = _time.monotonic()
+        with trace("pow.fetch") as fetch:
+            f = np.asarray(found_dev)
         record_launch("pallas_slab",
                       key=(rows, chunks, unroll, interpret),
                       dispatch_seconds=t_disp_end - t_disp,
-                      wait_seconds=t_done - t_f, span=(t_disp, t_done),
+                      wait_seconds=fetch.duration,
+                      span=(t_disp, fetch.end),
                       items=trials_per_slab, bytes_in=8,
                       bytes_out=int(f.nbytes))
         idx = int(f.argmax())
+        # the grid leaves at its first hit: steps up to it really ran
+        EXECUTED_TRIALS.labels(kind="slab").inc(
+            (idx + 1 if f[idx] else chunks) * rows * LANE_COLS * unroll)
         if not f[idx]:
             return None
         n = np.asarray(nonce_dev)
@@ -789,9 +801,7 @@ def solve(initial_hash: bytes, target: int, *,
                     progress(pending[3])
             raise PowInterrupted("Pallas PoW interrupted by shutdown")
         end_base = (base + trials_per_slab) & mask64
-        t_disp = _time.monotonic()
-        out = launch(base)
-        current = (out, t_disp, _time.monotonic(), end_base)
+        current = launch(base) + (end_base,)
         base = end_base
         if pending is not None:
             trials += trials_per_slab
@@ -802,6 +812,8 @@ def solve(initial_hash: bytes, target: int, *,
                 tuner.record(tuner_kind, chunks,
                              _time.monotonic() - pending[2])
             if nonce is not None:
+                # the slab just dispatched is left behind unfetched
+                ABANDONED_LAUNCHES.labels(kind="slab").inc()
                 return nonce, trials
             if progress is not None:
                 # the pending slab harvested miss-free: its end is the
@@ -811,8 +823,11 @@ def solve(initial_hash: bytes, target: int, *,
 
 
 register_program("pallas_slab", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="ops/sha512_pallas.py")
+                 module="ops/sha512_pallas.py",
+                 jit_names=("pallas_search",))
 register_program("batch_search", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="ops/sha512_pallas.py")
+                 module="ops/sha512_pallas.py",
+                 jit_names=("pallas_batch_search",))
 register_program("packed_search", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="ops/sha512_pallas.py")
+                 module="ops/sha512_pallas.py",
+                 jit_names=("pallas_packed_search",))

@@ -48,7 +48,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..observability import DEFAULT_SIZE_BUCKETS, REGISTRY
+from ..observability import DEFAULT_SIZE_BUCKETS, REGISTRY, trace
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              record_launch,
                                              register_program)
@@ -99,6 +99,19 @@ SLAB_SECONDS = REGISTRY.histogram(
 AUTOTUNE_CHUNKS = REGISTRY.gauge(
     "pow_slab_autotune_chunks",
     "Chunks-per-launch the autotuner currently suggests", ("kind",))
+LAUNCHES = REGISTRY.counter(
+    "pow_pipeline_launches_total",
+    "Search-kernel launches dispatched by the PoW host loops, by kind "
+    "(batch | packed | single-sync | slab)", ("kind",))
+ABANDONED_LAUNCHES = REGISTRY.counter(
+    "pow_pipeline_abandoned_launches_total",
+    "Speculative launches dispatched and never fetched because every "
+    "result was already in (the device still runs them)", ("kind",))
+EXECUTED_TRIALS = REGISTRY.counter(
+    "pow_pipeline_executed_trials_total",
+    "Trials the device computed in harvested launches, counted by the "
+    "grid steps each really ran (abandoned launches are not read "
+    "back, so their trials are not in here)", ("kind",))
 
 
 class SlabAutotuner:
@@ -233,7 +246,8 @@ def _packed_search_xla(ih_words, bases, targets, lanes: int, chunks: int):
 
 
 register_program("packed_search_xla", flops_per_item=POW_FLOPS_PER_HASH,
-                 module="pow/pipeline.py")
+                 module="pow/pipeline.py",
+                 jit_names=("_packed_search_xla",))
 
 
 # ---------------------------------------------------------------------------
@@ -343,7 +357,8 @@ class _PipelineDriver:
 
     def __init__(self, *, depth: int = 2,
                  should_stop: Callable[[], bool] | None = None,
-                 fetch=None, stall_timeout: float = 0.0):
+                 fetch=None, stall_timeout: float = 0.0,
+                 kind: str = "batch"):
         import numpy as np
 
         def default_fetch(dev):
@@ -364,11 +379,23 @@ class _PipelineDriver:
         #: (the wedged thread keeps the old executor, a fresh one takes
         #: over)
         self._guard_pool = None
+        #: label of this driver's launches in the pipeline counters
+        self.kind = kind
         self.wait_seconds = 0.0
+        #: blocking wait of the latest fetch (its ``pow.fetch`` span)
+        self.last_wait = 0.0
         self.wall_seconds = 0.0
         self.slabs = 0
 
     def _fetch(self, dev):
+        with trace("pow.fetch") as span:
+            host = self._guarded_fetch(dev)
+        self.last_wait = span.duration
+        self.wait_seconds += span.duration
+        DEVICE_WAIT.observe(span.duration)
+        return host
+
+    def _guarded_fetch(self, dev):
         if not self.stall_timeout or self.stall_timeout <= 0:
             return self.fetch(dev)
         import concurrent.futures as cf
@@ -408,7 +435,10 @@ class _PipelineDriver:
                     # is pure speculation — abandon it unfetched (the
                     # device finishes it in the background) instead of
                     # paying a blocking readback for nothing
-                    inflight.clear()
+                    if inflight:
+                        ABANDONED_LAUNCHES.labels(kind=self.kind).inc(
+                            len(inflight))
+                        inflight.clear()
                     break
                 if self.should_stop is not None and self.should_stop():
                     # drain what is already in flight — a pending slab
@@ -423,6 +453,7 @@ class _PipelineDriver:
                         break
                     inflight.append(nxt)
                     self.slabs += 1
+                    LAUNCHES.labels(kind=self.kind).inc()
                     PIPELINE_DEPTH.set(len(inflight))
                     _flight("slab_launch", n=self.slabs,
                             inflight=len(inflight))
@@ -430,13 +461,10 @@ class _PipelineDriver:
                     break
                 DISPATCH_AHEAD.observe(len(inflight))
                 tag, dev = inflight.popleft()
-                t0 = time.monotonic()
                 host = self._fetch(dev)
-                dt = time.monotonic() - t0
-                self.wait_seconds += dt
-                DEVICE_WAIT.observe(dt)
                 PIPELINE_DEPTH.set(len(inflight))
-                _flight("slab_harvest", wait_ms=round(dt * 1e3, 2),
+                _flight("slab_harvest",
+                        wait_ms=round(self.last_wait * 1e3, 2),
                         inflight=len(inflight))
                 harvest(tag, host)
         finally:
@@ -549,8 +577,10 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         impl = default_impl()
     autotuner = autotuner or AUTOTUNER
     if plan is None:
-        plan = plan_batch(items, rows=rows, unroll=unroll,
-                          autotuner=autotuner)
+        with trace("pow.plan", objects=n) as span:
+            plan = plan_batch(items, rows=rows, unroll=unroll,
+                              autotuner=autotuner)
+            span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
 
     if plan.mode == "single-sync":
@@ -592,11 +622,12 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         tele_prog = "batch_search"
         tele_key = (rows, plan.chunks, unroll, interpret)
 
-    groups = [
-        _LaunchGroup(items, plan.order[s:s + width], width,
-                     starts=start_nonces)
-        for s in range(0, n, width)
-    ]
+    with trace("pow.groups", objects=n, width=width):
+        groups = [
+            _LaunchGroup(items, plan.order[s:s + width], width,
+                         starts=start_nonces)
+            for s in range(0, n, width)
+        ]
     results: list = [None] * n
     executed = {"trials": 0, "launches": 0}
 
@@ -641,16 +672,17 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     break
         if cand is None:
             return None
+        live = cand.live()
         if plan.mode == "packed":
             # pack statistics describe lane sharing, which only the
             # packed kernel does — batched launches must not dilute
             # them (docs/observability.md semantics)
-            live = cand.live()
             PACK_SIZE.observe(live)
             PACK_OCCUPANCY.set(live / cand.width)
-        t0 = time.monotonic()
-        out = search(cand)
-        t1 = time.monotonic()
+        with trace("pow.launch", program=tele_prog, chunks=plan.chunks,
+                   live=live) as span:
+            out = search(cand)
+        t0, t1 = span.start, span.end
         inflight_groups.add(id(cand))
         cand.launches += 1
         executed["launches"] += 1
@@ -663,24 +695,22 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         end_bases = list(cand.bases)
         return ((cand, t0, t1, end_bases), out)
 
-    seen_wait = {"v": 0.0}
-
     def harvest(tag, out):
+        with trace("pow.harvest") as span:
+            _harvest(tag, out, span.start)
+
+    def _harvest(tag, out, t_h):
         g, t0, t1, end_bases = tag
         inflight_groups.discard(id(g))
-        t_h = time.monotonic()
         # normalize by the launch's total grid steps so storm-wide and
         # narrow launches feed one per-step EWMA
         autotuner.record(kind, plan.chunks * (g.width // pack), t_h - t0)
-        # the driver accumulated this harvest's blocking fetch into
-        # wait_seconds just before calling us — the delta since the
-        # last harvest is THIS slab's device wait
-        wait_dt = driver.wait_seconds - seen_wait["v"]
-        seen_wait["v"] = driver.wait_seconds
         before = executed["trials"]
         _record_pipeline_launch = functools.partial(
             record_launch, tele_prog, key=tele_key,
-            dispatch_seconds=t1 - t0, wait_seconds=wait_dt,
+            dispatch_seconds=t1 - t0,
+            # the driver fetched this slab just before calling us
+            wait_seconds=driver.last_wait,
             span=(t0, t_h), bytes_in=16 * g.width,
             bytes_out=12 * g.width,
             # the packed Mosaic kernel donates its base/target input
@@ -715,10 +745,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     # this slab proved [prev, end_bases[k]) miss-free:
                     # a resumed search may safely start there
                     progress(g.idx[k], end_bases[k])
+        EXECUTED_TRIALS.labels(kind=kind).inc(executed["trials"] - before)
         _record_pipeline_launch(items=executed["trials"] - before)
 
     driver = _PipelineDriver(depth=depth, should_stop=should_stop,
-                             stall_timeout=stall_timeout)
+                             stall_timeout=stall_timeout, kind=kind)
     try:
         driver.run(next_launch, harvest,
                    done=lambda: all(r is not None for r in results))
@@ -767,18 +798,21 @@ def _solve_single_sync(item, *, rows: int, unroll: int, chunks: int,
         # its base/target buffers
         t_arr = jnp.array([[target >> 32, target & 0xFFFFFFFF]],
                           dtype=U32)
-        t0 = time.monotonic()
-        if impl == "pallas":
-            out = pallas_packed_search(ih_words, b_arr, t_arr, rows=rows,
-                                       chunks=chunks, pack=1,
-                                       unroll=unroll, interpret=interpret)
-        else:
-            out = _packed_search_xla(ih_words, b_arr, t_arr,
-                                     lanes=step_trials, chunks=chunks)
-        t1 = time.monotonic()
-        inject("pow.readback")
-        out = np.asarray(out)
-        t2 = time.monotonic()
+        with trace("pow.launch", chunks=chunks, live=1,
+                   program=("packed_search" if impl == "pallas"
+                            else "packed_search_xla")) as launch:
+            if impl == "pallas":
+                out = pallas_packed_search(
+                    ih_words, b_arr, t_arr, rows=rows, chunks=chunks,
+                    pack=1, unroll=unroll, interpret=interpret)
+            else:
+                out = _packed_search_xla(ih_words, b_arr, t_arr,
+                                         lanes=step_trials, chunks=chunks)
+        LAUNCHES.labels(kind="single-sync").inc()
+        with trace("pow.fetch") as fetch:
+            inject("pow.readback")
+            out = np.asarray(out)
+        t0, t1, t2 = launch.start, launch.end, fetch.end
         autotuner.record("packed", chunks, t2 - t0)
         if impl == "pallas":
             record_launch("packed_search",
@@ -793,6 +827,8 @@ def _solve_single_sync(item, *, rows: int, unroll: int, chunks: int,
                           span=(t0, t2), items=slab_trials, bytes_in=16,
                           bytes_out=int(out.nbytes))
         step1 = int(out[0, 0])
+        EXECUTED_TRIALS.labels(kind="single-sync").inc(
+            step1 * step_trials if step1 else slab_trials)
         if step1:
             trials += step1 * step_trials
             nonce = (int(out[0, 1]) << 32) | int(out[0, 2])

@@ -29,11 +29,14 @@ Resilience (ISSUE 3, docs/resilience.md):
 from __future__ import annotations
 
 import asyncio
+import contextvars
+import itertools
 import logging
 import time
 from dataclasses import dataclass, field
 
-from ..observability import DEFAULT_SIZE_BUCKETS, REGISTRY
+from ..observability import (DEFAULT_SIZE_BUCKETS, REGISTRY, current_span,
+                             set_batch, trace)
 from ..observability.flightrec import record as _flight
 from ..observability.lifecycle import LIFECYCLE
 from ..ops.pow_search import PowInterrupted
@@ -61,6 +64,11 @@ REQUEUED = REGISTRY.counter(
     "Solve requests put back on the queue after a dispatcher failure "
     "or interrupt — the no-object-loss path", ("reason",))
 
+#: sequence numbers of coalesced batches, process-wide: the ``batch``
+#: attribute that ties a request's ``worker.pow`` span to the spans of
+#: the ``solve_batch`` that served it
+_BATCH_SEQ = itertools.count(1)
+
 #: default coalescing window in seconds; overridable per node via the
 #: ``powbatchwindow`` setting (core/config.py)
 DEFAULT_WINDOW = 0.05
@@ -81,6 +89,8 @@ class _Request:
     #: the future solver-farm protocol carries it on submit/requeue so
     #: a job's path through a remote farm stays one causal trace)
     trace_id: bytes = b""
+    #: sequence number of the batch that last took this request
+    batch: int = 0
 
 
 class PowService:
@@ -161,6 +171,18 @@ class PowService:
                              "without journal durability", site)
             return None
 
+    def _journal_each(self, batch, op: str, method: str) -> None:
+        """One journal write per journaled request of ``batch``, all
+        under one ``pow.queue.journal`` span."""
+        if self.journal is None:
+            return
+        write = getattr(self.journal, method)
+        with trace("pow.queue.journal", op=op):
+            for req in batch:
+                if req.job_id is not None:
+                    self._journal_call(lambda j=req.job_id: write(j),
+                                       site="pow.journal." + op)
+
     def _checkpoint(self, req: _Request, next_nonce: int) -> None:
         """Progress hook from the dispatcher (executor thread)."""
         req.start_nonce = max(req.start_nonce, next_nonce)
@@ -203,30 +225,36 @@ class PowService:
             req.trace_id = ctx.trace_id
         await self.queue.put(req)
         QUEUE_DEPTH.set(self.queue.qsize())
-        return await fut
+        result = await fut
+        waited = current_span()     # the caller's span: worker.pow
+        if waited is not None:
+            waited.attrs["batch"] = req.batch
+        return result
 
     # -- drain loop ----------------------------------------------------------
 
     async def _run(self) -> None:
         while True:
             first = await self.queue.get()
-            if self.window > 0:
-                await asyncio.sleep(self.window)
-            batch = [first]
-            while not self.queue.empty():
-                batch.append(self.queue.get_nowait())
-            now = time.monotonic()
+            seq = next(_BATCH_SEQ)
+            # this task's spans, and through the executor hop below
+            # every span of the solve, carry the batch's number
+            set_batch(seq)
+            with trace("pow.queue.window") as window:
+                if self.window > 0:
+                    await asyncio.sleep(self.window)
+                batch = [first]
+                while not self.queue.empty():
+                    batch.append(self.queue.get_nowait())
+                window.attrs["objects"] = len(batch)
             for req in batch:
-                QUEUE_WAIT.observe(now - req.enqueued)
+                req.batch = seq
+                QUEUE_WAIT.observe(window.end - req.enqueued)
             BATCH_SIZE.observe(len(batch))
             QUEUE_DEPTH.set(self.queue.qsize())
             items = [(r.initial_hash, r.target) for r in batch]
             starts = [r.start_nonce for r in batch]
-            for req in batch:
-                if req.job_id is not None:
-                    self._journal_call(
-                        lambda j=req.job_id: self.journal.mark_inflight(j),
-                        site="pow.journal.inflight")
+            self._journal_each(batch, "inflight", "mark_inflight")
 
             def progress(i, next_nonce, _batch=batch):
                 self._checkpoint(_batch[i], next_nonce)
@@ -236,9 +264,11 @@ class PowService:
                 kwargs.update(start_nonces=starts, progress=progress)
             loop = asyncio.get_running_loop()
             try:
+                # run_in_executor does not carry contextvars: copy the
+                # context so the solve's spans keep batch and parent
                 results = await loop.run_in_executor(
-                    None, lambda: self.dispatcher.solve_batch(
-                        items, **kwargs))
+                    None, contextvars.copy_context().run,
+                    lambda: self.dispatcher.solve_batch(items, **kwargs))
             except asyncio.CancelledError:
                 self._settle_interrupted(batch)
                 raise
@@ -255,11 +285,8 @@ class PowService:
             if len(batch) > 1:
                 logger.info("batched PoW: %d objects in one launch (%s)",
                             len(batch), self.dispatcher.last_backend)
+            self._journal_each(batch, "complete", "complete")
             for req, res in zip(batch, results):
-                if req.job_id is not None:
-                    self._journal_call(
-                        lambda j=req.job_id: self.journal.complete(j),
-                        site="pow.journal.complete")
                 LIFECYCLE.record(req.initial_hash, "pow_solved")
                 if not req.future.done():
                     req.future.set_result(res)

@@ -13,6 +13,7 @@ interruptible via the node's shutdown flag.
 from __future__ import annotations
 
 import asyncio
+import contextvars
 import logging
 import random
 import struct
@@ -64,6 +65,12 @@ _TYPE_NAMES = {OBJECT_GETPUBKEY: "getpubkey", OBJECT_MSG: "msg",
 
 def _jitter_ttl(ttl: int) -> int:
     return max(300, int(ttl + random.randrange(-300, 300)))
+
+
+#: sign and encrypt as ``_run_crypto`` hands them to the executor: each
+#: call is one span on the thread that does the work
+_sign = trace("sender.sign")(sign)
+_encrypt = trace("sender.encrypt")(encrypt)
 
 
 class SendWorker:
@@ -185,9 +192,11 @@ class SendWorker:
     async def _run_crypto(self, fn, *args):
         """Run a scalar-mult-heavy crypto call (sign/encrypt) off the
         event loop — the send path's counterpart of the receive-side
-        CryptoPool hop (keeps the loop-lag budget; lint-enforced)."""
+        CryptoPool hop (keeps the loop-lag budget; lint-enforced).
+        The context is copied across the hop, so the callable's span
+        is a child of the sweep that asked for it."""
         return await asyncio.get_running_loop().run_in_executor(
-            None, fn, *args)
+            None, contextvars.copy_context().run, fn, *args)
 
     async def _do_pow(self, payload_sans_nonce: bytes, ttl: int,
                       ntpb: int = 0, extra: int = 0) -> bytes:
@@ -212,6 +221,7 @@ class SendWorker:
                     trials, dt, trials / dt)
         return struct.pack(">Q", nonce) + payload_sans_nonce
 
+    @trace("sender.publish")
     def _publish(self, payload: bytes, object_type: int, stream: int,
                  tag: bytes = b"") -> bytes:
         h = inventory_hash(payload)
@@ -235,8 +245,10 @@ class SendWorker:
         # Send concurrently: each message's PoW request lands in the
         # PowService coalescing window, so a sweep of queued sends
         # becomes ONE batched (objects x nonce-lanes) device launch.
-        results = await asyncio.gather(
-            *(self._send_one_msg(m) for m in msgs), return_exceptions=True)
+        with trace("sender.sweep", kind="message", objects=len(msgs)):
+            results = await asyncio.gather(
+                *(self._send_one_msg(m) for m in msgs),
+                return_exceptions=True)
         for m, r in zip(msgs, results):
             if isinstance(r, BaseException) and \
                     not isinstance(r, asyncio.CancelledError):
@@ -305,10 +317,10 @@ class SendWorker:
         # (class_singleWorker.py:1224-1228)
         shell = object_shell(expires, OBJECT_MSG, 1, to.stream)
         plain.signature = await self._run_crypto(
-            sign, shell + unsigned, sender.priv_signing)
+            _sign, shell + unsigned, sender.priv_signing)
 
         encrypted = await self._run_crypto(
-            encrypt, plain.encode(), pub_enc)
+            _encrypt, plain.encode(), pub_enc)
         payload = shell + encrypted
         payload = await self._do_pow(payload, ttl, their_ntpb, their_extra)
         h = self._publish(payload, OBJECT_MSG, to.stream)
@@ -505,9 +517,10 @@ class SendWorker:
                 if not self.shutdown.is_set()]
         if not msgs:
             return
-        results = await asyncio.gather(
-            *(self._send_one_broadcast(m) for m in msgs),
-            return_exceptions=True)
+        with trace("sender.sweep", kind="broadcast", objects=len(msgs)):
+            results = await asyncio.gather(
+                *(self._send_one_broadcast(m) for m in msgs),
+                return_exceptions=True)
         for m, r in zip(msgs, results):
             if isinstance(r, BaseException) and \
                     not isinstance(r, asyncio.CancelledError):
@@ -541,7 +554,7 @@ class SendWorker:
             m.encodingtype or 2, body)
         unsigned = plain.encode_unsigned()
         plain.signature = await self._run_crypto(
-            sign, broadcast_signed_data(shell, unsigned),
+            _sign, broadcast_signed_data(shell, unsigned),
             sender.priv_signing)
         if sender.version <= 3:
             from ..models.payloads import broadcast_v4_key
@@ -550,7 +563,7 @@ class SendWorker:
             key = dh[:32]
         from ..crypto import priv_to_pub
         payload = shell + await self._run_crypto(
-            encrypt, plain.encode(), priv_to_pub(key))
+            _encrypt, plain.encode(), priv_to_pub(key))
         payload = await self._do_pow(payload, ttl)
         h = self._publish(payload, OBJECT_BROADCAST, sender.stream, tag)
         self.store.update_sent_status(m.ackdata, BROADCASTSENT)

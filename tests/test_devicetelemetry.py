@@ -94,22 +94,97 @@ def test_registered_programs_carry_module_and_flops():
 # ---------------------------------------------------------------------------
 
 
-def test_compile_vs_cache_split():
-    """First sighting of a (program, key) is a compile whose wall is
-    the dispatch time; repeats of the key are cache hits; a new key
-    compiles again."""
+def test_compile_vs_cache_split(monkeypatch):
+    """Compiles come from JAX's own events, never from launches: a
+    program's trace, lowering, cache lookup and backend compile arrive
+    in that order on one thread; a lookup that missed is a compile, one
+    that hit is a cache hit, and the seconds of the phases add up."""
+    from pybitmessage_tpu.observability import devicetelemetry as dt
     prog = "t_split_unit"
+    dt.register_program(prog, jit_names=("t_split_fn",))
     record_launch(prog, key=(128, 1), dispatch_seconds=0.5)
     record_launch(prog, key=(128, 1), dispatch_seconds=0.001)
-    record_launch(prog, key=(256, 1), dispatch_seconds=0.4)
-    assert _sample("device_launches_total", prog) == 3
-    assert _sample("device_program_compiles_total", prog) == 2
+    assert _sample("device_launches_total", prog) == 2
+    assert _sample("device_program_compiles_total", prog) == 0
+    assert (prog, (128, 1)) in DEVICE_TELEMETRY.launched_keys()
+
+    # one phase ends where the next begins, as in a real compile
+    from types import SimpleNamespace
+    clock = [5000.0]
+    monkeypatch.setattr(dt, "time", SimpleNamespace(
+        monotonic=lambda: clock[0]))
+    monkeypatch.setattr(dt, "_compiling", SimpleNamespace())
+
+    def fire(event, seconds, fun_name):
+        clock[0] += seconds + 0.001
+        dt._on_compile_duration("/jax/core/compile/" + event, seconds,
+                                fun_name=fun_name)
+
+    def phases(result, backend_seconds):
+        fire("jaxpr_trace_duration", 0.1, "t_split_fn")
+        fire("jaxpr_to_mlir_module_duration", 0.3, "jit(t_split_fn)")
+        dt._on_compile_event("/jax/compilation_cache/cache_" + result)
+        fire("backend_compile_duration", backend_seconds,
+             "jit(t_split_fn)")
+
+    lowered0 = REGISTRY.sample("jax_compile_events_total",
+                               {"phase": "lower"})
+    seconds0 = REGISTRY.sample("jax_compile_seconds_total",
+                               {"phase": "backend_compile"})
+    hits0 = REGISTRY.sample("jax_compile_cache_total", {"result": "hit"})
+    phases("misses", 0.5)
+    phases("hits", 0.02)
+    # an event of a function no program owns feeds the totals only,
+    # and takes the lookup's result with it
+    dt._on_compile_event("/jax/compilation_cache/cache_hits")
+    fire("backend_compile_duration", 0.01, "jit(convert_element_type)")
+    dt._on_compile_duration("/jax/some/other/event", 9.0)
+    assert _sample("device_program_compiles_total", prog) == 1
     assert _sample("device_program_cache_hits_total", prog) == 1
-    # compile seconds accumulated only the two first-key dispatch walls
     from pybitmessage_tpu.observability.devicetelemetry import _hist_stats
     count, total = _hist_stats("device_program_compile_seconds", prog)
     assert count == 2
-    assert total == pytest.approx(0.9)
+    assert total == pytest.approx(0.9 + 0.42)
+    assert REGISTRY.sample("jax_compile_events_total",
+                           {"phase": "lower"}) == lowered0 + 2
+    assert REGISTRY.sample(
+        "jax_compile_seconds_total",
+        {"phase": "backend_compile"}) == pytest.approx(seconds0 + 0.53)
+    assert REGISTRY.sample("jax_compile_cache_total",
+                           {"result": "hit"}) == hits0 + 2
+
+
+def test_nested_compile_events_are_not_counted_twice(monkeypatch):
+    """Tracing a program traces the jitted functions it calls, and each
+    reports its own duration before the outer one does: the seconds by
+    phase add up to the wall, while a program's lowering, which starts
+    where its trace ended, is counted whole."""
+    from types import SimpleNamespace
+
+    from pybitmessage_tpu.observability import devicetelemetry as dt
+    clock = [1000.0]
+    monkeypatch.setattr(dt, "time", SimpleNamespace(
+        monotonic=lambda: clock[0]))
+    monkeypatch.setattr(dt, "_compiling", SimpleNamespace())
+
+    def fire(event, at, seconds, name):
+        clock[0] = at
+        dt._on_compile_duration("/jax/core/compile/" + event, seconds,
+                                fun_name=name)
+
+    def seconds(phase):
+        return REGISTRY.sample("jax_compile_seconds_total",
+                               {"phase": phase})
+
+    trace0, lower0 = seconds("trace"), seconds("lower")
+    fire("jaxpr_trace_duration", 1001.0, 0.5, "inner_a")   # 1000.5..1001
+    fire("jaxpr_trace_duration", 1002.0, 0.25, "inner_b")  # 1001.75..1002
+    fire("jaxpr_trace_duration", 1003.0, 3.0, "outer")     # 1000..1003
+    fire("jaxpr_to_mlir_module_duration", 1005.0, 2.0, "jit(outer)")
+    fire("jaxpr_trace_duration", 1006.0, 0.5, "next")      # sequential
+    assert seconds("trace") - trace0 == pytest.approx(3.0 + 0.5)
+    assert seconds("lower") - lower0 == pytest.approx(2.0)
+    assert len(dt._compiling.intervals) == 3       # outer, lower, next
 
 
 def test_busy_union_overlap_credited_once():
@@ -181,28 +256,42 @@ def test_record_launch_never_raises():
 
 def test_pow_slab_live_compile_cache_and_verify_bytes():
     """A real ``ops/pow_search`` solve on the CPU backend populates
-    pow_slab with the compile/cache split, and verify() populates
-    pow_verify with upload bytes."""
+    pow_slab, its compile counted from JAX's events once setup_jax has
+    installed the listener, and verify() populates pow_verify with
+    upload bytes."""
+    from pybitmessage_tpu.core.jaxsetup import setup_jax
+    from pybitmessage_tpu.observability.devicetelemetry import _hist_stats
     from pybitmessage_tpu.ops import pow_search
-    DEVICE_TELEMETRY.reset()  # deterministic first-sighting below
-    launches0 = _sample("device_launches_total", "pow_slab")
-    compiles0 = _sample("device_program_compiles_total", "pow_slab")
+    setup_jax()
+    setup_jax()         # again: the listener must not count twice
+    assert REGISTRY.sample("jax_backend_init_seconds") >= 0
 
-    nonce, trials = pow_search.solve(IH, ALWAYS, lanes=128,
+    def shapes_compiled():
+        return (_sample("device_program_compiles_total", "pow_slab")
+                + _sample("device_program_cache_hits_total", "pow_slab"))
+
+    launches0 = _sample("device_launches_total", "pow_slab")
+    compiles0 = shapes_compiled()
+    lowered0 = REGISTRY.sample("jax_compile_events_total",
+                               {"phase": "lower"})
+
+    # a lane count no other test uses, so this is the shape's first use
+    nonce, trials = pow_search.solve(IH, ALWAYS, lanes=384,
                                      chunks_per_call=1)
     assert trials > 0
     assert _sample("device_launches_total", "pow_slab") > launches0
-    assert _sample("device_program_compiles_total",
-                   "pow_slab") == compiles0 + 1
+    assert shapes_compiled() == compiles0 + 1
+    assert REGISTRY.sample("jax_compile_events_total",
+                           {"phase": "lower"}) > lowered0
+    count, total = _hist_stats("device_program_compile_seconds",
+                               "pow_slab")
+    assert count >= 1 and total > 0
     assert _sample("device_busy_seconds_total", "pow_slab") > 0
     assert _sample("device_work_items_total", "pow_slab") > 0
 
-    hits0 = _sample("device_program_cache_hits_total", "pow_slab")
-    pow_search.solve(IH, ALWAYS, lanes=128, chunks_per_call=1)
-    # same static key -> no new compile, the launch was a cache hit
-    assert _sample("device_program_compiles_total",
-                   "pow_slab") == compiles0 + 1
-    assert _sample("device_program_cache_hits_total", "pow_slab") > hits0
+    pow_search.solve(IH, ALWAYS, lanes=384, chunks_per_call=1)
+    # same static shape: nothing was compiled or loaded again
+    assert shapes_compiled() == compiles0 + 1
 
     vlaunch0 = _sample("device_launches_total", "pow_verify")
     vbytes0 = _sample("device_h2d_bytes_total", "pow_verify")
@@ -344,6 +433,49 @@ def test_capture_device_trace_bounds_and_capture(tmp_path):
     assert out["ok"] is True
     assert out["traceDir"] == str(tmp_path)
     assert out["seconds"] >= 0.1
+
+
+def test_an_operators_capture_holds_the_programs_spans(tmp_path):
+    """``profileDevice`` / ``GET /debug/device`` (this function) taken
+    while a batch solves: the capture names the pipeline's launches
+    and fetches, with nobody switching a bridge on."""
+    import glob
+    import threading
+
+    from jax.profiler import ProfileData
+
+    from pybitmessage_tpu.pow.pipeline import (BatchPlan,
+                                               solve_batch_pipelined)
+    items = [(hashlib.sha512(b"captured %d" % i).digest(), 2 ** 64 // 2000)
+             for i in range(4)]
+
+    def solve():
+        # one small pinned shape: the capture must not be spent
+        # compiling, nor drowned in the CPU backend's own events
+        solve_batch_pipelined(items, impl="xla", rows=8,
+                              plan=BatchPlan("batched", 1, 2, [0, 1, 2, 3]))
+        time.sleep(0.01)
+    solve()                                     # compiled before
+    stop = threading.Event()
+
+    def keep_solving():
+        while not stop.is_set():
+            solve()
+
+    worker = threading.Thread(target=keep_solving)
+    worker.start()
+    try:
+        # under the profiler the CPU backend's stand-in kernel is slow
+        # (a solve takes 0.1-0.3 s): long enough for whole solves
+        out = capture_device_trace(3.0, out_dir=str(tmp_path))
+    finally:
+        stop.set()
+        worker.join()
+    assert out["ok"] is True
+    (path,) = glob.glob(str(tmp_path) + "/plugins/profile/*/*.xplane.pb")
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert {"pow.groups", "pow.launch", "pow.fetch", "pow.harvest"} <= names
 
 
 # ---------------------------------------------------------------------------

@@ -201,6 +201,72 @@ def test_pipeline_metrics_exported():
 # ---------------------------------------------------------------------------
 
 
+def test_a_run_that_ends_with_a_slab_in_flight_counts_it_abandoned():
+    """``_PipelineDriver.run`` drops what is still in flight once every
+    result is in; the program counts each such launch, and every
+    launch, where it happens."""
+    from pybitmessage_tpu.observability import REGISTRY
+    from pybitmessage_tpu.pow.pipeline import _PipelineDriver
+
+    def counted(name):
+        return REGISTRY.sample(name, {"kind": "t_abandon"})
+
+    launches0 = counted("pow_pipeline_launches_total")
+    abandoned0 = counted("pow_pipeline_abandoned_launches_total")
+    harvested = []
+    slabs = iter(range(10))
+    driver = _PipelineDriver(depth=2, fetch=lambda dev: dev,
+                             kind="t_abandon")
+    driver.run(lambda: (next(slabs),) * 2,
+               lambda tag, host: harvested.append(host),
+               done=lambda: bool(harvested))
+    # two dispatched ahead, the first harvested and enough: the second
+    # is left on the device unfetched
+    assert harvested == [0]
+    assert counted("pow_pipeline_launches_total") == launches0 + 2
+    assert counted("pow_pipeline_abandoned_launches_total") \
+        == abandoned0 + 1
+    assert driver.last_wait >= 0 and driver.wait_seconds >= driver.last_wait
+
+    # a run that needs every slab it dispatched abandons nothing
+    budget = iter(range(3))
+    driver = _PipelineDriver(depth=2, fetch=lambda dev: dev,
+                             kind="t_abandon")
+    driver.run(lambda: next(((b, b) for b in budget), None),
+               lambda tag, host: harvested.append(host))
+    assert harvested == [0, 0, 1, 2]
+    assert counted("pow_pipeline_launches_total") == launches0 + 5
+    assert counted("pow_pipeline_abandoned_launches_total") \
+        == abandoned0 + 1
+
+
+def test_pipelined_solve_counts_launches_and_executed_trials():
+    from pybitmessage_tpu.observability import REGISTRY, TRACER
+
+    def total(name):
+        fam = REGISTRY.get(name)
+        return sum(child.value for _v, child in fam.children())
+
+    launches0 = total("pow_pipeline_launches_total")
+    trials0 = total("pow_pipeline_executed_trials_total")
+    TRACER.clear()
+    stats = {}
+    items = _items(5, 2 ** 64 // 3000, tag=b"counted")
+    results = solve_batch_pipelined(items, impl="xla", rows=8, stats=stats)
+    assert all(r is not None for r in results)
+    assert total("pow_pipeline_launches_total") - launches0 \
+        == stats["launches"]
+    assert total("pow_pipeline_executed_trials_total") - trials0 \
+        == stats["executed_trials"] > 0
+    names = [s.name for s in TRACER.recent(500)]
+    for name in ("pow.plan", "pow.groups", "pow.launch", "pow.fetch",
+                 "pow.harvest"):
+        assert name in names, name
+    assert names.count("pow.launch") == stats["launches"]
+    launch = TRACER.recent(500, name="pow.launch")[-1]
+    assert set(launch.attrs) >= {"program", "chunks", "live"}
+
+
 def test_autotuner_targets_poll_interval():
     t = SlabAutotuner(target_seconds=0.5, min_chunks=4, max_chunks=2048)
     assert t.suggest("k", 64) == 64        # no data -> default
