@@ -41,8 +41,9 @@ traces are served behind ``profileDevice [seconds]`` and
 
 Flops-per-item model (documented estimates, BASELINE.md "Arithmetic
 utilization"): one double-SHA512 PoW trial executes
-:data:`POW_FLOPS_PER_HASH` = 21152 vector u32 ops (counted from the
-jaxpr of the unrolled schedule); one ECDSA verify is ~3.6e6 u32 ops
+:data:`POW_FLOPS_PER_HASH` = 20600 vector 32-bit ops (counted from the
+jaxpr of the Pallas kernels' body, which is what the v5e's compiler
+issues: tests/test_sha512_pallas_body.py); one ECDSA verify is ~3.6e6 u32 ops
 (Strauss-Shamir 256-step double ladder over 20x13-bit limbs), one
 ECDH ~2.4e6 (single 256-step Montgomery-style ladder).  Peak comes
 from :data:`DEVICE_PEAK_OPS`, keyed by the ``device_kind`` JAX
@@ -102,9 +103,13 @@ from .metrics import REGISTRY
 
 logger = logging.getLogger("pybitmessage_tpu.observability")
 
-#: vector u32 ops per double-SHA512 trial, counted from the jaxpr of
-#: the unrolled schedule the kernel executes (BASELINE.md)
-POW_FLOPS_PER_HASH = 21152.0
+#: vector 32-bit ops per double-SHA512 trial: the tile-shaped equations
+#: in the jaxpr of ``ops/sha512_pallas._double_sha512_tile`` with scalar
+#: initial-hash words (tests/test_sha512_pallas_body.py guards the
+#: count).  Since PR 26 the compiler's final bundles hold the same
+#: number (20,593 a vreg of trials); before it the jaxpr read 21,979
+#: and the bundles 23,220 (BASELINE.md "Arithmetic utilization")
+POW_FLOPS_PER_HASH = 20600.0
 #: ~order-of-magnitude u32 ops per batch ECDSA verify: Strauss-Shamir
 #: 256-step double ladder, ~7 field mults/step x ~400 limb ops x 2
 #: points + inversions (documented model, not a measurement)

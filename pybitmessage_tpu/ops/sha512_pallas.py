@@ -9,13 +9,16 @@ early exit: once a step hits, every later step's search body is skipped
 via ``pl.when`` and only writes its zeroed output row.
 
 Layout: grid = (chunks,); each grid step evaluates a (ROWS, 128) tile
-of nonces = base + step*ROWS*128 + lane.  Outputs per step: hit flag
-and winning (nonce_hi, nonce_lo); the host takes the first hit.
+of nonces = base + step*ROWS*128 + lane, one (8, 128) slice at a time.
+Outputs per step: hit flag and winning (nonce_hi, nonce_lo); the host
+takes the first hit.
 """
 
 from __future__ import annotations
 
 import functools
+import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -30,97 +33,217 @@ from .sha512_jax import _H0, _K
 from .u64 import U32
 
 LANE_COLS = 128
+#: sublanes of one 32-bit vreg: the slice of a tile hashed at a time
+SLICE_ROWS = 8
 
-#: measured v5e sweet spot: FIVE independent 128-row tiles per grid
-#: step — the 160-round chains are dependency-limited, so extra
-#: instruction streams let the VPU multi-issue.  r3 same-day ladder
-#: (rows=128, chunks=512): unroll=1: 77.8 MH/s, 2: 97.9, 3: 121.3,
-#: 4: 136.4, 6: 143.3; 64-row streams lose (64x8: 133.5, 64x4: 90.2),
-#: two 256-row streams thrash VMEM (77.2), rows=512 exceeds the 16 MB
-#: scoped VMEM limit, chunks>=1024 fails to compile.  r4 same-day
-#: ladder: 4: 138.0, 5: 149.2 (compile 170 s), 6: 151.0 (compile
-#: 228 s) — 5 is the knee.  A carry-save restructure of _add_many
-#: (hi parts summed as an independent tree off the carry chain)
-#: measured NEGATIVE same-day: 134.7 vs the 138.0 control — the VPU is
-#: issue-limited, not carry-latency-limited, so the only lever that
-#: moves the number is more independent streams.
+#: Tiles a grid step and steps a launch.  Since PR 26 a tile is hashed
+#: one (SLICE_ROWS, 128) slice at a time, so rows and unroll only say
+#: how many slices a grid step loops over (its fixed cost is about
+#: 0.35 us) and how often a search can leave at a hit; they no longer
+#: shape the instruction streams, and the r3/r4 unroll ladders that
+#: stood here (77.8 MH/s at unroll 1 to 151.0 at 6) measured a kernel
+#: that is gone.  rows=512 used to exceed the 16 MB scoped VMEM limit
+#: and chunks>=1024 does not compile (SMEM).  Measured on a v5e, batch
+#: kernel 64 x 64 x 4 x 128 rows, traced `chan_storm_256` runs (my chip
+#: runs, PR 26), with the compiler's final bundles of one grid step:
+#:   textbook body            23,220 vector ops a vreg of trials (the
+#:                            jaxpr shows 21,979: an unsigned compare is
+#:                            two xors more), 459,233 bundles, 351,067
+#:                            of them with a spill store   199.76 MH/s
+#:   this body, whole tiles   20,593 ops (jaxpr 20,600), 432,313
+#:                            bundles, 319,946 spill stores 211.63 MH/s
+#:   this body, slice loop    64 x 5,396 bundles, 201 spill stores a
+#:                            slice                         289.3 MH/s
+#: 289.3 MH/s x 20,600 = 5.96e12 ops/s, 97 % of the 6.1e12 ESTIMATE of
+#: the VPU's peak (8x128 lanes x 4 ALUs x 1.5 GHz): what is left is the
+#: number of operations a trial.  An earlier carry-save _add_many (same
+#: count, shorter chains) had measured no gain: issue-limited then too.
 DEFAULT_ROWS = 128
 DEFAULT_CHUNKS = 512
 DEFAULT_UNROLL = 5
 
 
-def _pair(value: int):
-    return jnp.uint32(value >> 32), jnp.uint32(value & 0xFFFFFFFF)
+_M32 = 0xFFFFFFFF
+_M64 = (1 << 64) - 1
+_TOP = 0x80000000
+I32 = jnp.int32
+
+
+# A 64-bit word is a (hi, lo) pair of 32-bit halves.  A half is a Python
+# int in [0, 2^32) where its value is known while tracing (the IV, K,
+# the padding and length words and all that follows from them alone),
+# else a traced int32: shape () where it is the same in every lane (the
+# single and batch kernels' initial-hash words, read from SMEM) or a
+# full tile.  The helpers do with Python ints what can be done while
+# tracing and emit an operation only where a traced value is involved,
+# so one _compress serves scalar and tile-shaped initial-hash words
+# alike.  The halves are SIGNED because the v5e's VPU compares signed
+# only: an unsigned ``lo < x`` costs two more xors a carry (PR 26 read
+# them in the compiler's final bundles).
+
+def _word(value: int):
+    return value >> 32, value & _M32
+
+
+def _known(x) -> bool:
+    return isinstance(x, int)
+
+
+def _i32(x):
+    return I32(x - ((x & _TOP) << 1)) if _known(x) else x
+
+
+def _rank(word) -> int:
+    """0 for a word known while tracing, 1 for one uniform across the
+    lanes (shape ()), 2 for a tile."""
+    return max(0 if _known(x) else 1 if x.shape == () else 2 for x in word)
+
+
+def _shl32(x, n):
+    return (x << n) & _M32 if _known(x) else x << n
+
+
+def _shr32(x, n):
+    return x >> n if _known(x) else jax.lax.shift_right_logical(x, I32(n))
+
+
+def _or32(x, y):
+    if _known(x) and _known(y):
+        return x | y
+    return _i32(x) | _i32(y)
+
+
+def _xor32(*xs):
+    const = functools.reduce(operator.xor, filter(_known, xs), 0)
+    traced = [x for x in xs if not _known(x)]
+    if not traced:
+        return const
+    if const:
+        traced.append(_i32(const))
+    return functools.reduce(operator.xor, traced)
+
+
+def _and32(x, y):
+    if _known(x) and _known(y):
+        return x & y
+    return _i32(x) & _i32(y)
+
+
+def _add32(x, y):
+    if _known(x) and _known(y):
+        return (x + y) & _M32
+    if _known(y):
+        x, y = y, x
+    return y if _known(x) and x == 0 else _i32(x) + y
 
 
 def _rotr(x, n):
     hi, lo = x
     if n == 32:
         return lo, hi
-    if n < 32:
-        m = 32 - n
-        return (hi >> n) | (lo << m), (lo >> n) | (hi << m)
-    n -= 32
+    if n > 32:
+        hi, lo, n = lo, hi, n - 32
     m = 32 - n
-    return (lo >> n) | (hi << m), (hi >> n) | (lo << m)
+    return (_or32(_shr32(hi, n), _shl32(lo, m)),
+            _or32(_shr32(lo, n), _shl32(hi, m)))
 
 
 def _shr(x, n):
     hi, lo = x
-    if n >= 32:
-        return jnp.zeros_like(hi), hi >> (n - 32)
-    return hi >> n, (lo >> n) | (hi << (32 - n))
+    return _shr32(hi, n), _or32(_shr32(lo, n), _shl32(hi, 32 - n))
 
 
-def _xor3(a, b, c):
-    return a[0] ^ b[0] ^ c[0], a[1] ^ b[1] ^ c[1]
+def _small_sigma0(x):
+    """rotr 1 ^ rotr 8 ^ shr 7, as rotr 1 of (x ^ rotr 7) ^ shr 7: the
+    rotation is the shift and one more piece, four operations less."""
+    top, low = _shr(x, 7)
+    rot7 = _or32(top, _shl32(x[1], 25)), low
+    return _xor(_rotr(_xor(x, rot7), 1), (top, low))
 
 
-def _add(a, b):
-    lo = a[1] + b[1]
-    carry = (lo < a[1]).astype(U32)
-    return a[0] + b[0] + carry, lo
+def _small_sigma1(x):
+    return _xor(_rotr(x, 19), _rotr(x, 61), _shr(x, 6))
 
 
-def _add_many(*terms):
-    acc = terms[0]
-    for t in terms[1:]:
-        acc = _add(acc, t)
-    return acc
+def _xor(*words):
+    return (_xor32(*[w[0] for w in words]), _xor32(*[w[1] for w in words]))
+
+
+def _and(a, b):
+    return _and32(a[0], b[0]), _and32(a[1], b[1])
+
+
+class _Open(tuple):
+    """A sum still open to further terms: (hi, lo ^ _TOP).
+
+    With the top bit of the low half flipped, signed order is the
+    unsigned order of the true values, so every further term's carry is
+    one signed compare of the new low half with the old.  ``_close``
+    flips the bit back; a sum that feeds two others (t1) is never
+    closed."""
+
+
+def _sum(*terms):
+    """Sum mod 2^64, as an :class:`_Open`.
+
+    At most one term is itself open, and the sum goes on from it.  The
+    others are taken known words first (folded here), then uniform
+    ones, then tiles: like sums with like, and a known or uniform start
+    is opened on the scalar core or not at all."""
+    start = [t for t in terms if isinstance(t, _Open)]
+    rest = sorted((t for t in terms if not isinstance(t, _Open)), key=_rank)
+    n_known = sum(_rank(t) == 0 for t in rest)
+    const = sum((hi << 32) | lo for hi, lo in rest[:n_known]) & _M64
+    rest = rest[n_known:]
+    if start:
+        (hi, lo), = start
+        if const:
+            rest.insert(0, _word(const))
+    elif n_known or not rest:
+        hi, lo = const >> 32, (const & _M32) ^ _TOP
+    else:
+        (hi, lo), rest = rest[0], rest[1:]
+        lo = _xor32(lo, _TOP)
+    for x_hi, x_lo in rest:
+        hi = _add32(hi, x_hi)
+        if _known(x_lo) and x_lo == 0:
+            continue                    # nothing to carry
+        if _known(lo) and lo == _TOP:   # nor into a low half of zero
+            lo = _xor32(x_lo, _TOP)
+            continue
+        was, lo = lo, _add32(lo, x_lo)
+        hi = _add32(hi, (lo < _i32(was)).astype(I32))
+    return _Open((hi, lo))
+
+
+def _close(open_sum):
+    hi, lo = open_sum
+    return hi, _xor32(lo, _TOP)
 
 
 def _compress(w):
-    """80 rounds over a 16-entry python-list window of tile pairs."""
-    a, b, c, d, e, f, g, h = [_broadcast_pair(_pair(x), w[0][0].shape)
-                              for x in _H0]
+    """80 rounds over a 16-entry python-list window of words."""
+    iv = [_word(x) for x in _H0]
+    a, b, c, d, e, f, g, h = iv
+    bc = _xor(b, c)
     for t in range(80):
         if t < 16:
             wt = w[t]
         else:
-            wt = _add_many(
-                _xor3(_rotr(w[(t - 2) % 16], 19), _rotr(w[(t - 2) % 16], 61),
-                      _shr(w[(t - 2) % 16], 6)),
-                w[(t - 7) % 16],
-                _xor3(_rotr(w[(t - 15) % 16], 1), _rotr(w[(t - 15) % 16], 8),
-                      _shr(w[(t - 15) % 16], 7)),
-                w[t % 16])
-            w[t % 16] = wt
-        ch = ((e[0] & f[0]) ^ (~e[0] & g[0]),
-              (e[1] & f[1]) ^ (~e[1] & g[1]))
-        maj = ((a[0] & b[0]) ^ (a[0] & c[0]) ^ (b[0] & c[0]),
-               (a[1] & b[1]) ^ (a[1] & c[1]) ^ (b[1] & c[1]))
-        s1e = _xor3(_rotr(e, 14), _rotr(e, 18), _rotr(e, 41))
-        s0a = _xor3(_rotr(a, 28), _rotr(a, 34), _rotr(a, 39))
-        t1 = _add_many(h, s1e, ch, _pair(_K[t]), wt)
-        t2 = _add(s0a, maj)
-        h, g, f, e = g, f, e, _add(d, t1)
-        d, c, b, a = c, b, a, _add(t1, t2)
-    return [_add(_broadcast_pair(_pair(_H0[i]), a[0].shape), v)
-            for i, v in enumerate([a, b, c, d, e, f, g, h])]
-
-
-def _broadcast_pair(pair, shape):
-    return (jnp.broadcast_to(pair[0], shape), jnp.broadcast_to(pair[1], shape))
+            wt = w[t % 16] = _close(_sum(
+                _small_sigma1(w[(t - 2) % 16]), w[(t - 7) % 16],
+                _small_sigma0(w[(t - 15) % 16]), w[t % 16]))
+        # Ch and Maj in three operations a half; this round's a ^ b is
+        # the next round's b ^ c
+        ch = _xor(g, _and(e, _xor(f, g)))
+        ab = _xor(a, b)
+        maj = _xor(b, _and(ab, bc))
+        s1e = _xor(_rotr(e, 14), _rotr(e, 18), _rotr(e, 41))
+        s0a = _xor(_rotr(a, 28), _rotr(a, 34), _rotr(a, 39))
+        t1 = _sum(h, s1e, ch, _word(_K[t]), wt)
+        h, g, f, e = g, f, e, _close(_sum(t1, d))
+        d, c, b, a, bc = c, b, a, _close(_sum(t1, s0a, maj)), ab
+    return [_close(_sum(x, v)) for x, v in zip(iv, (a, b, c, d, e, f, g, h))]
 
 
 def _double_sha512_tile(ih_pair, n_hi, n_lo):
@@ -134,24 +257,19 @@ def _double_sha512_tile(ih_pair, n_hi, n_lo):
     lane axis (w17/w19/w21 outright, plus the sigma contributions of
     w1..w15 feeding later extensions) then stays a shape-() value the
     compiler evaluates once per object on the scalar core, instead of
-    redundantly per lane on the VPU — the schedule-hoisting lever.
-    Mixed scalar/tile pairs combine through ordinary broadcasting in
-    ``_add``/``_xor3``.
+    redundantly per lane on the VPU -- the schedule-hoisting lever.
+    The padding and length words stay Python ints, so what follows from
+    them alone (``K[t] + W[t]`` of rounds 9-15, the sigmas of the zero
+    words) is folded while tracing and never emitted.
     """
-    zero = jnp.uint32(0)
-    w = [(n_hi, n_lo)]
-    w += [ih_pair(i) for i in range(8)]
-    w.append((jnp.uint32(0x80000000), zero))
-    w += [(zero, zero)] * 5
-    w.append((zero, jnp.uint32(576)))
-    h1 = _compress(w)
+    def signed(word):
+        return tuple(x.astype(I32) for x in word)
 
-    w2 = list(h1)
-    w2.append((jnp.uint32(0x80000000), zero))
-    w2 += [(zero, zero)] * 6
-    w2.append((zero, jnp.uint32(512)))
-    h2 = _compress(w2)
-    return h2[0]
+    w = [signed((n_hi, n_lo))] + [signed(ih_pair(i)) for i in range(8)]
+    w += [_word(1 << 63)] + [_word(0)] * 5 + [_word(576)]
+    h1 = _compress(w)
+    w2 = h1 + [_word(1 << 63)] + [_word(0)] * 6 + [_word(512)]
+    return tuple(x.astype(U32) for x in _compress(w2)[0])
 
 
 def _search_step(ih_pair, base_hi, base_lo, target_hi, target_lo,
@@ -159,50 +277,45 @@ def _search_step(ih_pair, base_hi, base_lo, target_hi, target_lo,
     """One grid step's search over a (rows, 128) nonce tile.
 
     ``ih_pair(i) -> (hi, lo)`` abstracts the initial-hash indexing so
-    the single-object and batched kernels share this body exactly.
-    Returns (hit int32, nonce_hi, nonce_lo).
+    the single-object and batched kernels share this body exactly; both
+    pass their ``unroll`` consecutive tiles as one of ``unroll`` times
+    the rows, whose lowest winning lane is the first tile's winner if it
+    has one.  Returns (hit int32, nonce_hi, nonce_lo).
+
+    The tile is hashed one (8, 128) slice at a time -- one vreg a value
+    -- in a loop: the 48 live halves of one double-SHA-512 then stay in
+    the 64 vregs, where a whole (128, 128) tile a value made the
+    compiler spill a quarter of all it computed (PR 26, final bundles
+    of the batch kernel: 351,067 spill stores beside 1,541,974 vector
+    operations a grid step, one store slot a bundle).
     """
-    shape = (rows, LANE_COLS)
-    lane = (jax.lax.broadcasted_iota(U32, shape, 0)
-            * jnp.uint32(LANE_COLS)
-            + jax.lax.broadcasted_iota(U32, shape, 1))
+    slice_rows = math.gcd(rows, SLICE_ROWS)
+    shape = (slice_rows, LANE_COLS)
+    lane0 = (jax.lax.broadcasted_iota(U32, shape, 0)
+             * jnp.uint32(LANE_COLS)
+             + jax.lax.broadcasted_iota(U32, shape, 1))
     offset = jnp.uint32(step) * jnp.uint32(rows * LANE_COLS)
-    lo = base_lo + offset + lane
-    carry = (lo < base_lo).astype(U32)  # offset+lane < 2^32 per slab
-    hi = jnp.broadcast_to(base_hi, shape) + carry
+    big = jnp.int32(0x7FFFFFFF)
 
-    v_hi, v_lo = _double_sha512_tile(ih_pair, hi, lo)
+    def hash_slice(i, best):
+        lane = lane0 + jnp.uint32(i) * jnp.uint32(slice_rows * LANE_COLS)
+        lo = base_lo + offset + lane
+        carry = (lo < base_lo).astype(U32)  # offset+lane < 2^32 per slab
+        hi = jnp.broadcast_to(base_hi, shape) + carry
+        v_hi, v_lo = _double_sha512_tile(ih_pair, hi, lo)
+        ok = (v_hi < target_hi) | ((v_hi == target_hi)
+                                   & (v_lo <= target_lo))
+        return jnp.minimum(best, jnp.where(ok, lane.astype(jnp.int32), big))
 
-    ok = (v_hi < target_hi) | ((v_hi == target_hi) & (v_lo <= target_lo))
     # winner = smallest lane index with a hit.  Mosaic has no unsigned
     # reductions; lane < 2^31 so int32 min is safe.
-    big = jnp.int32(0x7FFFFFFF)
-    win_i = jnp.min(jnp.where(ok, lane.astype(jnp.int32), big))
+    win_i = jnp.min(jax.lax.fori_loop(0, rows // slice_rows, hash_slice,
+                                      jnp.full(shape, big, jnp.int32)))
     hit = (win_i != big).astype(jnp.int32)
     win = win_i.astype(U32)
     wl = base_lo + offset + win
     wc = (wl < base_lo).astype(U32)
     return hit, base_hi + wc, wl
-
-
-def _unrolled_search(ih_pair, base_hi, base_lo, t_hi, t_lo, step,
-                     rows: int, unroll: int):
-    """``unroll`` independent (rows, 128) tiles for one grid step.
-
-    The 160-round chains are dependency-limited, so interleaving
-    independent instruction streams lets the VPU multi-issue (the MFU
-    lever, BASELINE.md "Arithmetic utilization").  Keeps the FIRST
-    sub-tile's winner (lowest nonce range).  Shared by the single and
-    batch kernels."""
-    hit, n_hi, n_lo = _search_step(ih_pair, base_hi, base_lo, t_hi, t_lo,
-                                   step * unroll, rows)
-    for u in range(1, unroll):
-        h2, nh2, nl2 = _search_step(ih_pair, base_hi, base_lo, t_hi, t_lo,
-                                    step * unroll + u, rows)
-        n_hi = jnp.where(hit == 1, n_hi, nh2)
-        n_lo = jnp.where(hit == 1, n_lo, nl2)
-        hit = jnp.maximum(hit, h2)
-    return hit, n_hi, n_lo
 
 
 def _kernel(ih_ref, base_ref, target_ref, found_ref, nonce_ref, flag_ref, *,
@@ -221,10 +334,10 @@ def _kernel(ih_ref, base_ref, target_ref, found_ref, nonce_ref, flag_ref, *,
 
     @pl.when(flag_ref[0] == 0)
     def do_search():
-        hit, n_hi, n_lo = _unrolled_search(
+        hit, n_hi, n_lo = _search_step(
             lambda i: (ih_ref[i, 0], ih_ref[i, 1]),
             base_ref[0], base_ref[1], target_ref[0], target_ref[1],
-            step, rows, unroll)
+            step, rows * unroll)
         found_ref[step, 0] = hit
         flag_ref[0] = hit
         nonce_ref[step, 0] = n_hi
@@ -237,9 +350,8 @@ def _batch_kernel(ih_ref, base_ref, target_ref, out_ref, flag_ref,
     exit flag, so easy objects stop costing compute while hard ones
     keep searching — the single-chip form of the (objects x
     nonce-lanes) batch design (SURVEY §6).  The search body is shared
-    with the single-object kernel (_search_step), including its
-    ``unroll`` independent instruction streams per grid step (the ILP
-    lever that lifted the single kernel 1.75x — BASELINE.md).
+    with the single-object kernel (_search_step), ``unroll`` tiles to a
+    grid step.
 
     Output is written ONCE per object, on its hit step: a (B, 3) u32
     row ``[hit_step + 1, nonce_hi, nonce_lo]`` (0 = not found).  r3's
@@ -259,10 +371,10 @@ def _batch_kernel(ih_ref, base_ref, target_ref, out_ref, flag_ref,
 
     @pl.when(flag_ref[obj] == 0)
     def do_search():
-        hit, n_hi, n_lo = _unrolled_search(
+        hit, n_hi, n_lo = _search_step(
             lambda i: (ih_ref[obj, i, 0], ih_ref[obj, i, 1]),
             base_ref[obj, 0], base_ref[obj, 1],
-            target_ref[obj, 0], target_ref[obj, 1], step, rows, unroll)
+            target_ref[obj, 0], target_ref[obj, 1], step, rows * unroll)
         flag_ref[obj] = hit
 
         @pl.when(hit == 1)
@@ -474,19 +586,16 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
 #: launches win the storm — 256-object test-difficulty storm ~300
 #: obj/s at 32-wide (8 launches) vs ~500 obj/s at 64-wide (4 launches,
 #: ~0.12 s each); at ~2^44 difficulty (every object searching ~1M
-#: trials) a 64-wide launch runs 0.45 s warm.  Mosaic compile for the
-#: 64-wide grid takes minutes (CHANGES.md PR 22 has the measured
-#: times); core/jaxsetup.py places the persistent cache that keeps it
-#: a once-per-machine cost.
+#: trials) a 64-wide launch runs 0.45 s warm.  Mosaic compiled the
+#: 64-wide grid in minutes until PR 26 (CHANGES.md PR 22 has those
+#: times) and in seconds since; core/jaxsetup.py places the persistent
+#: cache that keeps it a once-per-machine cost.
 BATCH_OBJS = 64
 BATCH_CHUNKS = 64
-#: the batch grid keeps the unroll-4 configuration (64 objects x 64
-#: chunks x 4 streams compiled + solve-verified on-chip r4); the storm
-#: is launch-overhead-bound, not VPU-bound, so the single kernel's
-#: unroll-5 knee doesn't transfer.  r5 measured the u5 batch grid
-#: anyway: storm 541 vs 531 obj/s (noise) and ~+5% on the
-#: real-difficulty batch, for +70 s Mosaic compile (142 -> 213 s) —
-#: below the knee, not worth the driver-bench wall time
+#: four tiles to a grid step of the batch grid (64 objects x 64 chunks
+#: x 4, solve-verified on-chip since r4).  Since PR 26 this sets only
+#: how often an object can leave at its hit, not the kernel's streams
+#: or its compile time (8 s for what took 190 s)
 BATCH_UNROLL = 4
 
 
@@ -709,9 +818,7 @@ def solve(initial_hash: bytes, target: int, *,
     unroll`` trials so the shutdown callback stays responsive
     (reference host loop: src/openclpow.py:96-107), and keeps one slab
     in flight ahead of the one being harvested so dispatch and
-    host-transfer gaps hide behind device compute.  The r3 production
-    slab (128 x 512 x 4) measures 136.4 MH/s — see BASELINE.md
-    "Arithmetic utilization" for the unroll ladder.  Trials are
+    host-transfer gaps hide behind device compute.  Trials are
     accounted at slab granularity.
     """
     import numpy as np
