@@ -123,30 +123,6 @@ def platform_ok(devices, chips: int) -> str | None:
 # ---------------------------------------------------------------------------
 
 
-class _PinnedTuner:
-    """Keeps every slab at the production constants for the smoke.
-
-    ``chunks`` is a static argument of the Mosaic kernels, so each chunk
-    count the autotuner suggests is a fresh minutes-long compile; the
-    smoke launches the shapes the code was meant to have (DEFAULT_*,
-    BATCH_*) and PRINTS what the real tuner would have asked for.
-    """
-
-    def __init__(self, real):
-        self.real = real
-        self.wanted: dict[tuple, int] = {}
-
-    def record(self, kind, units, seconds):
-        self.real.record(kind, units, seconds)
-
-    def suggest(self, kind, default, lo=None, hi=None, groups=1):
-        want = self.real.suggest(kind, default, lo=lo, hi=hi,
-                                 groups=groups)
-        if want != default:
-            self.wanted[(kind, default)] = want
-        return default
-
-
 def _family(name: str) -> dict[tuple, float]:
     from pybitmessage_tpu.observability import REGISTRY
     fam = REGISTRY.get(name)
@@ -487,9 +463,6 @@ def native_libraries(prebuilt: dict) -> str:
 
 
 async def run_one_chip(rep: Report, pair_factory=None) -> None:
-    from pybitmessage_tpu.pow import pipeline
-    tuner = _PinnedTuner(pipeline.AUTOTUNER)
-    pipeline.AUTOTUNER = tuner
     pair = None
     try:
         t0 = time.monotonic()
@@ -506,12 +479,7 @@ async def run_one_chip(rep: Report, pair_factory=None) -> None:
             rep.say("%s: %.1fs wall" % (phase.__name__,
                                         time.monotonic() - t0))
         check_no_hidden_fallback(rep, pair)
-        for (kind, default), want in sorted(tuner.wanted.items()):
-            rep.say("autotuner: would have moved %r from %d to %d "
-                    "chunks — a fresh Mosaic compile on a live node "
-                    "(pinned here)" % (kind, default, want))
     finally:
-        pipeline.AUTOTUNER = tuner.real
         if pair is not None:
             await pair[1].stop()
             await pair[0].stop()

@@ -53,7 +53,7 @@ class PowInterrupted(Exception):
 def _run_host_driver(search_once, initial_hash: bytes, target: int, *,
                      start_nonce: int, trials_per_call_step: int,
                      should_stop: Callable[[], bool] | None,
-                     on_slab: Callable[[float], None] | None = None,
+                     on_slab: Callable[[int, float], None] | None = None,
                      progress: Callable[[int], None] | None = None,
                      program: str = "pow_slab", program_key=None,
                      devices: int = 1):
@@ -61,8 +61,9 @@ def _run_host_driver(search_once, initial_hash: bytes, target: int, *,
 
     ``search_once(b_hi, b_lo) -> (found, n_hi, n_lo, chunks)``;
     ``trials_per_call_step`` = trials represented by one chunk across
-    all participating devices.  ``on_slab`` (if given) receives each
-    slab's measured wall seconds — the autotuner's latency feedback.
+    all participating devices.  ``on_slab`` (if given) receives the
+    chunks each slab really ran (it leaves at its first hit) and its
+    measured wall seconds — the autotuner's latency feedback.
     ``progress`` (if given) receives the next base after every
     miss-free slab — the resumable-PoW checkpoint hook.  Re-verifies
     the winning nonce with hashlib before returning, guarding against
@@ -92,7 +93,7 @@ def _run_host_driver(search_once, initial_hash: bytes, target: int, *,
                       items=chunks * trials_per_call_step,
                       bytes_out=16, devices=devices)
         if on_slab is not None:
-            on_slab(t2 - t0)
+            on_slab(chunks, t2 - t0)
         trials += chunks * trials_per_call_step
         if bool(found):
             nonce = u64_to_int(n_hi, n_lo)
@@ -185,7 +186,7 @@ def solve(initial_hash: bytes, target: int, *,
 
     on_slab = None
     if tuner is not None:
-        on_slab = lambda dt: tuner.record(tuner_kind, chunks, dt)  # noqa: E731
+        on_slab = functools.partial(tuner.record, tuner_kind)
 
     return _run_host_driver(
         search_once, initial_hash, target, start_nonce=start_nonce,

@@ -808,8 +808,7 @@ def solve(initial_hash: bytes, target: int, *,
           start_nonce: int = 0, rows: int = DEFAULT_ROWS,
           chunks_per_call: int = DEFAULT_CHUNKS,
           unroll: int = DEFAULT_UNROLL, should_stop=None,
-          interpret: bool = False, tuner=None,
-          tuner_kind: str = "pallas_single", progress=None):
+          interpret: bool = False, progress=None):
     """Find a nonce whose trial value is <= target (Pallas backend).
 
     Same contract as :func:`pow_search.solve`: returns
@@ -819,7 +818,12 @@ def solve(initial_hash: bytes, target: int, *,
     (reference host loop: src/openclpow.py:96-107), and keeps one slab
     in flight ahead of the one being harvested so dispatch and
     host-transfer gaps hide behind device compute.  Trials are
-    accounted at slab granularity.
+    accounted at slab granularity.  ``chunks_per_call`` is a static
+    argument of the kernel and every caller on a node passes the same
+    one: 512 is the largest power of two a v5e compiles (1024 asks for
+    1.01M of its 1.00M of SMEM; tests/test_tpu_compile.py), and the
+    grid leaves at its first hit, so a long slab costs a short solve
+    nothing.
     """
     import numpy as np
 
@@ -838,12 +842,6 @@ def solve(initial_hash: bytes, target: int, *,
     target_arr = jnp.array([target >> 32, target & 0xFFFFFFFF], dtype=U32)
 
     chunks = chunks_per_call
-    if tuner is not None:
-        # measured-latency slab sizing; the octave bound keeps Mosaic
-        # recompiles (one per distinct chunk count) rare
-        chunks = tuner.suggest(tuner_kind, chunks_per_call,
-                               lo=chunks_per_call // 2,
-                               hi=chunks_per_call * 2)
     trials_per_slab = rows * LANE_COLS * chunks * unroll
     mask64 = (1 << 64) - 1
 
@@ -889,8 +887,6 @@ def solve(initial_hash: bytes, target: int, *,
     # Double-buffered host loop: slab N+1 is dispatched BEFORE slab N's
     # results are pulled, so the host-side transfer/bookkeeping gap
     # hides behind device compute on long (multi-slab) searches.
-    import time as _time
-
     base = start_nonce & mask64
     trials = 0
     # ((found_dev, nonce_dev), dispatch_start, dispatch_end, end_base)
@@ -913,11 +909,6 @@ def solve(initial_hash: bytes, target: int, *,
         if pending is not None:
             trials += trials_per_slab
             nonce = harvest(*pending[0], pending[1], pending[2])
-            if tuner is not None:
-                # dispatch -> harvested wall of the pending slab: the
-                # cadence the autotuner steers toward target_seconds
-                tuner.record(tuner_kind, chunks,
-                             _time.monotonic() - pending[2])
             if nonce is not None:
                 # the slab just dispatched is left behind unfetched
                 ABANDONED_LAUNCHES.labels(kind="slab").inc()

@@ -539,19 +539,20 @@ class PowDispatcher:
                     tb.record_success()
                     return result
                 if self._on_accelerator() and pb.allow():
-                    # Mosaic kernel: ~3.3x the XLA path on a v5e chip
-                    # (84.6 vs 25.8 MH/s, BASELINE.md) — the fastest
-                    # usable backend leads the ladder, reference
-                    # proofofwork.py:288-325 / openclpow wiring
+                    # Mosaic kernel: 290.6 MH/s on a v5e chip
+                    # (kernel_mhash_per_s.slab of a traced single_send
+                    # run, PERF.md section 5, PR 27; the XLA path below
+                    # it was 25.8 MH/s in BASELINE.md and has no cell)
+                    # — the fastest usable backend leads the ladder,
+                    # reference proofofwork.py:288-325 / openclpow
+                    # wiring.  One static shape: see sha512_pallas.solve
                     try:
                         from ..ops.sha512_pallas import solve as pl_solve
-                        from .pipeline import AUTOTUNER
                         self.last_backend = "tpu-pallas"
                         ATTEMPTS.labels(backend=self.last_backend).inc()
                         result = pl_solve(initial_hash, target,
                                           start_nonce=start_nonce,
                                           should_stop=should_stop,
-                                          tuner=AUTOTUNER,
                                           progress=progress)
                         pb.record_success()
                         tb.record_success()
@@ -569,8 +570,9 @@ class PowDispatcher:
                 kwargs = self._xla_kwargs()
                 if not self.tpu_kwargs:
                     # no explicit powlanes/powchunks override: let the
-                    # measured-latency autotuner size the slab instead
-                    # of the hardcoded 2^19 x 64 constant
+                    # measured-latency autotuner size the XLA slab
+                    # (a shape is cheap here; the Mosaic tiers above
+                    # have one static shape each and never ask it)
                     from .pipeline import AUTOTUNER
                     kwargs = dict(kwargs, tuner=AUTOTUNER)
                 result = tpu_solve(initial_hash, target,

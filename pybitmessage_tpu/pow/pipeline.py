@@ -14,10 +14,13 @@ giving back most of the kernel's gains.  Three levers close the gap:
    slab N+1 is issued before slab N's hit flags are read back, hiding
    host verification/serialization behind device compute (the
    sync-slab penalty: 136.6M vs 202.9M H/s).
-3. **Early-exit cadence autotuning** (:class:`SlabAutotuner`): slab
-   size (chunks per launch) is derived from *measured* slab latency so
-   the shutdown-poll interval stays near a target regardless of
-   hardware, instead of the hardcoded 2^19 x 64 constant.
+3. **One static shape a kernel.**  ``chunks`` is a static argument of
+   every Mosaic kernel, so each value is a program of its own to trace,
+   lower and compile, and one the chip may refuse (``pallas_search``
+   at 1024 chunks needs more SMEM than a v5e has).  The planner hands
+   each kernel the one chunk count measured on the chip (PERF.md
+   section 6, PR 27); :class:`SlabAutotuner` sizes the XLA tier's
+   slabs only (``ops.pow_search.solve``), where a shape is cheap.
 
 The planner (:func:`plan_batch`) chooses per batch between the packed
 kernel (many small objects), the per-object batch kernel (few large
@@ -94,11 +97,22 @@ PIPELINE_MODE = REGISTRY.counter(
     "Pipelined solve launches by execution mode", ("mode",))
 SLAB_SECONDS = REGISTRY.histogram(
     "pow_slab_seconds",
-    "Wall latency of one device slab launch as seen by the pipeline "
-    "(dispatch to harvested) — the autotuner's input", ("kind",))
+    "Wall latency of one XLA-tier slab (dispatch to harvested) — the "
+    "autotuner's input, with pow_autotune_steps_total", ("kind",))
 AUTOTUNE_CHUNKS = REGISTRY.gauge(
     "pow_slab_autotune_chunks",
     "Chunks-per-launch the autotuner currently suggests", ("kind",))
+AUTOTUNE_STEPS = REGISTRY.counter(
+    "pow_autotune_steps_total",
+    "Grid steps fed to the autotuner with their seconds: the steps a "
+    "slab really ran, not the steps it was launched with", ("kind",))
+AUTOTUNE_SHAPE_CHANGES = REGISTRY.counter(
+    "pow_autotune_shape_changes_total",
+    "Times the autotuner asked a kind for another chunk count than "
+    "the last time (each is a program to compile)", ("kind",))
+# the one kind that asks exists at 0 from the start: "no change" then
+# reads 0, not "no such series"
+AUTOTUNE_SHAPE_CHANGES.labels(kind="xla")
 LAUNCHES = REGISTRY.counter(
     "pow_pipeline_launches_total",
     "Search-kernel launches dispatched by the PoW host loops, by kind "
@@ -115,19 +129,23 @@ EXECUTED_TRIALS = REGISTRY.counter(
 
 
 class SlabAutotuner:
-    """Derives slab size from measured latency (early-exit cadence).
+    """Derives the XLA tier's slab size from measured latency.
 
-    Tracks an EWMA of seconds-per-grid-step per slab ``kind``
-    (``record`` takes the launch's TOTAL grid steps — chunks times
-    groups — so a 64-group packed storm launch and a 1-group
-    single-sync launch feed the same normalized signal) and suggests a
-    power-of-two chunk count whose expected slab latency is closest to
+    Tracks an EWMA of seconds per grid step per slab ``kind`` and
+    suggests a power-of-two chunk count, within the bounds its caller
+    allows, whose expected slab latency is closest to
     ``target_seconds`` — the hit-poll / shutdown-poll granularity.
-    Power-of-two quantization bounds the number of distinct compiled
-    shapes; the EWMA plus a 10x outlier clamp make one slow
-    observation (a fresh jit compile, a host stall) decay instead of
-    permanently shrinking slabs.  Thread-safe: the dispatcher's
-    executor and the asyncio service may solve concurrently.
+    ``record`` takes the steps the slab really ran: a search leaves its
+    slab at the first hit, and a slab timed as if it had run whole
+    reads as a fast device (PR 24 on the chip: the tuner then asked
+    ``pallas_search`` for a shape the v5e cannot compile, and the
+    breaker took every solve off the chip).  The Mosaic kernels do
+    not come here: each has one measured shape (see :func:`plan_batch`
+    and ``ops.sha512_pallas.solve``).  The EWMA plus a 10x outlier
+    clamp make one slow observation (a fresh jit compile, a host
+    stall) decay instead of permanently shrinking slabs.  Thread-safe:
+    the dispatcher's executor and the asyncio service may solve
+    concurrently.
     """
 
     def __init__(self, *, target_seconds: float = 0.5,
@@ -138,17 +156,15 @@ class SlabAutotuner:
         self.max_chunks = max_chunks
         self.alpha = alpha
         self._per_chunk: dict[str, float] = {}
+        self._asked: dict[str, int] = {}
         self._lock = threading.Lock()
 
-    def record(self, kind: str, units: int, seconds: float) -> None:
-        """Feed one measured slab (dispatch->harvest wall seconds).
-
-        ``units``: total grid steps of the launch (chunks x groups for
-        the grouped kernels, plain chunks for single-grid slabs).
-        """
-        if units <= 0 or seconds <= 0:
+    def record(self, kind: str, steps: int, seconds: float) -> None:
+        """Feed one measured slab: the grid steps it really ran (up to
+        its first hit) and its dispatch->harvest wall seconds."""
+        if steps <= 0 or seconds <= 0:
             return
-        per = seconds / units
+        per = seconds / steps
         with self._lock:
             prev = self._per_chunk.get(kind)
             if prev is not None and per > 10 * prev:
@@ -157,27 +173,25 @@ class SlabAutotuner:
                 per = 10 * prev
             self._per_chunk[kind] = per if prev is None else (
                 self.alpha * per + (1 - self.alpha) * prev)
+        AUTOTUNE_STEPS.labels(kind=kind).inc(steps)
         SLAB_SECONDS.labels(kind=kind).observe(seconds)
 
     def suggest(self, kind: str, default: int,
-                lo: int | None = None, hi: int | None = None,
-                groups: int = 1) -> int:
-        """Chunk count targeting ``target_seconds`` per slab of
-        ``groups`` grid groups.
-
-        ``lo``/``hi`` narrow the ladder per call site — Mosaic kernels
-        pass tight bounds because every new chunk count is a fresh
-        (expensive) compile, while the XLA tier can roam a wider
-        range.
-        """
+                lo: int | None = None, hi: int | None = None) -> int:
+        """Chunk count within ``[lo, hi]`` targeting ``target_seconds``
+        per slab; ``default`` until a slab of ``kind`` was recorded."""
         with self._lock:
             per = self._per_chunk.get(kind)
-        if per is None or per <= 0:
-            return default
-        raw = self.target_seconds / (per * max(groups, 1))
-        chunks = 1 << max(0, round(math.log2(max(raw, 1.0))))
-        chunks = max(lo or self.min_chunks,
-                     min(hi or self.max_chunks, chunks))
+            chunks = default
+            if per is not None and per > 0:
+                raw = self.target_seconds / per
+                chunks = 1 << max(0, round(math.log2(max(raw, 1.0))))
+                chunks = max(lo or self.min_chunks,
+                             min(hi or self.max_chunks, chunks))
+            changed = self._asked.get(kind, default) != chunks
+            self._asked[kind] = chunks
+        if changed:
+            AUTOTUNE_SHAPE_CHANGES.labels(kind=kind).inc()
         AUTOTUNE_CHUNKS.labels(kind=kind).set(chunks)
         return chunks
 
@@ -187,7 +201,7 @@ class SlabAutotuner:
             return self._per_chunk.get(kind)
 
 
-#: process-wide autotuner — solve paths share latency knowledge
+#: process-wide autotuner of the XLA tier (``PowDispatcher._solve``)
 AUTOTUNER = SlabAutotuner()
 
 
@@ -257,11 +271,15 @@ register_program("packed_search_xla", flops_per_item=POW_FLOPS_PER_HASH,
 #: pack-factor ladder: rows//pack stays >= 8 (one VPU sublane) at the
 #: production row count
 PACK_CHOICES = (16, 8, 4, 2)
-#: chunk budget of one packed launch before autotuning kicks in; at
-#: pack=16 that is 8*128*chunks trials per object per launch
+#: grid steps of one packed launch; at pack=16 that is 8*128*chunks
+#: trials per object per launch
 DEFAULT_PACKED_CHUNKS = 64
-#: per-object batch geometry (mirrors sha512_pallas.BATCH_*)
-DEFAULT_BATCH_CHUNKS = 64
+#: grid steps an object of the per-object batch kernel: the shape every
+#: chip run of PR 24-26 settled at under the tuner (64 -> 128 in the
+#: first sweep of a 256-object storm), now the only one.  An object
+#: leaves at its hit, so a longer grid costs no trials, only fewer
+#: launches
+DEFAULT_BATCH_CHUNKS = 128
 #: leading-grid-axis cap of one packed launch: up to 64 tiles *
 #: pack objects ride one kernel call (the storm's launch-overhead
 #: amortization); group counts round up to powers of two so the
@@ -289,8 +307,8 @@ class BatchPlan:
                 % (self.mode, self.pack, self.chunks, len(self.order)))
 
 
-def plan_batch(items, *, rows: int = DEFAULT_ROWS, unroll: int = 1,
-               autotuner: SlabAutotuner | None = None) -> BatchPlan:
+def plan_batch(items, *, rows: int = DEFAULT_ROWS,
+               unroll: int = 1) -> BatchPlan:
     """Choose packing and slab geometry from the batch's difficulty.
 
     The pack factor is sized so one launch covers roughly every
@@ -299,45 +317,24 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS, unroll: int = 1,
     batch kernel), and a single small object degenerates to one
     synchronous latency-optimal launch.  Objects are difficulty-sorted
     so each packed group is homogeneous (a straggler would otherwise
-    hold its whole group's rows live).
+    hold its whole group's rows live).  Each mode has ONE chunk count,
+    so a node compiles each kernel once and launches nothing the chip
+    has not already accepted.
     """
-    autotuner = autotuner or AUTOTUNER
     n = len(items)
     exp = [expected_trials(t) for _, t in items]
     tile_step = rows * LANE_COLS * unroll      # full-tile trials/step
     if n == 1 and exp[0] <= SYNC_SINGLE_STEPS * tile_step:
-        chunks = autotuner.suggest("packed", SYNC_SINGLE_STEPS,
-                                   lo=4, hi=SYNC_SINGLE_STEPS, groups=1)
-        return BatchPlan("single-sync", 1, chunks, [0])
+        return BatchPlan("single-sync", 1, SYNC_SINGLE_STEPS, [0])
     order = sorted(range(n), key=lambda i: exp[i])
     med = sorted(exp)[n // 2]
-    # tight chunk ladder: every new chunk count is a fresh Mosaic
-    # compile, so the autotuner only moves within one octave up/down.
-    # groups estimated at the max pack factor (the common packed case)
-    # so the per-grid-step EWMA scales to this launch's width
-    est_groups = _pow2_at_least(-(-n // PACK_CHOICES[0]),
-                                PACKED_GROUPS_MAX)
-    chunks = autotuner.suggest("packed", DEFAULT_PACKED_CHUNKS,
-                               lo=DEFAULT_PACKED_CHUNKS // 2,
-                               hi=DEFAULT_PACKED_CHUNKS * 2,
-                               groups=est_groups)
-    pack = 1
     for p in PACK_CHOICES:
         # with pack p each object gets chunks*(rows/p)*128*unroll
         # trials per launch; take the largest p that still covers the
         # median object's expected work in ~one launch
-        if p <= n and med * p <= chunks * tile_step:
-            pack = p
-            break
-    if pack == 1:
-        from ..ops.sha512_pallas import BATCH_OBJS
-        return BatchPlan(
-            "batched", 1,
-            autotuner.suggest("batch", DEFAULT_BATCH_CHUNKS,
-                              lo=DEFAULT_BATCH_CHUNKS // 2,
-                              hi=DEFAULT_BATCH_CHUNKS * 2,
-                              groups=BATCH_OBJS), order)
-    return BatchPlan("packed", pack, chunks, order)
+        if p <= n and med * p <= DEFAULT_PACKED_CHUNKS * tile_step:
+            return BatchPlan("packed", p, DEFAULT_PACKED_CHUNKS, order)
+    return BatchPlan("batched", 1, DEFAULT_BATCH_CHUNKS, order)
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +534,6 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           unroll: int = 1, depth: int = 2,
                           impl: str | None = None,
                           interpret: bool = False,
-                          autotuner: SlabAutotuner | None = None,
                           plan: BatchPlan | None = None,
                           stats: dict | None = None,
                           should_stop: Callable[[], bool] | None = None,
@@ -575,11 +571,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         return []
     if impl is None:
         impl = default_impl()
-    autotuner = autotuner or AUTOTUNER
     if plan is None:
         with trace("pow.plan", objects=n) as span:
-            plan = plan_batch(items, rows=rows, unroll=unroll,
-                              autotuner=autotuner)
+            plan = plan_batch(items, rows=rows, unroll=unroll)
             span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
 
@@ -587,7 +581,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         return [_solve_single_sync(
             items[0], rows=rows, unroll=unroll,
             chunks=plan.chunks, impl=impl, interpret=interpret,
-            autotuner=autotuner, should_stop=should_stop,
+            should_stop=should_stop,
             start_nonce=(start_nonces[0] if start_nonces else 0),
             progress=(None if progress is None
                       else (lambda nxt: progress(0, nxt))))]
@@ -702,9 +696,6 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     def _harvest(tag, out, t_h):
         g, t0, t1, end_bases = tag
         inflight_groups.discard(id(g))
-        # normalize by the launch's total grid steps so storm-wide and
-        # narrow launches feed one per-step EWMA
-        autotuner.record(kind, plan.chunks * (g.width // pack), t_h - t0)
         before = executed["trials"]
         _record_pipeline_launch = functools.partial(
             record_launch, tele_prog, key=tele_key,
@@ -769,7 +760,6 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
 
 def _solve_single_sync(item, *, rows: int, unroll: int, chunks: int,
                        impl: str, interpret: bool,
-                       autotuner: SlabAutotuner,
                        should_stop: Callable[[], bool] | None,
                        start_nonce: int = 0, progress=None):
     """Latency-optimal degenerate path: one object, small synchronous
@@ -813,7 +803,6 @@ def _solve_single_sync(item, *, rows: int, unroll: int, chunks: int,
             inject("pow.readback")
             out = np.asarray(out)
         t0, t1, t2 = launch.start, launch.end, fetch.end
-        autotuner.record("packed", chunks, t2 - t0)
         if impl == "pallas":
             record_launch("packed_search",
                           key=(rows, chunks, 1, unroll, interpret),

@@ -9,7 +9,13 @@ in flight becomes the next batch, so batching emerges from load with
 ZERO added latency (``window`` stays 0 in production — a sleep there
 would serialize each connection's read loop against it).  Small
 batches skip the device — two short SHA-512s on the host beat a
-device round-trip for a single object (``ops.pow_search.verify``).
+device round-trip (``ops.pow_search.verify``), and every batch size
+(padded to a power of two) is a program of its own to trace, lower and
+compile when it is first seen.  On a v5e the handful of acks a
+one-at-a-time sender gets back in one announcement tick, sent to the
+device, cost twelve lowerings inside a 51 s window and milliseconds of
+device time for microseconds of hashing (PERF.md section 6, PR 27), so
+the floor is that of the crypto rung (``cryptotpubatchmin``): 64.
 """
 
 from __future__ import annotations
@@ -65,7 +71,7 @@ class BatchVerifier:
 
     def __init__(self, *, ntpb: int = 0, extra: int = 0,
                  clamp: bool = True, window: float = 0.0,
-                 min_device_batch: int = 4,
+                 min_device_batch: int = 64,
                  use_device: "bool | str" = "auto"):
         # Normalize 0 -> network defaults so the device path
         # (pow_target) and the host path (check_pow, which substitutes

@@ -113,6 +113,8 @@ def rehearsal(monkeypatch):
             for part in (node.sender, node.processor):
                 part.min_ntpb = part.min_extra = 1
             node.pow_verifier.ntpb = node.pow_verifier.extra = 1
+            # the burst is cut to VERIFY_BURST = 8 here
+            node.pow_verifier.min_device_batch = 8
         for ident in pair[2:]:
             ident.nonce_trials_per_byte = ident.extra_bytes = 1
         return pair

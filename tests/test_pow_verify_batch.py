@@ -109,6 +109,7 @@ async def test_flood_sync_uses_device_batches():
     # on host hashlib on the CPU mesh (docs/ingest.md), but this
     # test proves flood arrivals COALESCE into device batches
     node_b.pow_verifier.use_device = True
+    node_b.pow_verifier.min_device_batch = 4    # a flood of 30, not 64
     await node_a.start()
     await node_b.start()
     try:

@@ -100,6 +100,34 @@ def test_pallas_batch_search_compiles(one_chip, production):
     assert _has_kernel(compiled)
 
 
+def test_the_shape_the_pipeline_launches_compiles(one_chip):
+    """``plan_batch`` hands the batch kernel 128 grid steps an object
+    (pow/pipeline.py, ``DEFAULT_BATCH_CHUNKS``), not ``BATCH_CHUNKS``."""
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+    from pybitmessage_tpu.pow.pipeline import DEFAULT_BATCH_CHUNKS
+    n = sp.BATCH_OBJS
+    compiled = sp.pallas_batch_search.lower(
+        _u32((n, 8, 2), one_chip), _u32((n, 2), one_chip),
+        _u32((n, 2), one_chip), rows=sp.DEFAULT_ROWS,
+        chunks=DEFAULT_BATCH_CHUNKS, unroll=sp.BATCH_UNROLL).compile()
+    assert _has_kernel(compiled)
+
+
+def test_pallas_search_has_no_larger_shape_on_a_v5e(one_chip):
+    """Twice ``DEFAULT_CHUNKS`` is what PR 24's autotuner asked for at
+    a node's second single solve: the chip's compiler refuses it (its
+    per-step outputs no longer fit SMEM), which is why ``solve`` has
+    one shape and nothing may ask for another."""
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+    with pytest.raises(Exception) as refused:
+        sp.pallas_search.lower(
+            _u32((8, 2), one_chip), _u32((2,), one_chip),
+            _u32((2,), one_chip), rows=sp.DEFAULT_ROWS,
+            chunks=2 * sp.DEFAULT_CHUNKS,
+            unroll=sp.DEFAULT_UNROLL).compile()
+    assert "smem" in str(refused.value).lower()
+
+
 def test_pallas_packed_search_compiles(one_chip):
     from pybitmessage_tpu.ops import sha512_pallas as sp
     n = sp.BATCH_OBJS
