@@ -84,8 +84,12 @@ DEFAULTS: dict[str, str] = {
     "smtpdpassword": "",
     "powlanes": "131072",            # TPU search lanes per chunk
     "powchunks": "32",               # chunks per jitted call
-    "powbatchwindow": "0.05",        # PoW coalescing window, seconds
-                                     # (0 = launch immediately)
+    "powbatchwindow": "0.05",        # PoW coalescing window, seconds:
+                                     # the LONGEST a request waits for
+                                     # the rest of its send sweep; a
+                                     # request with no announced
+                                     # company is not held (0 = never
+                                     # wait, launch immediately)
     # -- ingest fast path (docs/ingest.md) --
     "ingestworkers": "8",            # concurrent objects in the
                                      # processor pipeline

@@ -8,10 +8,18 @@ batch goes through :meth:`PowDispatcher.solve_batch` — objects
 data-parallel over the mesh's object axis, each nonce range partitioned
 over the remaining chips (SURVEY §6: grid = nonce-lanes x objects).
 
-A single queued object never waits more than ``window`` seconds (the
-latency/batching tradeoff called out in SURVEY §7: dynamic batch
-assembly with padding, no recompilation per batch size thanks to the
-object-axis padding in ``sharded_solve_batch``).
+``window`` is the LONGEST a queued object waits for company, not a
+fixed delay (the latency/batching tradeoff called out in SURVEY §7:
+dynamic batch assembly with padding, no recompilation per batch size
+thanks to the object-axis padding in ``sharded_solve_batch``).  A send
+sweep announces its member tasks (:meth:`PowService.announce`) before
+any of them runs; a member is *outstanding* while it is announced and
+not blocked inside :meth:`PowService.solve`, and is withdrawn when its
+task ends, however it ends.  The window closes as soon as nobody
+outstanding is missing, or after ``window`` seconds, whichever is
+first: a lone send's ack and message are each dispatched on arrival,
+a sweep of 256 when its last member arrives.  A request with no
+announced company is not held at all.
 
 Resilience (ISSUE 3, docs/resilience.md):
 
@@ -33,6 +41,7 @@ import contextvars
 import itertools
 import logging
 import time
+from collections.abc import Iterable
 from dataclasses import dataclass, field
 
 from ..observability import (DEFAULT_SIZE_BUCKETS, REGISTRY, current_span,
@@ -59,6 +68,11 @@ BATCHES = REGISTRY.counter(
     "pow_batches_total", "Coalesced solve_batch launches")
 SOLVED = REGISTRY.counter(
     "pow_solved_total", "Solve requests completed through the service")
+WINDOW_CLOSED = REGISTRY.counter(
+    "pow_window_closed_total",
+    "Coalescing windows closed, by what closed them: every announced "
+    "member of the sweep had arrived (all_arrived) or the window ran "
+    "out with a member still missing (timeout)", ("reason",))
 REQUEUED = REGISTRY.counter(
     "pow_requeue_total",
     "Solve requests put back on the queue after a dispatcher failure "
@@ -69,7 +83,8 @@ REQUEUED = REGISTRY.counter(
 #: the ``solve_batch`` that served it
 _BATCH_SEQ = itertools.count(1)
 
-#: default coalescing window in seconds; overridable per node via the
+#: default coalescing window in seconds, the longest a request waits
+#: for announced company; overridable per node via the
 #: ``powbatchwindow`` setting (core/config.py)
 DEFAULT_WINDOW = 0.05
 
@@ -117,6 +132,12 @@ class PowService:
         self._journal_retry = RetryPolicy(attempts=3, base_delay=0.01,
                                           max_delay=0.05, jitter=0.0)
         self.queue: asyncio.Queue = asyncio.Queue()
+        #: announced sweep members not blocked inside :meth:`solve`:
+        #: the company a taken request may still wait for
+        self._outstanding: set[asyncio.Task] = set()
+        #: set while ``_outstanding`` is empty; what ``_run`` waits on
+        self._all_arrived = asyncio.Event()
+        self._all_arrived.set()
         self._task: asyncio.Task | None = None
         # injected solvers may predate the resumable-PoW kwargs —
         # detect once and degrade to the plain call shape
@@ -201,6 +222,40 @@ class PowService:
 
     # -- API -----------------------------------------------------------------
 
+    def announce(self, members: Iterable[asyncio.Task]) -> None:
+        """A sweep names the tasks that will each call :meth:`solve`
+        (once or twice), before any of them runs: the window then
+        closes when the last of them has arrived instead of on the
+        timer.  A member is withdrawn when its task ends, however it
+        ends (result, exception, cancellation before its first step)."""
+        for task in members:
+            self._outstanding.add(task)
+            task.add_done_callback(self._withdraw)
+        self._company_changed()
+
+    def _withdraw(self, task: asyncio.Task) -> None:
+        self._outstanding.discard(task)
+        self._company_changed()
+
+    def _company_changed(self) -> None:
+        if self._outstanding:
+            self._all_arrived.clear()
+        else:
+            self._all_arrived.set()
+
+    async def _await_company(self) -> str:
+        """Hold a taken request until no announced member is missing
+        or ``window`` has run out; returns which it was."""
+        if self._all_arrived.is_set():
+            return "all_arrived"
+        if self.window <= 0:
+            return "timeout"
+        try:
+            await asyncio.wait_for(self._all_arrived.wait(), self.window)
+        except asyncio.TimeoutError:
+            return "timeout"
+        return "all_arrived"
+
     async def solve(self, initial_hash: bytes, target: int):
         """Queue one solve; returns (nonce, trials) when its batch lands."""
         fut = asyncio.get_running_loop().create_future()
@@ -225,7 +280,18 @@ class PowService:
             req.trace_id = ctx.trace_id
         await self.queue.put(req)
         QUEUE_DEPTH.set(self.queue.qsize())
-        result = await fut
+        # an announced member has arrived: it is nobody's missing
+        # company until its result is back and it may ask again
+        me = asyncio.current_task()
+        member = me in self._outstanding
+        if member:
+            self._withdraw(me)
+        try:
+            result = await fut
+        finally:
+            if member:
+                self._outstanding.add(me)
+                self._company_changed()
         waited = current_span()     # the caller's span: worker.pow
         if waited is not None:
             waited.attrs["batch"] = req.batch
@@ -241,12 +307,13 @@ class PowService:
             # every span of the solve, carry the batch's number
             set_batch(seq)
             with trace("pow.queue.window") as window:
-                if self.window > 0:
-                    await asyncio.sleep(self.window)
+                closed = await self._await_company()
                 batch = [first]
                 while not self.queue.empty():
                     batch.append(self.queue.get_nowait())
                 window.attrs["objects"] = len(batch)
+                window.attrs["closed"] = closed
+            WINDOW_CLOSED.labels(reason=closed).inc()
             for req in batch:
                 req.batch = seq
                 QUEUE_WAIT.observe(window.end - req.enqueued)

@@ -246,13 +246,22 @@ class SendWorker:
         # PowService coalescing window, so a sweep of queued sends
         # becomes ONE batched (objects x nonce-lanes) device launch.
         with trace("sender.sweep", kind="message", objects=len(msgs)):
-            results = await asyncio.gather(
-                *(self._send_one_msg(m) for m in msgs),
-                return_exceptions=True)
+            results = await self._gather_sweep(
+                self._send_one_msg(m) for m in msgs)
         for m, r in zip(msgs, results):
             if isinstance(r, BaseException) and \
                     not isinstance(r, asyncio.CancelledError):
                 logger.error("send failed for %s: %r", m.toaddress, r)
+
+    async def _gather_sweep(self, sends) -> list:
+        """Run a sweep's sends concurrently, announced to the PoW
+        service before any of them runs: its coalescing window then
+        closes when the last of them has asked for its PoW, and a lone
+        send is not held for company that cannot come."""
+        members = [asyncio.ensure_future(send) for send in sends]
+        if self.pow_service is not None:
+            self.pow_service.announce(members)
+        return await asyncio.gather(*members, return_exceptions=True)
 
     async def _send_one_msg(self, m) -> None:
         to = decode_address(m.toaddress)
@@ -518,9 +527,8 @@ class SendWorker:
         if not msgs:
             return
         with trace("sender.sweep", kind="broadcast", objects=len(msgs)):
-            results = await asyncio.gather(
-                *(self._send_one_broadcast(m) for m in msgs),
-                return_exceptions=True)
+            results = await self._gather_sweep(
+                self._send_one_broadcast(m) for m in msgs)
         for m, r in zip(msgs, results):
             if isinstance(r, BaseException) and \
                     not isinstance(r, asyncio.CancelledError):
