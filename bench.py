@@ -165,7 +165,8 @@ def _device_rate_effective(initial_hash: bytes) -> float:
     (BASELINE.md)."""
     from pybitmessage_tpu.ops.pow_search import PowInterrupted
     from pybitmessage_tpu.ops.sha512_pallas import (
-        DEFAULT_CHUNKS, DEFAULT_ROWS, DEFAULT_UNROLL, LANE_COLS, solve)
+        DEFAULT_CHUNKS, DEFAULT_ROWS, DEFAULT_UNROLL, LANE_COLS)
+    from pybitmessage_tpu.pow.pipeline import solve_batch_pipelined
 
     slab = DEFAULT_ROWS * LANE_COLS * DEFAULT_CHUNKS * DEFAULT_UNROLL
     calls = {"n": 0}
@@ -179,7 +180,8 @@ def _device_rate_effective(initial_hash: bytes) -> float:
 
         t0 = time.perf_counter()
         try:
-            solve(initial_hash, 1, start_nonce=start, should_stop=stop)
+            solve_batch_pipelined([(initial_hash, 1)],
+                                  start_nonces=[start], should_stop=stop)
         except PowInterrupted:
             pass
         return budget * slab / (time.perf_counter() - t0)
@@ -308,7 +310,10 @@ def _bench_single_default(device_rate: float) -> dict:
     mean from the measured hash rate (solve time is exponentially
     distributed, so two samples + the implied mean tell more than
     either alone)."""
-    from pybitmessage_tpu.ops.sha512_pallas import solve
+    from pybitmessage_tpu.pow.pipeline import solve_batch_pipelined
+
+    def solve(ih, target):
+        return solve_batch_pipelined([(ih, target)])[0]
 
     ttl = 4 * 24 * 3600
     length = 1008 + 8

@@ -220,11 +220,11 @@ async def phase_single(rep: Report, pair) -> None:
              _family("pow_pipeline_mode_total").items() if v}
     rep.say("single-object path: %s (pipeline modes so far: %s)"
             % (node_a.solver.last_backend, modes or "none"))
-    # trials are credited by the slab, and a default-difficulty object
-    # (1.3e7 mean trials) ends inside its first 4.2e7-trial slab: this
-    # is the dispatcher's own figure, an upper bound on the device rate
+    # trials are credited by the grid steps the search ran (81,920 a
+    # step), over a wall that holds the launch and the read-back too:
+    # the dispatcher's own figure, a lower bound on the device rate
     rep.say("smoke timing, single: last solve %.0f credited trials/s "
-            "(%.2fs solve-only, slab-granular credit)"
+            "(%.2fs solve-only, step-granular credit)"
             % (node_a.solver.last_solve_rate,
                node_a.solver.last_solve_seconds))
 

@@ -12,6 +12,15 @@ Layout: grid = (chunks,); each grid step evaluates a (ROWS, 128) tile
 of nonces = base + step*ROWS*128 + lane, one (8, 128) slice at a time.
 Outputs per step: hit flag and winning (nonce_hi, nonce_lo); the host
 takes the first hit.
+
+This module holds kernels only: the three jitted entry points
+(``pallas_search``, ``pallas_batch_search``, ``pallas_packed_search``),
+their shape constants and their ``register_program`` lines.  Which of
+them serves an input, at which shape, and the host loop that launches
+it live one layer up, in ``pow/pipeline.py`` (``plan_batch``,
+``_PipelineDriver``); the pod's loops are in
+``parallel/pow_pallas_sharded.py``.  Nothing here imports from
+``pybitmessage_tpu.pow``.
 """
 
 from __future__ import annotations
@@ -26,9 +35,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
-                                             record_launch,
                                              register_program)
-from ..observability.tracing import trace
 from .sha512_jax import _H0, _K
 from .u64 import U32
 
@@ -591,180 +598,15 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
 #: times) and in seconds since; core/jaxsetup.py places the persistent
 #: cache that keeps it a once-per-machine cost.
 BATCH_OBJS = 64
+#: grid steps an object of the pod's batch launches
+#: (parallel/pow_pallas_sharded.py); the single-chip pipeline has its
+#: own, ``pow.pipeline.DEFAULT_BATCH_CHUNKS``
 BATCH_CHUNKS = 64
 #: four tiles to a grid step of the batch grid (64 objects x 64 chunks
 #: x 4, solve-verified on-chip since r4).  Since PR 26 this sets only
 #: how often an object can leave at its hit, not the kernel's streams
 #: or its compile time (8 s for what took 190 s)
 BATCH_UNROLL = 4
-
-
-class _BatchGroup:
-    """Host state for one ``BATCH_OBJS``-wide launch group."""
-
-    __slots__ = ("idx", "ih_words", "t_np", "t_dev", "t_dirty", "targets",
-                 "bases", "trials", "done", "harvested")
-
-    def __init__(self, items, idx, mask64):
-        import numpy as np
-
-        pad = BATCH_OBJS - len(idx)
-        ihs = [items[i][0] for i in idx] + [b"\x00" * 64] * pad
-        self.targets = ([items[i][1] & mask64 for i in idx]
-                        + [mask64] * pad)
-        words = [[int.from_bytes(ih[j:j + 8], "big")
-                  for j in range(0, 64, 8)] for ih in ihs]
-        self.ih_words = jnp.array(
-            [[[w >> 32, w & 0xFFFFFFFF] for w in ws] for ws in words],
-            dtype=U32)
-        # all per-launch mutation is staged in NUMPY and converted once
-        # per launch: a tiny device op per solved object (an
-        # .at[].set) is a dispatch each
-        self.t_np = np.array(
-            [[t >> 32, t & 0xFFFFFFFF] for t in self.targets],
-            dtype=np.uint32)
-        self.idx = idx
-        self.t_dev = None       # device-resident targets (lazy upload)
-        self.t_dirty = True     # re-upload only after a target flips
-        self.bases = [0] * BATCH_OBJS
-        self.trials = [0] * BATCH_OBJS
-        self.done = [i >= len(idx) for i in range(BATCH_OBJS)]
-        self.harvested = 0
-
-    @property
-    def finished(self) -> bool:
-        return all(self.done)
-
-
-def solve_batch(items, *, rows: int = DEFAULT_ROWS,
-                chunks_per_call: int = BATCH_CHUNKS,
-                unroll: int = BATCH_UNROLL, should_stop=None,
-                interpret: bool = False):
-    """Solve ``[(initial_hash, target), ...]`` in batched launches.
-
-    The single-chip production form of the pod-wide batch grid: up to
-    ``BATCH_OBJS`` objects share each kernel launch; solved (and pad)
-    objects flip their per-object flag and stop consuming grid steps.
-    Returns ``[(nonce, trials), ...]`` aligned with ``items``.
-
-    The host loop keeps ONE launch in flight ahead of the one being
-    harvested (the same pipeline as the single-object :func:`solve`):
-    bases advance optimistically at dispatch, and a launch is dispatched
-    for the NEXT group (or, for a group that has already proven it needs
-    more than one slab, the next slab of the same group) before the
-    pending launch's results are pulled, so the transfer and the
-    per-object host bookkeeping hide behind device compute.  A
-    speculative tail launch dispatched for a group whose pending launch
-    turns out to have finished it is abandoned unfetched; since every
-    finished object's target is flipped to always-hit, such a launch
-    exits after one chunk per object and costs almost nothing.
-    """
-    from ..utils.hashes import double_sha512
-    from .pow_search import PowInterrupted
-
-    n = len(items)
-    if n == 0:
-        return []
-    results: list = [None] * n
-    mask64 = (1 << 64) - 1
-    trials_per_slab = rows * LANE_COLS * chunks_per_call * unroll
-    step_trials = rows * LANE_COLS * unroll
-
-    groups = [
-        _BatchGroup(items,
-                    list(range(s, min(s + BATCH_OBJS, n))), mask64)
-        for s in range(0, n, BATCH_OBJS)
-    ]
-
-    def dispatch(g: _BatchGroup):
-        import time as _time
-
-        import numpy as np
-
-        b_arr = np.array(
-            [[(b >> 32) & 0xFFFFFFFF, b & 0xFFFFFFFF] for b in g.bases],
-            dtype=np.uint32)
-        live = sum(1 for d in g.done if not d)
-        uploaded = int(b_arr.nbytes)
-        t0 = _time.monotonic()
-        # targets change only when an object solves; keeping the device
-        # copy across launches saves one host->device transfer on
-        # every steady-state launch
-        if g.t_dirty:
-            g.t_dev = jnp.asarray(g.t_np.copy())
-            g.t_dirty = False
-            uploaded += int(g.t_np.nbytes)
-        out = pallas_batch_search(
-            g.ih_words, b_arr, g.t_dev, rows=rows,
-            chunks=chunks_per_call, unroll=unroll, interpret=interpret)
-        t1 = _time.monotonic()
-        for k in range(BATCH_OBJS):
-            if not g.done[k]:
-                g.bases[k] = (g.bases[k] + trials_per_slab) & mask64
-        return out, live, uploaded, t0, t1
-
-    def harvest(g: _BatchGroup, out_dev, live, uploaded, t0, t1):
-        import time as _time
-
-        import numpy as np
-
-        t2 = _time.monotonic()
-        out = np.asarray(out_dev)
-        t3 = _time.monotonic()
-        record_launch("batch_search",
-                      key=(rows, chunks_per_call, unroll, interpret),
-                      dispatch_seconds=t1 - t0, wait_seconds=t3 - t2,
-                      span=(t0, t3), items=live * trials_per_slab,
-                      bytes_in=uploaded, bytes_out=int(out.nbytes))
-        for k in range(BATCH_OBJS):
-            if g.done[k]:
-                continue
-            step1 = int(out[k, 0])
-            if step1:
-                # trials credited up to the hit step, not the slab
-                g.trials[k] += step1 * step_trials
-                val = (int(out[k, 1]) << 32) | int(out[k, 2])
-                ih = items[g.idx[k]][0]
-                check = double_sha512(val.to_bytes(8, "big") + ih)
-                if int.from_bytes(check[:8], "big") > g.targets[k]:
-                    raise ArithmeticError(
-                        "accelerator returned an invalid nonce")
-                results[g.idx[k]] = (val, g.trials[k])
-                g.done[k] = True
-                # pad semantics: hit instantly next launch, then skip
-                g.t_np[k] = (0xFFFFFFFF, 0xFFFFFFFF)
-                g.t_dirty = True
-            else:
-                g.trials[k] += trials_per_slab
-        g.harvested += 1
-
-    pending = None  # (group, in-flight device output)
-    rr = 0          # round-robin dispatch cursor over groups
-    while True:
-        if should_stop is not None and should_stop():
-            raise PowInterrupted("batched Pallas PoW interrupted")
-        live = [g for g in groups if not g.finished]
-        if not live and pending is None:
-            return results
-        pending_g = pending[0] if pending is not None else None
-        # round-robin over unfinished groups, never the pending one
-        # (its next slab would be speculative while fresh work exists);
-        # otherwise speculate one slab ahead on a group that has
-        # already needed >=1 full slab without finishing
-        cand = None
-        for off in range(len(groups)):
-            g = groups[(rr + off) % len(groups)]
-            if not g.finished and g is not pending_g:
-                cand = g
-                rr = (rr + off + 1) % len(groups)
-                break
-        if cand is None and pending_g is not None \
-                and pending_g.harvested >= 1 and not pending_g.finished:
-            cand = pending_g
-        cur = (cand,) + dispatch(cand) if cand is not None else None
-        if pending is not None and not pending[0].finished:
-            harvest(*pending)
-        pending = cur
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",
@@ -800,124 +642,6 @@ def pallas_search(ih_words, base, target, rows: int = 256,
         interpret=interpret,
     )(ih_words, base, target)
     return found[:, 0], nonce
-
-
-
-
-def solve(initial_hash: bytes, target: int, *,
-          start_nonce: int = 0, rows: int = DEFAULT_ROWS,
-          chunks_per_call: int = DEFAULT_CHUNKS,
-          unroll: int = DEFAULT_UNROLL, should_stop=None,
-          interpret: bool = False, progress=None):
-    """Find a nonce whose trial value is <= target (Pallas backend).
-
-    Same contract as :func:`pow_search.solve`: returns
-    ``(nonce, trials_done)`` or raises ``PowInterrupted``.  The host
-    re-invokes the kernel in slabs of ``chunks_per_call * rows * 128 *
-    unroll`` trials so the shutdown callback stays responsive
-    (reference host loop: src/openclpow.py:96-107), and keeps one slab
-    in flight ahead of the one being harvested so dispatch and
-    host-transfer gaps hide behind device compute.  Trials are
-    accounted at slab granularity.  ``chunks_per_call`` is a static
-    argument of the kernel and every caller on a node passes the same
-    one: 512 is the largest power of two a v5e compiles (1024 asks for
-    1.01M of its 1.00M of SMEM; tests/test_tpu_compile.py), and the
-    grid leaves at its first hit, so a long slab costs a short solve
-    nothing.
-    """
-    import numpy as np
-
-    # the host loops' counters live with the pipeline, which imports
-    # this module at its top
-    from ..pow.pipeline import (ABANDONED_LAUNCHES, EXECUTED_TRIALS,
-                                LAUNCHES)
-    from ..utils.hashes import double_sha512
-    from .pow_search import PowInterrupted
-
-    words = [int.from_bytes(initial_hash[i:i + 8], "big")
-             for i in range(0, 64, 8)]
-    ih_words = jnp.array([[w >> 32, w & 0xFFFFFFFF] for w in words],
-                         dtype=U32)
-    target &= (1 << 64) - 1
-    target_arr = jnp.array([target >> 32, target & 0xFFFFFFFF], dtype=U32)
-
-    chunks = chunks_per_call
-    trials_per_slab = rows * LANE_COLS * chunks * unroll
-    mask64 = (1 << 64) - 1
-
-    def launch(base_int: int):
-        import numpy as np
-
-        # numpy arg: the transfer rides the jit call itself instead of
-        # a separate explicit device-put
-        base = np.array([(base_int >> 32) & 0xFFFFFFFF,
-                         base_int & 0xFFFFFFFF], dtype=np.uint32)
-        with trace("pow.launch", program="pallas_slab", chunks=chunks,
-                   live=1) as span:
-            out = pallas_search(ih_words, base, target_arr, rows=rows,
-                                chunks=chunks, unroll=unroll,
-                                interpret=interpret)
-        LAUNCHES.labels(kind="slab").inc()
-        return out, span.start, span.end
-
-    def harvest(found_dev, nonce_dev, t_disp, t_disp_end):
-        """Sync one slab's results; returns the winning nonce or None."""
-        with trace("pow.fetch") as fetch:
-            f = np.asarray(found_dev)
-        record_launch("pallas_slab",
-                      key=(rows, chunks, unroll, interpret),
-                      dispatch_seconds=t_disp_end - t_disp,
-                      wait_seconds=fetch.duration,
-                      span=(t_disp, fetch.end),
-                      items=trials_per_slab, bytes_in=8,
-                      bytes_out=int(f.nbytes))
-        idx = int(f.argmax())
-        # the grid leaves at its first hit: steps up to it really ran
-        EXECUTED_TRIALS.labels(kind="slab").inc(
-            (idx + 1 if f[idx] else chunks) * rows * LANE_COLS * unroll)
-        if not f[idx]:
-            return None
-        n = np.asarray(nonce_dev)
-        offset = (int(n[idx, 0]) << 32) | int(n[idx, 1])
-        check = double_sha512(offset.to_bytes(8, "big") + initial_hash)
-        if int.from_bytes(check[:8], "big") > target:  # pragma: no cover
-            raise ArithmeticError("accelerator returned an invalid nonce")
-        return offset
-
-    # Double-buffered host loop: slab N+1 is dispatched BEFORE slab N's
-    # results are pulled, so the host-side transfer/bookkeeping gap
-    # hides behind device compute on long (multi-slab) searches.
-    base = start_nonce & mask64
-    trials = 0
-    # ((found_dev, nonce_dev), dispatch_start, dispatch_end, end_base)
-    pending = None
-    while True:
-        if should_stop is not None and should_stop():
-            # the in-flight slab may already hold the answer — check
-            # before discarding ~16.7M trials of completed device work
-            if pending is not None:
-                trials += trials_per_slab
-                nonce = harvest(*pending[0], pending[1], pending[2])
-                if nonce is not None:
-                    return nonce, trials
-                if progress is not None:
-                    progress(pending[3])
-            raise PowInterrupted("Pallas PoW interrupted by shutdown")
-        end_base = (base + trials_per_slab) & mask64
-        current = launch(base) + (end_base,)
-        base = end_base
-        if pending is not None:
-            trials += trials_per_slab
-            nonce = harvest(*pending[0], pending[1], pending[2])
-            if nonce is not None:
-                # the slab just dispatched is left behind unfetched
-                ABANDONED_LAUNCHES.labels(kind="slab").inc()
-                return nonce, trials
-            if progress is not None:
-                # the pending slab harvested miss-free: its end is the
-                # resumable-PoW checkpoint (resilience/journal.py)
-                progress(pending[3])
-        pending = current
 
 
 register_program("pallas_slab", flops_per_item=POW_FLOPS_PER_HASH,

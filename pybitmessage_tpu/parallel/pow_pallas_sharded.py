@@ -264,12 +264,12 @@ def pallas_sharded_solve(initial_hash: bytes, target: int, mesh: Mesh, *,
                          progress: Callable[[int], None] | None = None):
     """Pod-wide solve running the production Pallas kernel per chip.
 
-    Same contract as ``ops.solve`` / ``sha512_pallas.solve``: returns
+    Same contract as ``ops.solve``: returns
     ``(nonce, trials)`` or raises ``PowInterrupted``.  Double-buffered
     host loop (one pod slab in flight ahead of the harvest) with
     stride ``ndev * rows*128*chunks`` per call.  ``progress(next)``
     checkpoints resumable search state whenever a pod slab harvests
-    miss-free (same contract as ``sha512_pallas.solve``).
+    miss-free (same contract as ``pow.pipeline.solve_batch_pipelined``).
     """
     import time as _time
 

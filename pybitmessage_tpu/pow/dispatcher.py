@@ -3,7 +3,21 @@
 Reference semantics (proofofwork.py:288-325): try the fastest backend;
 on failure log and fall through to the next; every tier is
 interruptible; the winning nonce is host-verified before being trusted
-(the TPU tier already re-checks internally, ops/pow_search.py).
+(the device tiers already re-check internally).
+
+This module is the top one of the send path's three boxes
+(docs/pow_pipeline.md): it decides WHICH RUNG runs and whether that
+rung is healthy, and nothing about kernels or shapes — those are
+``pow/pipeline.py``'s (``plan_batch`` and the one dispatch-ahead loop),
+which in turn launches the kernels of ``ops/sha512_pallas.py``.  The
+rungs the topology admits are walked in order (:meth:`PowDispatcher.
+solve_batch` for a queue, :meth:`PowDispatcher._solve` for one object,
+which is also where a queue of one and a failed queue go), and every
+rung runs under the same bookkeeping, written once
+(:meth:`PowDispatcher._run_rung`): breaker gate, chaos site, attempt
+counter, then success closing its breakers, an interrupt giving the
+half-open probe back, or a failure counted, logged and noted as a
+fallback.  What differs from rung to rung is data (:class:`_Rung`).
 
 An attached :class:`~pybitmessage_tpu.powfarm.FarmSolverTier`
 (``attach_farm``) leads the ladder: jobs are delegated to a shared
@@ -14,14 +28,13 @@ requeued on the local ladder, so an unreachable farm degrades to
 exactly the pre-farm node (docs/pow_farm.md).
 
 Tier health is managed by per-tier circuit breakers
-(resilience/policy.py) instead of the old permanent latch: a failing
-tier opens after ``threshold`` consecutive failures (1 for the device
-tiers — a failed Mosaic compile costs ~75 s and must not be re-paid
-per solve), fallbacks stop paying the failure latency while it is
-open, and a half-open probe after the cooldown lets a recovered
-device rejoin the ladder.  ``pow.device_launch`` is a chaos injection
-site (docs/resilience.md); slab-level stall detection lives in
-pipeline.py and surfaces here as an ordinary tier failure.
+(resilience/policy.py): a failing tier opens after ``threshold``
+consecutive failures (1 for the device tiers — a failed Mosaic compile
+must not be re-paid per solve), fallbacks stop paying the failure
+latency while it is open, and a half-open probe after the cooldown
+lets a recovered device rejoin the ladder.  ``pow.device_launch`` is a
+chaos injection site (docs/resilience.md); slab-level stall detection
+lives in pipeline.py and surfaces here as an ordinary tier failure.
 """
 
 from __future__ import annotations
@@ -29,7 +42,7 @@ from __future__ import annotations
 import hashlib
 import logging
 import time
-from typing import Callable
+from typing import Callable, NamedTuple
 
 from ..observability import REGISTRY, trace
 from ..ops.pow_search import PowInterrupted
@@ -42,10 +55,11 @@ logger = logging.getLogger("pybitmessage_tpu.pow")
 
 #: slab-stall deadline handed to the pipeline: seconds ONE harvest (the
 #: blocking device->host fetch of a dispatched slab) may take; 0
-#: disables the watchdog.  A cold Mosaic compile (minutes) is not inside
-#: it: jit compiles synchronously in the dispatch call, on the solving
-#: thread, before the guarded fetch starts — chip_smoke.py's compile
-#: table shows it as first-launch DISPATCH seconds.
+#: disables the watchdog.  A cold Mosaic compile is not inside it: jit
+#: compiles synchronously in the dispatch call, which the driver waits
+#: for without a deadline, before the guarded fetch starts —
+#: chip_smoke.py's compile table shows it as first-launch DISPATCH
+#: seconds.
 DEFAULT_STALL_TIMEOUT = 120.0
 
 SOLVE_SECONDS = REGISTRY.histogram(
@@ -78,6 +92,51 @@ MESH_COMPILES = REGISTRY.counter(
     "pow_mesh_compiles_total",
     "Device mesh constructions, one per distinct (ndev, obj) shape — "
     "a proxy for per-shape XLA compiles", ("shape",))
+
+
+class _Rung(NamedTuple):
+    """What differs between rungs, for :meth:`PowDispatcher._run_rung`.
+    Where the rungs disagree (which breakers a success closes, what a
+    failure is called) each keeps what it always did."""
+
+    backend: str | None     # pow_attempts_total label; None: the body's
+    breaker: str            # gates the rung, takes its failures, and
+    #                         names its error site pow.tier.<breaker>
+    closes: tuple           # breakers a success closes besides its own
+    chaos: bool             # pow.device_launch is injected on entry
+    frm: str                # pow_fallback_total{from,to} of a failure;
+    to: str | None          # None: the next host tier (native | python)
+    message: str            # logged with the failure's traceback
+
+
+# a queue on a pod, then on one chip
+_PALLAS_SHARDED_BATCH = _Rung(
+    "tpu-pallas-sharded-batch", "tpu-pallas", ("tpu",), True,
+    "tpu-pallas", "tpu-xla",
+    "sharded batched Pallas PoW failed; using sharded XLA batch")
+_XLA_SHARDED_BATCH = _Rung(
+    "tpu-batch", "tpu", (), True, "tpu-batch", "ladder",
+    "batched TPU PoW failed; falling back to per-object solves")
+_PIPELINE_BATCH = _Rung(
+    "tpu-pallas-batch", "tpu-pallas", (), True, "tpu-pallas", "ladder",
+    "batched Pallas PoW failed; falling back to per-object solves")
+# one object: the ``tpu`` rung holds the topology probe, the Mosaic
+# rung of that topology (its own breaker inside this one) and the XLA
+# search, whose failures are this rung's
+_TPU = _Rung(
+    None, "tpu", (), True, "tpu", None,
+    "TPU PoW failed; falling through to C++ (breaker open, half-open "
+    "probe after cooldown)")
+_PALLAS_SHARDED = _Rung(
+    "tpu-pallas-sharded", "tpu-pallas", ("tpu",), False,
+    "tpu-pallas", "tpu-xla",
+    "sharded Pallas PoW failed; using sharded XLA search")
+_PALLAS = _Rung(
+    "tpu-pallas", "tpu-pallas", ("tpu",), False, "tpu-pallas", "tpu-xla",
+    "Pallas PoW failed; using XLA search")
+_CPP = _Rung(
+    "cpp", "cpp", (), False, "native", "python",
+    "C++ PoW failed; falling through to python")
 
 
 def host_trial(nonce: int, initial_hash: bytes) -> int:
@@ -119,12 +178,14 @@ def python_solve(initial_hash: bytes, target: int, *,
 
 
 class PowDispatcher:
-    """Callable solver with the GPU->C->python fallback ladder.
+    """Callable solver with the farm -> TPU -> C++ -> python ladder.
 
-    When more than one accelerator device is visible, single solves are
-    range-partitioned across the whole mesh (``sharded_solve``) and
-    :meth:`solve_batch` maps a queue of pending objects onto a 2D
-    (objects x nonce-range) mesh — the pod-wide production path.
+    On one chip every solve, a lone object included, goes through
+    ``pow.pipeline.solve_batch_pipelined``.  When more than one
+    accelerator device is visible, single solves are range-partitioned
+    across the whole mesh (``sharded_solve``) and :meth:`solve_batch`
+    maps a queue of pending objects onto a 2D (objects x nonce-range)
+    mesh — the pod-wide path.
 
     Timing attributes (also exported through the metrics registry):
 
@@ -262,10 +323,8 @@ class PowDispatcher:
                 "farm tier failed (%r); requeueing %d job(s) on the "
                 "local ladder (breaker: %s)", exc, len(items),
                 farm.breaker.state)
-            next_tier = "tpu" if self._tpu_enabled else (
-                "native" if self._native is not None
-                and self._native.available else "python")
-            _note_fallback("farm", next_tier)
+            _note_fallback("farm", "tpu" if self._tpu_enabled
+                           else self._host_tier())
             return None
 
     def backends(self) -> list[str]:
@@ -338,115 +397,18 @@ class PowDispatcher:
             return []
         starts = list(start_nonces) if start_nonces else [0] * len(items)
         t0 = time.monotonic()
-        pb = self.breakers["tpu-pallas"]
-        tb = self.breakers["tpu"]
         with trace("pow.solve_batch", objects=len(items)) as span:
             # the farm rung leads the ladder; a farm failure falls
             # through to the local tiers below with nothing lost
             results = self._try_farm(items, should_stop, starts)
-            ndev, on_accel = (
-                self._batch_topology()
-                if results is None and self._tpu_enabled else (0, False))
-            if len(items) > 1:
-                if ndev > 1:
-                    if on_accel and pb.allow():
-                        try:
-                            inject("pow.device_launch")
-                            from ..parallel import pallas_sharded_solve_batch
-                            self.last_backend = "tpu-pallas-sharded-batch"
-                            ATTEMPTS.labels(backend=self.last_backend).inc()
-                            results = pallas_sharded_solve_batch(
-                                items, self._mesh(ndev, len(items)),
-                                should_stop=should_stop,
-                                start_nonces=starts, progress=progress)
-                            pb.record_success()
-                            tb.record_success()
-                        except PowInterrupted:
-                            pb.release_probe()
-                            raise
-                        except Exception as exc:
-                            logger.exception(
-                                "sharded batched Pallas PoW failed; using "
-                                "sharded XLA batch")
-                            self._pallas_failed(exc, "tpu-xla")
-                    if results is None and tb.allow():
-                        try:
-                            inject("pow.device_launch")
-                            from ..parallel import sharded_solve_batch
-                            self.last_backend = "tpu-batch"
-                            ATTEMPTS.labels(backend=self.last_backend).inc()
-                            results = sharded_solve_batch(
-                                items, self._mesh(ndev, len(items)),
-                                should_stop=should_stop,
-                                **self._xla_kwargs())
-                            tb.record_success()
-                        except PowInterrupted:
-                            tb.release_probe()
-                            raise
-                        except Exception as exc:
-                            self._note_stall(exc)
-                            tb.record_failure()
-                            ERRORS.labels(site="pow.tier.tpu").inc()
-                            logger.exception(
-                                "batched TPU PoW failed; falling back to "
-                                "per-object solves")
-                            _note_fallback("tpu-batch", "ladder")
-                elif on_accel and pb.allow():
-                    # single chip: the async double-buffered pipeline
-                    # plans the launch shape (multi-object slab packing
-                    # for storms, the per-object (objects x chunks)
-                    # batch grid for network difficulty, a synchronous
-                    # latency-optimal launch for one tiny object) and
-                    # keeps slabs dispatched ahead of harvest
-                    try:
-                        inject("pow.device_launch")
-                        from .pipeline import solve_batch_pipelined
-                        self.last_backend = "tpu-pallas-batch"
-                        ATTEMPTS.labels(backend=self.last_backend).inc()
-                        results = solve_batch_pipelined(
-                            items, should_stop=should_stop,
-                            start_nonces=starts, progress=progress,
-                            stall_timeout=self.stall_timeout)
-                        pb.record_success()
-                    except PowInterrupted:
-                        pb.release_probe()
-                        raise
-                    except Exception as exc:
-                        # breaker opens like the per-object ladder: a
-                        # broken Mosaic kernel must not re-pay a ~75 s
-                        # failed compile on every subsequent batch
-                        logger.exception(
-                            "batched Pallas PoW failed; falling back to "
-                            "per-object solves")
-                        self._pallas_failed(exc, "ladder")
-            if (results is None and len(items) == 1 and on_accel
-                    and ndev <= 1 and pb.allow()):
-                # degenerate case: ONE object.  If it is tiny (expected
-                # to finish inside the first small launch) the pipeline
-                # takes its latency-optimal synchronous path instead of
-                # paying a full production slab + speculative dispatch.
-                try:
-                    inject("pow.device_launch")
-                    from .pipeline import plan_batch, solve_batch_pipelined
-                    if plan_batch(items).mode == "single-sync":
-                        self.last_backend = "tpu-pallas-batch"
-                        ATTEMPTS.labels(backend=self.last_backend).inc()
-                        results = solve_batch_pipelined(
-                            items, should_stop=should_stop,
-                            start_nonces=starts, progress=progress,
-                            stall_timeout=self.stall_timeout)
-                        pb.record_success()
-                    else:
-                        pb.release_probe()
-                except PowInterrupted:
-                    pb.release_probe()
-                    raise
-                except Exception as exc:
-                    logger.exception(
-                        "pipelined single-object PoW failed; using the "
-                        "ladder")
-                    self._pallas_failed(exc, "ladder")
-                    results = None
+            if results is None and self._tpu_enabled and len(items) > 1:
+                for rung, call in self._batch_rungs(
+                        items, starts, should_stop, progress):
+                    results = self._run_rung(rung, call)
+                    if results is not None:
+                        break
+            # one object, or a queue no device rung took: each object
+            # walks the per-object rungs
             if results is None:
                 results = []
                 for i, (ih, t) in enumerate(items):
@@ -484,139 +446,149 @@ class PowDispatcher:
             return {"lanes": 1 << 12, "chunks_per_call": 8}
         return {}
 
-    def _pallas_failed(self, exc: Exception, to: str) -> None:
-        """Bookkeeping shared by every Mosaic-tier failure path."""
-        self._note_stall(exc)
-        self.breakers["tpu-pallas"].record_failure()
-        ERRORS.labels(site="pow.tier.tpu-pallas").inc()
-        _note_fallback("tpu-pallas", to)
+    # -- the ladder ----------------------------------------------------------
+
+    def _enter(self, backend: str) -> None:
+        self.last_backend = backend
+        ATTEMPTS.labels(backend=backend).inc()
+
+    def _host_tier(self) -> str:
+        return ("native" if self._native is not None
+                and self._native.available else "python")
+
+    def _run_rung(self, rung: _Rung, call):
+        """One step of the ladder: run ``call`` as ``rung``.  Returns
+        its result, or None when the rung's breaker is open or the
+        rung failed (counted, logged, noted as a fallback) and the
+        walk goes on to the next."""
+        breaker = self.breakers[rung.breaker]
+        if not breaker.allow():
+            return None
+        try:
+            if rung.chaos:
+                inject("pow.device_launch")
+            if rung.backend is not None:
+                self._enter(rung.backend)
+            result = call()
+            breaker.record_success()
+            for name in rung.closes:
+                self.breakers[name].record_success()
+            return result
+        except PowInterrupted:
+            # an interrupt is not evidence of health
+            breaker.release_probe()
+            raise
+        except Exception as exc:
+            # the breaker opens: a broken Mosaic kernel must not re-pay
+            # a failed compile on every subsequent solve
+            self._note_stall(exc)
+            breaker.record_failure()
+            site = "pow.tier." + rung.breaker
+            ERRORS.labels(site=site).inc()
+            logger.exception(rung.message)
+            _note_fallback(rung.frm, rung.to or self._host_tier())
+            return None
+
+    def _batch_rungs(self, items, starts, should_stop, progress):
+        """The device rungs the topology admits for a queue, in order,
+        each with the call that runs it."""
+        ndev, on_accel = self._batch_topology()
+
+        def pallas_sharded_batch():
+            from ..parallel import pallas_sharded_solve_batch
+            return pallas_sharded_solve_batch(
+                items, self._mesh(ndev, len(items)),
+                should_stop=should_stop, start_nonces=starts,
+                progress=progress)
+
+        def xla_sharded_batch():
+            from ..parallel import sharded_solve_batch
+            return sharded_solve_batch(
+                items, self._mesh(ndev, len(items)),
+                should_stop=should_stop, **self._xla_kwargs())
+
+        def pipeline_batch():
+            from .pipeline import solve_batch_pipelined
+            return solve_batch_pipelined(
+                items, should_stop=should_stop, start_nonces=starts,
+                progress=progress, stall_timeout=self.stall_timeout)
+
+        if ndev > 1:
+            if on_accel:
+                yield _PALLAS_SHARDED_BATCH, pallas_sharded_batch
+            yield _XLA_SHARDED_BATCH, xla_sharded_batch
+        elif on_accel:
+            yield _PIPELINE_BATCH, pipeline_batch
 
     def _solve(self, initial_hash, target, start_nonce, should_stop,
                progress=None, try_farm=True):
+        item = (initial_hash, target)
         if try_farm:
-            farmed = self._try_farm([(initial_hash, target)],
-                                    should_stop, [start_nonce])
+            farmed = self._try_farm([item], should_stop, [start_nonce])
             if farmed is not None:
                 return farmed[0]
-        tb = self.breakers["tpu"]
-        pb = self.breakers["tpu-pallas"]
-        if self._tpu_enabled and tb.allow():
-            try:
-                inject("pow.device_launch")
-                ndev = self._device_count()
-                if ndev > 1:
-                    # pod-wide nonce partition over ICI, production
-                    # Pallas kernel per chip (VERDICT r2 #1: the pod
-                    # tier must not run the 3.3x-slower XLA kernel)
-                    if self._on_accelerator() and pb.allow():
-                        try:
-                            from ..parallel import pallas_sharded_solve
-                            self.last_backend = "tpu-pallas-sharded"
-                            ATTEMPTS.labels(backend=self.last_backend).inc()
-                            result = pallas_sharded_solve(
-                                initial_hash, target, self._mesh(ndev, 1),
-                                start_nonce=start_nonce,
-                                should_stop=should_stop,
-                                progress=progress)
-                            pb.record_success()
-                            tb.record_success()
-                            return result
-                        except PowInterrupted:
-                            pb.release_probe()
-                            raise
-                        except Exception as exc:
-                            logger.exception(
-                                "sharded Pallas PoW failed; using "
-                                "sharded XLA search")
-                            self._pallas_failed(exc, "tpu-xla")
-                    from ..parallel import sharded_solve
-                    self.last_backend = "tpu-sharded"
-                    ATTEMPTS.labels(backend=self.last_backend).inc()
-                    result = sharded_solve(
-                        initial_hash, target, self._mesh(ndev, 1),
-                        start_nonce=start_nonce, should_stop=should_stop,
-                        **self._xla_kwargs())
-                    tb.record_success()
-                    return result
-                if self._on_accelerator() and pb.allow():
-                    # Mosaic kernel: 290.6 MH/s on a v5e chip
-                    # (kernel_mhash_per_s.slab of a traced single_send
-                    # run, PERF.md section 5, PR 27; the XLA path below
-                    # it was 25.8 MH/s in BASELINE.md and has no cell)
-                    # — the fastest usable backend leads the ladder,
-                    # reference proofofwork.py:288-325 / openclpow
-                    # wiring.  One static shape: see sha512_pallas.solve
-                    try:
-                        from ..ops.sha512_pallas import solve as pl_solve
-                        self.last_backend = "tpu-pallas"
-                        ATTEMPTS.labels(backend=self.last_backend).inc()
-                        result = pl_solve(initial_hash, target,
-                                          start_nonce=start_nonce,
-                                          should_stop=should_stop,
-                                          progress=progress)
-                        pb.record_success()
-                        tb.record_success()
-                        return result
-                    except PowInterrupted:
-                        pb.release_probe()
-                        raise
-                    except Exception as exc:
-                        logger.exception(
-                            "Pallas PoW failed; using XLA search")
-                        self._pallas_failed(exc, "tpu-xla")
-                from ..ops.pow_search import solve as tpu_solve
-                self.last_backend = "tpu"
-                ATTEMPTS.labels(backend=self.last_backend).inc()
-                kwargs = self._xla_kwargs()
-                if not self.tpu_kwargs:
-                    # no explicit powlanes/powchunks override: let the
-                    # measured-latency autotuner size the XLA slab
-                    # (a shape is cheap here; the Mosaic tiers above
-                    # have one static shape each and never ask it)
-                    from .pipeline import AUTOTUNER
-                    kwargs = dict(kwargs, tuner=AUTOTUNER)
-                result = tpu_solve(initial_hash, target,
-                                   start_nonce=start_nonce,
-                                   should_stop=should_stop,
-                                   progress=progress,
-                                   **kwargs)
-                tb.record_success()
+        if self._tpu_enabled:
+            result = self._run_rung(_TPU, lambda: self._solve_on_device(
+                item, start_nonce, should_stop, progress))
+            if result is not None:
                 return result
-            except PowInterrupted:
-                tb.release_probe()
-                raise
-            except Exception as exc:
-                self._note_stall(exc)
-                tb.record_failure()
-                ERRORS.labels(site="pow.tier.tpu").inc()
-                logger.exception(
-                    "TPU PoW failed; falling through to C++ "
-                    "(breaker open, half-open probe after cooldown)")
-                next_tier = ("native"
-                             if self._native is not None
-                             and self._native.available else "python")
-                _note_fallback("tpu", next_tier)
         if self._native is not None and self._native.available:
-            cb = self.breakers["cpp"]
-            if cb.allow():
-                try:
-                    self.last_backend = "cpp"
-                    ATTEMPTS.labels(backend=self.last_backend).inc()
-                    result = self._native.solve(initial_hash, target,
-                                                start_nonce=start_nonce,
-                                                should_stop=should_stop)
-                    cb.record_success()
-                    return result
-                except PowInterrupted:
-                    cb.release_probe()
-                    raise
-                except Exception:
-                    cb.record_failure()
-                    ERRORS.labels(site="pow.tier.cpp").inc()
-                    logger.exception(
-                        "C++ PoW failed; falling through to python")
-                    _note_fallback("native", "python")
-        self.last_backend = "python"
-        ATTEMPTS.labels(backend=self.last_backend).inc()
+            result = self._run_rung(_CPP, lambda: self._native.solve(
+                initial_hash, target, start_nonce=start_nonce,
+                should_stop=should_stop))
+            if result is not None:
+                return result
+        self._enter("python")
         return python_solve(initial_hash, target, start_nonce=start_nonce,
                             should_stop=should_stop, progress=progress)
+
+    def _solve_on_device(self, item, start_nonce, should_stop, progress):
+        """The body of one object's ``tpu`` rung: on an accelerator the
+        Mosaic rung of this topology — the pod's sharded search, or on
+        one chip the pipeline with a batch of one — then the XLA
+        search."""
+        initial_hash, target = item
+        ndev = self._device_count()
+
+        def pallas_sharded():
+            from ..parallel import pallas_sharded_solve
+            return pallas_sharded_solve(
+                initial_hash, target, self._mesh(ndev, 1),
+                start_nonce=start_nonce, should_stop=should_stop,
+                progress=progress)
+
+        def pipeline_one():
+            from .pipeline import solve_batch_pipelined
+            return solve_batch_pipelined(
+                [item], should_stop=should_stop,
+                start_nonces=[start_nonce],
+                progress=(None if progress is None
+                          else lambda _i, nxt: progress(nxt)),
+                stall_timeout=self.stall_timeout)[0]
+
+        if self._on_accelerator():
+            result = (self._run_rung(_PALLAS_SHARDED, pallas_sharded)
+                      if ndev > 1 else
+                      self._run_rung(_PALLAS, pipeline_one))
+            if result is not None:
+                return result
+        if ndev > 1:
+            from ..parallel import sharded_solve
+            self._enter("tpu-sharded")
+            return sharded_solve(
+                initial_hash, target, self._mesh(ndev, 1),
+                start_nonce=start_nonce, should_stop=should_stop,
+                **self._xla_kwargs())
+        from ..ops.pow_search import solve as tpu_solve
+        self._enter("tpu")
+        kwargs = self._xla_kwargs()
+        if not self.tpu_kwargs:
+            # no explicit powlanes/powchunks override: let the
+            # measured-latency autotuner size the XLA slab (a shape is
+            # cheap here; the Mosaic kernels have one static shape
+            # each and never ask it)
+            from .pipeline import AUTOTUNER
+            kwargs = dict(kwargs, tuner=AUTOTUNER)
+        return tpu_solve(initial_hash, target, start_nonce=start_nonce,
+                         should_stop=should_stop, progress=progress,
+                         **kwargs)

@@ -1,41 +1,47 @@
-"""Asynchronous double-buffered PoW execution pipeline (ISSUE 2).
+"""Plan and driver of the single-chip PoW search: which kernel at which
+shape serves a batch, and the one host loop that launches it.
 
-BENCH_r05 measured the device kernel at 202.9M H/s per chip while the
-batched-queue config aggregated only 135.6M H/s and the broadcast storm
-(10k tiny objects) collapsed to 35.7M H/s — the host pipeline was
-giving back most of the kernel's gains.  Three levers close the gap:
+The send path below ``PowService`` is three boxes whose arrows point
+down (docs/pow_pipeline.md):
 
-1. **Multi-object slab packing** (``ops.sha512_pallas.
-   pallas_packed_search``): several pending objects share ONE device
-   slab along the lane axis with per-lane object identity and
-   per-object targets, so a storm of small objects fills the grid
-   instead of paying a full launch + host sync per object.
-2. **Dispatch-ahead double buffering** (:func:`_PipelineDriver.run`):
-   slab N+1 is issued before slab N's hit flags are read back, hiding
-   host verification/serialization behind device compute (the
-   sync-slab penalty: 136.6M vs 202.9M H/s).
-3. **One static shape a kernel.**  ``chunks`` is a static argument of
-   every Mosaic kernel, so each value is a program of its own to trace,
-   lower and compile, and one the chip may refuse (``pallas_search``
-   at 1024 chunks needs more SMEM than a v5e has).  The planner hands
-   each kernel the one chunk count measured on the chip (PERF.md
-   section 6, PR 27); :class:`SlabAutotuner` sizes the XLA tier's
-   slabs only (``ops.pow_search.solve``), where a shape is cheap.
+* ``pow/dispatcher.py`` — the ladder: which rung, and is it healthy;
+* this module — :func:`plan_batch` (the only place that says which
+  kernel and which static shape serve a batch, a lone object included)
+  and :class:`_PipelineDriver` (the only dispatch-ahead loop of the
+  single-chip path, with the one speculation rule, the stall watchdog,
+  the ``pow.*`` spans and the launch counters);
+* ``ops/sha512_pallas.py`` — the jitted kernels, and nothing else.
 
-The planner (:func:`plan_batch`) chooses per batch between the packed
-kernel (many small objects), the per-object batch kernel (few large
-objects) and a latency-optimal synchronous single launch (the
-degenerate one-tiny-object case must not pay speculative dispatch).
-Every stage reports through ``observability.REGISTRY`` — device-busy
-fraction, dispatch-ahead depth, pack occupancy — per the conventions
-in docs/observability.md; see docs/pow_pipeline.md for the full
-architecture.
+Four plan modes, one loop:
+
+``slab``         one object alone at network difficulty (a send of
+                 ``single_send``): ``pallas_search`` at 128 x 512 x 5,
+                 one slab in flight ahead of the one being read;
+``batched``      a queue of objects (``chan_storm_256``): the
+                 per-object grid ``pallas_batch_search``, 64 objects a
+                 launch at 128 chunks;
+``packed``       a storm of tiny objects sharing tiles along the lane
+                 axis (``pallas_packed_search``);
+``single-sync``  one tiny object: the packed kernel at pack 1, one
+                 launch at a time (dispatch-ahead would only delay an
+                 answer expected in the first launch).
+
+``chunks`` is a static argument of every Mosaic kernel, so each value
+is a program of its own to trace, lower and compile, and one the chip
+may refuse (``pallas_search`` at 1024 chunks needs more SMEM than a
+v5e has).  Each mode therefore has ONE chunk count, measured on the
+chip (PERF.md section 6, PR 27); :class:`SlabAutotuner` sizes the XLA
+tier's slabs only (``ops.pow_search.solve``), where a shape is cheap.
+
+The kernels that the benchmark's launch log and the tests replace
+(``pallas_search``, ``pallas_batch_search``) are looked up on
+``ops.sha512_pallas`` at every launch, never bound here by name.
 
 On hosts without an accelerator (the CI virtual CPU mesh) the Mosaic
 kernels are replaced by an XLA equivalent with the identical
-(pack, 3)-row output contract (``impl="xla"``), so the planning,
-pipelining and metrics logic is fully exercised without a TPU —
-the same pattern ``parallel/pow_pallas_sharded.py`` uses.
+(objects, 3)-row output contract (``impl="xla"``), so planning, the
+driver and the metrics are exercised without a TPU — the same pattern
+``parallel/pow_pallas_sharded.py`` uses.
 """
 
 from __future__ import annotations
@@ -59,8 +65,10 @@ from ..observability.flightrec import record as _flight
 from ..ops.pow_search import PowInterrupted
 from ..resilience.chaos import inject
 from ..resilience.watchdog import STALLS, SlabStallError
+from ..ops import sha512_pallas
 from ..ops.sha512_jax import double_sha512_trial
-from ..ops.sha512_pallas import (DEFAULT_ROWS, LANE_COLS,
+from ..ops.sha512_pallas import (BATCH_OBJS, BATCH_UNROLL, DEFAULT_CHUNKS,
+                                 DEFAULT_ROWS, DEFAULT_UNROLL, LANE_COLS,
                                  pallas_packed_search)
 from ..ops.u64 import U32
 from ..utils.hashes import double_sha512
@@ -140,12 +148,11 @@ class SlabAutotuner:
     reads as a fast device (PR 24 on the chip: the tuner then asked
     ``pallas_search`` for a shape the v5e cannot compile, and the
     breaker took every solve off the chip).  The Mosaic kernels do
-    not come here: each has one measured shape (see :func:`plan_batch`
-    and ``ops.sha512_pallas.solve``).  The EWMA plus a 10x outlier
-    clamp make one slow observation (a fresh jit compile, a host
-    stall) decay instead of permanently shrinking slabs.  Thread-safe:
-    the dispatcher's executor and the asyncio service may solve
-    concurrently.
+    not come here: each has one measured shape (see
+    :func:`plan_batch`).  The EWMA plus a 10x outlier clamp make one
+    slow observation (a fresh jit compile, a host stall) decay instead
+    of permanently shrinking slabs.  Thread-safe: the dispatcher's
+    executor and the asyncio service may solve concurrently.
     """
 
     def __init__(self, *, target_seconds: float = 0.5,
@@ -286,19 +293,20 @@ DEFAULT_BATCH_CHUNKS = 128
 #: compile cache stays a short ladder per pack
 PACKED_GROUPS_MAX = 64
 #: a single object expected to finish inside this many full-tile grid
-#: steps takes the latency-optimal synchronous path — speculative
+#: steps takes one launch at a time (mode ``single-sync``) — speculative
 #: dispatch-ahead would only add latency (the degenerate case)
 SYNC_SINGLE_STEPS = 8
 
 
 class BatchPlan:
-    """Execution plan for one pipelined batch (see :func:`plan_batch`)."""
+    """Which kernel at which static shape serves one batch (see
+    :func:`plan_batch`)."""
 
     __slots__ = ("mode", "pack", "chunks", "order")
 
     def __init__(self, mode: str, pack: int, chunks: int, order):
-        self.mode = mode        # "packed" | "batched" | "single-sync"
-        self.pack = pack        # objects per slab (packed mode)
+        self.mode = mode        # "slab" | "batched" | "packed" | "single-sync"
+        self.pack = pack        # objects per tile (packed mode)
         self.chunks = chunks    # grid steps per launch
         self.order = order      # item indices, difficulty-sorted
 
@@ -309,23 +317,32 @@ class BatchPlan:
 
 def plan_batch(items, *, rows: int = DEFAULT_ROWS,
                unroll: int = 1) -> BatchPlan:
-    """Choose packing and slab geometry from the batch's difficulty.
+    """Choose the kernel and its geometry from the batch's size and
+    difficulty — the only place that does.
 
-    The pack factor is sized so one launch covers roughly every
-    object's expected work: tiny (storm) objects pack 16 per slab,
-    network-default objects keep whole tiles (pack=1 -> the per-object
-    batch kernel), and a single small object degenerates to one
-    synchronous latency-optimal launch.  Objects are difficulty-sorted
-    so each packed group is homogeneous (a straggler would otherwise
-    hold its whole group's rows live).  Each mode has ONE chunk count,
-    so a node compiles each kernel once and launches nothing the chip
-    has not already accepted.
+    One object alone searches whole slabs of ``pallas_search`` (mode
+    ``slab``), or, when it is expected to finish inside
+    ``SYNC_SINGLE_STEPS`` grid steps, takes one small launch at a time
+    (``single-sync``).  For a queue the pack factor is sized so one
+    launch covers roughly every object's expected work: tiny (storm)
+    objects pack 16 per tile and network-default objects keep whole
+    tiles (pack=1 -> the per-object batch kernel).  Objects are
+    difficulty-sorted so each packed group is homogeneous (a straggler
+    would otherwise hold its whole group's rows live).  Each mode has
+    ONE chunk count, so a node compiles each kernel once and launches
+    nothing the chip has not already accepted.
     """
     n = len(items)
     exp = [expected_trials(t) for _, t in items]
     tile_step = rows * LANE_COLS * unroll      # full-tile trials/step
-    if n == 1 and exp[0] <= SYNC_SINGLE_STEPS * tile_step:
-        return BatchPlan("single-sync", 1, SYNC_SINGLE_STEPS, [0])
+    if n == 1:
+        if exp[0] <= SYNC_SINGLE_STEPS * tile_step:
+            return BatchPlan("single-sync", 1, SYNC_SINGLE_STEPS, [0])
+        # 512 is the largest power of two a v5e compiles for
+        # pallas_search (1024 asks for 1.01M of its 1.00M of SMEM;
+        # tests/test_tpu_compile.py), and the grid leaves at its first
+        # hit, so a long slab costs a short solve nothing
+        return BatchPlan("slab", 1, DEFAULT_CHUNKS, [0])
     order = sorted(range(n), key=lambda i: exp[i])
     med = sorted(exp)[n // 2]
     for p in PACK_CHOICES:
@@ -337,9 +354,18 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS,
     return BatchPlan("batched", 1, DEFAULT_BATCH_CHUNKS, order)
 
 
+#: what each plan mode is called in the launch counters (``kind``)
+_KIND = {"slab": "slab", "batched": "batch", "packed": "packed",
+         "single-sync": "single-sync"}
+
+
 # ---------------------------------------------------------------------------
 # dispatch-ahead driver
 # ---------------------------------------------------------------------------
+
+
+#: (program, static shape) pairs this process has launched once
+_TRACED_SHAPES: set = set()
 
 
 class _PipelineDriver:
@@ -355,7 +381,7 @@ class _PipelineDriver:
     def __init__(self, *, depth: int = 2,
                  should_stop: Callable[[], bool] | None = None,
                  fetch=None, stall_timeout: float = 0.0,
-                 kind: str = "batch"):
+                 kind: str = "batch", shape=None):
         import numpy as np
 
         def default_fetch(dev):
@@ -378,6 +404,10 @@ class _PipelineDriver:
         self._guard_pool = None
         #: label of this driver's launches in the pipeline counters
         self.kind = kind
+        #: (program, static shape) of the launches, if the caller knows:
+        #: the first launch of a shape in the process traces and lowers
+        #: the kernel (see :meth:`_on_device_thread`)
+        self.shape = shape
         self.wait_seconds = 0.0
         #: blocking wait of the latest fetch (its ``pow.fetch`` span)
         self.last_wait = 0.0
@@ -386,41 +416,57 @@ class _PipelineDriver:
 
     def _fetch(self, dev):
         with trace("pow.fetch") as span:
-            host = self._guarded_fetch(dev)
+            host = self._on_device_thread(self.fetch, dev,
+                                          timeout=self.stall_timeout)
         self.last_wait = span.duration
         self.wait_seconds += span.duration
         DEVICE_WAIT.observe(span.duration)
         return host
 
-    def _guarded_fetch(self, dev):
+    def _on_device_thread(self, fn, *args, timeout=None):
+        """``fn(*args)`` on this driver's one worker thread, the
+        caller's span context with it.  With the watchdog off
+        (``stall_timeout`` 0) it is called in place.
+
+        Fetches run there so that a wedged transfer can be left behind
+        (``timeout``).  So does the first launch of a shape in the
+        process, with no deadline (a cold compile is inside it): it
+        traces and lowers the kernel, and how long CPython 3.12 takes
+        over that depends on how deep the calling thread's frames
+        already are — every call that crosses a 16 KiB boundary of the
+        thread's frame stack frees and maps a chunk, and a trace that
+        hovers there took 2.5 times as long on the chip (PERF.md
+        section 6, PR 29).  From a worker's own shallow stack the
+        answer does not depend on what called the solve."""
         if not self.stall_timeout or self.stall_timeout <= 0:
-            return self.fetch(dev)
+            return fn(*args)
         import concurrent.futures as cf
+        import contextvars
         if self._guard_pool is None:
             self._guard_pool = cf.ThreadPoolExecutor(
                 1, thread_name_prefix="bmtpu-pow-slab-guard")
-        fut = self._guard_pool.submit(self.fetch, dev)
+        fut = self._guard_pool.submit(contextvars.copy_context().run,
+                                      fn, *args)
         try:
-            return fut.result(self.stall_timeout)
+            return fut.result(timeout)
         except cf.TimeoutError:
             STALLS.labels(site="pow.slab").inc()
             # black box: dump the ring while the pre-stall context
             # (launches, breaker flips, chaos fires) is still in it
             from ..observability.flightrec import FLIGHT_RECORDER
             FLIGHT_RECORDER.record("stall", site="pow.slab",
-                                   timeout=self.stall_timeout)
+                                   timeout=timeout)
             FLIGHT_RECORDER.dump("stall")
             logger.error("pow.slab stalled: harvest exceeded %.1fs; "
                          "abandoning the launch and falling back",
-                         self.stall_timeout)
+                         timeout)
             # consume whatever the wedged worker eventually produces so
             # its late exception is not reported as never-retrieved
             fut.add_done_callback(lambda f: f.exception())
             self._guard_pool.shutdown(wait=False)
             self._guard_pool = None
             raise SlabStallError(
-                "pow.slab exceeded %.1fs stall deadline"
-                % self.stall_timeout)
+                "pow.slab exceeded %.1fs stall deadline" % timeout)
 
     def run(self, next_launch, harvest, done=None) -> None:
         inflight: deque = deque()
@@ -445,9 +491,14 @@ class _PipelineDriver:
                         harvest(tag, self._fetch(dev))
                     raise PowInterrupted("pipelined PoW interrupted")
                 while len(inflight) < self.depth:
-                    nxt = next_launch()
+                    if self.shape in _TRACED_SHAPES:
+                        nxt = next_launch()
+                    else:
+                        nxt = self._on_device_thread(next_launch)
                     if nxt is None:
                         break
+                    if self.shape is not None:
+                        _TRACED_SHAPES.add(self.shape)
                     inflight.append(nxt)
                     self.slabs += 1
                     LAUNCHES.labels(kind=self.kind).inc()
@@ -480,8 +531,27 @@ class _PipelineDriver:
 
 
 # ---------------------------------------------------------------------------
-# the pipelined batch solve (production entry)
+# the pipelined solve (production entry)
 # ---------------------------------------------------------------------------
+
+
+def _split64(value: int):
+    """A 64-bit value as the ``(hi, lo)`` uint32 pair the kernels take."""
+    return (value >> 32) & 0xFFFFFFFF, value & 0xFFFFFFFF
+
+
+def _hash_words(initial_hash: bytes):
+    """The eight 64-bit words of an initial hash as ``(hi, lo)`` pairs."""
+    return [_split64(int.from_bytes(initial_hash[j:j + 8], "big"))
+            for j in range(0, 64, 8)]
+
+
+def _checked_nonce(nonce: int, initial_hash: bytes, target: int) -> int:
+    """The hashlib re-check of a nonce the device reported as a winner."""
+    check = double_sha512(nonce.to_bytes(8, "big") + initial_hash)
+    if int.from_bytes(check[:8], "big") > target:
+        raise ArithmeticError("accelerator returned an invalid PoW nonce")
+    return nonce
 
 
 class _LaunchGroup:
@@ -490,21 +560,19 @@ class _LaunchGroup:
     __slots__ = ("idx", "ih_words", "targets", "t_arr", "bases",
                  "trials", "done", "launches", "width")
 
-    def __init__(self, items, idx, width, starts=None):
+    def __init__(self, items, idx, width, starts=None, unbatched=False):
         import numpy as np
 
         pad = width - len(idx)
         ihs = [items[i][0] for i in idx] + [b"\x00" * 64] * pad
         self.targets = ([items[i][1] & _MASK64 for i in idx]
                         + [_ALWAYS_HIT] * pad)
-        words = [[int.from_bytes(ih[j:j + 8], "big")
-                  for j in range(0, 64, 8)] for ih in ihs]
-        self.ih_words = jnp.array(
-            [[[w >> 32, w & 0xFFFFFFFF] for w in ws] for ws in words],
-            dtype=U32)
-        self.t_arr = np.array(
-            [[t >> 32, t & 0xFFFFFFFF] for t in self.targets],
-            dtype=np.uint32)
+        words = np.array([_hash_words(ih) for ih in ihs], dtype=np.uint32)
+        # ``pallas_search`` takes its one object's words without the
+        # leading object axis
+        self.ih_words = jnp.asarray(words[0] if unbatched else words)
+        self.t_arr = np.array([_split64(t) for t in self.targets],
+                              dtype=np.uint32)
         self.idx = list(idx)
         self.width = width
         # resumable PoW: each object's search starts at its journaled
@@ -530,6 +598,19 @@ def _pow2_at_least(n: int, cap: int) -> int:
     return min(p, cap)
 
 
+def _slab_rows(out, found):
+    """``pallas_search``'s output (a hit flag and a nonce a grid step)
+    as the one ``[hit_step + 1, nonce_hi, nonce_lo]`` row the other
+    kernels return; the nonces are pulled only after a hit."""
+    import numpy as np
+
+    rows = np.zeros((1, 3), np.uint32)
+    step = int(found.argmax())
+    if found[step]:
+        rows[0] = (step + 1, *np.asarray(out[1])[step])
+    return rows
+
+
 def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           unroll: int = 1, depth: int = 2,
                           impl: str | None = None,
@@ -539,30 +620,30 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           should_stop: Callable[[], bool] | None = None,
                           start_nonces=None, progress=None,
                           stall_timeout: float = 0.0):
-    """Solve ``[(initial_hash, target), ...]`` through the async
-    double-buffered pipeline.  Returns ``[(nonce, trials), ...]``
-    aligned with ``items``; raises :class:`PowInterrupted` on
+    """Solve ``[(initial_hash, target), ...]`` — one object or a queue
+    — through the dispatch-ahead driver.  Returns ``[(nonce, trials),
+    ...]`` aligned with ``items``; raises :class:`PowInterrupted` on
     shutdown.
 
-    Mode selection (see :func:`plan_batch`): a storm of small objects
-    runs packed (up to ``PACKED_GROUPS_MAX * pack`` objects per
-    launch), network-difficulty batches run the per-object batch
-    kernel geometry (full tile per object), and a single tiny object
-    takes one synchronous latency-optimal launch with no speculative
-    dispatch.  Every returned nonce is host re-verified.  ``stats``
-    (optional dict) receives executed-trials/launch/wall accounting:
-    per-object ``trials`` in the results credit only the lanes the
-    object itself searched, while ``stats["executed_trials"]``
-    estimates total device hashing including straggler and pad waste —
-    the two diverge exactly where packing removes waste.
+    The plan (see :func:`plan_batch`) names the kernel and its shape;
+    every mode then runs the same loop: launch groups of ``width``
+    objects, up to ``depth`` launches in flight (one for
+    ``single-sync``), the oldest read back while the newer run.  Every
+    returned nonce is host re-verified.  Per-object ``trials`` credit
+    the grid steps the object's own search really ran (a search leaves
+    its launch at its first hit), for every mode;
+    ``stats["executed_trials"]`` (optional dict) estimates total device
+    hashing including straggler and pad waste — the two diverge
+    exactly where packing removes waste.
 
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
     proven miss-free for item ``i`` (safe resume point — speculative
     dispatch-ahead never moves a checkpoint before its slab is
-    harvested); ``stall_timeout > 0`` bounds each harvest's blocking
-    device wait.
+    harvested); on ``should_stop`` what is in flight is harvested
+    first, and an answer found there is returned; ``stall_timeout > 0``
+    bounds each harvest's blocking device wait.
     """
     import numpy as np
 
@@ -576,74 +657,51 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             plan = plan_batch(items, rows=rows, unroll=unroll)
             span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
+    mode, pack, chunks = plan.mode, plan.pack, plan.chunks
+    kind = _KIND[mode]
+    pallas = impl == "pallas"
 
-    if plan.mode == "single-sync":
-        return [_solve_single_sync(
-            items[0], rows=rows, unroll=unroll,
-            chunks=plan.chunks, impl=impl, interpret=interpret,
-            should_stop=should_stop,
-            start_nonce=(start_nonces[0] if start_nonces else 0),
-            progress=(None if progress is None
-                      else (lambda nxt: progress(0, nxt))))]
-
-    if plan.mode == "packed":
-        pack = plan.pack
+    # the launch geometry of each mode, and the jitted program it
+    # launches with the static-shape key that decides compile-vs-cache
+    # (mirrors each kernel's static_argnames) for device telemetry
+    width = 1
+    if mode == "packed":
         # one launch carries groups*pack objects on the leading grid
         # axis — the storm's launch-overhead amortization
-        n_groups = _pow2_at_least(-(-n // pack), PACKED_GROUPS_MAX)
-        width = n_groups * pack
-        step_trials = (rows // pack) * LANE_COLS * unroll
-        kind = "packed"
-    else:
-        from ..ops.sha512_pallas import BATCH_OBJS, BATCH_UNROLL
-        pack = 1
+        width = pack * _pow2_at_least(-(-n // pack), PACKED_GROUPS_MAX)
+    elif mode == "batched":
         width = BATCH_OBJS
-        unroll = BATCH_UNROLL if impl == "pallas" else unroll
-        step_trials = rows * LANE_COLS * unroll
-        kind = "batch"
-    slab_trials = step_trials * plan.chunks     # per object per launch
-
-    # device-telemetry attribution: which jitted program this plan
-    # actually launches, plus the static-shape key that decides
-    # compile-vs-cache (mirrors each kernel's static_argnames)
-    if impl != "pallas":
-        tele_prog = "packed_search_xla"
-        tele_key = (step_trials, plan.chunks)
-    elif plan.mode == "packed":
-        tele_prog = "packed_search"
-        tele_key = (rows, plan.chunks, pack, unroll, interpret)
+        unroll = BATCH_UNROLL if pallas else unroll
+    elif mode == "slab":
+        unroll = DEFAULT_UNROLL if pallas else unroll
     else:
-        tele_prog = "batch_search"
-        tele_key = (rows, plan.chunks, unroll, interpret)
+        depth = 1
+    step_trials = (rows // pack) * LANE_COLS * unroll
+    slab_trials = step_trials * chunks          # per object per launch
+    if not pallas:
+        tele_prog, tele_key = "packed_search_xla", (step_trials, chunks)
+    elif mode == "slab":
+        tele_prog, tele_key = "pallas_slab", (rows, chunks, unroll,
+                                              interpret)
+    elif mode == "batched":
+        tele_prog, tele_key = "batch_search", (rows, chunks, unroll,
+                                               interpret)
+    else:
+        tele_prog, tele_key = "packed_search", (rows, chunks, pack,
+                                                unroll, interpret)
+    # the packed Mosaic kernel donates its base/target input buffers,
+    # which is why they are made anew for every launch
+    donated = pallas and mode in ("packed", "single-sync")
+    unbatched = pallas and mode == "slab"
 
     with trace("pow.groups", objects=n, width=width):
         groups = [
             _LaunchGroup(items, plan.order[s:s + width], width,
-                         starts=start_nonces)
+                         starts=start_nonces, unbatched=unbatched)
             for s in range(0, n, width)
         ]
     results: list = [None] * n
     executed = {"trials": 0, "launches": 0}
-
-    def search(g: _LaunchGroup):
-        bases = np.array(
-            [[(b >> 32) & 0xFFFFFFFF, b & 0xFFFFFFFF] for b in g.bases],
-            dtype=np.uint32)
-        if impl != "pallas":
-            return _packed_search_xla(
-                g.ih_words, jnp.asarray(bases), jnp.asarray(g.t_arr),
-                lanes=step_trials, chunks=plan.chunks)
-        if plan.mode == "packed":
-            return pallas_packed_search(
-                g.ih_words, jnp.asarray(bases), jnp.asarray(g.t_arr),
-                rows=rows, chunks=plan.chunks, pack=pack, unroll=unroll,
-                interpret=interpret)
-        from ..ops.sha512_pallas import pallas_batch_search
-        out = pallas_batch_search(
-            g.ih_words, jnp.asarray(bases), jnp.asarray(g.t_arr),
-            rows=rows, chunks=plan.chunks, unroll=unroll,
-            interpret=interpret)
-        return out
 
     rr = {"i": 0}
     inflight_groups: set = set()
@@ -658,8 +716,10 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                 rr["i"] = (rr["i"] + off + 1) % len(groups)
                 break
         if cand is None:
-            # speculate one slab ahead on a group that already proved
-            # it needs more than one launch
+            # THE speculation rule, for every mode: with no fresh group
+            # left, dispatch the next slab of a group whose earlier
+            # slab is still unread; if that one hits, run() counts
+            # this one abandoned
             for g in groups:
                 if not g.finished and g.launches >= 1:
                     cand = g
@@ -667,16 +727,40 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         if cand is None:
             return None
         live = cand.live()
-        if plan.mode == "packed":
+        if mode == "packed":
             # pack statistics describe lane sharing, which only the
-            # packed kernel does — batched launches must not dilute
+            # packed kernel does — the other modes must not dilute
             # them (docs/observability.md semantics)
             PACK_SIZE.observe(live)
             PACK_OCCUPANCY.set(live / cand.width)
-        with trace("pow.launch", program=tele_prog, chunks=plan.chunks,
+        with trace("pow.launch", program=tele_prog, chunks=chunks,
                    live=live) as span:
-            out = search(cand)
-        t0, t1 = span.start, span.end
+            # the kernels are called from this frame, not through a
+            # helper: on the chip the first call of a process (trace
+            # and lowering of pallas_search) took 2.5 times as long
+            # from one frame further down (PERF.md section 6, PR 29)
+            bases = np.array([_split64(b) for b in cand.bases],
+                             dtype=np.uint32)
+            if not pallas:
+                out = _packed_search_xla(
+                    cand.ih_words, jnp.asarray(bases),
+                    jnp.asarray(cand.t_arr), lanes=step_trials,
+                    chunks=chunks)
+            elif mode == "slab":
+                # numpy arguments: the transfers ride the jit call
+                out = sha512_pallas.pallas_search(
+                    cand.ih_words, bases[0], cand.t_arr[0], rows=rows,
+                    chunks=chunks, unroll=unroll, interpret=interpret)
+            elif mode == "batched":
+                out = sha512_pallas.pallas_batch_search(
+                    cand.ih_words, jnp.asarray(bases),
+                    jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
+                    unroll=unroll, interpret=interpret)
+            else:
+                out = pallas_packed_search(
+                    cand.ih_words, jnp.asarray(bases),
+                    jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
+                    pack=pack, unroll=unroll, interpret=interpret)
         inflight_groups.add(id(cand))
         cand.launches += 1
         executed["launches"] += 1
@@ -686,45 +770,31 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         # snapshot of each object's post-slab offset: the safe resume
         # point to checkpoint once THIS slab harvests miss-free (the
         # live ``bases`` may already include speculative launches)
-        end_bases = list(cand.bases)
-        return ((cand, t0, t1, end_bases), out)
+        tag = (cand, span.start, span.end, list(cand.bases), out)
+        # what the driver blocks on: pallas_search's hit flags
+        return tag, (out[0] if unbatched else out)
 
-    def harvest(tag, out):
+    def harvest(tag, host):
         with trace("pow.harvest") as span:
-            _harvest(tag, out, span.start)
+            _harvest(tag, host, span.start)
 
-    def _harvest(tag, out, t_h):
-        g, t0, t1, end_bases = tag
+    def _harvest(tag, host, t_h):
+        g, t0, t1, end_bases, out = tag
+        rows_out = _slab_rows(out, host) if unbatched else host
         inflight_groups.discard(id(g))
         before = executed["trials"]
-        _record_pipeline_launch = functools.partial(
-            record_launch, tele_prog, key=tele_key,
-            dispatch_seconds=t1 - t0,
-            # the driver fetched this slab just before calling us
-            wait_seconds=driver.last_wait,
-            span=(t0, t_h), bytes_in=16 * g.width,
-            bytes_out=12 * g.width,
-            # the packed Mosaic kernel donates its base/target input
-            # buffers (see _solve_single_sync's fresh-per-iteration
-            # note); XLA and batch launches keep theirs
-            bytes_donated=(16 * g.width
-                           if impl == "pallas" and plan.mode == "packed"
-                           else 0))
         for k in range(g.width):
             if g.done[k]:
                 # solved/pad slots still executed one always-hit step
                 executed["trials"] += step_trials
                 continue
-            step1 = int(out[k, 0])
+            step1 = int(rows_out[k, 0])
             if step1:
                 g.trials[k] += step1 * step_trials
                 executed["trials"] += step1 * step_trials
-                nonce = (int(out[k, 1]) << 32) | int(out[k, 2])
-                ih = items[g.idx[k]][0]
-                check = double_sha512(nonce.to_bytes(8, "big") + ih)
-                if int.from_bytes(check[:8], "big") > g.targets[k]:
-                    raise ArithmeticError(
-                        "accelerator returned an invalid PoW nonce")
+                nonce = _checked_nonce(
+                    (int(rows_out[k, 1]) << 32) | int(rows_out[k, 2]),
+                    items[g.idx[k]][0], g.targets[k])
                 results[g.idx[k]] = (nonce, g.trials[k])
                 g.done[k] = True
                 # pad semantics: always-hit next launch, then idle
@@ -736,11 +806,18 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     # this slab proved [prev, end_bases[k]) miss-free:
                     # a resumed search may safely start there
                     progress(g.idx[k], end_bases[k])
-        EXECUTED_TRIALS.labels(kind=kind).inc(executed["trials"] - before)
-        _record_pipeline_launch(items=executed["trials"] - before)
+        ran = executed["trials"] - before
+        EXECUTED_TRIALS.labels(kind=kind).inc(ran)
+        record_launch(tele_prog, key=tele_key, dispatch_seconds=t1 - t0,
+                      # the driver fetched this slab just before calling us
+                      wait_seconds=driver.last_wait, span=(t0, t_h),
+                      items=ran, bytes_in=16 * g.width,
+                      bytes_out=12 * g.width,
+                      bytes_donated=16 * g.width if donated else 0)
 
     driver = _PipelineDriver(depth=depth, should_stop=should_stop,
-                             stall_timeout=stall_timeout, kind=kind)
+                             stall_timeout=stall_timeout, kind=kind,
+                             shape=(tele_prog, tele_key))
     try:
         driver.run(next_launch, harvest,
                    done=lambda: all(r is not None for r in results))
@@ -749,87 +826,13 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             raise
     if stats is not None:
         stats.update(
-            mode=plan.mode, pack=pack, width=width, chunks=plan.chunks,
+            mode=mode, pack=pack, width=width, chunks=chunks,
             launches=executed["launches"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),
             wall_seconds=driver.wall_seconds,
             device_busy_ratio=driver.busy_ratio)
     return results
-
-
-def _solve_single_sync(item, *, rows: int, unroll: int, chunks: int,
-                       impl: str, interpret: bool,
-                       should_stop: Callable[[], bool] | None,
-                       start_nonce: int = 0, progress=None):
-    """Latency-optimal degenerate path: one object, small synchronous
-    launches, no speculative dispatch-ahead (an extra in-flight slab
-    would only delay the answer for work expected to finish in the
-    first launch)."""
-    import numpy as np
-
-    initial_hash, target = item
-    target &= _MASK64
-    words = [int.from_bytes(initial_hash[i:i + 8], "big")
-             for i in range(0, 64, 8)]
-    ih_words = jnp.array([[[w >> 32, w & 0xFFFFFFFF] for w in words]],
-                         dtype=U32)
-    step_trials = rows * LANE_COLS * unroll
-    slab_trials = step_trials * chunks
-
-    base = start_nonce & _MASK64
-    trials = 0
-    while True:
-        if should_stop is not None and should_stop():
-            raise PowInterrupted("pipelined PoW interrupted")
-        b_arr = jnp.array([[(base >> 32) & 0xFFFFFFFF,
-                            base & 0xFFFFFFFF]], dtype=U32)
-        # fresh per-iteration (not hoisted): the packed kernel donates
-        # its base/target buffers
-        t_arr = jnp.array([[target >> 32, target & 0xFFFFFFFF]],
-                          dtype=U32)
-        with trace("pow.launch", chunks=chunks, live=1,
-                   program=("packed_search" if impl == "pallas"
-                            else "packed_search_xla")) as launch:
-            if impl == "pallas":
-                out = pallas_packed_search(
-                    ih_words, b_arr, t_arr, rows=rows, chunks=chunks,
-                    pack=1, unroll=unroll, interpret=interpret)
-            else:
-                out = _packed_search_xla(ih_words, b_arr, t_arr,
-                                         lanes=step_trials, chunks=chunks)
-        LAUNCHES.labels(kind="single-sync").inc()
-        with trace("pow.fetch") as fetch:
-            inject("pow.readback")
-            out = np.asarray(out)
-        t0, t1, t2 = launch.start, launch.end, fetch.end
-        if impl == "pallas":
-            record_launch("packed_search",
-                          key=(rows, chunks, 1, unroll, interpret),
-                          dispatch_seconds=t1 - t0, wait_seconds=t2 - t1,
-                          span=(t0, t2), items=slab_trials, bytes_in=16,
-                          bytes_out=int(out.nbytes), bytes_donated=16)
-        else:
-            record_launch("packed_search_xla",
-                          key=(step_trials, chunks),
-                          dispatch_seconds=t1 - t0, wait_seconds=t2 - t1,
-                          span=(t0, t2), items=slab_trials, bytes_in=16,
-                          bytes_out=int(out.nbytes))
-        step1 = int(out[0, 0])
-        EXECUTED_TRIALS.labels(kind="single-sync").inc(
-            step1 * step_trials if step1 else slab_trials)
-        if step1:
-            trials += step1 * step_trials
-            nonce = (int(out[0, 1]) << 32) | int(out[0, 2])
-            check = double_sha512(nonce.to_bytes(8, "big") + initial_hash)
-            if int.from_bytes(check[:8], "big") > target:
-                raise ArithmeticError(
-                    "accelerator returned an invalid PoW nonce")
-            return nonce, trials
-        trials += slab_trials
-        base = (base + slab_trials) & _MASK64
-        if progress is not None:
-            progress(base)
 
 
 def pipeline_snapshot() -> dict:

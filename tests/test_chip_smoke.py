@@ -79,13 +79,13 @@ def rehearsal(monkeypatch):
     monkeypatch.setattr(PowDispatcher, "_device_count", lambda self: 1)
     monkeypatch.setattr(verify_service, "_accelerator_backend",
                         lambda: True)
-    for fn, overrides in (
-            (sha512_pallas.solve,
-             {"rows": 8, "chunks_per_call": 4, "unroll": 1}),
-            (pipeline.solve_batch_pipelined,
-             {"rows": 8, "impl": "pallas"})):
-        for key, value in overrides.items():
-            monkeypatch.setitem(fn.__kwdefaults__, key, value)
+    for key, value in (("rows", 8), ("impl", "pallas")):
+        monkeypatch.setitem(pipeline.solve_batch_pipelined.__kwdefaults__,
+                            key, value)
+    # a lone object's slab, cut to the tile: the plan and the pipeline
+    # read these two where they launch pallas_search
+    monkeypatch.setattr(pipeline, "DEFAULT_CHUNKS", 4)
+    monkeypatch.setattr(pipeline, "DEFAULT_UNROLL", 1)
 
     import jax
 
@@ -131,7 +131,8 @@ async def test_rehearsal_passes_with_the_platform_check_patched(
     assert rep.failures == [], out
     assert "crypto rung: native" in out     # auto is off on a CPU
     assert "every nonce valid by hashlib" in out
-    assert "shape key: batch_search" in out  # the compile table
+    # the compile table; the 1,000-byte message is planned as slabs
+    assert "shape key: pallas_slab" in out
 
 
 @pytest.mark.asyncio

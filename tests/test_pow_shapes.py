@@ -26,7 +26,7 @@ from pybitmessage_tpu.pow import pipeline
 from pybitmessage_tpu.pow.dispatcher import PowDispatcher, python_solve
 from pybitmessage_tpu.pow.pipeline import (
     DEFAULT_BATCH_CHUNKS, DEFAULT_PACKED_CHUNKS, SYNC_SINGLE_STEPS,
-    SlabAutotuner, plan_batch)
+    SlabAutotuner, expected_trials, plan_batch)
 
 _MASK64 = (1 << 64) - 1
 
@@ -79,22 +79,31 @@ def _refusing(allowed: int, launched: list, answer):
 
 def test_a_thousand_single_solves_launch_one_shape_and_keep_the_breaker_closed(
         one_chip, monkeypatch):
+    # the node's own tile: an object of more than 8 x 128 x 128
+    # expected trials is planned as whole slabs of pallas_search
+    monkeypatch.setitem(pipeline.solve_batch_pipelined.__kwdefaults__,
+                        "rows", sha512_pallas.DEFAULT_ROWS)
+    ih = hashlib.sha512(b"one at a time").digest()
+    target = 2 ** 64 // 200000
+    assert expected_trials(target) > SYNC_SINGLE_STEPS * 128 * 128
+    winner, _ = python_solve(ih, target)
     launched = []
 
     def hit_at_once(ih_words, base, target, chunks):
         found = np.zeros(chunks, np.int32)
         found[0] = 1
-        return found, np.zeros((chunks, 2), np.uint32)
+        nonce = np.zeros((chunks, 2), np.uint32)
+        nonce[0] = (winner >> 32, winner & 0xFFFFFFFF)
+        return found, nonce
 
     monkeypatch.setattr(
         sha512_pallas, "pallas_search",
         _refusing(sha512_pallas.DEFAULT_CHUNKS, launched, hit_at_once))
     fallbacks0 = _fallbacks()
     d = PowDispatcher(use_native=False)
-    ih = hashlib.sha512(b"one at a time").digest()
     for _ in range(1000):
-        nonce, _trials = d.solve(ih, _MASK64)      # every nonce passes
-        assert nonce == 0 and d.last_backend == "tpu-pallas"
+        nonce, _trials = d.solve(ih, target)
+        assert nonce == winner and d.last_backend == "tpu-pallas"
     # each solve dispatched its slab and one ahead of it
     assert len(launched) == 2000
     assert set(launched) == {(sha512_pallas.DEFAULT_ROWS,
@@ -147,7 +156,7 @@ def test_batches_launch_one_shape_whatever_the_tuner_was_fed(
     (64, 1000, "packed", DEFAULT_PACKED_CHUNKS),
     (256, 6.8e6, "batched", DEFAULT_BATCH_CHUNKS),     # chan_storm_256
     (64, 1.3e7, "batched", DEFAULT_BATCH_CHUNKS),      # a burst of 1 kB
-    (1, 1.3e7, "batched", DEFAULT_BATCH_CHUNKS),
+    (1, 1.3e7, "slab", sha512_pallas.DEFAULT_CHUNKS),    # single_send
 ])
 def test_each_plan_mode_has_one_chunk_count(n, trials, mode, chunks):
     items = [(bytes(64), int(2 ** 64 / trials))] * n

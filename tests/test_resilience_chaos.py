@@ -112,16 +112,19 @@ def test_pipeline_readback_fault_then_resume_from_checkpoint():
         assert any(s > 0 for s in starts)
 
 
-def test_pipeline_stall_watchdog_abandons_wedged_readback():
+@pytest.mark.parametrize("mode, n", [("packed", 2), ("slab", 1)])
+def test_pipeline_stall_watchdog_abandons_wedged_readback(mode, n):
     """A wedged device->host transfer (simulated by an injected delay)
-    trips the slab-stall watchdog instead of hanging the pipeline."""
+    trips the slab-stall watchdog instead of hanging the pipeline —
+    for a queue and, since the lone-object search runs in the same
+    loop, for one object alone."""
     from pybitmessage_tpu.ops.pow_search import PowInterrupted
     from pybitmessage_tpu.pow.pipeline import (BatchPlan,
                                                solve_batch_pipelined)
     from pybitmessage_tpu.resilience import SlabStallError
 
-    items = [(_ih("stall0"), EASY), (_ih("stall1"), EASY)]
-    plan = BatchPlan("packed", 2, 8, [0, 1])
+    items = [(_ih("stall%d" % i), EASY) for i in range(n)]
+    plan = BatchPlan(mode, n, 8, list(range(n)))
     before = REGISTRY.sample("pow_stall_total", {"site": "pow.slab"})
     CHAOS.arm("pow.readback", delay=1.0, count=1)
     with pytest.raises((SlabStallError, PowInterrupted)):

@@ -122,32 +122,31 @@ def _probe_pow_verify():
     pow_search.verify([(0, _IH, _ALWAYS)])
 
 
+def _probe_pipeline(mode: str, n: int = 1, pack: int = 1,
+                    impl: str = "pallas"):
+    """One always-hit launch of the kernel plan mode ``mode`` names,
+    through the host loop the node itself uses."""
+    from pybitmessage_tpu.pow import pipeline
+    plan = pipeline.BatchPlan(mode, pack, 1, list(range(n)))
+    pipeline.solve_batch_pipelined(
+        [(_IH, _ALWAYS)] * n, rows=8, impl=impl, plan=plan,
+        interpret=impl == "pallas" and _interpret())
+
+
 def _probe_pallas_slab():
-    from pybitmessage_tpu.ops import sha512_pallas
-    sha512_pallas.solve(_IH, _ALWAYS, rows=8, chunks_per_call=1,
-                        unroll=1, interpret=_interpret())
+    _probe_pipeline("slab")
 
 
 def _probe_batch_search():
-    from pybitmessage_tpu.ops import sha512_pallas
-    sha512_pallas.solve_batch([(_IH, _ALWAYS)], rows=8,
-                              chunks_per_call=1, unroll=1,
-                              interpret=_interpret())
+    _probe_pipeline("batched")
 
 
 def _probe_packed_search():
-    from pybitmessage_tpu.pow import pipeline
-    items = [(_IH, _ALWAYS)] * 4
-    plan = pipeline.BatchPlan("packed", 2, 1, list(range(4)))
-    pipeline.solve_batch_pipelined(items, rows=8, impl="pallas",
-                                   interpret=_interpret(), plan=plan)
+    _probe_pipeline("packed", n=4, pack=2)
 
 
 def _probe_packed_search_xla():
-    from pybitmessage_tpu.pow import pipeline
-    items = [(_IH, _ALWAYS)] * 4
-    plan = pipeline.BatchPlan("packed", 2, 1, list(range(4)))
-    pipeline.solve_batch_pipelined(items, rows=8, impl="xla", plan=plan)
+    _probe_pipeline("packed", n=4, pack=2, impl="xla")
 
 
 def _probe_sharded_search():
