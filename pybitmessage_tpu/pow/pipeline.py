@@ -16,15 +16,16 @@ Four plan modes, one loop:
 
 ``slab``         one object alone at network difficulty (a send of
                  ``single_send``): ``pallas_search`` at 128 x 512 x 5,
-                 one slab in flight ahead of the one being read;
+                 a second slab in flight only for an object hard
+                 enough to be unlikely to hit in the first;
 ``batched``      a queue of objects (``chan_storm_256``): the
                  per-object grid ``pallas_batch_search``, 64 objects a
                  launch at 128 chunks;
 ``packed``       a storm of tiny objects sharing tiles along the lane
                  axis (``pallas_packed_search``);
 ``single-sync``  one tiny object: the packed kernel at pack 1, one
-                 launch at a time (dispatch-ahead would only delay an
-                 answer expected in the first launch).
+                 launch at a time (the speculation rule would withhold
+                 the second by itself now: ROADMAP D11).
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -129,6 +130,12 @@ ABANDONED_LAUNCHES = REGISTRY.counter(
     "pow_pipeline_abandoned_launches_total",
     "Speculative launches dispatched and never fetched because every "
     "result was already in (the device still runs them)", ("kind",))
+SPECULATION = REGISTRY.counter(
+    "pow_pipeline_speculation_total",
+    "Decisions of the speculation rule: each time no group without an "
+    "unread launch was left and an unfinished one had a launch in "
+    "flight, its next launch was dispatched ahead (launched) or held "
+    "back until that one is read (withheld)", ("kind", "decision"))
 EXECUTED_TRIALS = REGISTRY.counter(
     "pow_pipeline_executed_trials_total",
     "Trials the device computed in harvested launches, counted by the "
@@ -223,6 +230,39 @@ def expected_trials(target: int) -> float:
     return 2.0 ** 64 / max(target & _MASK64, 1)
 
 
+#: THE threshold of the speculation rule (:func:`worth_speculating`): a
+#: group's next launch is dispatched ahead only while the chance that
+#: its unread launches already finish it is below this.  From the costs
+#: measured on the chip (PERF.md section 5, PR 29): a speculation that
+#: was needed hides one host round trip R (``pow.harvest`` 0.85 ms +
+#: ``pow.launch`` 0.97 ms + the transfer: 2-3 ms) behind the launch in
+#: flight; one that was not leaves a launch on the device that runs to
+#: its own first hit before the next solve can start, W (43 ms for a
+#: lone object's slab, up to a whole batch launch of 144 ms for a
+#: queue's stragglers).  Speculating pays while (1 - p) R > p W, that is
+#: p < R / (R + W): 5.5 % for a slab, 1.7 % for a batch launch.  1/32
+#: lies between, and the chances that occur lie far from it on either
+#: side (a lone ack 0.98, a lone object of 4.9e9 trials 0.009 a slab, 64
+#: objects in mid sweep 1e-14), so no mode needs a value of its own.
+SPECULATE_BELOW = 1.0 / 32
+
+
+def worth_speculating(covered: float, targets) -> bool:
+    """Whether to dispatch a group's next launch before its unread
+    ones are read: ``covered`` is the trials each live object is
+    searched for in those unread launches, ``targets`` the live
+    objects' targets.  The search is memoryless, so the chance that
+    the unread launches finish every one of them is the product of
+    ``1 - exp(-covered / expected_trials(t))``; True while that is
+    below :data:`SPECULATE_BELOW`."""
+    p_finish = 1.0
+    for t in targets:
+        p_finish *= -math.expm1(-covered / expected_trials(t))
+        if p_finish < SPECULATE_BELOW:
+            return True         # every further factor is at most 1
+    return False
+
+
 # ---------------------------------------------------------------------------
 # XLA stand-in for the packed Mosaic kernel (CPU mesh / CI)
 # ---------------------------------------------------------------------------
@@ -293,8 +333,7 @@ DEFAULT_BATCH_CHUNKS = 128
 #: compile cache stays a short ladder per pack
 PACKED_GROUPS_MAX = 64
 #: a single object expected to finish inside this many full-tile grid
-#: steps takes one launch at a time (mode ``single-sync``) — speculative
-#: dispatch-ahead would only add latency (the degenerate case)
+#: steps takes one small launch at a time (mode ``single-sync``)
 SYNC_SINGLE_STEPS = 8
 
 
@@ -558,7 +597,7 @@ class _LaunchGroup:
     """Host state for one launch-wide slab group (``width`` objects)."""
 
     __slots__ = ("idx", "ih_words", "targets", "t_arr", "bases",
-                 "trials", "done", "launches", "width")
+                 "trials", "done", "unread", "width")
 
     def __init__(self, items, idx, width, starts=None, unbatched=False):
         import numpy as np
@@ -581,7 +620,8 @@ class _LaunchGroup:
                        for i in idx] + [0] * pad)
         self.trials = [0] * width
         self.done = [i >= len(idx) for i in range(width)]
-        self.launches = 0
+        #: launches dispatched and not yet harvested
+        self.unread = 0
 
     @property
     def finished(self) -> bool:
@@ -589,6 +629,9 @@ class _LaunchGroup:
 
     def live(self) -> int:
         return sum(1 for d in self.done if not d)
+
+    def live_targets(self):
+        return (t for t, d in zip(self.targets, self.done) if not d)
 
 
 def _pow2_at_least(n: int, cap: int) -> int:
@@ -628,7 +671,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     The plan (see :func:`plan_batch`) names the kernel and its shape;
     every mode then runs the same loop: launch groups of ``width``
     objects, up to ``depth`` launches in flight (one for
-    ``single-sync``), the oldest read back while the newer run.  Every
+    ``single-sync``), the oldest read back while the newer run; a
+    group's next launch goes ahead of its unread ones only where
+    :func:`worth_speculating` says so.  Every
     returned nonce is host re-verified.  Per-object ``trials`` credit
     the grid steps the object's own search really ran (a search leaves
     its launch at its first hit), for every mode;
@@ -704,26 +749,39 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     executed = {"trials": 0, "launches": 0}
 
     rr = {"i": 0}
-    inflight_groups: set = set()
+
+    def speculate():
+        # THE speculation rule, for every mode: with no fresh group
+        # left, dispatch the next launch of a group ahead of its unread
+        # ones only while those are unlikely to finish it (a lone ack
+        # is never speculated on, 64 objects in mid sweep always are);
+        # if they do finish it, run() counts this one abandoned
+        for g in groups:
+            if g.finished:
+                continue
+            ahead = worth_speculating(slab_trials * g.unread,
+                                      g.live_targets())
+            SPECULATION.labels(
+                kind=kind,
+                decision="launched" if ahead else "withheld").inc()
+            if ahead:
+                return g
+        return None
 
     def next_launch():
         cand = None
         # round-robin over unfinished groups without an in-flight slab
         for off in range(len(groups)):
             g = groups[(rr["i"] + off) % len(groups)]
-            if not g.finished and id(g) not in inflight_groups:
+            if not g.finished and not g.unread:
                 cand = g
                 rr["i"] = (rr["i"] + off + 1) % len(groups)
                 break
-        if cand is None:
-            # THE speculation rule, for every mode: with no fresh group
-            # left, dispatch the next slab of a group whose earlier
-            # slab is still unread; if that one hits, run() counts
-            # this one abandoned
-            for g in groups:
-                if not g.finished and g.launches >= 1:
-                    cand = g
-                    break
+        speculative = cand is None
+        if speculative:
+            # decided in a call that has returned before the kernel is
+            # called: none of it lies under a kernel's trace
+            cand = speculate()
         if cand is None:
             return None
         live = cand.live()
@@ -734,7 +792,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             PACK_SIZE.observe(live)
             PACK_OCCUPANCY.set(live / cand.width)
         with trace("pow.launch", program=tele_prog, chunks=chunks,
-                   live=live) as span:
+                   live=live, speculative=speculative) as span:
             # the kernels are called from this frame, not through a
             # helper: on the chip the first call of a process (trace
             # and lowering of pallas_search) took 2.5 times as long
@@ -761,8 +819,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     cand.ih_words, jnp.asarray(bases),
                     jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
                     pack=pack, unroll=unroll, interpret=interpret)
-        inflight_groups.add(id(cand))
-        cand.launches += 1
+        cand.unread += 1
         executed["launches"] += 1
         for k in range(cand.width):
             if not cand.done[k]:
@@ -781,7 +838,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     def _harvest(tag, host, t_h):
         g, t0, t1, end_bases, out = tag
         rows_out = _slab_rows(out, host) if unbatched else host
-        inflight_groups.discard(id(g))
+        g.unread -= 1
         before = executed["trials"]
         for k in range(g.width):
             if g.done[k]:

@@ -7,13 +7,16 @@ on the CPU, with the kernels' entry points replaced on the module
 attributes by stand-ins that follow a script (launch k misses, or hits
 at grid step s): the launches each of the four plan modes makes, at
 which shape, how many it leaves behind unfetched, what it reports as
-progress and how many trials it credits — the sequence the lone-object
-loop of ``ops/sha512_pallas.solve`` and ``_solve_single_sync`` produced
-before they were folded into the driver.
+progress and how many trials it credits.  Since ISSUE 30 a group's next
+launch goes ahead of its unread ones only while those are unlikely to
+finish it (``pipeline.worth_speculating``): the cases hold which solves
+still leave a launch behind, and the rule is held alone on the
+benchmark cells' own numbers.
 """
 
 import ast
 import hashlib
+import math
 import pathlib
 import sys
 
@@ -34,23 +37,51 @@ SLAB = 512 * SLAB_STEP
 _WINNERS: dict = {}
 
 
+#: expected trials above which no host finds a nonce inside a test
+SOLVABLE = 200000
+#: a lone object hard enough for a second slab to go ahead of the first
+HARD = 64 * SLAB
+
+
 def _item(tag: bytes, expected_trials: int):
-    """An object and a nonce that really solves it (found once)."""
+    """An object and a nonce that really solves it (found once).  Past
+    ``SOLVABLE`` the nonce is made up: see :func:`_skip_recheck`."""
     ih = hashlib.sha512(b"one loop " + tag).digest()
     target = 2 ** 64 // expected_trials
+    if expected_trials > SOLVABLE:
+        return (ih, target), int.from_bytes(ih[:8], "big")
     if (ih, target) not in _WINNERS:
         _WINNERS[ih, target] = python_solve(ih, target)[0]
     return (ih, target), _WINNERS[ih, target]
+
+
+def _skip_recheck(monkeypatch):
+    """An object hard enough to be speculated on is too hard to solve
+    here, so its scripted winner is not one: the hashlib re-check is
+    taken out for it (the solvable cases keep it)."""
+    monkeypatch.setattr(pipeline, "_checked_nonce",
+                        lambda nonce, initial_hash, target: nonce)
+
+
+def _hard_item(monkeypatch, tag: bytes = b"hard 0"):
+    _skip_recheck(monkeypatch)
+    return _item(tag, HARD)
 
 
 def _counted(name: str, kind: str) -> float:
     return REGISTRY.sample(name, {"kind": kind})
 
 
+def _of(step, k: int):
+    """What a script's entry says of object ``k``."""
+    return step[k] if isinstance(step, tuple) else step
+
+
 class Scripted:
     """Stand-ins for the three kernel entry points.  ``script[k]`` says
     what launch ``k`` reports for every live object: None (no hit) or
-    the grid step, counted from 1, at which each hits."""
+    the grid step, counted from 1, at which each hits; a tuple says it
+    object by object."""
 
     def __init__(self, script, winners):
         self.script, self.winners = list(script), list(winners)
@@ -81,8 +112,8 @@ class Scripted:
         for k, (t_hi, t_lo) in enumerate(np.asarray(targets)):
             if k >= len(self.winners) or (t_hi, t_lo) == (2 ** 32 - 1,) * 2:
                 out[k] = (1, 0, 0)          # pad or solved: always hits
-            elif step:
-                out[k] = (step, self.winners[k] >> 32,
+            elif _of(step, k):
+                out[k] = (_of(step, k), self.winners[k] >> 32,
                           self.winners[k] & 0xFFFFFFFF)
         return out
 
@@ -104,32 +135,75 @@ class Scripted:
         return self
 
 
+#: trials an object a launch of the batch kernel at 8 rows
+BATCH8 = 128 * 8 * 128 * 4
+
 # mode, objects (n, expected trials each), rows, the script, and what
-# the parent's loops did with it: entry point, static shape, width,
-# trials of a grid step, launches, abandoned, slabs reported miss-free
+# the loop does with it: entry point, static shape, width, trials of a
+# grid step, launches, abandoned
 CASES = {
     "slab hits in its first slab": (
-        "slab", (1, 200000), 128, [3, None],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 1, 0),
+        "slab", (1, 200000), 128, [3],
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 1, 0),
     "slab misses, then hits": (
-        "slab", (1, 200000), 128, [None, 7, None],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 3, 1, 1),
+        "slab", (1, 200000), 128, [None, 7],
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 0),
     "batched queue": (
-        "batched", (2, 50000), 8, [2, None],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 1, 0),
+        "batched", (2, 50000), 8, [2],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 1, 0),
     "packed storm": (
-        "packed", (4, 16), 128, [1, None],
-        "pallas_packed_search", (128, 64, 4, 1), 4, 32 * 128, 2, 1, 0),
+        "packed", (4, 16), 128, [1],
+        "pallas_packed_search", (128, 64, 4, 1), 4, 32 * 128, 1, 0),
     "single-sync misses, then hits": (
         "single-sync", (1, 16), 128, [None, 5],
-        "pallas_packed_search", (128, 8, 1, 1), 1, 128 * 128, 2, 0, 1),
+        "pallas_packed_search", (128, 8, 1, 1), 1, 128 * 128, 2, 0),
+    # expected to need 64 slabs: the second goes ahead of the first,
+    "hard slab keeps two in flight, hits in its first": (
+        "slab", (1, HARD), 128, [3, None],
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 1),
+    "hard slab misses, then hits": (
+        "slab", (1, HARD), 128, [None, 7, None],
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 3, 1),
+    # 64 objects that each need about one launch: all 64 finishing in
+    # the one in flight is out of the question, so the next goes ahead
+    "batched group with 64 live is speculated on": (
+        "batched", (64, BATCH8), 8, [2, None],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 1),
+    # the same group once 63 have hit: the launch that went ahead while
+    # 64 were live is read, and nothing goes ahead of it for the last
+    "batched group with one live is not": (
+        "batched", (64, BATCH8), 8, [(2,) * 63 + (None,), 5],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 0),
 }
 
 
+def _foreseen(script, n, width, slab, step_trials):
+    """What reading ``script``'s launches in order does, object by
+    object: trials credited, ends of miss-free slabs reported, trials
+    executed (a solved or pad slot still runs one always-hit step)."""
+    credit, reported, executed = [0] * n, [], 0
+    live = set(range(n))
+    for j, step in enumerate(script):
+        for i in range(width):
+            if i not in live:
+                executed += step_trials
+            elif _of(step, i):
+                credit[i] += _of(step, i) * step_trials
+                executed += _of(step, i) * step_trials
+                live.discard(i)
+            else:
+                credit[i] += slab
+                executed += slab
+                reported.append((i, (j + 1) * slab))
+    return credit, reported, executed
+
+
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_each_mode_launches_what_the_parent_launched(case, monkeypatch):
+def test_each_mode_launches_what_its_targets_call_for(case, monkeypatch):
     (mode, (n, expected), rows, script, entry, shape, width, step_trials,
-     launches, abandoned, misses) = CASES[case]
+     launches, abandoned) = CASES[case]
+    if expected > SOLVABLE:
+        _skip_recheck(monkeypatch)
     items, winners = zip(*(_item(b"%s %d" % (mode.encode(), i), expected)
                            for i in range(n)))
     kernels = Scripted(script, winners).install(monkeypatch)
@@ -154,20 +228,83 @@ def test_each_mode_launches_what_the_parent_launched(case, monkeypatch):
     grown = {name: _counted(name, kind) - v for name, v in before.items()}
     assert grown["pow_pipeline_launches_total"] == launches
     assert grown["pow_pipeline_abandoned_launches_total"] == abandoned
-    # a miss-free slab's end is the checkpoint, once a slab and object
-    assert reported == [(i, (m + 1) * slab)
-                        for m in range(misses) for i in range(n)]
-    # trials are credited by the grid steps a search really ran
-    hit_step = next(s for s in script if s)
-    credit = misses * slab + hit_step * step_trials
-    assert results == [(w, credit) for w in winners]
-    pads = width - n
-    assert grown["pow_pipeline_executed_trials_total"] \
-        == n * credit + pads * step_trials * (misses + 1)
+    # of the launches read: a miss-free slab's end is the checkpoint,
+    # once a slab and object, and trials are credited by the grid steps
+    # a search really ran
+    credit, checkpoints, executed = _foreseen(
+        script[:launches - abandoned], n, width, slab, step_trials)
+    assert reported == checkpoints
+    assert results == list(zip(winners, credit))
+    assert grown["pow_pipeline_executed_trials_total"] == executed
+
+
+#: a batch launch at the node's geometry: 128 chunks x 128 rows x 128 x 4
+BATCH_LAUNCH = 128 * 128 * 128 * 4
+
+# the benchmark cells' own numbers (PERF.md section 4): trials the
+# unread launch covers, live objects, expected trials each, and the
+# chance that the unread launch finishes them all, to two digits
+RULE = {
+    "single_send's ack against a slab": (SLAB, 1, 1.08e7, 0.98, False),
+    "single_send's message against a slab": (SLAB, 1, 1.56e7, 0.93, False),
+    "the storm's 64 live against a batch launch": (
+        BATCH_LAUNCH, 64, 8.8e6, 2.8e-14, True),
+    "the storm's last 8": (BATCH_LAUNCH, 8, 8.8e6, 0.020, True),
+    "the storm's last 4": (BATCH_LAUNCH, 4, 8.8e6, 0.14, False),
+    "pod_hard's object on one chip against a slab": (
+        SLAB, 1, 4.9e9, 0.0085, True),
+    "two slabs unread of an object worth three": (
+        2 * SLAB, 1, 3 * SLAB, 0.49, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE))
+def test_the_rule_on_the_cells_own_numbers(case):
+    covered, live, expected, p_finish, ahead = RULE[case]
+    target = int(2 ** 64 / expected)
+    # what the rule compares with its threshold, worked out here
+    p = (1 - math.exp(-covered / pipeline.expected_trials(target))) ** live
+    assert p == pytest.approx(p_finish, rel=0.05)
+    assert (p < pipeline.SPECULATE_BELOW) is ahead
+    assert pipeline.worth_speculating(covered, [target] * live) is ahead
+
+
+@pytest.mark.parametrize("expected,decision,speculative", [
+    # each slab is launched once the one before it was read,
+    (200000, "withheld", [False, False]),
+    # the second and the third went ahead of the slab before them
+    (HARD, "launched", [False, True, True]),
+])
+def test_each_decision_is_counted_and_marks_its_launch(
+        expected, decision, speculative, monkeypatch):
+    """A lone solve that misses, then hits, decides twice, once with
+    each of the two slabs in flight: ``pow_pipeline_speculation_total``
+    counts the decisions, and the ``pow.launch`` span of a launch that
+    went ahead says so."""
+    from pybitmessage_tpu.observability import TRACER
+
+    if expected > SOLVABLE:
+        _skip_recheck(monkeypatch)
+    item, winner = _item(b"decided", expected)
+    Scripted([None, 6, None], [winner]).install(monkeypatch)
+
+    def counted():
+        return {d: REGISTRY.sample("pow_pipeline_speculation_total",
+                                   {"kind": "slab", "decision": d})
+                for d in ("launched", "withheld")}
+
+    before = counted()
+    TRACER.clear()
+    pipeline.solve_batch_pipelined([item], impl="pallas")
+    grown = {d: v - before[d] for d, v in counted().items()}
+    assert grown == {"launched": 0, "withheld": 0, decision: 2}
+    assert [sp.attrs["speculative"]
+            for sp in TRACER.recent(50, name="pow.launch")] == speculative
 
 
 def test_should_stop_returns_the_answer_of_the_slab_in_flight(monkeypatch):
-    item, winner = _item(b"slab 0", 200000)
+    # hard enough for its second slab to go ahead of the first
+    item, winner = _hard_item(monkeypatch)
     kernels = Scripted([None, 9], [winner]).install(monkeypatch)
     polls = []
 
@@ -211,7 +348,7 @@ def test_the_first_launch_of_a_shape_runs_on_the_drivers_worker_thread(
 
     from pybitmessage_tpu.observability import TRACER, trace
 
-    item, winner = _item(b"slab 0", 200000)
+    item, winner = _hard_item(monkeypatch)
     monkeypatch.setattr(pipeline, "_TRACED_SHAPES", set())
     here = threading.current_thread().name
     threads = []
@@ -252,7 +389,7 @@ def test_the_first_launch_of_a_shape_runs_on_the_drivers_worker_thread(
 
 
 def test_a_resumed_lone_solve_starts_at_its_checkpoint(monkeypatch):
-    item, winner = _item(b"slab 0", 200000)
+    item, winner = _hard_item(monkeypatch)
     kernels = Scripted([1, None], [winner]).install(monkeypatch)
     start = (1 << 64) - SLAB_STEP           # the next slab wraps
     pipeline.solve_batch_pipelined([item], impl="pallas",
@@ -270,7 +407,7 @@ def test_the_benchmarks_launch_log_sees_every_slab_launch(monkeypatch):
         sys.path.insert(0, str(REPO))
     from benchmarks import probes
 
-    item, winner = _item(b"slab 0", 200000)
+    item, winner = _hard_item(monkeypatch)
     kernels = Scripted([None, 4, None], [winner]).install(monkeypatch)
     log = probes.LaunchLog(REPO)
     log.install()

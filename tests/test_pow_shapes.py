@@ -104,8 +104,9 @@ def test_a_thousand_single_solves_launch_one_shape_and_keep_the_breaker_closed(
     for _ in range(1000):
         nonce, _trials = d.solve(ih, target)
         assert nonce == winner and d.last_backend == "tpu-pallas"
-    # each solve dispatched its slab and one ahead of it
-    assert len(launched) == 2000
+    # each solve dispatched its one slab: an object this easy against
+    # a slab is not speculated on (pipeline.worth_speculating)
+    assert len(launched) == 1000
     assert set(launched) == {(sha512_pallas.DEFAULT_ROWS,
                               sha512_pallas.DEFAULT_CHUNKS,
                               sha512_pallas.DEFAULT_UNROLL)}
