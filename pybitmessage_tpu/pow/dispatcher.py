@@ -375,7 +375,7 @@ class PowDispatcher:
     solve = __call__
 
     def solve_batch(self, items, *, should_stop=None, start_nonces=None,
-                    progress=None):
+                    progress=None, on_solved=None, feed=None):
         """Solve ``[(initial_hash, target), ...]`` -> ``[(nonce, trials)]``.
 
         All pending objects go down in ONE pod-wide launch when a
@@ -391,11 +391,45 @@ class PowDispatcher:
         and the sequential ladder all honor both (the XLA
         ``sharded_solve_batch`` rescue tier still re-searches from 0
         but remains correct).
+
+        The solve as a stream (docs/pow_pipeline.md): ``on_solved(i,
+        (nonce, trials))`` is called once for every item, as soon as
+        its nonce is known — from the harvest that found it on the
+        single-chip pipeline, when the rung returns on the rungs that
+        cannot stream — and ``feed(room)`` lets the pipeline's
+        ``batched`` mode take queued requests ``(initial_hash, target,
+        start_nonce)`` into freed slots; they are numbered on from
+        ``len(items)`` and their results follow the items' in what is
+        returned.  A rung that fails after some objects resolved hands
+        only the rest to the rungs below.  ``pow_trials_total`` is
+        credited as objects resolve and ``pow_attempts_total`` counts
+        every time the solve takes objects in (its start and each
+        refill), so both move inside a solve that outlives a window.
         """
         items = list(items)
         if not items:
             return []
         starts = list(start_nonces) if start_nonces else [0] * len(items)
+        solved: dict[int, tuple] = {}
+
+        def resolve(i, result):
+            # once an item, whichever rung found its nonce
+            if i in solved:
+                return
+            solved[i] = result
+            TRIALS.labels(backend=self.last_backend).inc(result[1])
+            if on_solved is not None:
+                on_solved(i, result)
+
+        def take(room):
+            arrived = feed(room)
+            if arrived:
+                for initial_hash, target, start in arrived:
+                    items.append((initial_hash, target))
+                    starts.append(start)
+                ATTEMPTS.labels(backend=self.last_backend).inc()
+            return arrived
+
         t0 = time.monotonic()
         with trace("pow.solve_batch", objects=len(items)) as span:
             # the farm rung leads the ladder; a farm failure falls
@@ -403,15 +437,19 @@ class PowDispatcher:
             results = self._try_farm(items, should_stop, starts)
             if results is None and self._tpu_enabled and len(items) > 1:
                 for rung, call in self._batch_rungs(
-                        items, starts, should_stop, progress):
+                        items, starts, should_stop, progress, resolve,
+                        take if feed is not None else None):
                     results = self._run_rung(rung, call)
                     if results is not None:
                         break
-            # one object, or a queue no device rung took: each object
-            # walks the per-object rungs
+            # one object, or a queue no device rung took (or finished):
+            # each object still unsolved walks the per-object rungs
             if results is None:
                 results = []
                 for i, (ih, t) in enumerate(items):
+                    if i in solved:
+                        results.append(solved[i])
+                        continue
                     prog = None
                     if progress is not None:
                         prog = (lambda n, _i=i: progress(_i, n))
@@ -421,6 +459,9 @@ class PowDispatcher:
                     results.append(self._solve(ih, t, starts[i],
                                                should_stop, progress=prog,
                                                try_farm=False))
+                    resolve(i, results[i])
+            for i, result in enumerate(results):
+                resolve(i, result)
             span.attrs["backend"] = self.last_backend
         self._record_recovery()
         dt = max(time.monotonic() - t0, 1e-9)
@@ -429,7 +470,6 @@ class PowDispatcher:
         self.last_solve_rate = trials / dt
         self.last_rate = trials / dt
         SOLVE_SECONDS.labels(backend=self.last_backend).observe(dt)
-        TRIALS.labels(backend=self.last_backend).inc(trials)
         return results
 
     def _on_accelerator(self) -> bool:
@@ -489,9 +529,11 @@ class PowDispatcher:
             _note_fallback(rung.frm, rung.to or self._host_tier())
             return None
 
-    def _batch_rungs(self, items, starts, should_stop, progress):
+    def _batch_rungs(self, items, starts, should_stop, progress,
+                     on_solved, feed):
         """The device rungs the topology admits for a queue, in order,
-        each with the call that runs it."""
+        each with the call that runs it.  Only the single-chip pipeline
+        streams: it alone is given ``on_solved`` and ``feed``."""
         ndev, on_accel = self._batch_topology()
 
         def pallas_sharded_batch():
@@ -511,7 +553,8 @@ class PowDispatcher:
             from .pipeline import solve_batch_pipelined
             return solve_batch_pipelined(
                 items, should_stop=should_stop, start_nonces=starts,
-                progress=progress, stall_timeout=self.stall_timeout)
+                progress=progress, stall_timeout=self.stall_timeout,
+                on_solved=on_solved, feed=feed)
 
         if ndev > 1:
             if on_accel:

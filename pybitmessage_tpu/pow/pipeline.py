@@ -27,6 +27,13 @@ Four plan modes, one loop:
                  launch at a time (the speculation rule would withhold
                  the second by itself now: ROADMAP D11).
 
+A ``batched`` solve is a stream (docs/pow_pipeline.md): an object
+leaves it when its nonce has passed the hashlib re-check
+(``on_solved``), and before a group's next launch each of its done
+slots takes an object that has arrived since (``feed``).  A queue of at
+most one launch's objects is laid out as two groups, so two launches
+alternate and none is dispatched ahead of an unread one.
+
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
 may refuse (``pallas_search`` at 1024 chunks needs more SMEM than a
@@ -136,6 +143,21 @@ SPECULATION = REGISTRY.counter(
     "unread launch was left and an unfinished one had a launch in "
     "flight, its next launch was dispatched ahead (launched) or held "
     "back until that one is read (withheld)", ("kind", "decision"))
+REFILLS = REGISTRY.counter(
+    "pow_pipeline_refills_total",
+    "Objects a running solve took from the queue into a slot whose "
+    "object had solved (or a pad slot), before the group's next launch",
+    ("kind",))
+SLOTS = REGISTRY.counter(
+    "pow_pipeline_slots_total",
+    "Slots of the launches dispatched: those that searched (live) and "
+    "those that were solved or pad and cost one always-hit step (idle)",
+    ("kind", "state"))
+NEEDED_TRIALS = REGISTRY.counter(
+    "pow_pipeline_needed_trials_total",
+    "Trials of harvested launches that a search needed: a slot that "
+    "missed, its whole slab; a slot that hit, up to its winning nonce; a "
+    "solved or pad slot, none", ("kind",))
 EXECUTED_TRIALS = REGISTRY.counter(
     "pow_pipeline_executed_trials_total",
     "Trials the device computed in harvested launches, counted by the "
@@ -335,6 +357,11 @@ PACKED_GROUPS_MAX = 64
 #: a single object expected to finish inside this many full-tile grid
 #: steps takes one small launch at a time (mode ``single-sync``)
 SYNC_SINGLE_STEPS = 8
+#: launch groups a ``batched`` solve of at most one launch's objects
+#: is laid out as: with two, the round-robin always finds a group with
+#: no unread launch, so the device stays busy without speculation, as
+#: it does under the storm's four (pad slots cost one step a launch)
+MIN_BATCH_GROUPS = 2
 
 
 class BatchPlan:
@@ -594,10 +621,12 @@ def _checked_nonce(nonce: int, initial_hash: bytes, target: int) -> int:
 
 
 class _LaunchGroup:
-    """Host state for one launch-wide slab group (``width`` objects)."""
+    """Host state for one launch-wide slab group (``width`` slots, of
+    which those not given an object are pad)."""
 
-    __slots__ = ("idx", "ih_words", "targets", "t_arr", "bases",
-                 "trials", "done", "unread", "width")
+    __slots__ = ("idx", "words", "ih_words", "stale", "targets", "t_arr",
+                 "bases", "trials", "done", "unread", "width",
+                 "unbatched")
 
     def __init__(self, items, idx, width, starts=None, unbatched=False):
         import numpy as np
@@ -606,20 +635,25 @@ class _LaunchGroup:
         ihs = [items[i][0] for i in idx] + [b"\x00" * 64] * pad
         self.targets = ([items[i][1] & _MASK64 for i in idx]
                         + [_ALWAYS_HIT] * pad)
-        words = np.array([_hash_words(ih) for ih in ihs], dtype=np.uint32)
+        self.words = np.array([_hash_words(ih) for ih in ihs],
+                              dtype=np.uint32)
         # ``pallas_search`` takes its one object's words without the
         # leading object axis
-        self.ih_words = jnp.asarray(words[0] if unbatched else words)
+        self.unbatched = unbatched
+        #: the device copy of ``words`` is behind the host's
+        self.stale = True
+        self.device_words()
         self.t_arr = np.array([_split64(t) for t in self.targets],
                               dtype=np.uint32)
-        self.idx = list(idx)
+        #: the item each slot searches for (None: a pad slot)
+        self.idx = list(idx) + [None] * pad
         self.width = width
         # resumable PoW: each object's search starts at its journaled
         # checkpoint offset instead of 0 (pad slots stay at 0)
         self.bases = ([(starts[i] if starts else 0) & _MASK64
                        for i in idx] + [0] * pad)
         self.trials = [0] * width
-        self.done = [i >= len(idx) for i in range(width)]
+        self.done = [i is None for i in self.idx]
         #: launches dispatched and not yet harvested
         self.unread = 0
 
@@ -632,6 +666,29 @@ class _LaunchGroup:
 
     def live_targets(self):
         return (t for t, d in zip(self.targets, self.done) if not d)
+
+    def device_words(self):
+        """The initial hashes as the kernels take them, sent to the
+        device again only after a refill."""
+        if self.stale:
+            self.ih_words = jnp.asarray(
+                self.words[0] if self.unbatched else self.words)
+            self.stale = False
+        return self.ih_words
+
+    def refill(self, k: int, i: int, initial_hash: bytes, target: int,
+               base: int) -> None:
+        """Slot ``k``, solved or pad, takes item ``i``.  Only between
+        launches that have all been read: an unread launch still
+        answers for the slot's last object."""
+        self.idx[k] = i
+        self.words[k] = _hash_words(initial_hash)
+        self.stale = True
+        self.targets[k] = target & _MASK64
+        self.t_arr[k] = _split64(self.targets[k])
+        self.bases[k] = base & _MASK64
+        self.trials[k] = 0
+        self.done[k] = False
 
 
 def _pow2_at_least(n: int, cap: int) -> int:
@@ -662,11 +719,12 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           stats: dict | None = None,
                           should_stop: Callable[[], bool] | None = None,
                           start_nonces=None, progress=None,
-                          stall_timeout: float = 0.0):
+                          stall_timeout: float = 0.0,
+                          on_solved=None, feed=None):
     """Solve ``[(initial_hash, target), ...]`` — one object or a queue
     — through the dispatch-ahead driver.  Returns ``[(nonce, trials),
-    ...]`` aligned with ``items``; raises :class:`PowInterrupted` on
-    shutdown.
+    ...]`` aligned with ``items`` (then with what ``feed`` brought, in
+    the order it came); raises :class:`PowInterrupted` on shutdown.
 
     The plan (see :func:`plan_batch`) names the kernel and its shape;
     every mode then runs the same loop: launch groups of ``width``
@@ -681,6 +739,19 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     hashing including straggler and pad waste — the two diverge
     exactly where packing removes waste.
 
+    The solve as a stream: ``on_solved(i, (nonce, trials))`` is called
+    from the harvest that found item ``i``'s nonce, once the nonce has
+    passed the hashlib re-check, in hit order and before the solve
+    returns.  ``feed(room)`` returns at most ``room`` new requests
+    ``(initial_hash, target, start_nonce)`` that have arrived since it
+    was last asked; a ``batched`` solve asks before a group's launch,
+    when all of the group's launches have been read, and gives each
+    done slot of that group one of them (item numbers go on from
+    ``len(items)``).  The plan is made of what is there at the start
+    and never again: a solve that starts with one object stays
+    ``slab`` and asks nobody.  The solve ends when every slot is done
+    and ``feed`` has nothing.
+
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
@@ -692,6 +763,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     """
     import numpy as np
 
+    items = list(items)
     n = len(items)
     if n == 0:
         return []
@@ -705,6 +777,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     mode, pack, chunks = plan.mode, plan.pack, plan.chunks
     kind = _KIND[mode]
     pallas = impl == "pallas"
+    if mode != "batched":
+        feed = None             # only a queue of whole tiles takes in
 
     # the launch geometry of each mode, and the jitted program it
     # launches with the static-shape key that decides compile-vs-cache
@@ -739,16 +813,36 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     donated = pallas and mode in ("packed", "single-sync")
     unbatched = pallas and mode == "slab"
 
+    # what each group starts with: ``width`` objects of the plan's
+    # order, or for a queue one launch holds, the order dealt over
+    # MIN_BATCH_GROUPS groups
+    if mode == "batched" and n <= width:
+        per = -(-n // MIN_BATCH_GROUPS)
+        shares = [plan.order[s:s + per]
+                  for s in range(0, per * MIN_BATCH_GROUPS, per)]
+    else:
+        shares = [plan.order[s:s + width] for s in range(0, n, width)]
     with trace("pow.groups", objects=n, width=width):
-        groups = [
-            _LaunchGroup(items, plan.order[s:s + width], width,
-                         starts=start_nonces, unbatched=unbatched)
-            for s in range(0, n, width)
-        ]
+        groups = [_LaunchGroup(items, share, width, starts=start_nonces,
+                               unbatched=unbatched) for share in shares]
     results: list = [None] * n
     executed = {"trials": 0, "launches": 0}
 
     rr = {"i": 0}
+
+    def take_in(g) -> int:
+        """Give the done slots of ``g``, whose launches have all been
+        read, objects that have arrived since ``feed`` was last asked;
+        how many it took."""
+        free = [k for k in range(g.width) if g.done[k]]
+        arrived = feed(len(free)) if free else ()
+        for k, (initial_hash, target, start) in zip(free, arrived):
+            items.append((initial_hash, target))
+            results.append(None)
+            g.refill(k, len(items) - 1, initial_hash, target, start)
+        if arrived:
+            REFILLS.labels(kind=kind).inc(len(arrived))
+        return len(arrived)
 
     def speculate():
         # THE speculation rule, for every mode: with no fresh group
@@ -769,11 +863,17 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         return None
 
     def next_launch():
-        cand = None
-        # round-robin over unfinished groups without an in-flight slab
+        cand, refilled = None, 0
+        # round-robin over the groups without an in-flight slab: one
+        # with a done slot takes in what has arrived, an unfinished one
+        # is launched
         for off in range(len(groups)):
             g = groups[(rr["i"] + off) % len(groups)]
-            if not g.finished and not g.unread:
+            if g.unread:
+                continue
+            if feed is not None:
+                refilled = take_in(g)
+            if not g.finished:
                 cand = g
                 rr["i"] = (rr["i"] + off + 1) % len(groups)
                 break
@@ -785,14 +885,18 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         if cand is None:
             return None
         live = cand.live()
+        SLOTS.labels(kind=kind, state="live").inc(live)
+        SLOTS.labels(kind=kind, state="idle").inc(cand.width - live)
         if mode == "packed":
             # pack statistics describe lane sharing, which only the
             # packed kernel does — the other modes must not dilute
             # them (docs/observability.md semantics)
             PACK_SIZE.observe(live)
             PACK_OCCUPANCY.set(live / cand.width)
+        ih_words = cand.device_words()
         with trace("pow.launch", program=tele_prog, chunks=chunks,
-                   live=live, speculative=speculative) as span:
+                   live=live, speculative=speculative,
+                   refilled=refilled) as span:
             # the kernels are called from this frame, not through a
             # helper: on the chip the first call of a process (trace
             # and lowering of pallas_search) took 2.5 times as long
@@ -801,22 +905,22 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                              dtype=np.uint32)
             if not pallas:
                 out = _packed_search_xla(
-                    cand.ih_words, jnp.asarray(bases),
+                    ih_words, jnp.asarray(bases),
                     jnp.asarray(cand.t_arr), lanes=step_trials,
                     chunks=chunks)
             elif mode == "slab":
                 # numpy arguments: the transfers ride the jit call
                 out = sha512_pallas.pallas_search(
-                    cand.ih_words, bases[0], cand.t_arr[0], rows=rows,
+                    ih_words, bases[0], cand.t_arr[0], rows=rows,
                     chunks=chunks, unroll=unroll, interpret=interpret)
             elif mode == "batched":
                 out = sha512_pallas.pallas_batch_search(
-                    cand.ih_words, jnp.asarray(bases),
+                    ih_words, jnp.asarray(bases),
                     jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
                     unroll=unroll, interpret=interpret)
             else:
                 out = pallas_packed_search(
-                    cand.ih_words, jnp.asarray(bases),
+                    ih_words, jnp.asarray(bases),
                     jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
                     pack=pack, unroll=unroll, interpret=interpret)
         cand.unread += 1
@@ -839,32 +943,42 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         g, t0, t1, end_bases, out = tag
         rows_out = _slab_rows(out, host) if unbatched else host
         g.unread -= 1
-        before = executed["trials"]
+        before, needed = executed["trials"], 0
         for k in range(g.width):
             if g.done[k]:
                 # solved/pad slots still executed one always-hit step
                 executed["trials"] += step_trials
                 continue
             step1 = int(rows_out[k, 0])
+            i = g.idx[k]
             if step1:
                 g.trials[k] += step1 * step_trials
                 executed["trials"] += step1 * step_trials
                 nonce = _checked_nonce(
                     (int(rows_out[k, 1]) << 32) | int(rows_out[k, 2]),
-                    items[g.idx[k]][0], g.targets[k])
-                results[g.idx[k]] = (nonce, g.trials[k])
+                    items[i][0], g.targets[k])
+                results[i] = (nonce, g.trials[k])
+                # this launch searched on from end_bases[k] - slab_trials;
+                # the nonce lies in the step that reported it
+                needed += min(
+                    (nonce - end_bases[k] + slab_trials + 1) & _MASK64,
+                    step1 * step_trials)
                 g.done[k] = True
                 # pad semantics: always-hit next launch, then idle
                 g.t_arr[k] = (0xFFFFFFFF, 0xFFFFFFFF)
+                if on_solved is not None:
+                    on_solved(i, results[i])
             else:
                 g.trials[k] += slab_trials
                 executed["trials"] += slab_trials
+                needed += slab_trials
                 if progress is not None:
                     # this slab proved [prev, end_bases[k]) miss-free:
                     # a resumed search may safely start there
-                    progress(g.idx[k], end_bases[k])
+                    progress(i, end_bases[k])
         ran = executed["trials"] - before
         EXECUTED_TRIALS.labels(kind=kind).inc(ran)
+        NEEDED_TRIALS.labels(kind=kind).inc(needed)
         record_launch(tele_prog, key=tele_key, dispatch_seconds=t1 - t0,
                       # the driver fetched this slab just before calling us
                       wait_seconds=driver.last_wait, span=(t0, t_h),
@@ -872,12 +986,19 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                       bytes_out=12 * g.width,
                       bytes_donated=16 * g.width if donated else 0)
 
+    def done():
+        """Every slot is done, and the queue has nobody for a group
+        whose launches have all been read."""
+        if not all(g.finished for g in groups):
+            return False
+        return feed is None or not any(
+            take_in(g) for g in groups if not g.unread)
+
     driver = _PipelineDriver(depth=depth, should_stop=should_stop,
                              stall_timeout=stall_timeout, kind=kind,
                              shape=(tele_prog, tele_key))
     try:
-        driver.run(next_launch, harvest,
-                   done=lambda: all(r is not None for r in results))
+        driver.run(next_launch, harvest, done=done)
     except PowInterrupted:
         if any(r is None for r in results):
             raise

@@ -8,6 +8,13 @@ batch goes through :meth:`PowDispatcher.solve_batch` — objects
 data-parallel over the mesh's object axis, each nonce range partitioned
 over the remaining chips (SURVEY §6: grid = nonce-lanes x objects).
 
+A solve is a stream (docs/pow_pipeline.md): each object's future
+resolves when its own nonce has been found and re-checked, not when
+the batch it came in with is through, and a solve that is running
+takes the requests that arrive meanwhile into the slots that have come
+free, up to :data:`SOLVE_SLOTS` objects at a time; what is beyond waits
+in the queue.
+
 ``window`` is the LONGEST a queued object waits for company, not a
 fixed delay (the latency/batching tradeoff called out in SURVEY §7:
 dynamic batch assembly with padding, no recompilation per batch size
@@ -23,15 +30,17 @@ announced company is not held at all.
 
 Resilience (ISSUE 3, docs/resilience.md):
 
-- a dispatcher failure REQUEUES the in-flight batch with exponential
-  backoff instead of dropping it — a transient tier failure never
-  loses a queued object; only ``max_attempts`` consecutive failures
-  surface the error to the caller (and the job stays journaled);
+- a dispatcher failure REQUEUES what the solve had not resolved, with
+  exponential backoff, instead of dropping it — a transient tier
+  failure never loses a queued object and never resolves one twice;
+  only ``max_attempts`` consecutive failures surface the error to the
+  caller (and the job stays journaled);
 - with a :class:`~pybitmessage_tpu.resilience.journal.PowJournal`
   attached, every request is journaled before it is queued, search
-  progress is checkpointed as slabs harvest, and completion deletes
-  the row — queued/in-flight objects survive a process crash and a
-  resumed solve continues from its checkpointed nonce offset.
+  progress is checkpointed as slabs harvest, and an object's row is
+  deleted when its future resolves — queued/in-flight objects survive
+  a process crash and a resumed solve continues from its checkpointed
+  nonce offset.
 """
 
 from __future__ import annotations
@@ -41,6 +50,7 @@ import contextvars
 import itertools
 import logging
 import time
+from collections import deque
 from collections.abc import Iterable
 from dataclasses import dataclass, field
 
@@ -56,7 +66,8 @@ logger = logging.getLogger("pybitmessage_tpu.pow")
 
 BATCH_SIZE = REGISTRY.histogram(
     "pow_batch_size",
-    "Objects coalesced into one solve_batch launch (window occupancy)",
+    "Objects one solve_batch took in all told: the coalescing window's "
+    "occupancy, plus what a streamed solve took into freed slots",
     buckets=DEFAULT_SIZE_BUCKETS)
 QUEUE_WAIT = REGISTRY.histogram(
     "pow_queue_wait_seconds",
@@ -73,6 +84,10 @@ WINDOW_CLOSED = REGISTRY.counter(
     "Coalescing windows closed, by what closed them: every announced "
     "member of the sweep had arrived (all_arrived) or the window ran "
     "out with a member still missing (timeout)", ("reason",))
+RESOLVE_LAG = REGISTRY.histogram(
+    "pow_resolve_lag_seconds",
+    "From the harvest that found an object's nonce (solving thread) to "
+    "its future resolved on the event loop")
 REQUEUED = REGISTRY.counter(
     "pow_requeue_total",
     "Solve requests put back on the queue after a dispatcher failure "
@@ -82,6 +97,11 @@ REQUEUED = REGISTRY.counter(
 #: attribute that ties a request's ``worker.pow`` span to the spans of
 #: the ``solve_batch`` that served it
 _BATCH_SEQ = itertools.count(1)
+
+#: objects one solve holds at most: the four launch groups of 64 that
+#: the pipeline keeps two launches in flight over (``chan_storm_256``);
+#: what is beyond waits in the queue for a slot to come free
+SOLVE_SLOTS = 256
 
 #: default coalescing window in seconds, the longest a request waits
 #: for announced company; overridable per node via the
@@ -108,6 +128,48 @@ class _Request:
     batch: int = 0
 
 
+def _unresolved(taken: list) -> list:
+    return [req for req in taken if not req.future.done()]
+
+
+class _RequestQueue:
+    """Solve requests waiting for a slot.  Put and awaited on the event
+    loop; taken there (a new solve's first batch) or on the solving
+    thread (a running solve's refills): a deque, whose ends are atomic,
+    and an event for the loop to sleep on."""
+
+    def __init__(self):
+        self._items: deque = deque()
+        self._arrived = asyncio.Event()
+
+    def qsize(self) -> int:
+        return len(self._items)
+
+    def put_nowait(self, req) -> None:
+        self._items.append(req)
+        self._arrived.set()
+
+    async def get(self):
+        while True:
+            got = self.take(1)
+            if got:
+                return got[0]
+            # no await between the empty take and the wait, and puts
+            # come from this loop alone: none is slept through
+            self._arrived.clear()
+            await self._arrived.wait()
+
+    def take(self, room: int) -> list:
+        """At most ``room`` requests, oldest first; from any thread."""
+        out = []
+        while len(out) < room:
+            try:
+                out.append(self._items.popleft())
+            except IndexError:
+                break
+        return out
+
+
 class PowService:
     """Owns a background task that drains solve requests in batches."""
 
@@ -131,7 +193,7 @@ class PowService:
         #: network/API I/O while a broken journal thrashes)
         self._journal_retry = RetryPolicy(attempts=3, base_delay=0.01,
                                           max_delay=0.05, jitter=0.0)
-        self.queue: asyncio.Queue = asyncio.Queue()
+        self.queue = _RequestQueue()
         #: announced sweep members not blocked inside :meth:`solve`:
         #: the company a taken request may still wait for
         self._outstanding: set[asyncio.Task] = set()
@@ -139,15 +201,21 @@ class PowService:
         self._all_arrived = asyncio.Event()
         self._all_arrived.set()
         self._task: asyncio.Task | None = None
-        # injected solvers may predate the resumable-PoW kwargs —
-        # detect once and degrade to the plain call shape
+        # injected solvers may predate the resumable-PoW kwargs or the
+        # stream's — detect once and degrade to the plain call shape.
+        # The stream's hooks go to a solver that names them.  One that
+        # only passes ``**kwargs`` through may edit what comes back (the
+        # benchmark's ``SpoiledNonces`` does, and its test holds that
+        # the edit decides): its answer is its return value, until the
+        # wrapper wraps ``on_solved`` too (PERF.md section 7)
         import inspect
         try:
             params = inspect.signature(dispatcher.solve_batch).parameters
             self._resumable = ("start_nonces" in params or any(
                 p.kind == p.VAR_KEYWORD for p in params.values()))
+            self._streams = "on_solved" in params and "feed" in params
         except (TypeError, ValueError):
-            self._resumable = False
+            self._resumable = self._streams = False
         # batch/solve bookkeeping lives ONLY in the registry counters;
         # per-instance views subtract the construction-time baseline so
         # a fresh service still reports its own counts
@@ -278,7 +346,7 @@ class PowService:
         ctx = LIFECYCLE.trace_ctx_for(initial_hash)
         if ctx is not None:
             req.trace_id = ctx.trace_id
-        await self.queue.put(req)
+        self.queue.put_nowait(req)
         QUEUE_DEPTH.set(self.queue.qsize())
         # an announced member has arrived: it is nobody's missing
         # company until its result is back and it may ask again
@@ -308,28 +376,65 @@ class PowService:
             set_batch(seq)
             with trace("pow.queue.window") as window:
                 closed = await self._await_company()
-                batch = [first]
-                while not self.queue.empty():
-                    batch.append(self.queue.get_nowait())
+                batch = [first] + self.queue.take(SOLVE_SLOTS - 1)
                 window.attrs["objects"] = len(batch)
                 window.attrs["closed"] = closed
             WINDOW_CLOSED.labels(reason=closed).inc()
-            for req in batch:
-                req.batch = seq
-                QUEUE_WAIT.observe(window.end - req.enqueued)
-            BATCH_SIZE.observe(len(batch))
-            QUEUE_DEPTH.set(self.queue.qsize())
+            #: every request the solve has taken in, by its item number
+            taken: list[_Request] = []
+            self._take_in(taken, batch, seq, window.end)
             items = [(r.initial_hash, r.target) for r in batch]
             starts = [r.start_nonce for r in batch]
-            self._journal_each(batch, "inflight", "mark_inflight")
+            loop = asyncio.get_running_loop()
+            cancelled = False
 
-            def progress(i, next_nonce, _batch=batch):
-                self._checkpoint(_batch[i], next_nonce)
+            def progress(i, next_nonce):
+                self._checkpoint(taken[i], next_nonce)
+
+            #: hits the loop has yet to resolve, and whether it has
+            #: been woken for them: a harvest's hits share one wake
+            found: deque = deque()
+            woken = [False]
+
+            def resolve_found():
+                # the flag falls before the drain, so a hit that finds
+                # it raised was appended before the drain began
+                woken[0] = False
+                while found:
+                    self._resolve(*found.popleft())
+
+            def on_solved(i, result):
+                # solving thread: the future is the loop's to resolve.
+                # A lone object leaves nobody behind (a solve that starts
+                # alone takes nobody in): the solve's return resolves it,
+                # as ever, once the solve's books are closed
+                if len(taken) == 1:
+                    return
+                found.append((taken[i], result, time.monotonic()))
+                if woken[0]:
+                    return
+                woken[0] = True
+                try:
+                    loop.call_soon_threadsafe(resolve_found)
+                except RuntimeError:        # the loop has been closed
+                    raise PowInterrupted("event loop closed") from None
+
+            def feed(room):
+                # solving thread: what has arrived since the last launch
+                # (nothing more once this task has been cancelled: the
+                # thread may outlive it)
+                arrived = [] if cancelled else self.queue.take(room)
+                if arrived:
+                    self._take_in(taken, arrived, seq, time.monotonic())
+                return [(r.initial_hash, r.target, r.start_nonce)
+                        for r in arrived]
 
             kwargs = {"should_stop": self.shutdown.is_set}
             if self._resumable:
                 kwargs.update(start_nonces=starts, progress=progress)
-            loop = asyncio.get_running_loop()
+            if self._streams:
+                kwargs.update(on_solved=on_solved, feed=feed)
+            results = ()
             try:
                 # run_in_executor does not carry contextvars: copy the
                 # context so the solve's spans keep batch and parent
@@ -337,26 +442,51 @@ class PowService:
                     None, contextvars.copy_context().run,
                     lambda: self.dispatcher.solve_batch(items, **kwargs))
             except asyncio.CancelledError:
-                self._settle_interrupted(batch)
+                cancelled = True
+                self._settle_interrupted(_unresolved(taken))
                 raise
             except PowInterrupted:
                 # shutdown-driven: jobs stay journaled for the next
                 # process; the futures cancel so callers unwind
-                self._settle_interrupted(batch)
+                self._settle_interrupted(_unresolved(taken))
                 continue
             except Exception as exc:
-                await self._requeue_failed(batch, exc)
+                await self._requeue_failed(_unresolved(taken), exc)
                 continue
+            finally:
+                BATCH_SIZE.observe(len(taken))
             BATCHES.inc()
-            SOLVED.inc(len(batch))
-            if len(batch) > 1:
-                logger.info("batched PoW: %d objects in one launch (%s)",
-                            len(batch), self.dispatcher.last_backend)
-            self._journal_each(batch, "complete", "complete")
-            for req, res in zip(batch, results):
-                LIFECYCLE.record(req.initial_hash, "pow_solved")
-                if not req.future.done():
-                    req.future.set_result(res)
+            if len(taken) > 1:
+                logger.info("batched PoW: %d objects in one solve (%s)",
+                            len(taken), self.dispatcher.last_backend)
+            # a solver that knows nothing of ``on_solved`` answers here
+            for req, res in zip(taken, results):
+                self._resolve(req, res)
+
+    def _take_in(self, taken: list, reqs: list, seq: int,
+                 now: float) -> None:
+        """``reqs`` join solve ``seq``: the first batch on the loop, a
+        refill on the solving thread."""
+        for req in reqs:
+            req.batch = seq
+            QUEUE_WAIT.observe(now - req.enqueued)
+        QUEUE_DEPTH.set(self.queue.qsize())
+        self._journal_each(reqs, "inflight", "mark_inflight")
+        taken.extend(reqs)
+
+    def _resolve(self, req: _Request, result,
+                 found: float | None = None) -> None:
+        """One object's nonce is known and re-checked: its journal row
+        is complete and its future resolves, whatever the rest of its
+        solve is still doing.  ``found`` is when the harvest had it."""
+        if req.future.done():
+            return
+        SOLVED.inc()
+        self._journal_each((req,), "complete", "complete")
+        LIFECYCLE.record(req.initial_hash, "pow_solved")
+        if found is not None:
+            RESOLVE_LAG.observe(max(time.monotonic() - found, 0.0))
+        req.future.set_result(result)
 
     @staticmethod
     def _trace_ids(batch: list[_Request]) -> list[str]:

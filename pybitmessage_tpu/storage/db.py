@@ -20,7 +20,7 @@ from typing import Any, Iterable, Sequence
 
 logger = logging.getLogger("pybitmessage_tpu.storage")
 
-SCHEMA_VERSION = 12
+SCHEMA_VERSION = 13
 
 #: the version ``_SCHEMA`` below creates; _SCHEMA is frozen here —
 #: every later schema change goes into MIGRATIONS, which fresh and
@@ -47,6 +47,14 @@ MIGRATIONS: dict[int, tuple[str, ...]] = {
         " ON inventory(streamnumber, expirestime)",
         "CREATE INDEX IF NOT EXISTS idx_inventory_expires"
         " ON inventory(expirestime)",
+    ),
+    # v13: a sent row is found by its ackdata.  Every status change of
+    # a send (three a send) and every ``message_status`` was a scan of
+    # the whole outbox: 0.4 ms a statement at 3,000 rows, and a client
+    # polling the statuses of a thousand queued sends held the event
+    # loop for as long as it polled (PERF.md section 6, PR 32).
+    13: (
+        "CREATE INDEX IF NOT EXISTS idx_sent_ackdata ON sent(ackdata)",
     ),
 }
 

@@ -78,13 +78,18 @@ class MessageStore:
             (msgid, toaddress, toripe, fromaddress, subject, message,
              ackdata, now, now, 0, status, 0, folder, encoding, ttl))
 
-    def sent_by_status(self, *statuses: str) -> list[SentMessage]:
+    def sent_by_status(self, *statuses: str,
+                       limit: int | None = None) -> list[SentMessage]:
+        """Rows in one of ``statuses``, oldest first; at most ``limit``
+        of them (the sender reads a long outbox a screenful at a time)."""
         marks = ",".join("?" * len(statuses))
         rows = self._db.query(
             "SELECT msgid, toaddress, toripe, fromaddress, subject, message,"
             " ackdata, senttime, lastactiontime, sleeptill, status,"
             " retrynumber, folder, encodingtype, ttl FROM sent"
-            f" WHERE status IN ({marks}) AND folder='sent'", statuses)
+            f" WHERE status IN ({marks}) AND folder='sent'"
+            " ORDER BY rowid" + ("" if limit is None else " LIMIT %d" % limit),
+            statuses)
         return [self._sent_row(r) for r in rows]
 
     def sent_by_ackdata(self, ackdata: bytes) -> SentMessage | None:

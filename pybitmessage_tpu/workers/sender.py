@@ -8,6 +8,11 @@ TTL*2^retries at 1.1*TTL intervals.
 
 The PoW runs through an injected solver (TPU ladder); every solve is
 interruptible via the node's shutdown flag.
+
+Admission is rolling: a command does not wait for the sweep before it
+to end, so a send queued while others are in flight starts at once,
+and at most :data:`MAX_IN_FLIGHT` sends run at a time — the sent table
+holds a long outbox, not a thousand coroutines.
 """
 
 from __future__ import annotations
@@ -49,6 +54,14 @@ logger = logging.getLogger("pybitmessage_tpu.worker")
 
 #: re-request a pubkey after this long (class_singleWorker.py getpubkey)
 GETPUBKEY_RETRY = 2.5 * 24 * 3600
+#: sends (messages and broadcasts) in flight at once: the slots of one
+#: solve (``pow.service.SOLVE_SLOTS``), so a send's message follows its
+#: ack into the solve within a few launches; the rest of the outbox
+#: waits in the sent table
+MAX_IN_FLIGHT = 256
+#: a sweep's command and the sent-table statuses it admits
+_SWEEPS = {"message": (("sendmessage",), (MSGQUEUED, "forcepow")),
+           "broadcast": (("sendbroadcast",), ("broadcastqueued",))}
 
 POW_WAIT_SECONDS = REGISTRY.histogram(
     "worker_pow_wait_seconds",
@@ -118,6 +131,13 @@ class SendWorker:
         self.max_acceptable_extra = \
             RIDICULOUS_DIFFICULTY * DEFAULT_EXTRA_BYTES
         self._task: asyncio.Task | None = None
+        #: commands running beside the loop that took them
+        self._commands: set[asyncio.Task] = set()
+        #: ackdata of the sent rows whose send is in flight
+        self._in_flight: set[bytes] = set()
+        #: sweep commands that found rows and no room: put on the queue
+        #: again when a send ends
+        self._held: set[tuple] = set()
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -138,12 +158,11 @@ class SendWorker:
         return self._task
 
     async def stop(self) -> None:
-        if self._task:
-            self._task.cancel()
-            try:
-                await self._task
-            except asyncio.CancelledError:
-                pass
+        tasks = [t for t in (self._task, *self._commands) if t]
+        for task in tasks:
+            task.cancel()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        self._in_flight.clear()
 
     def _rebuild_watchlists(self) -> None:
         """Recover state from the sent table (class_singleWorker.py:72-117)."""
@@ -163,29 +182,28 @@ class SendWorker:
 
     async def _run(self) -> None:
         while not self.shutdown.is_set():
-            try:
-                cmd = await self.queue.get()
-            except asyncio.CancelledError:
-                raise
-            try:
-                await self._dispatch(cmd)
-            except asyncio.CancelledError:
-                raise
-            except Exception:
-                logger.exception("send worker command failed: %r", cmd[:1])
+            cmd = await self.queue.get()
+            # each command runs beside those before it: a send queued
+            # while a sweep is in flight is not held for the sweep's end
+            task = asyncio.create_task(self._dispatch(cmd))
+            self._commands.add(task)
+            task.add_done_callback(self._commands.discard)
 
     async def _dispatch(self, cmd: tuple) -> None:
         kind = cmd[0]
-        if kind == "sendmessage":
-            await self.process_queued_messages()
-        elif kind == "sendbroadcast":
-            await self.process_queued_broadcasts()
-        elif kind == "sendpubkey":
-            await self.send_my_pubkey(cmd[1])
-        elif kind == "sendonionpeer":
-            await self.send_onion_peer(*cmd[1:])
-        else:
-            logger.warning("unknown worker command %r", kind)
+        try:
+            if kind == "sendmessage":
+                await self.process_queued_messages()
+            elif kind == "sendbroadcast":
+                await self.process_queued_broadcasts()
+            elif kind == "sendpubkey":
+                await self.send_my_pubkey(cmd[1])
+            elif kind == "sendonionpeer":
+                await self.send_onion_peer(*cmd[1:])
+            else:
+                logger.warning("unknown worker command %r", kind)
+        except Exception:
+            logger.exception("send worker command failed: %r", cmd[:1])
 
     # -- PoW helper ----------------------------------------------------------
 
@@ -238,20 +256,57 @@ class SendWorker:
     # -- msg sending ---------------------------------------------------------
 
     async def process_queued_messages(self) -> None:
-        msgs = [m for m in self.store.sent_by_status(MSGQUEUED, "forcepow")
-                if not self.shutdown.is_set()]
+        # Send concurrently: each message's PoW request lands in the
+        # PowService coalescing window or, while a solve is running,
+        # in a slot that has come free.
+        await self._sweep("message", self._send_one_msg)
+
+    async def _sweep(self, kind: str, send_one) -> None:
+        """Admit queued rows of ``kind``, oldest first, as far as
+        :data:`MAX_IN_FLIGHT` allows, and run their sends; returns when
+        those it admitted have ended.  Where rows are left waiting, the
+        first send to end puts the command on the queue again, so the
+        next is admitted then and not at this sweep's end."""
+        command, statuses = _SWEEPS[kind]
+        if self.shutdown.is_set():
+            return
+        room = MAX_IN_FLIGHT - len(self._in_flight)
+        if room <= 0:
+            self._held.add(command)
+            return
+        # a row in flight may still be in its queued status (a message
+        # until its task first runs, a broadcast until it is published),
+        # so one row more than can be in flight is read: whatever the
+        # in-flight rows leave of that is ``room`` rows to admit and one
+        # that says the outbox goes on
+        rows = [m for m in self.store.sent_by_status(
+                    *statuses, limit=MAX_IN_FLIGHT + 1)
+                if m.ackdata not in self._in_flight]
+        msgs = rows[:room]
         if not msgs:
             return
-        # Send concurrently: each message's PoW request lands in the
-        # PowService coalescing window, so a sweep of queued sends
-        # becomes ONE batched (objects x nonce-lanes) device launch.
-        with trace("sender.sweep", kind="message", objects=len(msgs)):
-            results = await self._gather_sweep(
-                self._send_one_msg(m) for m in msgs)
+        if len(rows) > room:
+            self._held.add(command)
+
+        async def tracked(m):
+            try:
+                await send_one(m)
+            finally:
+                self._in_flight.discard(m.ackdata)
+            # a slot is free (a send that raised asks for nothing: its
+            # row may still be queued, and would be admitted again)
+            while self._held:
+                self.queue.put_nowait(self._held.pop())
+
+        self._in_flight.update(m.ackdata for m in msgs)
+        with trace("sender.sweep", kind=kind, objects=len(msgs),
+                   in_flight=len(self._in_flight)):
+            results = await self._gather_sweep(tracked(m) for m in msgs)
         for m, r in zip(msgs, results):
             if isinstance(r, BaseException) and \
                     not isinstance(r, asyncio.CancelledError):
-                logger.error("send failed for %s: %r", m.toaddress, r)
+                logger.error("%s send failed, %s -> %s: %r", kind,
+                             m.fromaddress, m.toaddress, r)
 
     async def _gather_sweep(self, sends) -> list:
         """Run a sweep's sends concurrently, announced to the PoW
@@ -522,17 +577,7 @@ class SendWorker:
     # -- broadcast sending ---------------------------------------------------
 
     async def process_queued_broadcasts(self) -> None:
-        msgs = [m for m in self.store.sent_by_status("broadcastqueued")
-                if not self.shutdown.is_set()]
-        if not msgs:
-            return
-        with trace("sender.sweep", kind="broadcast", objects=len(msgs)):
-            results = await self._gather_sweep(
-                self._send_one_broadcast(m) for m in msgs)
-        for m, r in zip(msgs, results):
-            if isinstance(r, BaseException) and \
-                    not isinstance(r, asyncio.CancelledError):
-                logger.error("broadcast failed for %s: %r", m.fromaddress, r)
+        await self._sweep("broadcast", self._send_one_broadcast)
 
     async def _send_one_broadcast(self, m) -> None:
         sender = self.keystore.get(m.fromaddress)

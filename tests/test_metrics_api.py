@@ -93,9 +93,16 @@ def test_metrics_endpoint_scrape_and_solve():
             finally:
                 await service.stop()
 
-            status, text = await _get(server.listen_port, "/metrics",
-                                      auth)
-            assert status == 200
+            # a solve is a stream: the future resolves from the
+            # solving thread, which books the solve as it returns
+            for _ in range(100):
+                status, text = await _get(server.listen_port, "/metrics",
+                                          auth)
+                assert status == 200
+                if _series_count(
+                        text, "pow_solve_seconds_count") > solves0:
+                    break
+                await asyncio.sleep(0.05)
             assert _series_count(
                 text, "pow_solve_seconds_count") == solves0 + 1
             assert _series_count(

@@ -83,9 +83,13 @@ class Scripted:
     the grid step, counted from 1, at which each hits; a tuple says it
     object by object."""
 
-    def __init__(self, script, winners):
+    def __init__(self, script, winners, items=None):
         self.script, self.winners = list(script), list(winners)
         self.launched = []          # (entry point, static shape, bases)
+        #: hash words -> item: a batch lays its objects out over groups
+        self.index = None if items is None else {
+            np.array(pipeline._hash_words(ih), np.uint32).tobytes(): i
+            for i, (ih, _t) in enumerate(items)}
 
     def _step(self, entry, shape, bases):
         step = self.script[len(self.launched)]
@@ -105,27 +109,31 @@ class Scripted:
                                self.winners[0] & 0xFFFFFFFF)
         return found, nonce
 
-    def _rows(self, entry, shape, bases, targets):
+    def _rows(self, entry, shape, ih_words, bases, targets):
         bases = [(int(hi) << 32) | int(lo) for hi, lo in np.asarray(bases)]
         step = self._step(entry, shape, bases)
+        words = np.asarray(ih_words)
         out = np.zeros((len(bases), 3), np.uint32)
         for k, (t_hi, t_lo) in enumerate(np.asarray(targets)):
-            if k >= len(self.winners) or (t_hi, t_lo) == (2 ** 32 - 1,) * 2:
+            i = k if self.index is None \
+                else self.index.get(words[k].tobytes(), len(self.winners))
+            if i >= len(self.winners) or (t_hi, t_lo) == (2 ** 32 - 1,) * 2:
                 out[k] = (1, 0, 0)          # pad or solved: always hits
-            elif _of(step, k):
-                out[k] = (_of(step, k), self.winners[k] >> 32,
-                          self.winners[k] & 0xFFFFFFFF)
+            elif _of(step, i):
+                out[k] = (_of(step, i), self.winners[i] >> 32,
+                          self.winners[i] & 0xFFFFFFFF)
         return out
 
     def batch(self, ih_words, bases, targets, rows, chunks,
               interpret=False, unroll=1):
         return self._rows("pallas_batch_search", (rows, chunks, unroll),
-                          bases, targets)
+                          ih_words, bases, targets)
 
     def packed(self, ih_words, bases, targets, rows, chunks, pack,
                unroll=1, interpret=False):
         return self._rows("pallas_packed_search",
-                          (rows, chunks, pack, unroll), bases, targets)
+                          (rows, chunks, pack, unroll), ih_words, bases,
+                          targets)
 
     def install(self, monkeypatch):
         monkeypatch.setattr(sha512_pallas, "pallas_search", self.search)
@@ -140,51 +148,63 @@ BATCH8 = 128 * 8 * 128 * 4
 
 # mode, objects (n, expected trials each), rows, the script, and what
 # the loop does with it: entry point, static shape, width, trials of a
-# grid step, launches, abandoned
+# grid step, launches, abandoned; for a queue laid out over two groups,
+# the group each launch belongs to
 CASES = {
     "slab hits in its first slab": (
         "slab", (1, 200000), 128, [3],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 1, 0),
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 1, 0, None),
     "slab misses, then hits": (
         "slab", (1, 200000), 128, [None, 7],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 0),
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 0, None),
+    # a queue one launch holds is two groups, launched in turn
     "batched queue": (
-        "batched", (2, 50000), 8, [2],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 1, 0),
+        "batched", (2, 50000), 8, [2, 2],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 0, [0, 1]),
     "packed storm": (
         "packed", (4, 16), 128, [1],
-        "pallas_packed_search", (128, 64, 4, 1), 4, 32 * 128, 1, 0),
+        "pallas_packed_search", (128, 64, 4, 1), 4, 32 * 128, 1, 0, None),
     "single-sync misses, then hits": (
         "single-sync", (1, 16), 128, [None, 5],
-        "pallas_packed_search", (128, 8, 1, 1), 1, 128 * 128, 2, 0),
+        "pallas_packed_search", (128, 8, 1, 1), 1, 128 * 128, 2, 0, None),
     # expected to need 64 slabs: the second goes ahead of the first,
     "hard slab keeps two in flight, hits in its first": (
         "slab", (1, HARD), 128, [3, None],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 1),
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 2, 1, None),
     "hard slab misses, then hits": (
         "slab", (1, HARD), 128, [None, 7, None],
-        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 3, 1),
-    # 64 objects that each need about one launch: all 64 finishing in
-    # the one in flight is out of the question, so the next goes ahead
-    "batched group with 64 live is speculated on": (
-        "batched", (64, BATCH8), 8, [2, None],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 1),
-    # the same group once 63 have hit: the launch that went ahead while
-    # 64 were live is read, and nothing goes ahead of it for the last
+        "pallas_search", (128, 512, 5), 1, SLAB_STEP, 3, 1, None),
+    # 32 objects that each need about one launch, left alone when the
+    # other group has finished: all 32 finishing in the launch in flight
+    # is out of the question, so the next goes ahead
+    "batched group left alone with 32 live is speculated on": (
+        "batched", (64, BATCH8), 8, [2, 2, None],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 3, 1,
+        [0, 1, 1]),
+    # the same group once 31 have hit: the launch that went ahead while
+    # 32 were live is read, and nothing goes ahead of it for the last
     "batched group with one live is not": (
-        "batched", (64, BATCH8), 8, [(2,) * 63 + (None,), 5],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 0),
+        "batched", (64, BATCH8), 8,
+        [2, (None,) * 32 + (2,) * 31 + (None,), 5],
+        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 3, 0,
+        [0, 1, 1]),
 }
 
 
-def _foreseen(script, n, width, slab, step_trials):
+def _foreseen(script, shares, turns, width, slab, step_trials):
     """What reading ``script``'s launches in order does, object by
     object: trials credited, ends of miss-free slabs reported, trials
-    executed (a solved or pad slot still runs one always-hit step)."""
+    executed (a solved or pad slot still runs one always-hit step).
+    ``shares`` are the items of each group, ``turns`` the group of each
+    launch."""
+    n = sum(len(share) for share in shares)
     credit, reported, executed = [0] * n, [], 0
     live = set(range(n))
-    for j, step in enumerate(script):
-        for i in range(width):
+    turn = [0] * len(shares)
+    for step, g in zip(script, turns):
+        turn[g] += 1
+        executed += (width - len(shares[g])) * step_trials      # pad
+        for i in shares[g]:
             if i not in live:
                 executed += step_trials
             elif _of(step, i):
@@ -194,19 +214,19 @@ def _foreseen(script, n, width, slab, step_trials):
             else:
                 credit[i] += slab
                 executed += slab
-                reported.append((i, (j + 1) * slab))
+                reported.append((i, turn[g] * slab))
     return credit, reported, executed
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
 def test_each_mode_launches_what_its_targets_call_for(case, monkeypatch):
     (mode, (n, expected), rows, script, entry, shape, width, step_trials,
-     launches, abandoned) = CASES[case]
+     launches, abandoned, turns) = CASES[case]
     if expected > SOLVABLE:
         _skip_recheck(monkeypatch)
     items, winners = zip(*(_item(b"%s %d" % (mode.encode(), i), expected)
                            for i in range(n)))
-    kernels = Scripted(script, winners).install(monkeypatch)
+    kernels = Scripted(script, winners, items).install(monkeypatch)
     kind = pipeline._KIND[mode]
     before = {name: _counted(name, kind) for name in (
         "pow_pipeline_launches_total",
@@ -222,17 +242,22 @@ def test_each_mode_launches_what_its_targets_call_for(case, monkeypatch):
         == [(entry, shape)] * launches
     chunks = shape[1]
     slab = chunks * step_trials
-    # every launch starts where the one before it ended
+    if turns is None:
+        shares, turns = [list(range(n))], [0] * launches
+    else:
+        shares = [list(range(n // 2)), list(range(n // 2, n))]
+    # every launch of a group starts where its last one ended
     assert [b[0] for _e, _s, b in kernels.launched] \
-        == [k * slab for k in range(launches)]
+        == [turns[:k].count(g) * slab for k, g in enumerate(turns)]
     grown = {name: _counted(name, kind) - v for name, v in before.items()}
     assert grown["pow_pipeline_launches_total"] == launches
     assert grown["pow_pipeline_abandoned_launches_total"] == abandoned
     # of the launches read: a miss-free slab's end is the checkpoint,
     # once a slab and object, and trials are credited by the grid steps
     # a search really ran
+    read = launches - abandoned
     credit, checkpoints, executed = _foreseen(
-        script[:launches - abandoned], n, width, slab, step_trials)
+        script[:read], shares, turns[:read], width, slab, step_trials)
     assert reported == checkpoints
     assert results == list(zip(winners, credit))
     assert grown["pow_pipeline_executed_trials_total"] == executed

@@ -20,7 +20,7 @@ def db():
 
 
 def test_schema_and_settings(db):
-    assert db.get_setting("version") == "12"
+    assert db.get_setting("version") == "13"
     db.set_setting("k", "v")
     assert db.get_setting("k") == "v"
     assert db.get_setting("missing", "dflt") == "dflt"

@@ -399,15 +399,31 @@ def test_migration_applies_to_existing_v11_db(tmp_path):
     conn = sqlite3.connect(path)
     conn.execute("DROP INDEX IF EXISTS idx_inventory_stream_expires")
     conn.execute("DROP INDEX IF EXISTS idx_inventory_expires")
+    conn.execute("DROP INDEX IF EXISTS idx_sent_ackdata")
     conn.execute("PRAGMA user_version = 11")
     conn.commit()
     conn.close()
     db = Database(path)
     names = {r[0] for r in db.query(
         "SELECT name FROM sqlite_master WHERE type='index'")}
-    assert {"idx_inventory_stream_expires",
-            "idx_inventory_expires"} <= names
-    assert db.get_setting("version") == "12"
+    assert {"idx_inventory_stream_expires", "idx_inventory_expires",
+            "idx_sent_ackdata"} <= names
+    assert db.get_setting("version") == "13"
+    db.close()
+
+
+@pytest.mark.parametrize("statement", [
+    "SELECT status FROM sent WHERE ackdata=?",
+    "UPDATE sent SET status='msgsent' WHERE ackdata=?",
+])
+def test_a_sent_row_is_found_by_its_ackdata_without_a_scan(statement):
+    """v13 migration: a status change and ``message_status`` search the
+    index; each was a scan of the whole outbox, a thousand rows long in
+    ``queue_1k``."""
+    db = Database()
+    plan = " ".join(str(r) for r in db.query(
+        "EXPLAIN QUERY PLAN " + statement, (b"ack",)))
+    assert "idx_sent_ackdata" in plan, plan
     db.close()
 
 
