@@ -235,6 +235,8 @@ class PowDispatcher:
         #: monotonic time of the last slab stall — recovery latency is
         #: observed when a fallback tier completes the rescued work
         self._stalled_at: float | None = None
+        #: ``_batch_topology``'s answer, once a probe has succeeded
+        self._topology: tuple[int, bool] | None = None
 
     # -- device topology -----------------------------------------------------
 
@@ -248,9 +250,13 @@ class PowDispatcher:
         """``(device count, on accelerator)`` for the batch paths.  A
         failed probe is counted and logged as a failure of the ``tpu``
         tier (never read in silence as "no accelerator") and answers
-        ``(0, False)``, which no device branch below takes."""
+        ``(0, False)``, which no device branch below takes; a probe
+        that succeeded is kept (``streams`` asks at every window)."""
+        if self._topology is not None:
+            return self._topology
         try:
-            return self._device_count(), self._on_accelerator()
+            self._topology = self._device_count(), self._on_accelerator()
+            return self._topology
         except Exception:
             self.breakers["tpu"].record_failure()
             ERRORS.labels(site="pow.tier.tpu").inc()
@@ -374,8 +380,33 @@ class PowDispatcher:
     # keep the explicit name too
     solve = __call__
 
+    def streams(self, items, expect: int = 0) -> bool:
+        """Whether a solve of ``items``, laid out for ``expect``
+        objects, would now take late arrivals in (``feed``): the rung
+        that would serve it is the single-chip pipeline (the TPU rungs
+        enabled, no farm ahead of them, one accelerator device, the
+        pipeline's breaker not open), and the plan for it is the mode
+        that takes in (``batched``: objects at network difficulty; a
+        queue of tiny objects is packed and holds who it starts with).
+        ``PowService`` asks per window, and starts a sweep's solve at
+        its first member only where this says yes, and then hands the
+        solve an ``expect`` above what it holds: that is the verdict,
+        and ``solve_batch`` does not ask again.  No breaker's probe is
+        consumed by asking, and the topology is probed once a process
+        (``_batch_topology``)."""
+        if not self._tpu_enabled or not \
+                self.breakers["tpu-pallas"].available():
+            return False
+        if self.farm is not None and self.farm.breaker.available():
+            return False
+        if self._batch_topology() != (1, True):
+            return False
+        from .pipeline import plan_batch
+        return plan_batch(items, expect=expect).mode == "batched"
+
     def solve_batch(self, items, *, should_stop=None, start_nonces=None,
-                    progress=None, on_solved=None, feed=None):
+                    progress=None, on_solved=None, feed=None,
+                    expect: int = 0):
         """Solve ``[(initial_hash, target), ...]`` -> ``[(nonce, trials)]``.
 
         All pending objects go down in ONE pod-wide launch when a
@@ -405,6 +436,12 @@ class PowDispatcher:
         credited as objects resolve and ``pow_attempts_total`` counts
         every time the solve takes objects in (its start and each
         refill), so both move inside a solve that outlives a window.
+
+        ``expect`` is the number of objects the solve should be laid
+        out for, where more are announced than ``items`` holds (a
+        sweep's solve that starts at its first member): it goes to the
+        streaming rung only, and a lone item with ``expect`` above 1 is
+        offered that rung, and no other batch rung, as a queue is.
         """
         items = list(items)
         if not items:
@@ -431,14 +468,19 @@ class PowDispatcher:
             return arrived
 
         t0 = time.monotonic()
-        with trace("pow.solve_batch", objects=len(items)) as span:
+        expect = max(expect, len(items))
+        with trace("pow.solve_batch", objects=len(items),
+                   expect=expect) as span:
             # the farm rung leads the ladder; a farm failure falls
             # through to the local tiers below with nothing lost
             results = self._try_farm(items, should_stop, starts)
-            if results is None and self._tpu_enabled and len(items) > 1:
+            # a lone item with announced company is a queue: the
+            # caller has asked ``streams``, and only the rung that
+            # takes company in is offered it (``_batch_rungs``)
+            if results is None and self._tpu_enabled and expect > 1:
                 for rung, call in self._batch_rungs(
                         items, starts, should_stop, progress, resolve,
-                        take if feed is not None else None):
+                        take if feed is not None else None, expect):
                     results = self._run_rung(rung, call)
                     if results is not None:
                         break
@@ -530,10 +572,11 @@ class PowDispatcher:
             return None
 
     def _batch_rungs(self, items, starts, should_stop, progress,
-                     on_solved, feed):
+                     on_solved, feed, expect):
         """The device rungs the topology admits for a queue, in order,
         each with the call that runs it.  Only the single-chip pipeline
-        streams: it alone is given ``on_solved`` and ``feed``."""
+        streams: it alone is given ``on_solved``, ``feed`` and
+        ``expect``."""
         ndev, on_accel = self._batch_topology()
 
         def pallas_sharded_batch():
@@ -554,9 +597,12 @@ class PowDispatcher:
             return solve_batch_pipelined(
                 items, should_stop=should_stop, start_nonces=starts,
                 progress=progress, stall_timeout=self.stall_timeout,
-                on_solved=on_solved, feed=feed)
+                on_solved=on_solved, feed=feed, expect=expect)
 
         if ndev > 1:
+            if len(items) == 1:
+                # laid out for company the pod's rungs cannot take in
+                return
             if on_accel:
                 yield _PALLAS_SHARDED_BATCH, pallas_sharded_batch
             yield _XLA_SHARDED_BATCH, xla_sharded_batch

@@ -32,7 +32,10 @@ leaves it when its nonce has passed the hashlib re-check
 (``on_solved``), and before a group's next launch each of its done
 slots takes an object that has arrived since (``feed``).  A queue of at
 most one launch's objects is laid out as two groups, so two launches
-alternate and none is dispatched ahead of an unread one.
+alternate and none is dispatched ahead of an unread one.  A solve that
+is told how many objects to ``expect`` is planned and laid out for that
+many: it may start with the first member of a sweep, the slots of the
+members still to come start as pad slots, and ``feed`` fills them.
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -382,9 +385,13 @@ class BatchPlan:
 
 
 def plan_batch(items, *, rows: int = DEFAULT_ROWS,
-               unroll: int = 1) -> BatchPlan:
+               unroll: int = 1, expect: int = 0) -> BatchPlan:
     """Choose the kernel and its geometry from the batch's size and
-    difficulty — the only place that does.
+    difficulty — the only place that does.  ``expect`` is the number of
+    objects the solve is to be laid out for, where more are announced
+    than are there: the mode is then that of a queue of ``expect``
+    objects (one object with announced company is a queue, not a lone
+    object), read from the targets that are there.
 
     One object alone searches whole slabs of ``pallas_search`` (mode
     ``slab``), or, when it is expected to finish inside
@@ -398,7 +405,8 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS,
     ONE chunk count, so a node compiles each kernel once and launches
     nothing the chip has not already accepted.
     """
-    n = len(items)
+    there = len(items)
+    n = max(there, expect)
     exp = [expected_trials(t) for _, t in items]
     tile_step = rows * LANE_COLS * unroll      # full-tile trials/step
     if n == 1:
@@ -409,8 +417,8 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS,
         # tests/test_tpu_compile.py), and the grid leaves at its first
         # hit, so a long slab costs a short solve nothing
         return BatchPlan("slab", 1, DEFAULT_CHUNKS, [0])
-    order = sorted(range(n), key=lambda i: exp[i])
-    med = sorted(exp)[n // 2]
+    order = sorted(range(there), key=lambda i: exp[i])
+    med = sorted(exp)[there // 2]
     for p in PACK_CHOICES:
         # with pack p each object gets chunks*(rows/p)*128*unroll
         # trials per launch; take the largest p that still covers the
@@ -642,7 +650,10 @@ class _LaunchGroup:
         self.unbatched = unbatched
         #: the device copy of ``words`` is behind the host's
         self.stale = True
-        self.device_words()
+        if idx:
+            # a group of pad slots alone is never launched: its words
+            # go to the device with its first refill
+            self.device_words()
         self.t_arr = np.array([_split64(t) for t in self.targets],
                               dtype=np.uint32)
         #: the item each slot searches for (None: a pad slot)
@@ -720,7 +731,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           should_stop: Callable[[], bool] | None = None,
                           start_nonces=None, progress=None,
                           stall_timeout: float = 0.0,
-                          on_solved=None, feed=None):
+                          on_solved=None, feed=None, expect: int = 0):
     """Solve ``[(initial_hash, target), ...]`` — one object or a queue
     — through the dispatch-ahead driver.  Returns ``[(nonce, trials),
     ...]`` aligned with ``items`` (then with what ``feed`` brought, in
@@ -747,10 +758,20 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     was last asked; a ``batched`` solve asks before a group's launch,
     when all of the group's launches have been read, and gives each
     done slot of that group one of them (item numbers go on from
-    ``len(items)``).  The plan is made of what is there at the start
-    and never again: a solve that starts with one object stays
-    ``slab`` and asks nobody.  The solve ends when every slot is done
-    and ``feed`` has nothing.
+    ``len(items)``).  The plan is made once, at the start, of what is
+    there and of what the caller says is announced: ``expect`` is the
+    number of objects the solve is laid out for.  With ``expect`` above
+    ``len(items)`` (a sweep's solve that starts at its first member)
+    the mode is that of a queue of ``expect`` objects, the groups are
+    those ``expect`` objects would fill (``ceil(expect / 64)`` of 64
+    slots, :data:`MIN_BATCH_GROUPS` for at most 64), the objects that
+    are there are dealt over them and every other slot starts as a pad
+    slot for ``feed`` to fill; a group with no live slot is not
+    launched, and takes arrivals in when its turn comes.  Without
+    ``expect`` nothing is announced: a solve that starts with one
+    object stays ``slab`` and asks nobody.  The solve ends when every
+    slot is done and ``feed`` has nothing, whoever may still be
+    missing.
 
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
@@ -769,9 +790,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         return []
     if impl is None:
         impl = default_impl()
+    expect = max(expect, n)
     if plan is None:
-        with trace("pow.plan", objects=n) as span:
-            plan = plan_batch(items, rows=rows, unroll=unroll)
+        with trace("pow.plan", objects=n, expect=expect) as span:
+            plan = plan_batch(items, rows=rows, unroll=unroll,
+                              expect=expect)
             span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
     mode, pack, chunks = plan.mode, plan.pack, plan.chunks
@@ -814,12 +837,14 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     unbatched = pallas and mode == "slab"
 
     # what each group starts with: ``width`` objects of the plan's
-    # order, or for a queue one launch holds, the order dealt over
-    # MIN_BATCH_GROUPS groups
-    if mode == "batched" and n <= width:
-        per = -(-n // MIN_BATCH_GROUPS)
+    # order; or, for a queue one launch holds or one laid out for
+    # announced company, the order dealt evenly over the groups that
+    # queue would fill, at least MIN_BATCH_GROUPS
+    if mode == "batched" and (expect > n or n <= width):
+        count = max(MIN_BATCH_GROUPS, -(-expect // width))
+        per = -(-n // count)
         shares = [plan.order[s:s + per]
-                  for s in range(0, per * MIN_BATCH_GROUPS, per)]
+                  for s in range(0, per * count, per)]
     else:
         shares = [plan.order[s:s + width] for s in range(0, n, width)]
     with trace("pow.groups", objects=n, width=width):
@@ -1005,7 +1030,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     if stats is not None:
         stats.update(
             mode=mode, pack=pack, width=width, chunks=chunks,
-            launches=executed["launches"],
+            groups=len(groups), launches=executed["launches"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),
             wall_seconds=driver.wall_seconds,

@@ -22,11 +22,31 @@ thanks to the object-axis padding in ``sharded_solve_batch``).  A send
 sweep announces its member tasks (:meth:`PowService.announce`) before
 any of them runs; a member is *outstanding* while it is announced and
 not blocked inside :meth:`PowService.solve`, and is withdrawn when its
-task ends, however it ends.  The window closes as soon as nobody
-outstanding is missing, or after ``window`` seconds, whichever is
-first: a lone send's ack and message are each dispatched on arrival,
-a sweep of 256 when its last member arrives.  A request with no
-announced company is not held at all.
+task ends, however it ends.  A request with no announced company is
+not held at all.  What closes the window while somebody is missing
+depends on the solver, asked at every window:
+
+- a solver that streams now (it names ``on_solved``, ``feed`` and
+  ``expect``, and its ``streams(items, expect)`` says that a solve of
+  what the queue holds would take late arrivals in: ``PowDispatcher``
+  on one accelerator chip with its pipeline healthy, for objects at
+  network difficulty) holds nothing back.  The window closes at the
+  first arrival (``first_arrival``), the solve is told how many
+  objects to lay itself out for (``expect``: what the queue held plus
+  the members outstanding, at most :data:`SOLVE_SLOTS`), and the rest
+  of the sweep enters through ``feed`` while the chip already
+  searches: a sweep of 256 broadcasts is still one solve, begun while
+  255 of them are being signed and encrypted.  A solve that runs out
+  of live slots with members still missing ends as any solve does;
+  the next arrival opens the next window at once;
+- any other solver (the CPU ladder, the pod's rungs, the farm, a
+  wrapper that only passes ``**kwargs`` through) cannot take a late
+  member in, so the window closes when nobody outstanding is missing
+  (``all_arrived``) or after ``window`` seconds (``timeout``),
+  whichever is first: a sweep of 256 when its last member arrives.
+
+A lone send's ack and message are each dispatched on arrival either
+way: nobody is outstanding when they ask.
 
 Resilience (ISSUE 3, docs/resilience.md):
 
@@ -82,8 +102,10 @@ SOLVED = REGISTRY.counter(
 WINDOW_CLOSED = REGISTRY.counter(
     "pow_window_closed_total",
     "Coalescing windows closed, by what closed them: every announced "
-    "member of the sweep had arrived (all_arrived) or the window ran "
-    "out with a member still missing (timeout)", ("reason",))
+    "member of the sweep had arrived (all_arrived), the solver streams "
+    "and the solve began with members still missing (first_arrival), or "
+    "the window ran out with a member still missing (timeout)",
+    ("reason",))
 RESOLVE_LAG = REGISTRY.histogram(
     "pow_resolve_lag_seconds",
     "From the harvest that found an object's nonce (solving thread) to "
@@ -214,8 +236,13 @@ class PowService:
             self._resumable = ("start_nonces" in params or any(
                 p.kind == p.VAR_KEYWORD for p in params.values()))
             self._streams = "on_solved" in params and "feed" in params
+            #: the solver can be laid out for company still to come,
+            #: and says per window whether it would take it in
+            self._lays_out = (self._streams and "expect" in params
+                              and callable(getattr(dispatcher, "streams",
+                                                   None)))
         except (TypeError, ValueError):
-            self._resumable = self._streams = False
+            self._resumable = self._streams = self._lays_out = False
         # batch/solve bookkeeping lives ONLY in the registry counters;
         # per-instance views subtract the construction-time baseline so
         # a fresh service still reports its own counts
@@ -260,17 +287,16 @@ class PowService:
                              "without journal durability", site)
             return None
 
-    def _journal_each(self, batch, op: str, method: str) -> None:
-        """One journal write per journaled request of ``batch``, all
+    def _journal_all(self, batch, op: str, method: str) -> None:
+        """One journal write for the journaled requests of ``batch``,
         under one ``pow.queue.journal`` span."""
-        if self.journal is None:
+        jobs = [req.job_id for req in batch if req.job_id is not None]
+        if self.journal is None or not jobs:
             return
         write = getattr(self.journal, method)
         with trace("pow.queue.journal", op=op):
-            for req in batch:
-                if req.job_id is not None:
-                    self._journal_call(lambda j=req.job_id: write(j),
-                                       site="pow.journal." + op)
+            self._journal_call(lambda: write(*jobs),
+                               site="pow.journal." + op)
 
     def _checkpoint(self, req: _Request, next_nonce: int) -> None:
         """Progress hook from the dispatcher (executor thread)."""
@@ -292,10 +318,13 @@ class PowService:
 
     def announce(self, members: Iterable[asyncio.Task]) -> None:
         """A sweep names the tasks that will each call :meth:`solve`
-        (once or twice), before any of them runs: the window then
-        closes when the last of them has arrived instead of on the
-        timer.  A member is withdrawn when its task ends, however it
-        ends (result, exception, cancellation before its first step)."""
+        (once or twice), before any of them runs.  For a solver that
+        streams they are the company a solve is laid out for when it
+        begins at the first of them (``expect``); for any other the
+        window closes when the last of them has arrived instead of on
+        the timer.  A member is withdrawn when its task ends, however
+        it ends (result, exception, cancellation before its first
+        step)."""
         for task in members:
             self._outstanding.add(task)
             task.add_done_callback(self._withdraw)
@@ -312,8 +341,13 @@ class PowService:
             self._all_arrived.set()
 
     async def _await_company(self) -> str:
-        """Hold a taken request until no announced member is missing
-        or ``window`` has run out; returns which it was."""
+        """The rule of a solver that cannot take a late member in (PR
+        28's, and the only one before a solve could stream): hold a
+        taken request until no announced member is missing
+        (``all_arrived``) or ``window`` has run out (``timeout``);
+        returns which it was.  A solver that streams is not held at
+        all: :meth:`_form_batch` closes its window at the first
+        arrival."""
         if self._all_arrived.is_set():
             return "all_arrived"
         if self.window <= 0:
@@ -323,6 +357,24 @@ class PowService:
         except asyncio.TimeoutError:
             return "timeout"
         return "all_arrived"
+
+    async def _form_batch(self, first: _Request):
+        """The batch a solve begins with, the number of objects it is
+        to be laid out for, and what closed the window over ``first``.
+        With an announced member missing, a solver that streams begins
+        with what is there, laid out for the missing too (each asks
+        once more at least, or ends and leaves a pad slot), and takes
+        them in as they come; any other solver waits for them."""
+        batch = [first]
+        if self._lays_out and not self._all_arrived.is_set():
+            batch += self.queue.take(SOLVE_SLOTS - 1)
+            expect = min(len(batch) + len(self._outstanding), SOLVE_SLOTS)
+            if self.dispatcher.streams(
+                    [(r.initial_hash, r.target) for r in batch], expect):
+                return batch, expect, "first_arrival"
+        closed = await self._await_company()
+        batch += self.queue.take(SOLVE_SLOTS - len(batch))
+        return batch, len(batch), closed
 
     async def solve(self, initial_hash: bytes, target: int):
         """Queue one solve; returns (nonce, trials) when its batch lands."""
@@ -375,9 +427,9 @@ class PowService:
             # every span of the solve, carry the batch's number
             set_batch(seq)
             with trace("pow.queue.window") as window:
-                closed = await self._await_company()
-                batch = [first] + self.queue.take(SOLVE_SLOTS - 1)
+                batch, expect, closed = await self._form_batch(first)
                 window.attrs["objects"] = len(batch)
+                window.attrs["expect"] = expect
                 window.attrs["closed"] = closed
             WINDOW_CLOSED.labels(reason=closed).inc()
             #: every request the solve has taken in, by its item number
@@ -406,9 +458,10 @@ class PowService:
             def on_solved(i, result):
                 # solving thread: the future is the loop's to resolve.
                 # A lone object leaves nobody behind (a solve that starts
-                # alone takes nobody in): the solve's return resolves it,
-                # as ever, once the solve's books are closed
-                if len(taken) == 1:
+                # alone, with no company announced, takes nobody in): the
+                # solve's return resolves it, as ever, once the solve's
+                # books are closed
+                if expect == 1 and len(taken) == 1:
                     return
                 found.append((taken[i], result, time.monotonic()))
                 if woken[0]:
@@ -434,6 +487,8 @@ class PowService:
                 kwargs.update(start_nonces=starts, progress=progress)
             if self._streams:
                 kwargs.update(on_solved=on_solved, feed=feed)
+            if self._lays_out:
+                kwargs.update(expect=expect)
             results = ()
             try:
                 # run_in_executor does not carry contextvars: copy the
@@ -471,7 +526,7 @@ class PowService:
             req.batch = seq
             QUEUE_WAIT.observe(now - req.enqueued)
         QUEUE_DEPTH.set(self.queue.qsize())
-        self._journal_each(reqs, "inflight", "mark_inflight")
+        self._journal_all(reqs, "inflight", "mark_inflight")
         taken.extend(reqs)
 
     def _resolve(self, req: _Request, result,
@@ -482,7 +537,7 @@ class PowService:
         if req.future.done():
             return
         SOLVED.inc()
-        self._journal_each((req,), "complete", "complete")
+        self._journal_all((req,), "complete", "complete")
         LIFECYCLE.record(req.initial_hash, "pow_solved")
         if found is not None:
             RESOLVE_LAG.observe(max(time.monotonic() - found, 0.0))

@@ -143,13 +143,22 @@ class PowJournal:
         self._update_depth()
         return job_id, 0
 
-    def mark_inflight(self, job_id: int) -> None:
+    def mark_inflight(self, *job_ids: int) -> None:
+        """The jobs a solve has taken in, marked by ONE statement: a
+        refill of sixty is one write, and one release of the
+        interpreter lock, on the solving thread between a harvest and
+        the next launch (a statement a job, while a sweep was being
+        signed and encrypted beside it, held a launch back 211 ms on
+        the chip: PERF.md section 6, PR 33)."""
+        if not job_ids:
+            return
         inject("db.write")
         with self._lock:
             self._conn.execute(
                 "UPDATE powjobs SET status=?, attempts=attempts+1,"
-                " updated_at=? WHERE id=?",
-                (INFLIGHT, time.time(), job_id))
+                " updated_at=? WHERE id IN (%s)"
+                % ",".join("?" * len(job_ids)),
+                (INFLIGHT, time.time(), *job_ids))
 
     def checkpoint(self, job_id: int, next_nonce: int) -> None:
         """Record that every nonce below ``next_nonce`` was searched

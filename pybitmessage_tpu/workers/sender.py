@@ -48,6 +48,7 @@ from ..storage.messages import (
 from ..utils.addresses import decode_address
 from ..utils.hashes import inventory_hash, sha512
 from ..utils.varint import decode_varint, encode_varint
+from .cryptopool import CryptoPool
 from .keystore import KeyStore, OwnIdentity
 
 logger = logging.getLogger("pybitmessage_tpu.worker")
@@ -86,8 +87,32 @@ _sign = trace("sender.sign")(sign)
 _encrypt = trace("sender.encrypt")(encrypt)
 
 
+def _sign_and_encrypt(plain, signed: bytes, priv_signing: bytes,
+                      pub_enc: bytes) -> bytes:
+    """A send's signature and its encryption as ONE executor job: the
+    sends of a sweep then leave the executor one by one from the start
+    (two jobs a send, first in first out, put every signature of the
+    sweep before its first encryption), so the first asks for its PoW
+    while the others are still being sealed."""
+    plain.signature = _sign(signed, priv_signing)
+    return _encrypt(plain.encode(), pub_enc)
+
+
 class SendWorker:
     """Consumes send commands; drives the sent table state machine."""
+
+    #: where ``_run_crypto`` runs its jobs: the receive side's pool,
+    #: sized to ONE thread.  A job here is mostly Python around short
+    #: OpenSSL calls, so it holds the interpreter lock: fanned over the
+    #: default executor's 17 threads a sweep of 256 is sealed no sooner
+    #: (0.6-0.7 s either way, on the chip's host), every job takes ten
+    #: times as long (20.7 ms against 1.9), and the event loop, one
+    #: waiter among 18, sees the first member sealed 658 ms after the
+    #: sweep began, not 20 ms: the sweep's solve then starts as late as
+    #: at the parent and the device idles 6.95 % against 1.08 (traced,
+    #: PR 33: PERF.md section 6).  Members have to leave one by one for
+    #: a solve to begin with the first.
+    crypto = CryptoPool(1)
 
     def __init__(self, *, keystore: KeyStore, store: MessageStore,
                  inventory, pool, solver: Callable,
@@ -213,8 +238,8 @@ class SendWorker:
         CryptoPool hop (keeps the loop-lag budget; lint-enforced).
         The context is copied across the hop, so the callable's span
         is a child of the sweep that asked for it."""
-        return await asyncio.get_running_loop().run_in_executor(
-            None, contextvars.copy_context().run, fn, *args)
+        return await self.crypto.run(
+            contextvars.copy_context().run, fn, *args)
 
     async def _do_pow(self, payload_sans_nonce: bytes, ttl: int,
                       ntpb: int = 0, extra: int = 0) -> bytes:
@@ -380,11 +405,9 @@ class SendWorker:
         # signature covers shell-sans-nonce + plaintext through ackdata
         # (class_singleWorker.py:1224-1228)
         shell = object_shell(expires, OBJECT_MSG, 1, to.stream)
-        plain.signature = await self._run_crypto(
-            _sign, shell + unsigned, sender.priv_signing)
-
         encrypted = await self._run_crypto(
-            _encrypt, plain.encode(), pub_enc)
+            _sign_and_encrypt, plain, shell + unsigned,
+            sender.priv_signing, pub_enc)
         payload = shell + encrypted
         payload = await self._do_pow(payload, ttl, their_ntpb, their_extra)
         h = self._publish(payload, OBJECT_MSG, to.stream)
@@ -606,9 +629,6 @@ class SendWorker:
             sender.nonce_trials_per_byte, sender.extra_bytes,
             m.encodingtype or 2, body)
         unsigned = plain.encode_unsigned()
-        plain.signature = await self._run_crypto(
-            _sign, broadcast_signed_data(shell, unsigned),
-            sender.priv_signing)
         if sender.version <= 3:
             from ..models.payloads import broadcast_v4_key
             key = broadcast_v4_key(sender.version, sender.stream, sender.ripe)
@@ -616,7 +636,9 @@ class SendWorker:
             key = dh[:32]
         from ..crypto import priv_to_pub
         payload = shell + await self._run_crypto(
-            _encrypt, plain.encode(), priv_to_pub(key))
+            _sign_and_encrypt, plain,
+            broadcast_signed_data(shell, unsigned), sender.priv_signing,
+            priv_to_pub(key))
         payload = await self._do_pow(payload, ttl)
         h = self._publish(payload, OBJECT_BROADCAST, sender.stream, tag)
         self.store.update_sent_status(m.ackdata, BROADCASTSENT)

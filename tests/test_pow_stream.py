@@ -12,6 +12,12 @@ that starts alone asks nobody; a rung that fails hands on only what
 is unresolved, in the dispatcher and in ``PowService``; an interrupt
 leaves the rest journaled; the ladder's counters move inside one
 solve; the new series and attributes move.
+
+A solve laid out for announced company (ISSUE 33): ``expect`` plans a
+lone first member as the queue it belongs to, lays out the groups that
+queue would fill, starts the missing members' slots as pad slots and
+deals arrivals evenly; the dispatcher says whether it streams, and
+passes ``expect`` to the one rung that does.
 """
 
 import asyncio
@@ -257,6 +263,270 @@ def test_the_dispatcher_hands_a_lone_object_no_feed(monkeypatch):
     results = d.solve_batch([item], on_solved=lambda i, r: calls.append(i),
                             feed=lambda room: asked.append(room) or [])
     assert calls == [0] and asked == [] and len(results) == 1
+
+
+# -- a solve laid out for the company that is announced -----------------
+
+#: a target at network difficulty (a 300-byte broadcast: 8.2e6 trials)
+NETWORK = 2 ** 64 // 8_200_000
+
+
+@pytest.mark.parametrize("there, expect, mode", [
+    (1, 0, "slab"), (1, 1, "slab"), (1, 2, "batched"), (1, 200, "batched"),
+    (3, 256, "batched"), (5, 2, "batched"), (5, 0, "batched")])
+def test_the_plan_is_that_of_the_queue_announced(there, expect, mode):
+    items = [(bytes([i]) * 64, NETWORK) for i in range(there)]
+    plan = pipeline.plan_batch(items, expect=expect)
+    assert plan.mode == mode
+    # the order names what is there, never a member still to come
+    assert sorted(plan.order) == list(range(there))
+
+
+def test_the_pack_rule_still_reads_the_targets_that_are_there():
+    (item,) = _items("tiny", 1, expected=1000)
+    assert pipeline.plan_batch([item]).mode == "single-sync"
+    plan = pipeline.plan_batch([item], expect=200)
+    assert (plan.mode, plan.pack, plan.order) == ("packed", 16, [0])
+
+
+def _real_plan(monkeypatch):
+    """``plan_batch`` itself plans test objects as it plans objects at
+    network difficulty: no packing, the test tile."""
+    monkeypatch.setattr(pipeline, "PACK_CHOICES", ())
+    monkeypatch.setattr(pipeline, "DEFAULT_BATCH_CHUNKS", CHUNKS)
+
+
+@pytest.mark.parametrize("there, expect, groups, feeds", [
+    (1, 200, 4, 7), (1, 40, 2, 3), (3, 256, 4, 5), (5, 64, 2, 1),
+    (10, 130, 3, 4), (2, 65, 2, 2)])
+def test_a_solve_laid_out_for_company_takes_it_in(there, expect, groups,
+                                                  feeds, monkeypatch):
+    """The solve starts with ``there`` objects, laid out for ``expect``:
+    mode ``batched`` in the groups ``expect`` objects would fill, the
+    rest through ``feed``; results aligned with the order of arrival
+    and hashlib-valid; no launch without a live slot."""
+    _real_plan(monkeypatch)
+    items = _items("first %d" % expect, there, expected=3000)
+    fed = _items("company %d" % expect, expect - there, expected=3000)
+    per = -(-len(fed) // feeds)
+    waiting = [fed[k:k + per] for k in range(0, len(fed), per)]
+    calls, stats = [], {}
+    label = {"kind": "batch"}
+    launches0 = REGISTRY.sample("pow_pipeline_launches_total", label)
+    slots0 = [REGISTRY.sample("pow_pipeline_slots_total",
+                              dict(label, state=state))
+              for state in ("live", "idle")]
+    refills0 = REGISTRY.sample("pow_pipeline_refills_total", label)
+    TRACER.clear()
+
+    def feed(room):
+        part = waiting.pop(0) if waiting else []
+        out, rest = part[:room], part[room:]
+        if rest:
+            waiting.insert(0, rest)
+        return [(ih, target, 0) for ih, target in out]
+
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="xla", stats=stats, expect=expect,
+        on_solved=lambda i, r: calls.append((i, r)), feed=feed)
+    assert (stats["mode"], stats["groups"], stats["width"]) == \
+        ("batched", groups, 64)
+    assert len(results) == expect
+    for (ih, target), (nonce, trials) in zip(items + fed, results):
+        assert hashlib.sha512(hashlib.sha512(
+            nonce.to_bytes(8, "big") + ih).digest()).digest()[:8] \
+            <= target.to_bytes(8, "big")
+        assert trials > 0
+    assert sorted(i for i, _r in calls) == list(range(expect))
+    assert _grown("pow_pipeline_refills_total", label,
+                  refills0) == expect - there
+    # a group with no live slot was never launched
+    launches = int(_grown("pow_pipeline_launches_total", label, launches0))
+    spans = TRACER.recent(launches, name="pow.launch")
+    assert len(spans) == launches and all(
+        s.attrs["live"] >= 1 for s in spans)
+    live, idle = (REGISTRY.sample("pow_pipeline_slots_total",
+                                  dict(label, state=state)) - before
+                  for state, before in zip(("live", "idle"), slots0))
+    assert live >= launches and live + idle == 64 * launches
+    (plan_span,) = TRACER.recent(10, name="pow.plan")
+    assert (plan_span.attrs["objects"], plan_span.attrs["expect"],
+            plan_span.attrs["mode"]) == (there, expect, "batched")
+
+
+@pytest.mark.parametrize("there, expect, full", [
+    (6, 256, [64, 64, 64, 64]), (1, 130, [64, 64, 2])])
+def test_a_sweep_that_arrives_before_anybody_hits_fills_whole_groups(
+        there, expect, full, monkeypatch):
+    """Everybody arrives before anybody hits: a group takes what has
+    arrived into every free slot when its turn comes, so the storm's
+    256 still search as four groups of 64."""
+    everything = _items("full %d" % expect, expect, expected=10 ** 7)
+    items, fed = everything[:there], everything[there:]
+    # nobody hits until every group has been launched full; then all do
+    hits = [set()] * (3 * len(full))
+    script = Script(everything, hits, monkeypatch)
+    waiting = list(fed)
+
+    def feed(room):
+        out = waiting[:room]
+        del waiting[:room]
+        return [(ih, t, 0) for ih, t in out]
+
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="pallas", plan=_batched(there), feed=feed,
+        expect=expect)
+    assert len(results) == expect and all(r is not None for r in results)
+    assert not waiting
+    assert sorted(len(live) for live in script.live[:len(full)]) == \
+        sorted(full)
+    assert all(script.live), "a launch held no live object"
+
+
+def test_a_solve_that_runs_dry_with_members_missing_ends(monkeypatch):
+    """Nobody comes: the solve ends when what it holds is solved, the
+    pad slots of the missing never launched alone."""
+    _real_plan(monkeypatch)
+    items = _items("dry", 2)
+    asked, stats = [], {}
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="xla", stats=stats, expect=10,
+        feed=lambda room: asked.append(room) or [])
+    assert len(results) == 2 and all(r is not None for r in results)
+    assert stats["groups"] == 2 and asked
+
+
+@pytest.mark.parametrize("there, expect", [(5, 0), (5, 3), (5, 5),
+                                            (70, 70), (65, 0)])
+def test_without_company_the_layout_is_what_it_was(there, expect,
+                                                   monkeypatch):
+    everything = _items("as ever", there, expected=20000)
+    script = Script(everything, [], monkeypatch)    # all hit at once
+    asked = []
+    pipeline.solve_batch_pipelined(
+        everything, rows=ROWS, impl="pallas", plan=_batched(there),
+        feed=lambda room: asked.append(room) or [], expect=expect)
+    want = ([-(-there // 2), there // 2] if there <= 64
+            else [64, there - 64])
+    assert [len(live) for live in script.live[:2]] == want
+    # and a group is offered every free slot it has
+    assert max(asked) == 64
+
+
+@pytest.mark.parametrize("topology, tpu, breaker, farm, streams", [
+    ((1, True), True, "closed", None, True),
+    ((1, False), True, "closed", None, False),      # the CPU ladder
+    ((4, True), True, "closed", None, False),       # a pod
+    ((0, False), True, "closed", None, False),      # the probe failed
+    ((1, True), False, "closed", None, False),
+    ((1, True), True, "open", None, False),
+    ((1, True), True, "closed", "up", False),       # the farm leads
+    ((1, True), True, "closed", "down", True)])
+def test_the_dispatcher_says_whether_a_queue_would_stream(
+        topology, tpu, breaker, farm, streams, monkeypatch):
+    monkeypatch.setattr(PowDispatcher, "_batch_topology",
+                        lambda self: topology)
+    d = PowDispatcher(use_tpu=tpu, use_native=False)
+    if breaker == "open":
+        d.breakers["tpu-pallas"].record_failure()
+    if farm is not None:
+        class Farm:
+            class breaker:
+                available = staticmethod(lambda: farm == "up")
+        d.attach_farm(Farm())
+    items = [(b"\x01" * 64, NETWORK)]
+    assert d.streams(items, 200) is streams
+    # asking consumes nothing: the answer stands
+    assert d.streams(items, 200) is streams
+    # a queue of tiny objects is packed, and a packed solve holds who
+    # it starts with
+    assert d.streams(_items("tiny", 1, expected=1000), 200) is False
+
+
+@pytest.mark.parametrize("topology, expect, rung", [
+    ((1, True), 200, "pipeline"), ((1, True), 0, "ladder"),
+    ((1, True), 1, "ladder"), ((1, False), 200, "ladder"),
+    ((4, True), 200, "ladder")])
+def test_a_lone_item_with_company_is_offered_the_streaming_rung(
+        topology, expect, rung, monkeypatch):
+    monkeypatch.setattr(PowDispatcher, "_batch_topology",
+                        lambda self: topology)
+    monkeypatch.setattr(PowDispatcher, "_on_accelerator",
+                        lambda self: False)
+    monkeypatch.setattr(PowDispatcher, "_device_count", lambda self: 1)
+    _real_plan(monkeypatch)
+    (item,) = _items("lone %s %d" % (topology, expect), 1, expected=3000)
+    seen = []
+
+    def recorded(items, *, expect, feed, on_solved, **_kw):
+        seen.append((len(items), expect, feed is not None))
+        return [python_solve(ih, t) for ih, t in items]
+
+    from pybitmessage_tpu.pow.dispatcher import python_solve
+    monkeypatch.setattr(pipeline, "solve_batch_pipelined", recorded)
+    d = PowDispatcher(use_native=False)
+    TRACER.clear()
+    calls = []
+    (result,) = d.solve_batch([item], expect=expect,
+                              on_solved=lambda i, r: calls.append(i),
+                              feed=lambda room: [])
+    assert reference.trial_value(result[0].to_bytes(8, "big"),
+                                 item[0]) <= item[1]
+    assert calls == [0]
+    assert seen == ([(1, 200, True)] if rung == "pipeline" else [])
+    assert d.last_backend == ("tpu-pallas-batch" if rung == "pipeline"
+                              else "tpu")
+    (span,) = TRACER.recent(5, name="pow.solve_batch")
+    assert (span.attrs["objects"], span.attrs["expect"]) == \
+        (1, max(expect, 1))
+
+
+@pytest.mark.asyncio
+async def test_a_staggered_sweep_is_one_solve_through_the_real_ladder(
+        monkeypatch):
+    """``PowService`` over ``PowDispatcher`` told it has one chip, the
+    XLA stand-in where the kernel would be: twenty members arriving one
+    by one are ONE solve that began with the first."""
+    monkeypatch.setattr(PowDispatcher, "_batch_topology",
+                        lambda self: (1, True))
+    _real_plan(monkeypatch)
+    monkeypatch.setitem(pipeline.solve_batch_pipelined.__kwdefaults__,
+                        "rows", ROWS)
+    n = 20
+    items = _items("sweep", n, expected=12000)
+    service = PowService(PowDispatcher(use_native=False), window=5.0)
+    first0 = REGISTRY.sample("pow_window_closed_total",
+                             {"reason": "first_arrival"})
+    sum0, n0 = _histogram("pow_batch_size")
+
+    async def member(i):
+        await asyncio.sleep(0.002 * i)
+        return await service.solve(*items[i])
+
+    TRACER.clear()
+    service.start()
+    try:
+        tasks = [asyncio.ensure_future(member(i)) for i in range(n)]
+        service.announce(tasks)
+        results = await asyncio.gather(*tasks)
+    finally:
+        await service.stop()
+    for (ih, target), (nonce, _trials) in zip(items, results):
+        assert reference.trial_value(nonce.to_bytes(8, "big"), ih) \
+            <= target
+    windows = TRACER.recent(50, name="pow.queue.window")
+    assert windows[0].attrs["closed"] == "first_arrival"
+    assert windows[0].attrs["objects"] < n
+    assert windows[0].attrs["expect"] == n
+    plans = TRACER.recent(50, name="pow.plan")
+    assert (plans[0].attrs["mode"], plans[0].attrs["expect"]) == \
+        ("batched", n)
+    # every member was in some solve exactly once, most in the first
+    total, count = _histogram("pow_batch_size")
+    assert total - sum0 == n
+    assert _grown("pow_window_closed_total", {"reason": "first_arrival"},
+                  first0) >= 1
+    assert service.solved == n
 
 
 # -- a rung that fails part of the way ----------------------------------
@@ -512,6 +782,12 @@ async def test_the_resolve_lag_is_observed_once_an_object():
         await asyncio.sleep(0)
         dispatcher.late.set()
         assert await asyncio.gather(*late) == [(3, 1), (4, 1)]
+        # the last hit resolves its future before the solving thread
+        # has booked the solve: wait for what is asserted below
+        for _ in range(500):
+            if dispatcher.sizes:
+                break
+            await asyncio.sleep(0.01)
     finally:
         await service.stop()
     assert dispatcher.sizes == [5]
@@ -565,6 +841,58 @@ async def test_the_hits_of_one_harvest_wake_the_loop_once():
         loop.call_soon_threadsafe = real
         await service.stop()
     assert wakes.count("resolve_found") == 1
+
+
+@pytest.mark.parametrize("on_disk", [False, True])
+def test_the_jobs_a_solve_takes_in_are_marked_by_one_statement(
+        on_disk, tmp_path):
+    journal = PowJournal(str(tmp_path / "pow.dat") if on_disk
+                         else ":memory:")
+    jobs = [journal.add(_hash(i), 1 << 60)[0] for i in range(6)]
+    statements = []
+    journal._conn.set_trace_callback(statements.append)
+    journal.mark_inflight(*jobs[:4])
+    journal._conn.set_trace_callback(None)
+    assert len(statements) == 1 and statements[0].startswith("UPDATE")
+    by_id = {job.job_id: job for job in journal.pending()}
+    assert [by_id[j].status for j in jobs] == \
+        ["inflight"] * 4 + ["queued"] * 2
+    assert [by_id[j].attempts for j in jobs] == [1] * 4 + [0] * 2
+    # one job alone, as the farm marks them, is the same call
+    journal.mark_inflight(jobs[4])
+    assert journal.get(jobs[4]).status == "inflight"
+    journal.close()
+
+
+@pytest.mark.asyncio
+async def test_a_refill_costs_the_journal_one_write():
+    """What ``feed`` hands a running solve is marked in flight by one
+    journal call, on the solving thread, before the solve has it."""
+    calls = []
+
+    class Counting(PowJournal):
+        def mark_inflight(self, *job_ids):
+            calls.append((len(job_ids), threading.current_thread()))
+            return super().mark_inflight(*job_ids)
+
+    dispatcher, journal = StreamingDispatcher(), Counting()
+    service = PowService(dispatcher, window=0.0, journal=journal)
+    service.start()
+    try:
+        first = [asyncio.ensure_future(service.solve(_hash(i), 1 << 60))
+                 for i in range(3)]
+        await asyncio.gather(*first)
+        late = [asyncio.ensure_future(service.solve(_hash(i), 1 << 60))
+                for i in (3, 4, 5, 6)]
+        await asyncio.sleep(0)
+        dispatcher.late.set()
+        assert await asyncio.gather(*late) == [(i, 1) for i in (3, 4, 5, 6)]
+    finally:
+        await service.stop()
+    assert [n for n, _thread in calls] == [3, 4]
+    assert calls[0][1] is threading.main_thread()
+    assert calls[1][1] is not threading.main_thread()
+    assert journal.pending_count() == 0
 
 
 def test_the_slots_of_a_solve_are_the_senders_in_flight():
