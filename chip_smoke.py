@@ -535,16 +535,17 @@ def compile_side_by_side(rep: Report, programs) -> None:
 
 
 def run_pod(rep: Report) -> None:
-    """One default-difficulty object and a 64-object batch through
-    PowDispatcher on every chip, and the same objects on a one-device
-    mesh to compare with."""
+    """Through PowDispatcher on every chip: default-difficulty objects
+    one at a time, each nonce range partitioned over the chips, and a
+    64-object queue, its launch groups placed over them; and the same
+    objects on one device to compare with."""
     import jax
 
     from pybitmessage_tpu.ops.sha512_pallas import LANE_COLS
     from pybitmessage_tpu.parallel import (make_mesh, pallas_sharded_solve,
-                                           pallas_sharded_solve_batch,
                                            pow_pallas_sharded)
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
+    from pybitmessage_tpu.pow.pipeline import solve_batch_pipelined
 
     ndev = len(jax.devices())
     rng = random.Random(SEED)
@@ -563,31 +564,20 @@ def run_pod(rep: Report) -> None:
         rep.say("%s: %.1fs wall" % (label, dt))
         return out, dt
 
-    # the four programs (pod mesh and one-device mesh, single and
-    # batch) at the shapes the product launches by default
+    # the partitioned search's two programs (pod mesh and one-device
+    # mesh) at the shape the product launches by default
     import jax.numpy as jnp
     mesh1 = make_mesh(1)
-    mesh1x1 = make_mesh(1, obj_axis="obj", obj_size=1)
     impl = pow_pallas_sharded.default_impl()
     u32 = functools.partial(jnp.zeros, dtype=jnp.uint32)
+    kw = pallas_sharded_solve.__kwdefaults__
     programs = []
-    for kind, solver, meshes in (
-            ("single", pallas_sharded_solve,
-             (d._mesh(ndev, 1), mesh1)),
-            ("batch", pallas_sharded_solve_batch,
-             (d._mesh(ndev, len(batch)), mesh1x1))):
-        kw = solver.__kwdefaults__
-        for mesh in meshes:
-            fn = pow_pallas_sharded._get_fn(
-                mesh, kind, kw["rows"], kw["chunks_per_call"],
-                kw["unroll"], impl, False, kw["variant"])
-            n = (pow_pallas_sharded.POD_BATCH_PER_DEVICE
-                 * mesh.shape.get("obj", 1))
-            args = ((u32((8, 2)), u32((2,)), u32((2,)))
-                    if kind == "single" else
-                    (u32((n, 8, 2)), u32((n, 2)), u32((n, 2))))
-            programs.append(("%s on %d device(s)"
-                             % (kind, mesh.devices.size), fn, args))
+    for mesh in (d._mesh(ndev, 1), mesh1):
+        fn = pow_pallas_sharded._get_fn(
+            mesh, "single", kw["rows"], kw["chunks_per_call"],
+            kw["unroll"], impl, False, kw["variant"])
+        programs.append(("single on %d device(s)" % mesh.devices.size, fn,
+                         (u32((8, 2)), u32((2,)), u32((2,)))))
     compile_side_by_side(rep, programs)
 
     warm = _default_item(b"pod warm", 1016)
@@ -620,29 +610,33 @@ def run_pod(rep: Report) -> None:
         rep.say("smoke timing, single, %s: %.0f trials/s"
                 % (label, sum(r[1] for r in res) / dt))
 
+    # a queue is the pipeline's on any number of chips: its launch
+    # groups dealt over them, an object's whole range on one chip
+    by_device = "pow_pipeline_device_launches_total"
     timed("first batch, %d devices" % ndev,
-          lambda: d.solve_batch(batch[:2]))
-    rep.check(d.last_backend == "tpu-pallas-sharded-batch",
+          lambda: d.solve_batch(batch))
+    rep.check(d.last_backend == "tpu-pallas-batch",
               "batch solve backend %r" % d.last_backend)
-    timed("first batch, 1 device",
-          lambda: pallas_sharded_solve_batch(batch[:2], mesh1x1))
+    timed("first batch, 1 device", lambda: solve_batch_pipelined(batch))
+    before = _family(by_device)
     bres_n, bdt_n = timed("batch of %d, %d devices" % (len(batch), ndev),
                           lambda: d.solve_batch(batch))
+    took = {k[0]: int(v - before.get(k, 0))
+            for k, v in sorted(_family(by_device).items())}
     bres_1, bdt_1 = timed("batch of %d, 1 device" % len(batch),
-                          lambda: pallas_sharded_solve_batch(batch,
-                                                             mesh1x1))
+                          lambda: solve_batch_pipelined(batch))
     rep.check(all(_valid(it, r) for it, r in zip(batch, bres_n))
               and all(_valid(it, r) for it, r in zip(batch, bres_1)),
-              "batch nonces valid by hashlib on both meshes")
+              "batch nonces valid by hashlib on %d devices and on one"
+              % ndev)
+    rep.check(bres_n == bres_1, "the placed batch found the nonces and "
+              "trials of the one-device batch")
+    rep.check(len(took) == ndev and all(took.values()),
+              "every device took launches of the batch: %s" % took)
     for label, res, dt in (("%d devices" % ndev, bres_n, bdt_n),
                            ("1 device", bres_1, bdt_1)):
         rep.say("smoke timing, batch, %s: %.0f trials/s (%.1fs)"
                 % (label, sum(r[1] for r in res) / dt, dt))
-    mesh = d._mesh(ndev, len(batch))
-    per_dev = pow_pallas_sharded.POD_BATCH_PER_DEVICE
-    rep.say("batch mesh %s: %d objects dealt round-robin over %d "
-            "obj-axis device(s), %d slots each"
-            % (dict(mesh.shape), len(batch), mesh.shape["obj"], per_dev))
     fell = rep.since_start("pow_fallback_total")
     rep.check(not fell, "no fall to the XLA sharded tier %s"
               % (fell or ""))

@@ -507,12 +507,13 @@ class BMConnection:
                      self.host, self.skip_until - time.time(),
                      " (initial)" if initial else " (missing object)")
 
-    async def flush_uploads(self, limit: int = 10) -> None:
+    async def flush_uploads(self, limit: int = 10) -> int:
         """Serve up to ``limit`` queued getdata requests
-        (reference uploadthread.py:15-69).  Objects still in the
-        dandelion stem phase are withheld as if unknown."""
+        (reference uploadthread.py:15-69); returns how many it served.
+        Objects still in the dandelion stem phase are withheld as if
+        unknown."""
         if time.time() < self.skip_until:
-            return  # antiIntersectionDelay window — serve nothing yet
+            return 0  # antiIntersectionDelay window — serve nothing yet
         dand = self.ctx.dandelion
         served = 0
         while self.pending_upload and served < limit:
@@ -538,6 +539,7 @@ class BMConnection:
             await self.send_object(h, item.payload)
             self.tracker.object_received(h)
             served += 1
+        return served
 
     # -- wire trace context (docs/observability.md) --------------------------
 

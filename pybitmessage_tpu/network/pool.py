@@ -66,6 +66,9 @@ DEFAULT_MAX_TOTAL = 200
 PING_INTERVAL = 300
 INV_INTERVAL = 1.0
 DOWNLOAD_INTERVAL = 1.0
+#: rest of the upload loop after a round that served nothing, and the
+#: longest a round waits for a connection's ten objects
+UPLOAD_INTERVAL = 1.0
 #: TCP connect budget for one outbound dial (``connecttimeout``)
 DEFAULT_DIAL_TIMEOUT = 10.0
 #: version/verack must complete within this or the slot is reclaimed —
@@ -239,6 +242,7 @@ class ConnectionPool:
             asyncio.create_task(self._dial_loop()),
             asyncio.create_task(self._inv_loop()),
             asyncio.create_task(self._download_loop()),
+            asyncio.create_task(self._upload_loop()),
             asyncio.create_task(self._maintenance_loop()),
         ]
 
@@ -581,14 +585,71 @@ class ConnectionPool:
             try:
                 for conn in self.established():
                     await conn.request_objects()
-                    # drain queued getdata backlogs (10/round cadence of
-                    # the reference's uploadthread)
-                    await conn.flush_uploads()
             except asyncio.CancelledError:
                 raise
             except Exception:
                 ERRORS.labels(site="net.download_loop").inc()
                 logger.exception("download loop error")
+
+    async def _upload_loop(self) -> None:
+        """Serve the queued getdata backlogs in a task of their own, at
+        the cadence of the reference's uploadthread
+        (uploadthread.py:15-69): ten objects a connection a round, round
+        after round while any was served, a rest of ``UPLOAD_INTERVAL``
+        after a round that served nothing.  One round a second inside
+        the download loop capped a peer at ten objects a second, which a
+        sender on four chips outruns four times over (PERF.md section 6,
+        PR 37).
+
+        A connection's ten are sent in a task of that connection's and
+        a round waits ``UPLOAD_INTERVAL`` for them at most
+        (``send_object`` waits for the socket's buffer to drain, with
+        no deadline): a peer that asks for much and reads slowly
+        finishes its ten in its own time and is given no more until it
+        has, the others are served at no less than the ten a second a
+        link always carried, and ``request_objects`` waits for no
+        upload at all."""
+        sending: dict = {}      # connection -> its ten, on their way
+        try:
+            while True:
+                try:
+                    served, waiting = await self._upload_round(sending)
+                except asyncio.CancelledError:
+                    raise
+                except Exception:
+                    ERRORS.labels(site="net.upload_loop").inc()
+                    logger.exception("upload loop error")
+                    served = waiting = 0
+                if not served and not waiting:
+                    await asyncio.sleep(UPLOAD_INTERVAL)
+        finally:
+            for task in sending.values():
+                task.cancel()
+
+    async def _upload_round(self, sending: dict) -> tuple[int, int]:
+        """One round of :meth:`_upload_loop`: how many objects it
+        served, and how many connections are still sending theirs."""
+        for conn in self.established():
+            if conn.pending_upload and conn not in sending:
+                sending[conn] = asyncio.create_task(conn.flush_uploads())
+        if not sending:
+            return 0, 0
+        done, pending = await asyncio.wait(sending.values(),
+                                           timeout=UPLOAD_INTERVAL)
+        served = 0
+        for conn in [c for c, t in sending.items() if t in done]:
+            task = sending.pop(conn)
+            if task.cancelled():
+                continue
+            if task.exception() is None:
+                served += task.result()
+            else:
+                # a send that failed: the connection's reader sees the
+                # same socket and closes it
+                ERRORS.labels(site="net.send").inc()
+                logger.debug("upload to %s failed (%r)", conn.host,
+                             task.exception())
+        return served, len(pending)
 
     async def _maintenance_loop(self) -> None:
         while True:

@@ -18,8 +18,9 @@ This module holds kernels only: the three jitted entry points
 their shape constants and their ``register_program`` lines.  Which of
 them serves an input, at which shape, and the host loop that launches
 it live one layer up, in ``pow/pipeline.py`` (``plan_batch``,
-``_PipelineDriver``); the pod's loops are in
-``parallel/pow_pallas_sharded.py``.  Nothing here imports from
+``_PipelineDriver``), which also places a queue over the chips of a
+host; the nonce-range partition of a lone object over several chips
+is in ``parallel/pow_pallas_sharded.py``.  Nothing here imports from
 ``pybitmessage_tpu.pow``.
 """
 
@@ -599,8 +600,8 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
 #: cache that keeps it a once-per-machine cost.
 BATCH_OBJS = 64
 #: grid steps an object of the pod's batch launches
-#: (parallel/pow_pallas_sharded.py); the single-chip pipeline has its
-#: own, ``pow.pipeline.DEFAULT_BATCH_CHUNKS``
+#: (parallel/pow_pallas_sharded.py); the pipeline has its own,
+#: ``pow.pipeline.DEFAULT_BATCH_CHUNKS``
 BATCH_CHUNKS = 64
 #: four tiles to a grid step of the batch grid (64 objects x 64 chunks
 #: x 4, solve-verified on-chip since r4).  Since PR 26 this sets only

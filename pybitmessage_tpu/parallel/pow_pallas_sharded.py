@@ -1,9 +1,18 @@
 """Pod-sharded PoW built on the production Pallas kernel.
 
+What the node calls here is :func:`pallas_sharded_solve`: an object
+that is alone on a host of several chips has its nonce range
+partitioned over all of them (``PowDispatcher._solve_on_device``).  A
+QUEUE on such a host is not this module's any more: it goes through
+``pow/pipeline.py``, whose launch groups are dealt over the chips, an
+object's whole nonce range on one chip (docs/pow_pipeline.md, "A solve
+placed over several chips").  :func:`pallas_sharded_solve_batch`, the
+2D (objects x nonce-range) loop, is left with no caller in the node
+(``tools/tpu_doctor.py`` and the tests call it; ROADMAP D10).
+
 The per-chip slab is the SAME Mosaic kernel the single-chip tier runs
-(``ops/sha512_pallas.py``, 84.6 MH/s/chip on a v5e vs 25.8 for the XLA
-windowed fallback): a ``pl.pallas_call`` per device under ``shard_map``,
-device *d* searching the contiguous slab
+(``ops/sha512_pallas.py``): a ``pl.pallas_call`` per device under
+``shard_map``, device *d* searching the contiguous slab
 ``[base + d*slab, base + (d+1)*slab)`` — the multi-chip generalization
 of the reference's per-thread nonce striding
 (src/bitmsghash/bitmsghash.cpp:76-125), with the OpenCL host-loop slab

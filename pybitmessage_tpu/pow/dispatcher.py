@@ -44,6 +44,13 @@ import logging
 import time
 from typing import Callable, NamedTuple
 
+# imported with the dispatcher and not at a rung's first call: that
+# call is a solve somebody waits for, and ``parallel.pow_pallas_sharded``
+# binds the Mosaic kernels by name for its ``shard_map`` bodies, which
+# run under a trace, so it takes them as they are when the node starts
+# and not as whatever may have wrapped them since (a wrapper that keeps
+# a launch's outputs would keep tracers there)
+from .. import parallel
 from ..observability import REGISTRY, trace
 from ..ops.pow_search import PowInterrupted
 from ..resilience import CircuitBreaker, inject
@@ -109,17 +116,14 @@ class _Rung(NamedTuple):
     message: str            # logged with the failure's traceback
 
 
-# a queue on a pod, then on one chip
-_PALLAS_SHARDED_BATCH = _Rung(
-    "tpu-pallas-sharded-batch", "tpu-pallas", ("tpu",), True,
-    "tpu-pallas", "tpu-xla",
-    "sharded batched Pallas PoW failed; using sharded XLA batch")
+# a queue: the pipeline on the accelerator's chips, then on several
+# devices the XLA search sharded over them
+_PIPELINE_BATCH = _Rung(
+    "tpu-pallas-batch", "tpu-pallas", (), True, "tpu-pallas", "ladder",
+    "batched Pallas PoW failed; falling back to the next rung")
 _XLA_SHARDED_BATCH = _Rung(
     "tpu-batch", "tpu", (), True, "tpu-batch", "ladder",
     "batched TPU PoW failed; falling back to per-object solves")
-_PIPELINE_BATCH = _Rung(
-    "tpu-pallas-batch", "tpu-pallas", (), True, "tpu-pallas", "ladder",
-    "batched Pallas PoW failed; falling back to per-object solves")
 # one object: the ``tpu`` rung holds the topology probe, the Mosaic
 # rung of that topology (its own breaker inside this one) and the XLA
 # search, whose failures are this rung's
@@ -180,12 +184,14 @@ def python_solve(initial_hash: bytes, target: int, *,
 class PowDispatcher:
     """Callable solver with the farm -> TPU -> C++ -> python ladder.
 
-    On one chip every solve, a lone object included, goes through
-    ``pow.pipeline.solve_batch_pipelined``.  When more than one
-    accelerator device is visible, single solves are range-partitioned
-    across the whole mesh (``sharded_solve``) and :meth:`solve_batch`
-    maps a queue of pending objects onto a 2D (objects x nonce-range)
-    mesh — the pod-wide path.
+    On an accelerator a queue goes through
+    ``pow.pipeline.solve_batch_pipelined`` however many chips there
+    are: its launch groups are dealt over them, an object's whole nonce
+    range on one chip.  On one chip a lone object goes the same way;
+    with several, a lone object's nonce range is partitioned across
+    the whole mesh (``pallas_sharded_solve``, then ``sharded_solve``).
+    Several devices that are no accelerator (a CPU mesh) take a queue
+    as one XLA search on a 2D (objects x nonce-range) mesh.
 
     Timing attributes (also exported through the metrics registry):
 
@@ -289,14 +295,13 @@ class PowDispatcher:
                     break
         key = (ndev, obj_size)
         if key not in self._meshes:
-            from ..parallel import make_mesh
             # shape values are bounded by the pod topology (device
             # count x slab obj_size), not by traffic
             MESH_COMPILES.labels(shape="%dx%d" % key).inc()  # bmlint: allow(metric-labels)
             if obj_size == 1:
-                self._meshes[key] = make_mesh(ndev)
+                self._meshes[key] = parallel.make_mesh(ndev)
             else:
-                self._meshes[key] = make_mesh(
+                self._meshes[key] = parallel.make_mesh(
                     ndev, obj_axis="obj", obj_size=obj_size)
         return self._meshes[key]
 
@@ -383,8 +388,8 @@ class PowDispatcher:
     def streams(self, items, expect: int = 0) -> bool:
         """Whether a solve of ``items``, laid out for ``expect``
         objects, would now take late arrivals in (``feed``): the rung
-        that would serve it is the single-chip pipeline (the TPU rungs
-        enabled, no farm ahead of them, one accelerator device, the
+        that would serve it is the pipeline (the TPU rungs enabled, no
+        farm ahead of them, an accelerator of one chip or several, the
         pipeline's breaker not open), and the plan for it is the mode
         that takes in (``batched``: objects at network difficulty; a
         queue of tiny objects is packed and holds who it starts with).
@@ -399,7 +404,7 @@ class PowDispatcher:
             return False
         if self.farm is not None and self.farm.breaker.available():
             return False
-        if self._batch_topology() != (1, True):
+        if not self._batch_topology()[1]:
             return False
         from .pipeline import plan_batch
         return plan_batch(items, expect=expect).mode == "batched"
@@ -409,8 +414,9 @@ class PowDispatcher:
                     expect: int = 0):
         """Solve ``[(initial_hash, target), ...]`` -> ``[(nonce, trials)]``.
 
-        All pending objects go down in ONE pod-wide launch when a
-        multi-device mesh is available (objects data-parallel x nonce
+        On an accelerator the queue is the pipeline's, placed over
+        every chip; on a multi-device mesh that is none, all pending
+        objects go down in ONE launch (objects data-parallel x nonce
         range partitioned); otherwise objects are solved sequentially
         through the normal ladder.
 
@@ -418,16 +424,15 @@ class PowDispatcher:
         resumes each object's search from a journaled checkpoint, and
         ``progress(i, next_nonce)`` is called as slabs harvest with
         the highest offset known fully searched for item ``i`` — the
-        pipelined single-chip path, the pod-sharded Pallas batch loop
-        and the sequential ladder all honor both (the XLA
+        pipeline and the sequential ladder honor both (the XLA
         ``sharded_solve_batch`` rescue tier still re-searches from 0
         but remains correct).
 
         The solve as a stream (docs/pow_pipeline.md): ``on_solved(i,
         (nonce, trials))`` is called once for every item, as soon as
         its nonce is known — from the harvest that found it on the
-        single-chip pipeline, when the rung returns on the rungs that
-        cannot stream — and ``feed(room)`` lets the pipeline's
+        pipeline, when the rung returns on the rungs that cannot
+        stream — and ``feed(room)`` lets the pipeline's
         ``batched`` mode take queued requests ``(initial_hash, target,
         start_nonce)`` into freed slots; they are numbered on from
         ``len(items)`` and their results follow the items' in what is
@@ -574,40 +579,31 @@ class PowDispatcher:
     def _batch_rungs(self, items, starts, should_stop, progress,
                      on_solved, feed, expect):
         """The device rungs the topology admits for a queue, in order,
-        each with the call that runs it.  Only the single-chip pipeline
-        streams: it alone is given ``on_solved``, ``feed`` and
-        ``expect``."""
+        each with the call that runs it.  Only the pipeline streams: it
+        alone is given ``on_solved``, ``feed`` and ``expect``, and on
+        several chips the devices to place its launch groups on."""
         ndev, on_accel = self._batch_topology()
 
-        def pallas_sharded_batch():
-            from ..parallel import pallas_sharded_solve_batch
-            return pallas_sharded_solve_batch(
-                items, self._mesh(ndev, len(items)),
-                should_stop=should_stop, start_nonces=starts,
-                progress=progress)
-
         def xla_sharded_batch():
-            from ..parallel import sharded_solve_batch
-            return sharded_solve_batch(
+            return parallel.sharded_solve_batch(
                 items, self._mesh(ndev, len(items)),
                 should_stop=should_stop, **self._xla_kwargs())
 
         def pipeline_batch():
+            import jax
+
             from .pipeline import solve_batch_pipelined
             return solve_batch_pipelined(
                 items, should_stop=should_stop, start_nonces=starts,
                 progress=progress, stall_timeout=self.stall_timeout,
-                on_solved=on_solved, feed=feed, expect=expect)
+                on_solved=on_solved, feed=feed, expect=expect,
+                devices=jax.devices()[:ndev] if ndev > 1 else None)
 
-        if ndev > 1:
-            if len(items) == 1:
-                # laid out for company the pod's rungs cannot take in
-                return
-            if on_accel:
-                yield _PALLAS_SHARDED_BATCH, pallas_sharded_batch
-            yield _XLA_SHARDED_BATCH, xla_sharded_batch
-        elif on_accel:
+        if on_accel:
             yield _PIPELINE_BATCH, pipeline_batch
+        # a lone item laid out for company: this rung cannot take it in
+        if ndev > 1 and len(items) > 1:
+            yield _XLA_SHARDED_BATCH, xla_sharded_batch
 
     def _solve(self, initial_hash, target, start_nonce, should_stop,
                progress=None, try_farm=True):
@@ -633,15 +629,15 @@ class PowDispatcher:
 
     def _solve_on_device(self, item, start_nonce, should_stop, progress):
         """The body of one object's ``tpu`` rung: on an accelerator the
-        Mosaic rung of this topology — the pod's sharded search, or on
-        one chip the pipeline with a batch of one — then the XLA
-        search."""
+        Mosaic rung of this topology — on several chips the search
+        sharded by nonce range over all of them (an object that is
+        alone has nobody to share the chips with), on one chip the
+        pipeline with a batch of one — then the XLA search."""
         initial_hash, target = item
         ndev = self._device_count()
 
         def pallas_sharded():
-            from ..parallel import pallas_sharded_solve
-            return pallas_sharded_solve(
+            return parallel.pallas_sharded_solve(
                 initial_hash, target, self._mesh(ndev, 1),
                 start_nonce=start_nonce, should_stop=should_stop,
                 progress=progress)
@@ -662,9 +658,8 @@ class PowDispatcher:
             if result is not None:
                 return result
         if ndev > 1:
-            from ..parallel import sharded_solve
             self._enter("tpu-sharded")
-            return sharded_solve(
+            return parallel.sharded_solve(
                 initial_hash, target, self._mesh(ndev, 1),
                 start_nonce=start_nonce, should_stop=should_stop,
                 **self._xla_kwargs())

@@ -1,5 +1,6 @@
-"""Plan and driver of the single-chip PoW search: which kernel at which
-shape serves a batch, and the one host loop that launches it.
+"""Plan and driver of the PoW search on an accelerator's chips: which
+kernel at which shape serves a batch, which chip each launch group
+lives on, and the one host loop that launches it.
 
 The send path below ``PowService`` is three boxes whose arrows point
 down (docs/pow_pipeline.md):
@@ -7,9 +8,9 @@ down (docs/pow_pipeline.md):
 * ``pow/dispatcher.py`` — the ladder: which rung, and is it healthy;
 * this module — :func:`plan_batch` (the only place that says which
   kernel and which static shape serve a batch, a lone object included)
-  and :class:`_PipelineDriver` (the only dispatch-ahead loop of the
-  single-chip path, with the one speculation rule, the stall watchdog,
-  the ``pow.*`` spans and the launch counters);
+  and :class:`_PipelineDriver` (the only dispatch-ahead loop, over
+  one chip or several, with the one speculation rule, the stall
+  watchdog, the ``pow.*`` spans and the launch counters);
 * ``ops/sha512_pallas.py`` — the jitted kernels, and nothing else.
 
 Four plan modes, one loop:
@@ -36,6 +37,11 @@ alternate and none is dispatched ahead of an unread one.  A solve that
 is told how many objects to ``expect`` is planned and laid out for that
 many: it may start with the first member of a sweep, the slots of the
 members still to come start as pad slots, and ``feed`` fills them.
+Given several ``devices`` the launch groups are dealt over them, at
+least two a device: a group's arrays and launches stay on its chip, an
+object's whole nonce range with them, and each chip has its own
+launches in flight (docs/pow_pipeline.md, "A solve placed over several
+chips").
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -136,6 +142,11 @@ LAUNCHES = REGISTRY.counter(
     "pow_pipeline_launches_total",
     "Search-kernel launches dispatched by the PoW host loops, by kind "
     "(batch | packed | single-sync | slab)", ("kind",))
+DEVICE_LAUNCHES = REGISTRY.counter(
+    "pow_pipeline_device_launches_total",
+    "Search-kernel launches dispatched by the pipeline's host loop, by "
+    "the device of the launch group (its index among the devices the "
+    "solve was given; 0 where it was given none)", ("device",))
 ABANDONED_LAUNCHES = REGISTRY.counter(
     "pow_pipeline_abandoned_launches_total",
     "Speculative launches dispatched and never fetched because every "
@@ -438,21 +449,32 @@ _KIND = {"slab": "slab", "batched": "batch", "packed": "packed",
 # ---------------------------------------------------------------------------
 
 
-#: (program, static shape) pairs this process has launched once
+#: ((program, static shape), device lane) pairs this process has
+#: launched once: a program is lowered and compiled anew for each
+#: device it runs on
 _TRACED_SHAPES: set = set()
 
 
 class _PipelineDriver:
     """Generic dispatch-ahead loop: keep up to ``depth`` slabs in
-    flight, harvesting the oldest while newer ones run on device.
+    flight on each of ``lanes`` devices, harvesting a device's oldest
+    while its newer ones run.
 
-    ``next_launch()`` returns an opaque (tag, device_future) pair or
-    None when no work remains; ``harvest(tag, host_result)`` consumes
-    one finished slab.  ``fetch`` pulls a device value to the host
-    (the blocking transfer whose wait time is the device-busy proxy).
+    ``next_launch(lane)`` returns an opaque (tag, device_future) pair
+    for device ``lane`` or None when nothing is to be launched there
+    now; ``harvest(tag, host_result)`` consumes one finished slab;
+    ``load(lane)`` says how much a device has to do (its live slots),
+    and of the devices with as many launches in flight the one with
+    the least is asked first.
+    ``fetch`` pulls a device value to the host (the blocking transfer
+    whose wait time is the device-busy proxy).  A device's launches
+    come in in the order they went out; with several devices the
+    oldest launch of each is fetched on a thread of its own and the
+    first to come in is harvested, so no device that has run out waits
+    for another's launch to be read.
     """
 
-    def __init__(self, *, depth: int = 2,
+    def __init__(self, *, depth: int = 2, lanes: int = 1,
                  should_stop: Callable[[], bool] | None = None,
                  fetch=None, stall_timeout: float = 0.0,
                  kind: str = "batch", shape=None):
@@ -464,22 +486,28 @@ class _PipelineDriver:
             return np.asarray(dev)
 
         self.depth = max(1, depth)
+        self.lanes = max(1, lanes)
         self.should_stop = should_stop
         self.fetch = fetch or default_fetch
         #: per-harvest stall deadline (0 disables the watchdog); a
         #: wedged transfer raises SlabStallError out of run(), which
         #: the dispatcher treats as a tier failure and requeues the
         #: batch to the next ladder tier
-        self.stall_timeout = stall_timeout
-        #: one reusable guard worker per driver — the guarded path must
-        #: not pay a thread spawn per harvest; only a stall abandons it
-        #: (the wedged thread keeps the old executor, a fresh one takes
-        #: over)
+        self.stall_timeout = max(stall_timeout or 0.0, 0.0)
+        #: reusable guard workers: with several devices one a device
+        #: and one for a first launch beside their fetches, with one
+        #: device one worker for both (no fetch is out while the loop
+        #: launches) — the guarded path must not pay a thread spawn per
+        #: harvest; only a stall abandons them (the wedged thread keeps
+        #: the old executor, a fresh one takes over).  None: one device
+        #: and no watchdog, everything runs in place
         self._guard_pool = None
+        #: lane -> (fetch of that device's oldest launch, its deadline)
+        self._fetching: dict = {}
         #: label of this driver's launches in the pipeline counters
         self.kind = kind
         #: (program, static shape) of the launches, if the caller knows:
-        #: the first launch of a shape in the process traces and lowers
+        #: the first launch of a shape on a device traces and lowers
         #: the kernel (see :meth:`_on_device_thread`)
         self.shape = shape
         self.wait_seconds = 0.0
@@ -488,65 +516,116 @@ class _PipelineDriver:
         self.wall_seconds = 0.0
         self.slabs = 0
 
-    def _fetch(self, dev):
-        with trace("pow.fetch") as span:
-            host = self._on_device_thread(self.fetch, dev,
-                                          timeout=self.stall_timeout)
-        self.last_wait = span.duration
-        self.wait_seconds += span.duration
-        DEVICE_WAIT.observe(span.duration)
-        return host
+    @property
+    def _guarded(self) -> bool:
+        return self.lanes > 1 or self.stall_timeout > 0
 
-    def _on_device_thread(self, fn, *args, timeout=None):
-        """``fn(*args)`` on this driver's one worker thread, the
-        caller's span context with it.  With the watchdog off
-        (``stall_timeout`` 0) it is called in place.
-
-        Fetches run there so that a wedged transfer can be left behind
-        (``timeout``).  So does the first launch of a shape in the
-        process, with no deadline (a cold compile is inside it): it
-        traces and lowers the kernel, and how long CPython 3.12 takes
-        over that depends on how deep the calling thread's frames
-        already are — every call that crosses a 16 KiB boundary of the
-        thread's frame stack frees and maps a chunk, and a trace that
-        hovers there took 2.5 times as long on the chip (PERF.md
-        section 6, PR 29).  From a worker's own shallow stack the
-        answer does not depend on what called the solve."""
-        if not self.stall_timeout or self.stall_timeout <= 0:
-            return fn(*args)
+    def _submit(self, fn, *args):
         import concurrent.futures as cf
         import contextvars
         if self._guard_pool is None:
             self._guard_pool = cf.ThreadPoolExecutor(
-                1, thread_name_prefix="bmtpu-pow-slab-guard")
-        fut = self._guard_pool.submit(contextvars.copy_context().run,
-                                      fn, *args)
+                self.lanes + (self.lanes > 1),
+                thread_name_prefix="bmtpu-pow-slab-guard")
+        return self._guard_pool.submit(contextvars.copy_context().run,
+                                       fn, *args)
+
+    def _stalled(self) -> SlabStallError:
+        timeout = self.stall_timeout
+        STALLS.labels(site="pow.slab").inc()
+        # black box: dump the ring while the pre-stall context
+        # (launches, breaker flips, chaos fires) is still in it
+        from ..observability.flightrec import FLIGHT_RECORDER
+        FLIGHT_RECORDER.record("stall", site="pow.slab", timeout=timeout)
+        FLIGHT_RECORDER.dump("stall")
+        logger.error("pow.slab stalled: harvest exceeded %.1fs; "
+                     "abandoning the launch and falling back", timeout)
+        self._drop_guards()
+        return SlabStallError(
+            "pow.slab exceeded %.1fs stall deadline" % timeout)
+
+    def _drop_guards(self) -> None:
+        """Leave the workers behind, a wedged one with its executor."""
+        for fut, _deadline in self._fetching.values():
+            # consume whatever a worker eventually produces so its late
+            # exception is not reported as never-retrieved
+            fut.add_done_callback(lambda f: f.exception())
+        self._fetching.clear()
+        if self._guard_pool is not None:
+            self._guard_pool.shutdown(wait=False)
+            self._guard_pool = None
+
+    def _first_in(self, queues):
+        """Take the launch that comes in first among each device's
+        oldest: ``(tag, host_result)``.  One device is fetched as it
+        always was: in place with the watchdog off, else on the guard
+        worker with ``stall_timeout`` seconds to come in.  With several
+        each oldest launch has a fetch running on a guard worker, and
+        one that is not in ``stall_timeout`` seconds after its fetch
+        began is a stall."""
+        import concurrent.futures as cf
+        with trace("pow.fetch") as span:
+            if self.lanes == 1:
+                lane, host = 0, self._on_device_thread(
+                    self.fetch, queues[0][0][2], timeout=self.stall_timeout)
+            else:
+                for k, q in enumerate(queues):
+                    if q and k not in self._fetching:
+                        self._fetching[k] = (
+                            self._submit(self.fetch, q[0][2]),
+                            time.monotonic() + self.stall_timeout)
+                due = min(d for _f, d in self._fetching.values())
+                cf.wait([f for f, _d in self._fetching.values()],
+                        max(due - time.monotonic(), 0)
+                        if self.stall_timeout else None,
+                        return_when=cf.FIRST_COMPLETED)
+                # of those that are in, the one launched first
+                lane = min((k for k, (f, _d) in self._fetching.items()
+                            if f.done()),
+                           key=lambda k: queues[k][0][0], default=None)
+                if lane is None:
+                    raise self._stalled()
+                host = self._fetching.pop(lane)[0].result()
+            span.attrs["device"] = lane
+        self.last_wait = span.duration
+        self.wait_seconds += span.duration
+        DEVICE_WAIT.observe(span.duration)
+        return queues[lane].popleft()[1], host
+
+    def _on_device_thread(self, fn, *args, timeout=None):
+        """``fn(*args)`` on one of this driver's worker threads, the
+        caller's span context with it; not back in ``timeout`` seconds
+        (0 or None: no deadline) it is a stall.  With one device and
+        the watchdog off (``stall_timeout`` 0) it is called in place.
+
+        One device's fetches run there so that a wedged transfer can be
+        left behind.  So does the first launch of a shape on a device,
+        with no deadline (a cold compile is inside it): it traces and
+        lowers the kernel, and how long CPython 3.12 takes over that
+        depends on how deep the calling thread's frames already are —
+        every call that crosses a 16 KiB boundary of the thread's frame
+        stack frees and maps a chunk, and a trace that hovers there
+        took 2.5 times as long on the chip (PERF.md section 6, PR 29).
+        From a worker's own shallow stack the answer does not depend on
+        what called the solve."""
+        if not self._guarded:
+            return fn(*args)
+        import concurrent.futures as cf
+        fut = self._submit(fn, *args)
         try:
-            return fut.result(timeout)
+            return fut.result(timeout or None)
         except cf.TimeoutError:
-            STALLS.labels(site="pow.slab").inc()
-            # black box: dump the ring while the pre-stall context
-            # (launches, breaker flips, chaos fires) is still in it
-            from ..observability.flightrec import FLIGHT_RECORDER
-            FLIGHT_RECORDER.record("stall", site="pow.slab",
-                                   timeout=timeout)
-            FLIGHT_RECORDER.dump("stall")
-            logger.error("pow.slab stalled: harvest exceeded %.1fs; "
-                         "abandoning the launch and falling back",
-                         timeout)
             # consume whatever the wedged worker eventually produces so
             # its late exception is not reported as never-retrieved
             fut.add_done_callback(lambda f: f.exception())
-            self._guard_pool.shutdown(wait=False)
-            self._guard_pool = None
-            raise SlabStallError(
-                "pow.slab exceeded %.1fs stall deadline" % timeout)
+            raise self._stalled() from None
 
-    def run(self, next_launch, harvest, done=None) -> None:
-        inflight: deque = deque()
+    def run(self, next_launch, harvest, done=None, load=None) -> None:
+        queues = [deque() for _ in range(self.lanes)]
         t_start = time.monotonic()
         try:
             while True:
+                inflight = sum(map(len, queues))
                 if done is not None and done():
                     # every result is in: any remaining in-flight slab
                     # is pure speculation — abandon it unfetched (the
@@ -554,46 +633,56 @@ class _PipelineDriver:
                     # paying a blocking readback for nothing
                     if inflight:
                         ABANDONED_LAUNCHES.labels(kind=self.kind).inc(
-                            len(inflight))
-                        inflight.clear()
+                            inflight)
                     break
                 if self.should_stop is not None and self.should_stop():
                     # drain what is already in flight — a pending slab
                     # may hold the answer the caller checkpoints on
-                    while inflight:
-                        tag, dev = inflight.popleft()
-                        harvest(tag, self._fetch(dev))
+                    while any(queues):
+                        harvest(*self._first_in(queues))
                     raise PowInterrupted("pipelined PoW interrupted")
-                while len(inflight) < self.depth:
-                    if self.shape in _TRACED_SHAPES:
-                        nxt = next_launch()
+                # the devices with room for a launch, the one with the
+                # fewest in flight asked first (one that has run out
+                # before any other), then the one with the least to do:
+                # what has arrived since goes to the chip that needs it
+                room = [k for k, q in enumerate(queues)
+                        if len(q) < self.depth]
+                while room:
+                    lane = room[0] if len(room) == 1 else min(
+                        room, key=lambda k: (len(queues[k]),
+                                             load(k) if load else 0))
+                    if (self.shape, lane) in _TRACED_SHAPES:
+                        nxt = next_launch(lane)
                     else:
-                        nxt = self._on_device_thread(next_launch)
+                        nxt = self._on_device_thread(next_launch, lane)
                     if nxt is None:
-                        break
+                        room.remove(lane)
+                        continue
                     if self.shape is not None:
-                        _TRACED_SHAPES.add(self.shape)
-                    inflight.append(nxt)
+                        _TRACED_SHAPES.add((self.shape, lane))
                     self.slabs += 1
+                    queues[lane].append((self.slabs, *nxt))
+                    if len(queues[lane]) >= self.depth:
+                        room.remove(lane)
+                    inflight += 1
                     LAUNCHES.labels(kind=self.kind).inc()
-                    PIPELINE_DEPTH.set(len(inflight))
+                    # bounded by the host's device count
+                    DEVICE_LAUNCHES.labels(device="%d" % lane).inc()  # bmlint: allow(metric-labels)
+                    PIPELINE_DEPTH.set(inflight)
                     _flight("slab_launch", n=self.slabs,
-                            inflight=len(inflight))
+                            inflight=inflight)
                 if not inflight:
                     break
-                DISPATCH_AHEAD.observe(len(inflight))
-                tag, dev = inflight.popleft()
-                host = self._fetch(dev)
-                PIPELINE_DEPTH.set(len(inflight))
+                DISPATCH_AHEAD.observe(inflight)
+                tag, host = self._first_in(queues)
+                PIPELINE_DEPTH.set(inflight - 1)
                 _flight("slab_harvest",
                         wait_ms=round(self.last_wait * 1e3, 2),
-                        inflight=len(inflight))
+                        inflight=inflight - 1)
                 harvest(tag, host)
         finally:
             PIPELINE_DEPTH.set(0)
-            if self._guard_pool is not None:
-                self._guard_pool.shutdown(wait=False)
-                self._guard_pool = None
+            self._drop_guards()
             self.wall_seconds = max(time.monotonic() - t_start, 1e-9)
             DEVICE_BUSY.set(self.busy_ratio)
 
@@ -630,14 +719,19 @@ def _checked_nonce(nonce: int, initial_hash: bytes, target: int) -> int:
 
 class _LaunchGroup:
     """Host state for one launch-wide slab group (``width`` slots, of
-    which those not given an object are pad)."""
+    which those not given an object are pad).  A group lives on one
+    device: ``device`` is where its arrays are put and so where its
+    launches run (None: wherever JAX puts an array by default)."""
 
     __slots__ = ("idx", "words", "ih_words", "stale", "targets", "t_arr",
                  "bases", "trials", "done", "unread", "width",
-                 "unbatched")
+                 "unbatched", "device")
 
-    def __init__(self, items, idx, width, starts=None, unbatched=False):
+    def __init__(self, items, idx, width, starts=None, unbatched=False,
+                 device=None):
         import numpy as np
+
+        self.device = device
 
         pad = width - len(idx)
         ihs = [items[i][0] for i in idx] + [b"\x00" * 64] * pad
@@ -682,8 +776,9 @@ class _LaunchGroup:
         """The initial hashes as the kernels take them, sent to the
         device again only after a refill."""
         if self.stale:
-            self.ih_words = jnp.asarray(
-                self.words[0] if self.unbatched else self.words)
+            self.ih_words = jax.device_put(
+                self.words[0] if self.unbatched else self.words,
+                self.device)
             self.stale = False
         return self.ih_words
 
@@ -731,7 +826,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                           should_stop: Callable[[], bool] | None = None,
                           start_nonces=None, progress=None,
                           stall_timeout: float = 0.0,
-                          on_solved=None, feed=None, expect: int = 0):
+                          on_solved=None, feed=None, expect: int = 0,
+                          devices=None):
     """Solve ``[(initial_hash, target), ...]`` — one object or a queue
     — through the dispatch-ahead driver.  Returns ``[(nonce, trials),
     ...]`` aligned with ``items`` (then with what ``feed`` brought, in
@@ -773,6 +869,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     slot is done and ``feed`` has nothing, whoever may still be
     missing.
 
+    ``devices`` places the solve: the launch groups are dealt over
+    them in turn, a group's arrays and launches stay on its device (an
+    object's whole nonce range is searched on one chip), each device
+    has its own ``depth`` launches in flight and its own round-robin
+    over its groups, and a freed slot takes what ``feed`` brings on
+    the chip that freed it.  A queue is laid out as at least
+    :data:`MIN_BATCH_GROUPS` groups a device, so that no device needs
+    a launch dispatched ahead of an unread one to stay busy.  Without
+    ``devices`` there is one lane, on JAX's default device.
+
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
@@ -791,6 +897,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     if impl is None:
         impl = default_impl()
     expect = max(expect, n)
+    devices = list(devices) if devices else [None]
     if plan is None:
         with trace("pow.plan", objects=n, expect=expect) as span:
             plan = plan_batch(items, rows=rows, unroll=unroll,
@@ -837,23 +944,29 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     unbatched = pallas and mode == "slab"
 
     # what each group starts with: ``width`` objects of the plan's
-    # order; or, for a queue one launch holds or one laid out for
-    # announced company, the order dealt evenly over the groups that
-    # queue would fill, at least MIN_BATCH_GROUPS
-    if mode == "batched" and (expect > n or n <= width):
-        count = max(MIN_BATCH_GROUPS, -(-expect // width))
+    # order; or, for a queue that would fill fewer groups than
+    # MIN_BATCH_GROUPS a device or one laid out for announced company,
+    # the order dealt evenly over the groups that queue would fill, at
+    # least that many
+    count, least = -(-expect // width), MIN_BATCH_GROUPS * len(devices)
+    if mode == "batched" and (expect > n or count < least):
+        count = max(least, count)
         per = -(-n // count)
         shares = [plan.order[s:s + per]
                   for s in range(0, per * count, per)]
     else:
         shares = [plan.order[s:s + width] for s in range(0, n, width)]
-    with trace("pow.groups", objects=n, width=width):
+    with trace("pow.groups", objects=n, width=width,
+               devices=len(devices)):
         groups = [_LaunchGroup(items, share, width, starts=start_nonces,
-                               unbatched=unbatched) for share in shares]
+                               unbatched=unbatched,
+                               device=devices[j % len(devices)])
+                  for j, share in enumerate(shares)]
+    #: the groups of each device, and where its round-robin stands
+    lanes = [groups[k::len(devices)] for k in range(len(devices))]
+    rr = [0] * len(lanes)
     results: list = [None] * n
     executed = {"trials": 0, "launches": 0}
-
-    rr = {"i": 0}
 
     def take_in(g) -> int:
         """Give the done slots of ``g``, whose launches have all been
@@ -869,13 +982,14 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             REFILLS.labels(kind=kind).inc(len(arrived))
         return len(arrived)
 
-    def speculate():
+    def speculate(mine):
         # THE speculation rule, for every mode: with no fresh group
-        # left, dispatch the next launch of a group ahead of its unread
-        # ones only while those are unlikely to finish it (a lone ack
-        # is never speculated on, 64 objects in mid sweep always are);
-        # if they do finish it, run() counts this one abandoned
-        for g in groups:
+        # left on a device, dispatch the next launch of one of its
+        # groups ahead of its unread ones only while those are unlikely
+        # to finish it (a lone ack is never speculated on, 64 objects in
+        # mid sweep always are); if they do finish it, run() counts
+        # this one abandoned
+        for g in mine:
             if g.finished:
                 continue
             ahead = worth_speculating(slab_trials * g.unread,
@@ -887,26 +1001,27 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                 return g
         return None
 
-    def next_launch():
+    def next_launch(lane):
+        mine = lanes[lane]
         cand, refilled = None, 0
-        # round-robin over the groups without an in-flight slab: one
-        # with a done slot takes in what has arrived, an unfinished one
-        # is launched
-        for off in range(len(groups)):
-            g = groups[(rr["i"] + off) % len(groups)]
+        # round-robin over the device's groups without an in-flight
+        # slab: one with a done slot takes in what has arrived, an
+        # unfinished one is launched
+        for off in range(len(mine)):
+            g = mine[(rr[lane] + off) % len(mine)]
             if g.unread:
                 continue
             if feed is not None:
                 refilled = take_in(g)
             if not g.finished:
                 cand = g
-                rr["i"] = (rr["i"] + off + 1) % len(groups)
+                rr[lane] = (rr[lane] + off + 1) % len(mine)
                 break
         speculative = cand is None
         if speculative:
             # decided in a call that has returned before the kernel is
             # called: none of it lies under a kernel's trace
-            cand = speculate()
+            cand = speculate(mine)
         if cand is None:
             return None
         live = cand.live()
@@ -921,7 +1036,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         ih_words = cand.device_words()
         with trace("pow.launch", program=tele_prog, chunks=chunks,
                    live=live, speculative=speculative,
-                   refilled=refilled) as span:
+                   refilled=refilled, device=lane) as span:
             # the kernels are called from this frame, not through a
             # helper: on the chip the first call of a process (trace
             # and lowering of pallas_search) took 2.5 times as long
@@ -930,9 +1045,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                              dtype=np.uint32)
             if not pallas:
                 out = _packed_search_xla(
-                    ih_words, jnp.asarray(bases),
-                    jnp.asarray(cand.t_arr), lanes=step_trials,
-                    chunks=chunks)
+                    ih_words, jax.device_put(bases, cand.device),
+                    jax.device_put(cand.t_arr, cand.device),
+                    lanes=step_trials, chunks=chunks)
             elif mode == "slab":
                 # numpy arguments: the transfers ride the jit call
                 out = sha512_pallas.pallas_search(
@@ -940,14 +1055,15 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     chunks=chunks, unroll=unroll, interpret=interpret)
             elif mode == "batched":
                 out = sha512_pallas.pallas_batch_search(
-                    ih_words, jnp.asarray(bases),
-                    jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
-                    unroll=unroll, interpret=interpret)
+                    ih_words, jax.device_put(bases, cand.device),
+                    jax.device_put(cand.t_arr, cand.device), rows=rows,
+                    chunks=chunks, unroll=unroll, interpret=interpret)
             else:
                 out = pallas_packed_search(
-                    ih_words, jnp.asarray(bases),
-                    jnp.asarray(cand.t_arr), rows=rows, chunks=chunks,
-                    pack=pack, unroll=unroll, interpret=interpret)
+                    ih_words, jax.device_put(bases, cand.device),
+                    jax.device_put(cand.t_arr, cand.device), rows=rows,
+                    chunks=chunks, pack=pack, unroll=unroll,
+                    interpret=interpret)
         cand.unread += 1
         executed["launches"] += 1
         for k in range(cand.width):
@@ -956,16 +1072,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         # snapshot of each object's post-slab offset: the safe resume
         # point to checkpoint once THIS slab harvests miss-free (the
         # live ``bases`` may already include speculative launches)
-        tag = (cand, span.start, span.end, list(cand.bases), out)
+        tag = (cand, lane, span.start, span.end, list(cand.bases), out)
         # what the driver blocks on: pallas_search's hit flags
         return tag, (out[0] if unbatched else out)
 
     def harvest(tag, host):
-        with trace("pow.harvest") as span:
+        with trace("pow.harvest", device=tag[1]) as span:
             _harvest(tag, host, span.start)
 
     def _harvest(tag, host, t_h):
-        g, t0, t1, end_bases, out = tag
+        g, _lane, t0, t1, end_bases, out = tag
         rows_out = _slab_rows(out, host) if unbatched else host
         g.unread -= 1
         before, needed = executed["trials"], 0
@@ -1019,18 +1135,21 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         return feed is None or not any(
             take_in(g) for g in groups if not g.unread)
 
-    driver = _PipelineDriver(depth=depth, should_stop=should_stop,
+    driver = _PipelineDriver(depth=depth, lanes=len(lanes),
+                             should_stop=should_stop,
                              stall_timeout=stall_timeout, kind=kind,
                              shape=(tele_prog, tele_key))
     try:
-        driver.run(next_launch, harvest, done=done)
+        driver.run(next_launch, harvest, done=done,
+                   load=lambda lane: sum(g.live() for g in lanes[lane]))
     except PowInterrupted:
         if any(r is None for r in results):
             raise
     if stats is not None:
         stats.update(
             mode=mode, pack=pack, width=width, chunks=chunks,
-            groups=len(groups), launches=executed["launches"],
+            groups=len(groups), devices=len(lanes),
+            launches=executed["launches"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),
             wall_seconds=driver.wall_seconds,

@@ -2,11 +2,11 @@
 
 The reference worker solves strictly one object at a time
 (src/class_singleWorker.py:1274-1276).  Here every concurrently pending
-solve joins a single pod-wide launch: requests are queued, a short
-coalescing window lets the rest of a send sweep arrive, and the whole
-batch goes through :meth:`PowDispatcher.solve_batch` — objects
-data-parallel over the mesh's object axis, each nonce range partitioned
-over the remaining chips (SURVEY §6: grid = nonce-lanes x objects).
+solve joins one solve: requests are queued, a short coalescing window
+lets the rest of a send sweep arrive, and the whole batch goes through
+:meth:`PowDispatcher.solve_batch` — on an accelerator the pipeline's
+launch groups, dealt over its chips (SURVEY §6: grid = nonce-lanes x
+objects).
 
 A solve is a stream (docs/pow_pipeline.md): each object's future
 resolves when its own nonce has been found and re-checked, not when
@@ -29,8 +29,8 @@ depends on the solver, asked at every window:
 - a solver that streams now (it names ``on_solved``, ``feed`` and
   ``expect``, and its ``streams(items, expect)`` says that a solve of
   what the queue holds would take late arrivals in: ``PowDispatcher``
-  on one accelerator chip with its pipeline healthy, for objects at
-  network difficulty) holds nothing back.  The window closes at the
+  on an accelerator, of one chip or several, with its pipeline
+  healthy, for objects at network difficulty) holds nothing back.  The window closes at the
   first arrival (``first_arrival``), the solve is told how many
   objects to lay itself out for (``expect``: what the queue held plus
   the members outstanding, at most :data:`SOLVE_SLOTS`), and the rest
@@ -39,8 +39,8 @@ depends on the solver, asked at every window:
   255 of them are being signed and encrypted.  A solve that runs out
   of live slots with members still missing ends as any solve does;
   the next arrival opens the next window at once;
-- any other solver (the CPU ladder, the pod's rungs, the farm, a
-  wrapper that only passes ``**kwargs`` through) cannot take a late
+- any other solver (the CPU ladder, a CPU mesh's XLA rung, the
+  farm, a wrapper that only passes ``**kwargs`` through) cannot take a late
   member in, so the window closes when nobody outstanding is missing
   (``all_arrived``) or after ``window`` seconds (``timeout``),
   whichever is first: a sweep of 256 when its last member arrives.
@@ -120,9 +120,10 @@ REQUEUED = REGISTRY.counter(
 #: the ``solve_batch`` that served it
 _BATCH_SEQ = itertools.count(1)
 
-#: objects one solve holds at most: the four launch groups of 64 that
-#: the pipeline keeps two launches in flight over (``chan_storm_256``);
-#: what is beyond waits in the queue for a slot to come free
+#: objects one solve begins with, and is laid out for, at most: on one
+#: chip the four launch groups of 64 that the pipeline keeps two
+#: launches in flight over (``chan_storm_256``); what is beyond waits
+#: in the queue for a slot to come free
 SOLVE_SLOTS = 256
 
 #: default coalescing window in seconds, the longest a request waits

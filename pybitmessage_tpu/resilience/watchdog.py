@@ -47,7 +47,7 @@ class StallGuard:
     ``timeout <= 0`` disables the guard (the callable runs inline with
     zero overhead).  One worker thread per ``run()`` — fine for
     one-shot guards; the pipeline's per-harvest hot path instead keeps
-    a reusable worker (``_PipelineDriver._fetch``).  Recovery latency
+    a reusable worker (``_PipelineDriver._first_in``).  Recovery latency
     is tracked by the caller (the dispatcher observes
     :data:`STALL_RECOVERY_SECONDS` when a fallback tier completes the
     rescued work) — the guard only detects and counts the stall.

@@ -175,8 +175,10 @@ def test_cache_dir_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
 
 def test_pod_path_rehearsal_on_virtual_devices(monkeypatch, capsys):
     """--chips 4's phase on the virtual CPU devices: the dispatcher's
-    pod tiers with their XLA stand-in slabs at a tiny tile."""
+    partitioned search and the pipeline placed over the devices, with
+    their XLA stand-ins at a tiny tile."""
     from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
+    from pybitmessage_tpu.pow import pipeline
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
 
     monkeypatch.setattr(chip_smoke, "NETWORK_NTPB", 1)
@@ -186,14 +188,20 @@ def test_pod_path_rehearsal_on_virtual_devices(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "POD_SINGLE_OBJECTS", 4)
     monkeypatch.setattr(PowDispatcher, "_on_accelerator",
                         lambda self: True)
-    monkeypatch.setattr(pod, "POD_BATCH_PER_DEVICE", 2)
-    for fn in (pod.pallas_sharded_solve, pod.pallas_sharded_solve_batch):
-        for key, value in (("rows", 8), ("chunks_per_call", 4),
-                           ("unroll", 1)):
-            monkeypatch.setitem(fn.__kwdefaults__, key, value)
+    for key, value in (("rows", 8), ("chunks_per_call", 4),
+                       ("unroll", 1)):
+        monkeypatch.setitem(pod.pallas_sharded_solve.__kwdefaults__,
+                            key, value)
+    monkeypatch.setitem(pipeline.solve_batch_pipelined.__kwdefaults__,
+                        "rows", 8)
+    # a queue of easy objects planned as one at network difficulty is:
+    # whole tiles, no packing
+    monkeypatch.setattr(pipeline, "PACK_CHOICES", ())
+    monkeypatch.setattr(pipeline, "DEFAULT_BATCH_CHUNKS", 4)
     rep = chip_smoke.Report()
     chip_smoke.run_pod(rep)
     out = capsys.readouterr().out
     assert rep.failures == [], out
     assert "winners came from more than one device index" in out
-    assert "'tpu-pallas-sharded-batch'" in out
+    assert "'tpu-pallas-batch'" in out
+    assert "every device took launches of the batch" in out

@@ -1,11 +1,11 @@
 """Solver-ladder routing on multi-device meshes (pow/dispatcher.py).
 
-The real-pod tiers (Pallas-sharded single + batch) can't execute on the
-CPU mesh, so these tests pin the ROUTING contract with stubs: which
-tier is tried first, what the fallback order is, and that a Mosaic
-failure latches the Pallas tiers off instead of re-paying a failed
-compile on every solve (reference resetPoW semantics,
-proofofwork.py:173-194)."""
+The real-pod tiers (the Pallas-sharded single search, the pipeline
+placed over the chips) can't execute on the CPU mesh, so these tests
+pin the ROUTING contract with stubs: which tier is tried first, what
+the fallback order is, and that a Mosaic failure latches the Pallas
+tiers off instead of re-paying a failed compile on every solve
+(reference resetPoW semantics, proofofwork.py:173-194)."""
 
 import hashlib
 
@@ -65,28 +65,31 @@ def test_multidev_solve_falls_back_and_latches(monkeypatch,
     assert d.last_backend == "tpu-sharded"
 
 
-def test_multidev_batch_prefers_pallas_sharded_batch(monkeypatch,
-                                                     on_accelerator):
-    import pybitmessage_tpu.parallel as par
+def test_multidev_batch_prefers_the_pipeline_over_the_chips(
+        monkeypatch, on_accelerator):
+    import jax
 
-    def fake_batch(items, mesh, **kw):
+    from pybitmessage_tpu.pow import pipeline
+
+    def fake_batch(items, *, devices, **kw):
+        assert devices == jax.devices()
         return [(100 + i, 50) for i in range(len(items))]
 
-    monkeypatch.setattr(par, "pallas_sharded_solve_batch", fake_batch)
+    monkeypatch.setattr(pipeline, "solve_batch_pipelined", fake_batch)
     d = PowDispatcher(use_native=False)
     items = [(hashlib.sha512(b"o%d" % i).digest(), 2**60)
              for i in range(3)]
     results = d.solve_batch(items)
-    assert d.last_backend == "tpu-pallas-sharded-batch"
+    assert d.last_backend == "tpu-pallas-batch"
     assert results == [(100, 50), (101, 50), (102, 50)]
 
 
 def test_multidev_batch_falls_back_to_xla_sharded(monkeypatch,
                                                   on_accelerator):
-    import pybitmessage_tpu.parallel as par
+    from pybitmessage_tpu.pow import pipeline
 
     monkeypatch.setattr(
-        par, "pallas_sharded_solve_batch",
+        pipeline, "solve_batch_pipelined",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom")))
     d = PowDispatcher(use_native=False, tpu_kwargs={
         "lanes": 1 << 12, "chunks_per_call": 8})

@@ -296,3 +296,171 @@ async def test_download_throttle_paces_before_buffering():
     finally:
         await pool_b.stop()
         await pool_a.stop()
+
+
+# --- a peer link carries what a fast sender publishes (ISSUE 37) -------------
+
+
+def test_the_download_window_gates_a_hand_out_and_does_not_size_it():
+    """Reference semantics (randomtrackingdict.py): below ``max_pending``
+    keys out, a poll hands out its whole count; at or above, none, until
+    arrivals take keys out of the window again.  Sized by the window a
+    poll a second fetched ten objects a second from a peer."""
+    from pybitmessage_tpu.utils.randomtracking import RandomTrackingDict
+    d = RandomTrackingDict()
+    for k in range(300):
+        d[k] = True
+    first = d.random_keys(100)
+    assert len(first) == len(set(first)) == 100
+    assert d.random_keys(100) == []         # 100 out: the window is shut
+    for k in first[:90]:
+        del d[k]
+    assert d.random_keys(100) == []         # ten still out
+    del d[first[90]]
+    second = d.random_keys(1000)            # nine out: all the rest
+    assert len(second) == 200 - 9 + 9 and not set(second) & set(first)
+    assert sorted(second + first[91:]) == sorted(d)
+    # a key handed out and never delivered is eligible again in time
+    e = RandomTrackingDict()
+    e.pending_timeout = 0.0
+    e["k"] = True
+    assert e.random_keys(5) == ["k"] and e.random_keys(5) == ["k"]
+
+
+class _UploadConn:
+    """A connection as ``_upload_loop`` sees it: a getdata backlog, and
+    ``flush_uploads`` serving ten of it (none while ``armed``: the
+    anti-intersection delay; never returning while ``wedged``: a peer
+    that does not read, ``writer.drain()`` has no deadline)."""
+    host = "peer"
+
+    def __init__(self, backlog, armed=False, wedged=False):
+        self.pending_upload = list(range(backlog))
+        self.armed, self.wedged, self.rounds = armed, wedged, 0
+
+    async def flush_uploads(self, limit=10):
+        self.rounds += 1
+        if self.wedged:
+            await asyncio.Event().wait()
+        served = 0 if self.armed else min(limit, len(self.pending_upload))
+        del self.pending_upload[:served]
+        return served
+
+
+async def _run_upload_loop(monkeypatch, conns, until, interval=0.05):
+    import pybitmessage_tpu.network.pool as pool_mod
+    monkeypatch.setattr(pool_mod, "UPLOAD_INTERVAL", interval)
+
+    class Pool:
+        established = staticmethod(lambda: conns)
+        _upload_round = ConnectionPool._upload_round
+
+    task = asyncio.create_task(ConnectionPool._upload_loop(Pool()))
+    try:
+        t0 = time.monotonic()
+        assert await _wait_for(until, timeout=5.0)
+        return time.monotonic() - t0
+    finally:
+        task.cancel()
+        await asyncio.gather(task, return_exceptions=True)
+
+
+@pytest.mark.asyncio
+async def test_upload_backlogs_are_served_round_after_round(monkeypatch):
+    """The upload loop keeps serving, ten a connection a round, while
+    any connection was served, and rests only after a round that served
+    nothing (reference uploadthread.py); a connection inside its
+    anti-intersection delay serves nothing and does not hold it up."""
+    conns = [_UploadConn(35), _UploadConn(4), _UploadConn(50, armed=True)]
+    took = await _run_upload_loop(
+        monkeypatch, conns, interval=0.5,
+        until=lambda: not conns[0].pending_upload)
+    assert took < 0.5                       # four rounds and not one rest
+    assert [len(c.pending_upload) for c in conns] == [0, 0, 50]
+    assert conns[0].rounds == 4             # 10, 10, 10, 5
+    await asyncio.sleep(0.1)
+    assert conns[2].rounds <= 6             # ... and then it rests
+
+
+@pytest.mark.asyncio
+async def test_a_peer_that_does_not_read_holds_up_nobody_else(monkeypatch):
+    """A peer with a large backlog whose socket does not drain is given
+    its ten once and no more until they are through; the others are
+    still served a round an ``UPLOAD_INTERVAL``, the pace a link always
+    had (REVIEW of PR 37: the loop must not wait for every backlog)."""
+    wedged, other = _UploadConn(50000, wedged=True), _UploadConn(35)
+    await _run_upload_loop(monkeypatch, [wedged, other],
+                           until=lambda: not other.pending_upload)
+    assert wedged.rounds == 1 and other.rounds == 4
+
+
+@pytest.mark.asyncio
+async def test_getdata_goes_out_while_an_upload_is_stuck(trivial_pow,
+                                                         monkeypatch):
+    """The download loop asks for objects on its own cadence whatever
+    the upload loop is waiting for: with every ``flush_uploads`` of the
+    serving node stuck, a hundred objects announced TO it are still
+    requested and arrive."""
+    from pybitmessage_tpu.network.connection import BMConnection
+    ctx_a, pool_a = _make_node()
+    ctx_b, pool_b = _make_node()
+    trivial_pow.apply(ctx_a)
+    trivial_pow.apply(ctx_b)
+    hashes = []
+    for i in range(100):
+        payload = trivial_pow.solved_object(b"object %d of a hundred" % i)
+        hashes.append(inventory_hash(payload))
+        ctx_a.inventory.add(hashes[-1], 2, 1, payload,
+                            int.from_bytes(payload[8:16], "big"))
+    await pool_a.start()
+    await pool_b.start(listen=False)
+    flush = BMConnection.flush_uploads
+
+    async def stuck_on_b(self, limit=10):
+        if self.ctx is ctx_b:               # B's uploads never return
+            await asyncio.Event().wait()
+        return await flush(self, limit)
+
+    monkeypatch.setattr(BMConnection, "flush_uploads", stuck_on_b)
+    try:
+        conn = await pool_b.connect_to(Peer("127.0.0.1", pool_a.listen_port))
+        assert conn is not None
+        assert await _wait_for(lambda: conn.fully_established)
+        conn.pending_upload.append(hashes[0])   # B owes A one, for ever
+        assert await _wait_for(
+            lambda: all(h in ctx_b.inventory for h in hashes), timeout=7.0)
+    finally:
+        await pool_b.stop()
+        await pool_a.stop()
+
+
+@pytest.mark.asyncio
+async def test_a_hundred_objects_cross_a_link_in_seconds(trivial_pow):
+    """Before PR 37 a link carried ten objects a second in each
+    direction (a download window of ten polled once a second, an upload
+    round of ten a second): a hundred objects took ten seconds."""
+    ctx_a, pool_a = _make_node()
+    ctx_b, pool_b = _make_node()
+    trivial_pow.apply(ctx_a)
+    trivial_pow.apply(ctx_b)
+    hashes = []
+    for i in range(100):
+        payload = trivial_pow.solved_object(b"object %d of a hundred" % i)
+        hashes.append(inventory_hash(payload))
+        ctx_a.inventory.add(hashes[-1], 2, 1, payload,
+                            int.from_bytes(payload[8:16], "big"))
+    await pool_a.start()
+    await pool_b.start(listen=False)
+    try:
+        conn = await pool_b.connect_to(Peer("127.0.0.1", pool_a.listen_port))
+        assert conn is not None
+        assert await _wait_for(lambda: conn.fully_established)
+        t0 = time.monotonic()
+        assert await _wait_for(
+            lambda: all(h in ctx_b.inventory for h in hashes), timeout=7.0), \
+            "%d of 100 objects after 7 s" % sum(
+                h in ctx_b.inventory for h in hashes)
+        assert time.monotonic() - t0 < 7.0
+    finally:
+        await pool_b.stop()
+        await pool_a.stop()

@@ -67,6 +67,12 @@ async def test_pow_service_coalesces_concurrent_solves():
             *(svc.solve(ih, t) for ih, t in items))
         for (ih, target), (nonce, _) in zip(items, results):
             assert _host_trial(nonce, ih) <= target
+        # the futures resolve from the solve's thread, the batch is
+        # counted at the solve's own end, a turn of the loop or two later
+        for _ in range(200):
+            if svc.batches:
+                break
+            await asyncio.sleep(0.01)
         assert svc.batches == 1, "concurrent solves should form one batch"
         assert svc.solved == 3
         assert d.last_backend == "tpu-batch"

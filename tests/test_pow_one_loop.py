@@ -398,7 +398,7 @@ def test_the_first_launch_of_a_shape_runs_on_the_drivers_worker_thread(
     launches = TRACER.recent(50, name="pow.launch")
     assert [s.parent_id for s in launches] == [caller.span_id] * 3
     assert pipeline._TRACED_SHAPES \
-        == {("pallas_slab", (128, 512, 5, False))}
+        == {(("pallas_slab", (128, 512, 5, False)), 0)}
     # the next solve of the shape launches in place from the start,
     del threads[:]
     install([1, None])

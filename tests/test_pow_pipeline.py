@@ -9,6 +9,7 @@ device path — the same CI pattern as the sharded Pallas tier.
 
 import asyncio
 import hashlib
+import time
 
 import pytest
 
@@ -217,7 +218,7 @@ def test_a_run_that_ends_with_a_slab_in_flight_counts_it_abandoned():
     slabs = iter(range(10))
     driver = _PipelineDriver(depth=2, fetch=lambda dev: dev,
                              kind="t_abandon")
-    driver.run(lambda: (next(slabs),) * 2,
+    driver.run(lambda lane: (next(slabs),) * 2,
                lambda tag, host: harvested.append(host),
                done=lambda: bool(harvested))
     # two dispatched ahead, the first harvested and enough: the second
@@ -232,12 +233,153 @@ def test_a_run_that_ends_with_a_slab_in_flight_counts_it_abandoned():
     budget = iter(range(3))
     driver = _PipelineDriver(depth=2, fetch=lambda dev: dev,
                              kind="t_abandon")
-    driver.run(lambda: next(((b, b) for b in budget), None),
+    driver.run(lambda lane: next(((b, b) for b in budget), None),
                lambda tag, host: harvested.append(host))
     assert harvested == [0, 0, 1, 2]
     assert counted("pow_pipeline_launches_total") == launches0 + 5
     assert counted("pow_pipeline_abandoned_launches_total") \
         == abandoned0 + 1
+
+
+@pytest.mark.parametrize("stall_timeout", [0.0, 30.0])
+def test_a_slow_device_does_not_hold_the_others_launches_back(
+        stall_timeout):
+    """Two devices: the launch on device 0 comes in only when device 1
+    has had six launches read.  Each device's oldest launch is fetched
+    on a thread of its own, so device 1 is harvested and launched again
+    meanwhile, and never waits for device 0's launch to be read."""
+    import threading
+
+    from pybitmessage_tpu.pow.pipeline import _PipelineDriver
+
+    release = threading.Event()
+    launched, harvested = {0: 0, 1: 0}, []
+
+    def fetch(dev):
+        if dev[0] == 0:
+            assert release.wait(20)
+        return dev
+
+    def next_launch(lane):
+        if launched[lane] >= (1, 6)[lane]:
+            return None
+        launched[lane] += 1
+        return "tag", (lane, launched[lane])
+
+    def harvest(_tag, host):
+        harvested.append(host)
+        if host == (1, 6):
+            release.set()
+
+    driver = _PipelineDriver(depth=2, lanes=2, fetch=fetch,
+                             stall_timeout=stall_timeout, kind="t_lanes")
+    driver.run(next_launch, harvest)
+    assert harvested == [(1, k) for k in range(1, 7)] + [(0, 1)]
+    assert driver.slabs == 7
+
+
+def test_a_wedged_device_is_a_stall_though_the_others_come_in():
+    """The watchdog's deadline is each fetch's own: a launch that is
+    not in ``stall_timeout`` seconds after its fetch began raises,
+    whatever the other devices have delivered meanwhile."""
+    import threading
+
+    from pybitmessage_tpu.pow.pipeline import _PipelineDriver
+    from pybitmessage_tpu.resilience.watchdog import SlabStallError
+
+    never = threading.Event()
+    harvested = []
+
+    def fetch(dev):
+        if dev[0] == 0:
+            never.wait(5)
+        else:
+            time.sleep(0.02)
+        return dev
+
+    count = [0]
+
+    def next_launch(lane):
+        count[0] += 1
+        return "tag", (lane, count[0])
+
+    driver = _PipelineDriver(depth=1, lanes=2, fetch=fetch,
+                             stall_timeout=0.3, kind="t_lanes")
+    t0 = time.monotonic()
+    with pytest.raises(SlabStallError):
+        driver.run(next_launch, lambda _tag, host: harvested.append(host))
+    never.set()
+    assert time.monotonic() - t0 < 3
+    assert harvested and all(host[0] == 1 for host in harvested)
+
+
+def test_the_device_with_least_in_flight_and_least_to_do_is_asked_first():
+    """Four devices, ``depth`` 2.  A turn asks breadth first: every
+    device gets its first launch before any gets its second, so one
+    that has run out is asked before the others are topped up; among
+    devices with as many launches in flight the one whose ``load`` is
+    least is asked first, so what has arrived since goes to the chip
+    that needs it (REVIEW of PR 37; in index order an arrival went to
+    the chip read last and a chip that had run out was asked last)."""
+    from pybitmessage_tpu.pow.pipeline import _PipelineDriver
+
+    load = {0: 40, 1: 10, 2: 30, 3: 20}
+    asked, budget = [], [10]
+
+    def next_launch(lane):
+        asked.append(lane)
+        if not budget[0]:
+            return None
+        budget[0] -= 1
+        return "tag", (lane, len(asked))
+
+    driver = _PipelineDriver(depth=2, lanes=4, fetch=lambda dev: dev,
+                             kind="t_lanes")
+    harvested = []
+    driver.run(next_launch, lambda _tag, host: harvested.append(host),
+               load=load.get)
+    # the first turn: all four once by load, then all four again
+    assert asked[:8] == [1, 3, 2, 0, 1, 3, 2, 0]
+    # the oldest launch, device 1's, is read: it alone has room
+    assert harvested[0] == (1, 1) and asked[8] == 1
+    assert driver.slabs == 10
+
+
+@pytest.mark.parametrize("stall_timeout", [0.0, 30.0])
+def test_one_device_is_fetched_as_it_always_was(stall_timeout):
+    """One device: in place with the watchdog off, else one guard
+    worker and ``Future.result(stall_timeout)`` — no table of fetches
+    in progress, no ``concurrent.futures.wait`` (the parent's path,
+    REVIEW of PR 37)."""
+    import threading
+
+    from pybitmessage_tpu.pow.pipeline import _PipelineDriver
+
+    threads, count = set(), [0]
+
+    def fetch(dev):
+        threads.add(threading.current_thread().name)
+        return dev
+
+    def next_launch(lane):
+        assert lane == 0
+        count[0] += 1
+        return ("tag", count[0]) if count[0] <= 5 else None
+
+    driver = _PipelineDriver(depth=2, fetch=fetch,
+                             stall_timeout=stall_timeout, kind="t_lanes")
+    class NoFetchTable(dict):
+        def __setitem__(self, key, value):
+            raise AssertionError("a fetch in progress was tabled")
+
+    driver._fetching = NoFetchTable()
+    harvested = []
+    driver.run(next_launch, lambda _tag, host: harvested.append(host))
+    assert harvested == [1, 2, 3, 4, 5]
+    if stall_timeout:
+        assert len(threads) == 1 and "slab-guard" in threads.pop()
+    else:
+        assert threads == {threading.current_thread().name}
 
 
 def test_pipelined_solve_counts_launches_and_executed_trials():

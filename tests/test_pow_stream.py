@@ -18,6 +18,13 @@ lone first member as the queue it belongs to, lays out the groups that
 queue would fill, starts the missing members' slots as pad slots and
 deals arrivals evenly; the dispatcher says whether it streams, and
 passes ``expect`` to the one rung that does.
+
+A solve placed over several chips (ISSUE 37): the launch groups are
+dealt over the devices the solve is given, and nothing else changes —
+the same nonces and trials as on one device, every device launching,
+a freed slot refilled on the chip that freed it, no wait on a chip
+that has run out; the dispatcher offers a queue the pipeline on any
+accelerator, and a lone object the nonce-range partition.
 """
 
 import asyncio
@@ -416,7 +423,8 @@ def test_without_company_the_layout_is_what_it_was(there, expect,
 @pytest.mark.parametrize("topology, tpu, breaker, farm, streams", [
     ((1, True), True, "closed", None, True),
     ((1, False), True, "closed", None, False),      # the CPU ladder
-    ((4, True), True, "closed", None, False),       # a pod
+    ((4, True), True, "closed", None, True),        # four chips
+    ((4, False), True, "closed", None, False),      # a CPU mesh
     ((0, False), True, "closed", None, False),      # the probe failed
     ((1, True), False, "closed", None, False),
     ((1, True), True, "open", None, False),
@@ -446,7 +454,7 @@ def test_the_dispatcher_says_whether_a_queue_would_stream(
 @pytest.mark.parametrize("topology, expect, rung", [
     ((1, True), 200, "pipeline"), ((1, True), 0, "ladder"),
     ((1, True), 1, "ladder"), ((1, False), 200, "ladder"),
-    ((4, True), 200, "ladder")])
+    ((4, True), 200, "pipeline"), ((4, False), 200, "ladder")])
 def test_a_lone_item_with_company_is_offered_the_streaming_rung(
         topology, expect, rung, monkeypatch):
     monkeypatch.setattr(PowDispatcher, "_batch_topology",
@@ -898,3 +906,232 @@ async def test_a_refill_costs_the_journal_one_write():
 def test_the_slots_of_a_solve_are_the_senders_in_flight():
     assert SOLVE_SLOTS == sender.MAX_IN_FLIGHT \
         == 4 * sha512_pallas.BATCH_OBJS
+
+
+# -- a solve placed over several chips (ISSUE 37) -----------------------
+
+
+def _device_launches() -> dict:
+    fam = REGISTRY.get("pow_pipeline_device_launches_total")
+    return {values[0]: child.value for values, child in fam.children()}
+
+
+@pytest.mark.parametrize("ndev", [1, 2, 4])
+@pytest.mark.parametrize("n, m", [(40, 0), (9, 30), (130, 12)])
+def test_a_placed_solve_returns_the_one_device_solves_answers(ndev, n, m):
+    """Object for object the ``(nonce, trials)`` of the solve that is
+    given no devices, each nonce accepted by the plain reference."""
+    import jax
+    items, fed = _items("placed %d" % n, n), _items("placed fed %d" % n, m)
+    want, _calls, _asked = _stream(items, fed)
+    before = _device_launches()
+    stats = {}
+    got, calls, _asked = _stream(items, fed, stats=stats,
+                                 devices=jax.devices()[:ndev])
+    assert stats["devices"] == ndev
+    assert stats["groups"] >= pipeline.MIN_BATCH_GROUPS * ndev
+    assert got == want and len(got) == n + m
+    assert sorted(i for i, _r in calls) == list(range(n + m))
+    assert all(got[i] == r for i, r in calls)
+    for (ih, target), (nonce, trials) in zip(items + fed, got):
+        assert reference.trial_value(nonce.to_bytes(8, "big"), ih) \
+            <= target
+        assert trials > 0
+    grown = {k: v - before.get(k, 0) for k, v in
+             _device_launches().items() if v - before.get(k, 0)}
+    assert sorted(grown) == [str(k) for k in range(ndev)]
+    assert sum(grown.values()) == stats["launches"]
+
+
+class Placed:
+    """Stands where ``pallas_batch_search`` is, for a solve on several
+    devices, where the order of the launches is not scripted: item
+    ``i`` misses until its ``after[i]``-th launch and hits in that one.
+    Keeps, for every launch, the device its arrays were on and the
+    live items it held."""
+
+    def __init__(self, items, after, monkeypatch):
+        self.index = {
+            np.array(pipeline._hash_words(ih), np.uint32).tobytes(): i
+            for i, (ih, _t) in enumerate(items)}
+        self.left = dict(enumerate(after))
+        self.launches = []          # (device, [live items])
+        self._lock = threading.Lock()
+        monkeypatch.setattr(sha512_pallas, "pallas_batch_search", self)
+        monkeypatch.setattr(pipeline, "_checked_nonce",
+                            lambda nonce, initial_hash, target: nonce)
+
+    def __call__(self, ih_words, bases, targets, rows, chunks, unroll,
+                 interpret):
+        (device,) = ih_words.devices()
+        assert bases.devices() == targets.devices() == {device}
+        words, targets = np.asarray(ih_words), np.asarray(targets)
+        out = np.zeros((len(words), 3), np.uint32)
+        live = []
+        with self._lock:
+            for k in range(len(words)):
+                if tuple(targets[k]) == (2 ** 32 - 1,) * 2:
+                    out[k] = (1, 0, 0)
+                    continue
+                i = self.index[words[k].tobytes()]
+                live.append(i)
+                self.left[i] -= 1
+                if self.left[i] <= 0:
+                    out[k] = (1, 0, i)
+            self.launches.append((device, live))
+        return out
+
+    def devices_of(self, i) -> set:
+        return {dev for dev, live in self.launches if i in live}
+
+
+def test_on_four_devices_a_freed_slot_is_refilled_on_its_own_chip(
+        monkeypatch):
+    """Eight objects, a group each, two groups a chip.  Object 2 hits
+    in its first launch, the others in their third; the queue holds
+    one more object, handed only to a group whose own object has
+    solved (it asks for 64 slots, the others for 63): the newcomer
+    searches on the chip object 2 searched on."""
+    import jax
+    devices = jax.devices()[:4]
+    items = _items("chip", 8, expected=10 ** 7)
+    (late,) = _items("chip late", 1, expected=10 ** 7)
+    placed = Placed(items + [late], [3, 3, 1, 3, 3, 3, 3, 3, 2],
+                    monkeypatch)
+    calls, handed = [], []
+
+    def feed(room):
+        if room < 64 or handed:
+            return []
+        # on_solved has fired for the object whose slot this is,
+        # before the solve has returned
+        handed.append(list(calls))
+        return [(late[0], late[1], 0)]
+
+    before = _device_launches()
+    stats = {}
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="pallas", plan=_batched(8), feed=feed,
+        on_solved=lambda i, r: calls.append(i), devices=devices,
+        stats=stats, stall_timeout=30.0)
+    assert len(results) == 9 and all(r is not None for r in results)
+    assert (stats["groups"], stats["devices"]) == (8, 4)
+    assert handed == [[2]]
+    assert sorted(calls) == list(range(9)) and calls[0] == 2
+    # the groups are dealt in turn: object j on device j % 4
+    for j in range(8):
+        assert placed.devices_of(j) == {devices[j % 4]}
+    assert placed.devices_of(8) == placed.devices_of(2)
+    assert {dev for dev, _live in placed.launches} == set(devices)
+    grown = {k: v - before.get(k, 0)
+             for k, v in _device_launches().items()}
+    assert all(grown[str(k)] >= 2 for k in range(4))
+    assert sum(grown.values()) == stats["launches"] \
+        == len(placed.launches)
+
+
+def test_the_driver_is_told_how_many_live_slots_each_chip_has(
+        monkeypatch):
+    """``load`` is what the driver orders its asking by among chips
+    with as many launches in flight (tests/test_pow_pipeline.py holds
+    the order): six objects dealt over eight groups on four devices
+    are two live slots on chips 0 and 1 and one on 2 and 3,
+    and none anywhere once all have solved."""
+    import jax
+    items = _items("load", 6, expected=10 ** 7)
+    Placed(items, [2] * 6, monkeypatch)
+    seen = {}
+    run = pipeline._PipelineDriver.run
+
+    def spy(self, next_launch, harvest, done=None, load=None):
+        seen["before"] = [load(k) for k in range(self.lanes)]
+        run(self, next_launch, harvest, done=done, load=load)
+        seen["after"] = [load(k) for k in range(self.lanes)]
+
+    monkeypatch.setattr(pipeline._PipelineDriver, "run", spy)
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="pallas", plan=_batched(6),
+        feed=lambda room: [], devices=jax.devices()[:4])
+    assert all(r is not None for r in results)
+    assert seen == {"before": [2, 2, 1, 1], "after": [0, 0, 0, 0]}
+
+
+def test_a_device_that_has_run_out_is_not_waited_for(monkeypatch):
+    """The two objects of device 0 hit at once and nothing is queued:
+    its two groups are launched (and one once more, ahead of its unread
+    launch, when the other had finished) and never again, while the
+    others' objects take five launches each, resolve, and the solve
+    ends."""
+    import jax
+    devices = jax.devices()[:4]
+    items = _items("early", 8, expected=10 ** 7)
+    placed = Placed(items, [1 if j % 4 == 0 else 5 for j in range(8)],
+                    monkeypatch)
+    asked, calls = [], []
+    TRACER.clear()
+    results = pipeline.solve_batch_pipelined(
+        items, rows=ROWS, impl="pallas", plan=_batched(8),
+        feed=lambda room: asked.append(room) or [],
+        on_solved=lambda i, r: calls.append(i), devices=devices)
+    assert all(r is not None for r in results)
+    assert set(calls[:2]) == {0, 4}
+    per_device = [sum(1 for dev, _l in placed.launches if dev == d)
+                  for d in devices]
+    assert per_device[0] <= 3
+    assert all(10 <= k <= 11 for k in per_device[1:])
+    # the spans say where each launch and harvest was
+    n = len(placed.launches)
+    launched = TRACER.recent(n + 1, name="pow.launch")
+    assert [devices[s.attrs["device"]] for s in launched] \
+        == [dev for dev, _live in placed.launches]
+    # the launches left unread at the end have no harvest
+    harvests = TRACER.recent(n + 1, name="pow.harvest")
+    assert n - 3 <= len(harvests) <= n
+    assert sorted({s.attrs["device"] for s in harvests}) == [0, 1, 2, 3]
+    (groups,) = TRACER.recent(3, name="pow.groups")
+    assert groups.attrs["devices"] == 4
+
+
+@pytest.mark.parametrize("ndev, accel, queue, lone", [
+    (4, True, ["tpu-pallas-batch", "tpu-batch"], "tpu-pallas-sharded"),
+    (4, False, ["tpu-batch"], "tpu-sharded"),
+    (1, True, ["tpu-pallas-batch"], "tpu-pallas"),
+    (1, False, [], "tpu")])
+def test_the_rungs_a_topology_admits(ndev, accel, queue, lone,
+                                     monkeypatch):
+    """A queue is offered the pipeline on an accelerator however many
+    chips it has, with the devices where there are several; a lone
+    object on several chips still takes the nonce-range partition."""
+    import jax
+
+    import pybitmessage_tpu.parallel as par
+    monkeypatch.setattr(PowDispatcher, "_device_count",
+                        lambda self: ndev)
+    monkeypatch.setattr(PowDispatcher, "_on_accelerator",
+                        lambda self: accel)
+    d = PowDispatcher(use_native=False)
+    items = [(b"\x01" * 64, NETWORK)] * 3
+    rungs = list(d._batch_rungs(items, [0] * 3, None, None, None, None, 3))
+    assert [rung.backend for rung, _call in rungs] == queue
+    assert d.streams(items, 3) is accel
+    seen = {}
+
+    def recorded(items, *, devices=None, **_kw):
+        seen["devices"] = devices
+        return [(7, 1)] * len(items)
+
+    monkeypatch.setattr(pipeline, "solve_batch_pipelined", recorded)
+    if accel:
+        assert rungs[0][1]() == [(7, 1)] * 3
+        assert seen["devices"] == (jax.devices()[:4] if ndev > 1
+                                   else None)
+    # a lone object: the partition on several chips, the pipeline on one
+    for name in ("pallas_sharded_solve", "sharded_solve"):
+        monkeypatch.setattr(
+            par, name, lambda ih, target, mesh, **kw: (11, 1))
+    from pybitmessage_tpu.ops import pow_search
+    monkeypatch.setattr(pow_search, "solve",
+                        lambda ih, target, **kw: (11, 1))
+    (result,) = d.solve_batch([items[0]])
+    assert d.last_backend == lone
+    assert result == ((7, 1) if lone == "tpu-pallas" else (11, 1))
