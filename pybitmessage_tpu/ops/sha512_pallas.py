@@ -44,28 +44,35 @@ LANE_COLS = 128
 #: sublanes of one 32-bit vreg: the slice of a tile hashed at a time
 SLICE_ROWS = 8
 
-#: Tiles a grid step and steps a launch.  Since PR 26 a tile is hashed
-#: one (SLICE_ROWS, 128) slice at a time, so rows and unroll only say
-#: how many slices a grid step loops over (its fixed cost is about
+#: Tiles a step and steps a launch.  Since PR 26 a tile is hashed one
+#: (SLICE_ROWS, 128) slice at a time, so rows and unroll only say how
+#: many slices a step loops over (a grid step's fixed cost is about
 #: 0.35 us) and how often a search can leave at a hit; they no longer
 #: shape the instruction streams, and the r3/r4 unroll ladders that
 #: stood here (77.8 MH/s at unroll 1 to 151.0 at 6) measured a kernel
 #: that is gone.  rows=512 used to exceed the 16 MB scoped VMEM limit
-#: and chunks>=1024 does not compile (SMEM).  Measured on a v5e, batch
-#: kernel 64 x 64 x 4 x 128 rows, traced `chan_storm_256` runs (my chip
-#: runs, PR 26), with the compiler's final bundles of one grid step:
+#: and chunks>=1024 does not compile for ``pallas_search`` (SMEM).
+#: Measured on a v5e, the batch kernel at 64 objects x 128 chunks x 4
+#: tiles of 128 rows, traced `chan_storm_256` runs (my chip runs, PR
+#: 26), with the compiler's final bundles of one grid step:
 #:   textbook body            23,220 vector ops a vreg of trials (the
 #:                            jaxpr shows 21,979: an unsigned compare is
 #:                            two xors more), 459,233 bundles, 351,067
 #:                            of them with a spill store   199.76 MH/s
 #:   this body, whole tiles   20,593 ops (jaxpr 20,600), 432,313
 #:                            bundles, 319,946 spill stores 211.63 MH/s
-#:   this body, slice loop    64 x 5,396 bundles, 201 spill stores a
-#:                            slice                         289.3 MH/s
+#:   this body, slice loop    5,396 bundles a slice, 201 spill stores,
+#:                            64 slices a grid step         289.3 MH/s
 #: 289.3 MH/s x 20,600 = 5.96e12 ops/s, 97 % of the 6.1e12 ESTIMATE of
 #: the VPU's peak (8x128 lanes x 4 ALUs x 1.5 GHz): what is left is the
 #: number of operations a trial.  An earlier carry-save _add_many (same
 #: count, shorter chains) had measured no gain: issue-limited then too.
+#: Since PR 40 the batch kernel's step is ONE tile of 64 rows (8
+#: slices) and its steps run in a loop inside the kernel
+#: (``BATCH_ROWS``, ``BATCH_INNER`` below): the same slice body (the
+#: whole kernel 5,377 -> 5,387 final bundles, 205 spill stores both),
+#: an eighth of the trials thrown away at a hit or by a slot with
+#: nothing to search.
 DEFAULT_ROWS = 128
 DEFAULT_CHUNKS = 512
 DEFAULT_UNROLL = 5
@@ -353,13 +360,21 @@ def _kernel(ih_ref, base_ref, target_ref, found_ref, nonce_ref, flag_ref, *,
 
 
 def _batch_kernel(ih_ref, base_ref, target_ref, out_ref, flag_ref,
-                  *, rows: int, unroll: int = 1):
-    """2D grid (objects, chunks): each object owns a per-object early-
-    exit flag, so easy objects stop costing compute while hard ones
-    keep searching — the single-chip form of the (objects x
+                  *, rows: int, unroll: int = 1, inner: int = 1):
+    """2D grid (objects, chunks // inner): each object owns a per-object
+    early-exit flag, so easy objects stop costing compute while hard
+    ones keep searching — the single-chip form of the (objects x
     nonce-lanes) batch design (SURVEY §6).  The search body is shared
     with the single-object kernel (_search_step), ``unroll`` tiles to a
-    grid step.
+    step.
+
+    A grid step runs ``inner`` of the object's steps in a loop that
+    leaves at the first hit, so an object that is done (or a pad slot,
+    done after its first step) skips ``chunks // inner`` grid steps and
+    not ``chunks``: a skipped grid step costs 0.06 us, and with one step
+    a grid step a dead slot's 1,023 of them would cost more than the one
+    tile it hashes (PERF.md section 6, PR 40: at 512 steps a launch of
+    64 dead slots took 6.38 ms without the loop, 4.29 with it).
 
     Output is written ONCE per object, on its hit step: a (B, 3) u32
     row ``[hit_step + 1, nonce_hi, nonce_lo]`` (0 = not found).  r3's
@@ -368,9 +383,9 @@ def _batch_kernel(ih_ref, base_ref, target_ref, out_ref, flag_ref,
     row is chunk-count-independent — 64 objects compile comfortably —
     and the harvest is ONE small device->host fetch."""
     obj = pl.program_id(0)
-    step = pl.program_id(1)
+    outer = pl.program_id(1)
 
-    @pl.when(step == 0)
+    @pl.when(outer == 0)
     def _init():
         flag_ref[obj] = jnp.int32(0)
         out_ref[obj, 0] = jnp.uint32(0)
@@ -379,17 +394,29 @@ def _batch_kernel(ih_ref, base_ref, target_ref, out_ref, flag_ref,
 
     @pl.when(flag_ref[obj] == 0)
     def do_search():
-        hit, n_hi, n_lo = _search_step(
-            lambda i: (ih_ref[obj, i, 0], ih_ref[obj, i, 1]),
-            base_ref[obj, 0], base_ref[obj, 1],
-            target_ref[obj, 0], target_ref[obj, 1], step, rows * unroll)
-        flag_ref[obj] = hit
+        def searching(carry):
+            i, hit = carry
+            return (i < inner) & (hit == 0)
 
-        @pl.when(hit == 1)
-        def _record():
-            out_ref[obj, 0] = jnp.uint32(step + 1)
-            out_ref[obj, 1] = n_hi
-            out_ref[obj, 2] = n_lo
+        def one_step(carry):
+            step = outer * inner + carry[0]
+            hit, n_hi, n_lo = _search_step(
+                lambda i: (ih_ref[obj, i, 0], ih_ref[obj, i, 1]),
+                base_ref[obj, 0], base_ref[obj, 1],
+                target_ref[obj, 0], target_ref[obj, 1], step,
+                rows * unroll)
+
+            @pl.when(hit == 1)
+            def _record():
+                flag_ref[obj] = jnp.int32(1)
+                out_ref[obj, 0] = jnp.uint32(step + 1)
+                out_ref[obj, 1] = n_hi
+                out_ref[obj, 2] = n_lo
+
+            return carry[0] + 1, hit
+
+        jax.lax.while_loop(searching, one_step,
+                           (jnp.int32(0), jnp.int32(0)))
 
 
 def _packed_kernel(ih_hi_ref, ih_lo_ref, t_hi_ref, t_lo_ref,
@@ -556,24 +583,17 @@ def pallas_packed_search(ih_words, bases, targets, rows: int = DEFAULT_ROWS,
     return out.reshape(n_obj, 3)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",
-                                             "unroll"))
-def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
-                        chunks: int = 128, interpret: bool = False,
-                        unroll: int = 1):
-    """Search B objects' nonce ranges in ONE kernel launch.
-
-    ``ih_words``: (B, 8, 2) uint32; ``bases``/``targets``: (B, 2).
-    Returns a (B, 3) uint32 array of ``[hit_step + 1, nonce_hi,
-    nonce_lo]`` rows (first column 0 = no hit in this launch); each
-    grid step covers ``unroll`` consecutive (rows, 128) tiles.
-    """
+def _batch_search(ih_words, bases, targets, *, rows, chunks, interpret,
+                  unroll, inner):
+    """``pallas_batch_search`` with the steps a grid step loops over
+    as an argument (the tests' handle on the loop)."""
     n_obj = ih_words.shape[0]
-    kernel = functools.partial(_batch_kernel, rows=rows, unroll=unroll)
-    out = pl.pallas_call(
+    kernel = functools.partial(_batch_kernel, rows=rows, unroll=unroll,
+                               inner=inner)
+    return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((n_obj, 3), U32),
-        grid=(n_obj, chunks),
+        grid=(n_obj, chunks // inner),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -583,7 +603,25 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
         scratch_shapes=[pltpu.SMEM((n_obj,), jnp.int32)],
         interpret=interpret,
     )(ih_words, bases, targets)
-    return out
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",
+                                             "unroll"))
+def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
+                        chunks: int = 128, interpret: bool = False,
+                        unroll: int = 1):
+    """Search B objects' nonce ranges in ONE kernel launch.
+
+    ``ih_words``: (B, 8, 2) uint32; ``bases``/``targets``: (B, 2).
+    Returns a (B, 3) uint32 array of ``[hit_step + 1, nonce_hi,
+    nonce_lo]`` rows (first column 0 = no hit in this launch); an
+    object runs up to ``chunks`` steps of ``unroll`` consecutive
+    (rows, 128) tiles, :data:`BATCH_INNER` of them (or as many as
+    divide ``chunks``) to a grid step.
+    """
+    return _batch_search(ih_words, bases, targets, rows=rows, chunks=chunks,
+                         interpret=interpret, unroll=unroll,
+                         inner=math.gcd(chunks, BATCH_INNER))
 
 
 #: pad batches to this many objects per launch — one compiled program
@@ -600,14 +638,27 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
 #: cache that keeps it a once-per-machine cost.
 BATCH_OBJS = 64
 #: grid steps an object of the pod's batch launches
-#: (parallel/pow_pallas_sharded.py); the pipeline has its own,
-#: ``pow.pipeline.DEFAULT_BATCH_CHUNKS``
+#: (parallel/pow_pallas_sharded.py, four tiles to a step there); the
+#: pipeline has its own, ``pow.pipeline.DEFAULT_BATCH_CHUNKS``
 BATCH_CHUNKS = 64
-#: four tiles to a grid step of the batch grid (64 objects x 64 chunks
-#: x 4, solve-verified on-chip since r4).  Since PR 26 this sets only
-#: how often an object can leave at its hit, not the kernel's streams
-#: or its compile time (8 s for what took 190 s)
-BATCH_UNROLL = 4
+#: the step of the batch kernel: ONE tile of 64 rows, 8,192 trials (8
+#: slices, 28 us), and that is all a hit or a dead slot throws away.
+#: Four tiles of 128 rows to a step (PR 24-39) cost every solved or pad
+#: slot of a launch 65,536 trials: 15.4 % of all the trials of
+#: ``pod4_burst_64``, where 60 slots of 64 are dead.  Settled on the
+#: chip (PERF.md section 6, PR 40: launches of 64 dead slots 15.66 ms
+#: at 65,536, 4.29 at 16,384, 2.55 at 8,192; of 64 live ones 290.55,
+#: 289.66, 288.47 MH/s): the smallest step that keeps the rate of a
+#: launch of live slots within 1 % of what it was.  Since PR 26 these
+#: set only how often an object can leave, not the kernel's streams or
+#: its compile time.  The pipeline takes ``min(rows, BATCH_ROWS)``
+BATCH_ROWS = 64
+BATCH_UNROLL = 1
+#: steps of one object a grid step loops over (``_batch_kernel``): a
+#: launch of 1,024 steps is 16 grid steps an object, which is what a
+#: done slot skips (0.06 us each; 64 or 128 to a grid step, 8 or 512 at
+#: 16,384 trials a step, read the same on the chip)
+BATCH_INNER = 64
 
 
 @functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",

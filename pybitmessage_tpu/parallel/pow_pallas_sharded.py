@@ -50,7 +50,7 @@ from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              record_launch,
                                              register_program)
 from ..ops.sha512_jax import DEFAULT_VARIANT, trial_values
-from ..ops.sha512_pallas import (BATCH_CHUNKS, BATCH_OBJS, BATCH_UNROLL,
+from ..ops.sha512_pallas import (BATCH_CHUNKS, BATCH_OBJS,
                                  LANE_COLS, DEFAULT_CHUNKS,
                                  DEFAULT_ROWS, DEFAULT_UNROLL,
                                  pallas_batch_search, pallas_search)
@@ -70,6 +70,11 @@ register_program("pod_batch", flops_per_item=POW_FLOPS_PER_HASH,
 #: removed the r3 SMEM scaling that capped this at 16).  The host loop
 #: groups the batch so each device's local share stays within this.
 POD_BATCH_PER_DEVICE = BATCH_OBJS
+#: tiles to a step of the pod's batch launches: the four that the
+#: single-chip queue had until PR 40 (``sha512_pallas.BATCH_UNROLL`` is
+#: 1 since), so a pod launch is the trials it was.  Nothing in the node
+#: calls this path (ROADMAP D10)
+POD_BATCH_UNROLL = 4
 
 
 def default_impl() -> str:
@@ -355,7 +360,7 @@ _ALWAYS_HIT = _MASK64
 def pallas_sharded_solve_batch(items, mesh: Mesh, *,
                                rows: int = DEFAULT_ROWS,
                                chunks_per_call: int = BATCH_CHUNKS,
-                               unroll: int = BATCH_UNROLL,
+                               unroll: int = POD_BATCH_UNROLL,
                                impl: str | None = None,
                                interpret: bool = False,
                                variant: str = DEFAULT_VARIANT,
@@ -369,7 +374,7 @@ def pallas_sharded_solve_batch(items, mesh: Mesh, *,
     chunk of the next launch, and its trials stop accruing; the batch
     is padded with always-hit dummies (never duplicated real work).
     Defaults mirror the single-chip batch geometry (32 objects x 64
-    chunks x 4 streams per device, ``BATCH_UNROLL`` — pinned to the
+    chunks x 4 tiles per device, ``POD_BATCH_UNROLL`` — pinned to the
     configuration compiled + verified on real hardware, independent of
     the single kernel's unroll knee).  Returns ``[(nonce, trials),
     ...]`` aligned with ``items``.

@@ -21,7 +21,7 @@ Four plan modes, one loop:
                  enough to be unlikely to hit in the first;
 ``batched``      a queue of objects (``chan_storm_256``): the
                  per-object grid ``pallas_batch_search``, 64 objects a
-                 launch at 128 chunks;
+                 launch at 1,024 steps of one tile of 64 rows;
 ``packed``       a storm of tiny objects sharing tiles along the lane
                  axis (``pallas_packed_search``);
 ``single-sync``  one tiny object: the packed kernel at pack 1, one
@@ -84,7 +84,8 @@ from ..resilience.chaos import inject
 from ..resilience.watchdog import STALLS, SlabStallError
 from ..ops import sha512_pallas
 from ..ops.sha512_jax import double_sha512_trial
-from ..ops.sha512_pallas import (BATCH_OBJS, BATCH_UNROLL, DEFAULT_CHUNKS,
+from ..ops.sha512_pallas import (BATCH_OBJS, BATCH_ROWS, BATCH_UNROLL,
+                                 DEFAULT_CHUNKS,
                                  DEFAULT_ROWS, DEFAULT_UNROLL, LANE_COLS,
                                  pallas_packed_search)
 from ..ops.u64 import U32
@@ -357,12 +358,19 @@ PACK_CHOICES = (16, 8, 4, 2)
 #: grid steps of one packed launch; at pack=16 that is 8*128*chunks
 #: trials per object per launch
 DEFAULT_PACKED_CHUNKS = 64
-#: grid steps an object of the per-object batch kernel: the shape every
-#: chip run of PR 24-26 settled at under the tuner (64 -> 128 in the
-#: first sweep of a 256-object storm), now the only one.  An object
-#: leaves at its hit, so a longer grid costs no trials, only fewer
-#: launches
-DEFAULT_BATCH_CHUNKS = 128
+#: steps an object of the per-object batch kernel: 1,024 of one tile of
+#: 64 rows (``sha512_pallas.BATCH_ROWS``, ``BATCH_UNROLL``), 8,388,608
+#: trials a launch as at the 128 steps of four tiles of 128 rows every
+#: chip run of PR 24-39 launched (the tuner's 64 -> 128 in the first
+#: sweep of a 256-object storm).  An object leaves at its hit and a
+#: solved or pad slot after one step, so the step is what either throws
+#: away: 8,192 trials since PR 40
+DEFAULT_BATCH_CHUNKS = 1024
+#: the most steps a launch of the XLA stand-in scans for a queue's
+#: object (of 128 rows x 128 lanes): it has no early exit, so the Mosaic
+#: kernel's 1,024 would cost a host without an accelerator sixteen
+#: times the hashing for nothing
+XLA_BATCH_CHUNKS = 128
 #: leading-grid-axis cap of one packed launch: up to 64 tiles *
 #: pack objects ride one kernel call (the storm's launch-overhead
 #: amortization); group counts round up to powers of two so the
@@ -920,7 +928,10 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         width = pack * _pow2_at_least(-(-n // pack), PACKED_GROUPS_MAX)
     elif mode == "batched":
         width = BATCH_OBJS
-        unroll = BATCH_UNROLL if pallas else unroll
+        if pallas:
+            rows, unroll = min(rows, BATCH_ROWS), BATCH_UNROLL
+        else:
+            chunks = min(chunks, XLA_BATCH_CHUNKS)
     elif mode == "slab":
         unroll = DEFAULT_UNROLL if pallas else unroll
     else:

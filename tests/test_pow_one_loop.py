@@ -144,7 +144,7 @@ class Scripted:
 
 
 #: trials an object a launch of the batch kernel at 8 rows
-BATCH8 = 128 * 8 * 128 * 4
+BATCH8 = 1024 * 8 * 128
 
 # mode, objects (n, expected trials each), rows, the script, and what
 # the loop does with it: entry point, static shape, width, trials of a
@@ -160,7 +160,7 @@ CASES = {
     # a queue one launch holds is two groups, launched in turn
     "batched queue": (
         "batched", (2, 50000), 8, [2, 2],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 2, 0, [0, 1]),
+        "pallas_batch_search", (8, 1024, 1), 64, 8 * 128, 2, 0, [0, 1]),
     "packed storm": (
         "packed", (4, 16), 128, [1],
         "pallas_packed_search", (128, 64, 4, 1), 4, 32 * 128, 1, 0, None),
@@ -179,14 +179,14 @@ CASES = {
     # is out of the question, so the next goes ahead
     "batched group left alone with 32 live is speculated on": (
         "batched", (64, BATCH8), 8, [2, 2, None],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 3, 1,
+        "pallas_batch_search", (8, 1024, 1), 64, 8 * 128, 3, 1,
         [0, 1, 1]),
     # the same group once 31 have hit: the launch that went ahead while
     # 32 were live is read, and nothing goes ahead of it for the last
     "batched group with one live is not": (
         "batched", (64, BATCH8), 8,
         [2, (None,) * 32 + (2,) * 31 + (None,), 5],
-        "pallas_batch_search", (8, 128, 4), 64, 8 * 128 * 4, 3, 0,
+        "pallas_batch_search", (8, 1024, 1), 64, 8 * 128, 3, 0,
         [0, 1, 1]),
 }
 
@@ -263,8 +263,10 @@ def test_each_mode_launches_what_its_targets_call_for(case, monkeypatch):
     assert grown["pow_pipeline_executed_trials_total"] == executed
 
 
-#: a batch launch at the node's geometry: 128 chunks x 128 rows x 128 x 4
-BATCH_LAUNCH = 128 * 128 * 128 * 4
+#: a batch launch at the node's geometry: 1,024 steps of one tile of 64
+#: rows x 128 lanes (128 steps of four tiles of 128 rows until PR 40:
+#: the same trials)
+BATCH_LAUNCH = 1024 * 64 * 128 * 1
 
 # the benchmark cells' own numbers (PERF.md section 4): trials the
 # unread launch covers, live objects, expected trials each, and the

@@ -245,3 +245,169 @@ def test_interpret_kernel_parity_batch():
     got0 = (int(out[0, 1]) << 32) | int(out[0, 2])
     assert got0 == winner0
     assert out[1, 0] == 0, "false positive below the brute-force min"
+
+
+# --- the batch kernel's loop, with a hash the interpreter can afford ---
+#
+# What PR 40 changed in ``_batch_kernel`` is control: a grid step runs
+# INNER of an object's steps in a loop that leaves at the first hit.
+# The 160 rounds are what make interpret mode unusable on a CPU, and
+# the loop does not care what the trial function is, so these cases
+# give ``_search_step`` a two-multiply mixer in its place and run in
+# tier-1; the real rounds stay with the ``slow`` cases above and with
+# the chip, where every nonce is re-checked with hashlib.
+
+ROWS, CHUNKS, INNER = 16, 8, 4          # two outer steps of four
+STEP = ROWS * 128
+
+
+def _toy_trial(xp, key_hi, key_lo, n_hi, n_lo):
+    u = xp.uint32
+    x = (n_lo ^ key_lo) * u(0x9E3779B1)
+    x = (x ^ (x >> u(15))) * u(0x85EBCA77)
+    x = x ^ (x >> u(13)) ^ n_hi
+    return x ^ key_hi, x * u(0xC2B2AE3D) + key_lo
+
+
+def _toy_values(key, base, n):
+    """64-bit toy trial values of ``n`` nonces from ``base``."""
+    import numpy as np
+    nonce = base + np.arange(n, dtype=np.uint64)
+    with np.errstate(over="ignore"):
+        hi, lo = _toy_trial(np, np.uint32(key >> 32),
+                            np.uint32(key & 0xFFFFFFFF),
+                            (nonce >> np.uint64(32)).astype(np.uint32),
+                            nonce.astype(np.uint32))
+    return (hi.astype(np.uint64) << np.uint64(32)) | lo.astype(np.uint64)
+
+
+def _per_step_reference(key, base, target):
+    """The plain search: one step of STEP nonces after another, the
+    first hit's row and the trials that were run to get there."""
+    for step in range(CHUNKS):
+        values = _toy_values(key, base + step * STEP, STEP)
+        hits = (values <= target).nonzero()[0]
+        if len(hits):
+            nonce = base + step * STEP + int(hits[0])
+            return ([step + 1, nonce >> 32, nonce & 0xFFFFFFFF],
+                    (step + 1) * STEP)
+    return [0, 0, 0], CHUNKS * STEP
+
+
+#: where the one admissible nonce lies: (step, lane of the step)
+LOOP_CASES = {
+    "hit_in_step_0": (0, 5),
+    "hit_inside_an_inner_loop": (2, STEP - 1),
+    "hit_in_the_last_step_of_an_outer_step": (INNER - 1, 700),
+    "hit_in_the_first_step_of_the_next": (INNER, 0),
+    "hit_in_the_last_step_of_the_launch": (CHUNKS - 1, STEP - 1),
+    "no_hit": None,
+    "dead_slot": "always",
+}
+
+
+@pytest.fixture(scope="module")
+def loop_launch():
+    """One launch of the batch kernel over LOOP_CASES, in interpret
+    mode with the toy trial function: ``{case: (row, key, base,
+    target)}`` and the whole output."""
+    import functools
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+
+    def toy_tile(ih_pair, n_hi, n_lo):
+        key_hi, key_lo = ih_pair(0)
+        return _toy_trial(jnp, key_hi, key_lo, n_hi, n_lo)
+
+    span = CHUNKS * STEP
+    args, seed = {}, 0
+    for case, where in LOOP_CASES.items():
+        # a key whose least value over three launches' nonces lies in
+        # the middle one: wherever the launch's base is put below it,
+        # that nonce is the only one at or under the target
+        while True:
+            seed += 1
+            key = int.from_bytes(
+                hashlib.sha512(b"loop case %d" % seed).digest()[:8], "big")
+            values = _toy_values(key, 1 << 32, 3 * span)
+            best = int(values.argmin())
+            if span <= best < 2 * span:
+                break
+        least = int(values[best])
+        if where is None:
+            base, target = (1 << 32) + span, least - 1
+        elif where == "always":
+            base, target = (1 << 32) - 3, (1 << 64) - 1     # lo wraps
+        else:
+            step, lane = where
+            base, target = (1 << 32) + best - step * STEP - lane, least
+        args[case] = (key, base, target)
+
+    def pair(x):
+        return [x >> 32, x & 0xFFFFFFFF]
+
+    ih_words = np.zeros((len(args), 8, 2), np.uint32)
+    ih_words[:, 0] = [pair(key) for key, _b, _t in args.values()]
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(sp, "_double_sha512_tile", toy_tile)
+        out = np.asarray(jax.jit(functools.partial(
+            sp._batch_search, rows=ROWS, chunks=CHUNKS, interpret=True,
+            unroll=1, inner=INNER))(
+                jnp.asarray(ih_words),
+                jnp.array([pair(b) for _k, b, _t in args.values()],
+                          jnp.uint32),
+                jnp.array([pair(t) for _k, _b, t in args.values()],
+                          jnp.uint32)))
+    return {case: (out[k].tolist(), *args[case])
+            for k, case in enumerate(args)}, out
+
+
+@pytest.mark.parametrize("case", list(LOOP_CASES))
+def test_the_batch_kernels_loop_reports_the_row_of_a_plain_search(
+        loop_launch, case):
+    row, key, base, target = loop_launch[0][case]
+    want, _trials = _per_step_reference(key, base, target)
+    assert row == want
+    where = LOOP_CASES[case]
+    if isinstance(where, tuple):
+        assert row[0] == where[0] + 1       # the case is what it says
+    else:
+        assert row[0] == (0 if where is None else 1)
+
+
+def test_the_benchmarks_step_count_is_the_trials_the_loop_ran(loop_launch):
+    """``benchmarks/kernel_work.batch_steps`` reads a launch's trials
+    off its output rows: with a step of one tile it is exact for the
+    objects that hit, those that did not, and the dead slots."""
+    import os
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from benchmarks import kernel_work
+
+    cases, out = loop_launch
+    ran = sum(_per_step_reference(key, base, target)[1]
+              for _row, key, base, target in cases.values())
+    assert kernel_work.batch_steps(out, CHUNKS) * ROWS * 128 * 1 == ran
+    assert kernel_work.launch_trials(
+        "batch_steps", out, {"rows": ROWS, "chunks": CHUNKS,
+                             "unroll": 1}) == ran
+    assert ran == (1 + 3 + 4 + 5 + 8 + 8 + 1) * STEP
+
+
+@pytest.mark.parametrize("chunks, inner", [(1024, 64), (128, 64), (4, 4),
+                                           (6, 2), (1, 1)])
+def test_a_grid_step_loops_over_as_many_steps_as_divide_the_launch(
+        monkeypatch, chunks, inner):
+    """``INNER`` is not an argument of ``pallas_batch_search``: the
+    production launch of 1,024 steps is 16 grid steps an object, and a
+    short launch (the tests', the pod's) is looped over whole."""
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+    seen = {}
+    monkeypatch.setattr(sp, "_batch_search",
+                        lambda *arrays, **static: seen.update(static))
+    sp.pallas_batch_search.__wrapped__(None, None, None, rows=8,
+                                       chunks=chunks)
+    assert (seen["chunks"], seen["inner"]) == (chunks, inner)

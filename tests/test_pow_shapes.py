@@ -167,8 +167,67 @@ def test_each_plan_mode_has_one_chunk_count(n, trials, mode, chunks):
 
 def test_the_batch_shape_is_the_one_the_storm_settled_at():
     # PR 24-26: every chip run of chan_storm_256 went 64 -> 128 chunks
-    # in its first sweep and stayed; the constant is that shape
-    assert DEFAULT_BATCH_CHUNKS == 128
+    # of four tiles of 128 rows in its first sweep and stayed: 8,388,608
+    # trials an object a launch.  PR 40 kept the launch and made the
+    # step one tile of 64 rows, which is all that a hit or a dead slot
+    # throws away
+    step = (sha512_pallas.BATCH_ROWS * sha512_pallas.LANE_COLS
+            * sha512_pallas.BATCH_UNROLL)
+    assert step == 8_192
+    assert DEFAULT_BATCH_CHUNKS * step == 8_388_608
+    # a done slot skips 16 grid steps a launch, not 1,023
+    assert DEFAULT_BATCH_CHUNKS // sha512_pallas.BATCH_INNER == 16
+    assert DEFAULT_BATCH_CHUNKS % sha512_pallas.BATCH_INNER == 0
+
+
+def test_a_queue_on_the_chip_is_launched_at_the_batch_kernels_rows(
+        one_chip, monkeypatch):
+    """``rows`` is the slab's (128); a queue's launches take the batch
+    kernel's own 64, and a caller's fewer rows (the rehearsals' 8) stay
+    as they are."""
+    launched = []
+
+    def first_step(ih_words, bases, targets, chunks):
+        out = np.zeros((len(bases), 3), np.uint32)
+        out[:, 0] = 1
+        return out
+
+    monkeypatch.setattr(
+        sha512_pallas, "pallas_batch_search",
+        _refusing(DEFAULT_BATCH_CHUNKS, launched, first_step))
+    from pybitmessage_tpu.pow import pipeline
+    monkeypatch.setattr(pipeline, "_checked_nonce",
+                        lambda nonce, ih, target: nonce)
+    items = [(bytes([i]) * 64, 2 ** 64 // 10 ** 7) for i in range(3)]
+    plan = pipeline.BatchPlan("batched", 1, DEFAULT_BATCH_CHUNKS,
+                              [0, 1, 2])
+    for rows, want in ((sha512_pallas.DEFAULT_ROWS, 64), (8, 8)):
+        del launched[:]
+        pipeline.solve_batch_pipelined(items, rows=rows, impl="pallas",
+                                       plan=plan)
+        assert {(r, u) for r, _chunks, u in launched} == {(want, 1)}
+
+
+def test_the_xla_stand_in_scans_no_more_steps_than_it_did(monkeypatch):
+    """The stand-in has no early exit: a host without an accelerator
+    is not given the Mosaic kernel's 512 steps to scan."""
+    from pybitmessage_tpu.pow import pipeline
+    seen = []
+    real = pipeline._packed_search_xla
+
+    def scan(ih_words, bases, targets, lanes, chunks):
+        seen.append((lanes, chunks))
+        return real(ih_words, bases, targets, lanes=lanes, chunks=chunks)
+
+    monkeypatch.setattr(pipeline, "_packed_search_xla", scan)
+    items = [(bytes([i]) * 64, 2 ** 64 // 3000) for i in range(3)]
+    stats = {}
+    pipeline.solve_batch_pipelined(
+        items, rows=8, impl="xla", stats=stats,
+        plan=pipeline.BatchPlan("batched", 1, DEFAULT_BATCH_CHUNKS,
+                                [0, 1, 2]))
+    assert set(seen) == {(8 * 128, pipeline.XLA_BATCH_CHUNKS)}
+    assert stats["chunks"] == pipeline.XLA_BATCH_CHUNKS == 128
 
 
 def test_a_slab_that_leaves_at_step_k_feeds_the_tuner_k_steps():

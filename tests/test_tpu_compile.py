@@ -89,9 +89,13 @@ def test_pallas_search_compiles(one_chip, production):
 
 @pytest.mark.parametrize("production", UNROLLS)
 def test_pallas_batch_search_compiles(one_chip, production):
+    """The batch kernel with one grid step an object (a launch short
+    enough for its loop to take whole), at one tile a step and at the
+    four of the pod's launches."""
     from pybitmessage_tpu.ops import sha512_pallas as sp
+    from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
     n = sp.BATCH_OBJS
-    unroll = sp.BATCH_UNROLL if production else 1
+    unroll = pod.POD_BATCH_UNROLL if production else 1
     chunks = sp.BATCH_CHUNKS if production else 4
     compiled = sp.pallas_batch_search.lower(
         _u32((n, 8, 2), one_chip), _u32((n, 2), one_chip),
@@ -101,14 +105,18 @@ def test_pallas_batch_search_compiles(one_chip, production):
 
 
 def test_the_shape_the_pipeline_launches_compiles(one_chip):
-    """``plan_batch`` hands the batch kernel 128 grid steps an object
-    (pow/pipeline.py, ``DEFAULT_BATCH_CHUNKS``), not ``BATCH_CHUNKS``."""
+    """``plan_batch`` hands the batch kernel 1,024 steps of one tile of
+    64 rows an object (pow/pipeline.py, ``DEFAULT_BATCH_CHUNKS``;
+    ``BATCH_ROWS``), not ``BATCH_CHUNKS``: 16 grid steps of a
+    ``lax.while_loop`` over 64 steps, which Mosaic has to take
+    (PR 40)."""
     from pybitmessage_tpu.ops import sha512_pallas as sp
     from pybitmessage_tpu.pow.pipeline import DEFAULT_BATCH_CHUNKS
     n = sp.BATCH_OBJS
+    assert DEFAULT_BATCH_CHUNKS // sp.BATCH_INNER > 1
     compiled = sp.pallas_batch_search.lower(
         _u32((n, 8, 2), one_chip), _u32((n, 2), one_chip),
-        _u32((n, 2), one_chip), rows=sp.DEFAULT_ROWS,
+        _u32((n, 2), one_chip), rows=sp.BATCH_ROWS,
         chunks=DEFAULT_BATCH_CHUNKS, unroll=sp.BATCH_UNROLL).compile()
     assert _has_kernel(compiled)
 
@@ -167,12 +175,13 @@ def test_sharded_batch_search_compiles_for_four_chips(pod_mesh,
     from pybitmessage_tpu.ops import sha512_pallas as sp
     from pybitmessage_tpu.parallel import \
         make_pallas_sharded_batch_search
+    from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
     _, mesh = pod_mesh
     n = sp.BATCH_OBJS * mesh.shape["obj"]
     fn = make_pallas_sharded_batch_search(
         mesh, rows=sp.DEFAULT_ROWS,
         chunks=sp.BATCH_CHUNKS if production else 4,
-        unroll=sp.BATCH_UNROLL if production else 1)
+        unroll=pod.POD_BATCH_UNROLL if production else 1)
     compiled = fn.lower(
         _u32((n, 8, 2), NamedSharding(mesh, P("obj", None, None)),),
         _u32((n, 2), NamedSharding(mesh, P("obj", None))),
