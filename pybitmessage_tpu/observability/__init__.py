@@ -21,14 +21,15 @@ from .metrics import (DEFAULT_LATENCY_BUCKETS, DEFAULT_SIZE_BUCKETS,
                       peer_bucket, peer_bucket_label, set_peer_buckets)
 from .profiling import PROFILER, SamplingProfiler, cost_status
 from .tracing import (TRACE_CTX_LEN, TRACER, SkewEstimator, Span,
-                      TraceContext, Tracer, current_span, new_span_id,
-                      new_trace_id, set_batch, trace)
+                      TraceContext, Tracer, current_span, interval,
+                      new_span_id, new_trace_id, set_batch, trace)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "Registry", "REGISTRY",
     "DEFAULT_LATENCY_BUCKETS", "DEFAULT_SIZE_BUCKETS",
     "peer_bucket", "peer_bucket_label", "set_peer_buckets",
-    "Span", "Tracer", "TRACER", "trace", "current_span", "set_batch",
+    "Span", "Tracer", "TRACER", "trace", "interval", "current_span",
+    "set_batch",
     "TraceContext", "TRACE_CTX_LEN", "SkewEstimator",
     "new_trace_id", "new_span_id",
     "render_prometheus", "snapshot", "log_snapshot_task",

@@ -31,6 +31,13 @@ parentage (the service's loop is a task of its own):
 :func:`set_batch` puts a sequence number into the context and every
 span entered under it carries it as the attribute ``batch``.
 
+``interval("pow.lane.starved", device=2)`` is a STATE rather than a
+step: ``open()`` in one turn of a loop and ``close()`` in a later one,
+several open at once on one thread (one a lane of the pipeline
+driver), closed in any order.  It is recorded and mirrored exactly as
+a span is, but it is nobody's parent: it never touches the current
+span, so what is opened meanwhile keeps the parent it would have had.
+
 A span may be given ``histogram=<Histogram child or family>`` — its
 duration is observed on exit, which is how the solve-latency
 histograms are fed without a second ``time.monotonic()`` pair at the
@@ -128,6 +135,40 @@ class Tracer:
 TRACER = Tracer()
 
 
+def _begin(name: str, attrs: dict):
+    """A started :class:`Span` under the current one, and its mirror in
+    the profiler's trace (None before ``jax`` is imported)."""
+    parent = _current_span.get()
+    batch = _batch.get()
+    if batch is not None:
+        attrs.setdefault("batch", batch)
+    annotation = _annotation or _find_annotation()
+    jax_ctx = None
+    if annotation is not None:
+        # the interval starts at construction; attributes known
+        # now ride along as the event's stats
+        try:
+            jax_ctx = annotation(name, **attrs)
+        except Exception:
+            jax_ctx = None
+    return Span(name=name, span_id=next(_span_ids),
+                parent_id=parent.span_id if parent is not None else None,
+                start=time.monotonic(), attrs=attrs), jax_ctx
+
+
+def _end(span: Span, jax_ctx, tracer: Tracer, exc_type=None, exc=None,
+         tb=None) -> None:
+    """Stamp the duration, end the mirror, put the span into the ring."""
+    span.duration = time.monotonic() - span.start
+    if jax_ctx is not None:
+        try:
+            jax_ctx.__exit__(exc_type, exc, tb)
+        except Exception:
+            logger.debug("jax trace annotation exit failed",
+                         exc_info=True)
+    tracer.record(span)
+
+
 class trace:
     """Span context manager / decorator.
 
@@ -151,41 +192,18 @@ class trace:
         self._jax_ctx = None
 
     def __enter__(self) -> Span:
-        parent = _current_span.get()
-        batch = _batch.get()
-        if batch is not None:
-            self.attrs.setdefault("batch", batch)
-        annotation = _annotation or _find_annotation()
-        if annotation is not None:
-            # the interval starts at construction; attributes known
-            # now ride along as the event's stats
-            try:
-                self._jax_ctx = annotation(self.name, **self.attrs)
-            except Exception:
-                self._jax_ctx = None
-        self.span = Span(
-            name=self.name, span_id=next(_span_ids),
-            parent_id=parent.span_id if parent is not None else None,
-            start=time.monotonic(), attrs=self.attrs)
+        self.span, self._jax_ctx = _begin(self.name, self.attrs)
         self._token = _current_span.set(self.span)
         return self.span
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        duration = time.monotonic() - self.span.start
-        if self._jax_ctx is not None:
-            try:
-                self._jax_ctx.__exit__(exc_type, exc, tb)
-            except Exception:
-                logger.debug("jax trace annotation exit failed",
-                             exc_info=True)
-            self._jax_ctx = None
         _current_span.reset(self._token)
-        self.span.duration = duration
         if exc_type is not None:
             self.span.attrs["error"] = exc_type.__name__
-        self.tracer.record(self.span)
+        _end(self.span, self._jax_ctx, self.tracer, exc_type, exc, tb)
+        self._jax_ctx = None
         if self.histogram is not None:
-            self.histogram.observe(duration)
+            self.histogram.observe(self.span.duration)
         return False
 
     def __call__(self, fn):
@@ -196,6 +214,42 @@ class trace:
                        tracer=self.tracer, **self.attrs):
                 return fn(*args, **kwargs)
         return wrapper
+
+
+class interval:
+    """A state of a loop, as an interval: ``open()`` now, ``close()``
+    in some later turn.
+
+    >>> starved = interval("pow.lane.starved", device=2, lane=2)
+    >>> starved.open()
+    >>> ...                     # spans come and go on this thread
+    >>> starved.close()
+
+    Recorded into the ring and mirrored into the profiler's trace as a
+    span is (parent: the span current at ``open``; ``batch`` as spans
+    carry it; the attributes known at ``open`` ride as the event's
+    stats), but never the current span itself: intervals overlap, close
+    out of order and stay open across the spans of their thread, and a
+    span opened meanwhile is not their child.
+    """
+
+    __slots__ = ("name", "attrs", "tracer", "span", "_jax_ctx")
+
+    def __init__(self, name: str, *, tracer: Tracer = None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.tracer = tracer or TRACER
+        self.span = None
+        self._jax_ctx = None
+
+    def open(self) -> Span:
+        self.span, self._jax_ctx = _begin(self.name, self.attrs)
+        return self.span
+
+    def close(self) -> Span:
+        _end(self.span, self._jax_ctx, self.tracer)
+        self._jax_ctx = None
+        return self.span
 
 
 def current_span() -> Span | None:

@@ -74,7 +74,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from ..observability import DEFAULT_SIZE_BUCKETS, REGISTRY, trace
+from ..observability import DEFAULT_SIZE_BUCKETS, REGISTRY, interval, trace
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              record_launch,
                                              register_program)
@@ -99,9 +99,21 @@ _ALWAYS_HIT = _MASK64
 
 DEVICE_BUSY = REGISTRY.gauge(
     "pow_pipeline_device_busy_ratio",
-    "Fraction of the last pipelined solve's wall time the host spent "
-    "blocked on device results — a lower bound on true device "
-    "occupancy; the sync-path penalty shows up as this dropping")
+    "Fraction of the last pipelined solve's lane time (its devices "
+    "times its wall time) in which a device had a launch in flight: "
+    "that solve's inflight seconds of pow_pipeline_lane_seconds_total "
+    "over all three states'")
+#: what a device's lane is doing while a solve is under way; the last
+#: two are intervals in the profiler's trace (``_Lane``)
+LANE_STATES = ("inflight", "turn", "starved")
+LANE_SECONDS = REGISTRY.counter(
+    "pow_pipeline_lane_seconds_total",
+    "Host-clock seconds the devices of the pipelined solves spent in "
+    "each lane state: a launch in flight (inflight), the queue empty "
+    "and the host loop yet to launch or to find nothing (turn), "
+    "nothing left to search on that device (starved); over one solve "
+    "the three add up to its devices times its wall time",
+    ("device", "state"))
 PIPELINE_DEPTH = REGISTRY.gauge(
     "pow_pipeline_depth", "Slabs currently in flight (dispatch-ahead)")
 DISPATCH_AHEAD = REGISTRY.histogram(
@@ -480,12 +492,17 @@ class _PipelineDriver:
     oldest launch of each is fetched on a thread of its own and the
     first to come in is harvested, so no device that has run out waits
     for another's launch to be read.
+
+    While ``run`` is under way every lane is in one of
+    :data:`LANE_STATES` (:class:`_Lane`); ``devices`` are the JAX ids
+    of the lanes' devices, which name their planes in a profiler trace
+    (the lanes' indices where none are given).
     """
 
     def __init__(self, *, depth: int = 2, lanes: int = 1,
                  should_stop: Callable[[], bool] | None = None,
                  fetch=None, stall_timeout: float = 0.0,
-                 kind: str = "batch", shape=None):
+                 kind: str = "batch", shape=None, devices=None):
         import numpy as np
 
         def default_fetch(dev):
@@ -518,7 +535,12 @@ class _PipelineDriver:
         #: the first launch of a shape on a device traces and lowers
         #: the kernel (see :meth:`_on_device_thread`)
         self.shape = shape
-        self.wait_seconds = 0.0
+        self.devices = list(devices) if devices else list(range(self.lanes))
+        #: the lanes' states while ``run`` is under way
+        self._lanes: list = []
+        #: state -> seconds of the latest ``run``, its lanes summed:
+        #: together ``lanes`` times ``wall_seconds``
+        self.lane_seconds = dict.fromkeys(LANE_STATES, 0.0)
         #: blocking wait of the latest fetch (its ``pow.fetch`` span)
         self.last_wait = 0.0
         self.wall_seconds = 0.0
@@ -596,9 +618,13 @@ class _PipelineDriver:
                 host = self._fetching.pop(lane)[0].result()
             span.attrs["device"] = lane
         self.last_wait = span.duration
-        self.wait_seconds += span.duration
         DEVICE_WAIT.observe(span.duration)
-        return queues[lane].popleft()[1], host
+        tag = queues[lane].popleft()[1]
+        if not queues[lane]:
+            # nothing queued behind it: the device has nothing to run
+            # until this loop has harvested and come round to the lane
+            self._lanes[lane].enter("turn")
+        return tag, host
 
     def _on_device_thread(self, fn, *args, timeout=None):
         """``fn(*args)`` on one of this driver's worker threads, the
@@ -631,6 +657,8 @@ class _PipelineDriver:
     def run(self, next_launch, harvest, done=None, load=None) -> None:
         queues = [deque() for _ in range(self.lanes)]
         t_start = time.monotonic()
+        self._lanes = [_Lane(k, self.devices[k], load, t_start)
+                       for k in range(self.lanes)]
         try:
             while True:
                 inflight = sum(map(len, queues))
@@ -665,11 +693,15 @@ class _PipelineDriver:
                         nxt = self._on_device_thread(next_launch, lane)
                     if nxt is None:
                         room.remove(lane)
+                        if not queues[lane]:
+                            self._lanes[lane].enter("starved")
                         continue
                     if self.shape is not None:
                         _TRACED_SHAPES.add((self.shape, lane))
                     self.slabs += 1
                     queues[lane].append((self.slabs, *nxt))
+                    if len(queues[lane]) == 1:
+                        self._lanes[lane].enter("inflight")
                     if len(queues[lane]) >= self.depth:
                         room.remove(lane)
                     inflight += 1
@@ -691,14 +723,81 @@ class _PipelineDriver:
         finally:
             PIPELINE_DEPTH.set(0)
             self._drop_guards()
-            self.wall_seconds = max(time.monotonic() - t_start, 1e-9)
+            t_end = time.monotonic()
+            left = [ln.leave(t_end) for ln in self._lanes]
+            self.lane_seconds = {state: sum(s[state] for s in left)
+                                 for state in LANE_STATES}
+            self.wall_seconds = max(t_end - t_start, 1e-9)
             DEVICE_BUSY.set(self.busy_ratio)
 
     @property
     def busy_ratio(self) -> float:
-        if self.wall_seconds <= 0:
-            return 0.0
-        return min(self.wait_seconds / self.wall_seconds, 1.0)
+        """The share of the latest ``run``'s lane time with a launch in
+        flight."""
+        return self.lane_seconds["inflight"] / (
+            self.lanes * self.wall_seconds) if self.wall_seconds else 0.0
+
+
+class _Lane:
+    """Why one device has or has not something to run, while
+    ``_PipelineDriver.run`` is under way.  ``inflight``: from a launch
+    appended to the lane's empty queue to the read that empties it.
+    ``turn``: from that read (and from ``run``'s start) until the host
+    loop has launched for the lane again or found nothing for it.
+    ``starved``: from a turn that found nothing to launch with the
+    queue empty, every object of the device solved, until the lane's
+    next launch or ``run``'s end.
+
+    ``turn`` and ``starved`` are intervals in the profiler's trace,
+    with the device's id, the lane and the lane's live slots when they
+    opened (``inflight`` is their absence inside a solve); every
+    state's seconds are credited to
+    ``pow_pipeline_lane_seconds_total`` on the host's clock when the
+    lane leaves it, so one ``run`` credits ``lanes`` times its wall
+    time whatever ends it.
+    """
+
+    __slots__ = ("lane", "device", "load", "state", "since", "seconds",
+                 "_interval")
+
+    def __init__(self, lane: int, device: int, load, now: float):
+        self.lane, self.device, self.load = lane, device, load
+        self.state, self.since, self._interval = None, now, None
+        self.seconds = dict.fromkeys(LANE_STATES, 0.0)
+        self.enter("turn")
+
+    def enter(self, state, now=None) -> None:
+        """The lane is in ``state`` from now on (None: no longer
+        driven); a lane that is there already stays as it is."""
+        if state == self.state:
+            return
+        if self._interval is not None:
+            end = self._interval.close().end
+            now = end if now is None else now
+            self._interval = None
+        if state in ("turn", "starved"):
+            attrs = dict(device=self.device, lane=self.lane,
+                         live=self.load(self.lane) if self.load else 0)
+            self._interval = interval("pow.lane.turn", **attrs) \
+                if state == "turn" else interval("pow.lane.starved", **attrs)
+            start = self._interval.open().start
+            now = start if now is None else now
+        elif now is None:
+            now = time.monotonic()
+        if self.state is not None:
+            self.seconds[self.state] += now - self.since
+            # bounded by the host's device count
+            LANE_SECONDS.labels(  # bmlint: allow(metric-labels)
+                device="%d" % self.device, state=self.state).inc(
+                    now - self.since)
+            self.since = now
+        self.state = state
+
+    def leave(self, now: float) -> dict:
+        """``run`` is over at ``now``: what is open is closed; the
+        lane's seconds by state."""
+        self.enter(None, now)
+        return self.seconds
 
 
 # ---------------------------------------------------------------------------
@@ -1149,7 +1248,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     driver = _PipelineDriver(depth=depth, lanes=len(lanes),
                              should_stop=should_stop,
                              stall_timeout=stall_timeout, kind=kind,
-                             shape=(tele_prog, tele_key))
+                             shape=(tele_prog, tele_key),
+                             devices=[getattr(d, "id", k)
+                                      for k, d in enumerate(devices)])
     try:
         driver.run(next_launch, harvest, done=done,
                    load=lambda lane: sum(g.live() for g in lanes[lane]))

@@ -227,7 +227,10 @@ def test_a_run_that_ends_with_a_slab_in_flight_counts_it_abandoned():
     assert counted("pow_pipeline_launches_total") == launches0 + 2
     assert counted("pow_pipeline_abandoned_launches_total") \
         == abandoned0 + 1
-    assert driver.last_wait >= 0 and driver.wait_seconds >= driver.last_wait
+    # the fetch's wait lies inside the lane's seconds with a launch in
+    # flight (the second launch is still out when the run ends)
+    assert driver.last_wait >= 0 \
+        and driver.lane_seconds["inflight"] >= driver.last_wait
 
     # a run that needs every slab it dispatched abandons nothing
     budget = iter(range(3))
