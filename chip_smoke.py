@@ -611,7 +611,8 @@ def run_pod(rep: Report) -> None:
                 % (label, sum(r[1] for r in res) / dt))
 
     # a queue is the pipeline's on any number of chips: its launch
-    # groups dealt over them, an object's whole range on one chip
+    # groups dealt over them, an object's own range on one chip and a
+    # copy of a straggler's on a chip that has run out
     by_device = "pow_pipeline_device_launches_total"
     timed("first batch, %d devices" % ndev,
           lambda: d.solve_batch(batch))
@@ -629,8 +630,12 @@ def run_pod(rep: Report) -> None:
               and all(_valid(it, r) for it, r in zip(batch, bres_1)),
               "batch nonces valid by hashlib on %d devices and on one"
               % ndev)
-    rep.check(bres_n == bres_1, "the placed batch found the nonces and "
-              "trials of the one-device batch")
+    share = (1 << 64) // ndev
+    rep.check(all(placed[1] >= alone[1] if placed[0] == alone[0]
+                  else placed[0] >= share
+                  for placed, alone in zip(bres_n, bres_1)),
+              "the placed batch found the nonces of the one-device batch, "
+              "or a copy's from its own share of the nonce space")
     rep.check(len(took) == ndev and all(took.values()),
               "every device took launches of the batch: %s" % took)
     for label, res, dt in (("%d devices" % ndev, bres_n, bdt_n),

@@ -5,8 +5,9 @@ that is alone on a host of several chips has its nonce range
 partitioned over all of them (``PowDispatcher._solve_on_device``).  A
 QUEUE on such a host is not this module's any more: it goes through
 ``pow/pipeline.py``, whose launch groups are dealt over the chips, an
-object's whole nonce range on one chip (docs/pow_pipeline.md, "A solve
-placed over several chips").  :func:`pallas_sharded_solve_batch`, the
+object's own nonce range on one chip and copies of a straggler's on
+the chips that have run out (docs/pow_pipeline.md, "A solve placed
+over several chips").  :func:`pallas_sharded_solve_batch`, the
 2D (objects x nonce-range) loop, is left with no caller in the node
 (``tools/tpu_doctor.py`` and the tests call it; ROADMAP D10).
 

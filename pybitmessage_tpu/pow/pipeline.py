@@ -39,9 +39,11 @@ many: it may start with the first member of a sweep, the slots of the
 members still to come start as pad slots, and ``feed`` fills them.
 Given several ``devices`` the launch groups are dealt over them, at
 least two a device: a group's arrays and launches stay on its chip, an
-object's whole nonce range with them, and each chip has its own
-launches in flight (docs/pow_pipeline.md, "A solve placed over several
-chips").
+object's own nonce range with them, and each chip has its own launches
+in flight.  A chip that has run out takes a nonce-range copy of
+another chip's unresolved object, one a turn; the first slot to hit
+resolves the object and every other slot of it is cancelled
+(docs/pow_pipeline.md, "A solve placed over several chips").
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -175,6 +177,13 @@ REFILLS = REGISTRY.counter(
     "Objects a running solve took from the queue into a slot whose "
     "object had solved (or a pad slot), before the group's next launch",
     ("kind",))
+COPIES = REGISTRY.counter(
+    "pow_pipeline_copies_total",
+    "Nonce-range copies of another device's unresolved object that a "
+    "device with nothing live took into a done slot, credited when the "
+    "copy's slot is retired: its own slot found the nonce (won) or "
+    "another slot of the object did (cancelled); not in "
+    "pow_pipeline_refills_total", ("kind", "outcome"))
 SLOTS = REGISTRY.counter(
     "pow_pipeline_slots_total",
     "Slots of the launches dispatched: those that searched (live) and "
@@ -183,8 +192,10 @@ SLOTS = REGISTRY.counter(
 NEEDED_TRIALS = REGISTRY.counter(
     "pow_pipeline_needed_trials_total",
     "Trials of harvested launches that a search needed: a slot that "
-    "missed, its whole slab; a slot that hit, up to its winning nonce; a "
-    "solved or pad slot, none", ("kind",))
+    "missed, its whole slab (a copy's too: it proved a range empty); a "
+    "slot that hit, up to its winning nonce; a solved or pad slot, or "
+    "one whose object another slot had resolved by then, none",
+    ("kind",))
 EXECUTED_TRIALS = REGISTRY.counter(
     "pow_pipeline_executed_trials_total",
     "Trials the device computed in harvested launches, counted by the "
@@ -746,7 +757,12 @@ class _Lane:
     loop has launched for the lane again or found nothing for it.
     ``starved``: from a turn that found nothing to launch with the
     queue empty, every object of the device solved, until the lane's
-    next launch or ``run``'s end.
+    next launch or ``run``'s end.  In a ``batched`` solve over several
+    devices such a turn first takes a nonce-range copy of another
+    lane's unresolved object and launches that
+    (``solve_batch_pipelined``), so a lane is starved only while no
+    other lane has an object left to copy, or no group of its own has
+    every launch read.
 
     ``turn`` and ``starved`` are intervals in the profiler's trace,
     with the device's id, the lane and the lane's live slots when they
@@ -831,7 +847,7 @@ class _LaunchGroup:
     launches run (None: wherever JAX puts an array by default)."""
 
     __slots__ = ("idx", "words", "ih_words", "stale", "targets", "t_arr",
-                 "bases", "trials", "done", "unread", "width",
+                 "bases", "trials", "done", "copy", "unread", "width",
                  "unbatched", "device")
 
     def __init__(self, items, idx, width, starts=None, unbatched=False,
@@ -866,6 +882,8 @@ class _LaunchGroup:
                        for i in idx] + [0] * pad)
         self.trials = [0] * width
         self.done = [i is None for i in self.idx]
+        #: the slot searches a nonce-range copy of another lane's object
+        self.copy = [False] * width
         #: launches dispatched and not yet harvested
         self.unread = 0
 
@@ -890,10 +908,11 @@ class _LaunchGroup:
         return self.ih_words
 
     def refill(self, k: int, i: int, initial_hash: bytes, target: int,
-               base: int) -> None:
-        """Slot ``k``, solved or pad, takes item ``i``.  Only between
-        launches that have all been read: an unread launch still
-        answers for the slot's last object."""
+               base: int, copy: bool = False) -> None:
+        """Slot ``k``, solved or pad, takes item ``i`` (``copy``: a
+        range of it that another lane's slot does not search).  Only
+        between launches that have all been read: an unread launch
+        still answers for the slot's last object."""
         self.idx[k] = i
         self.words[k] = _hash_words(initial_hash)
         self.stale = True
@@ -902,6 +921,24 @@ class _LaunchGroup:
         self.bases[k] = base & _MASK64
         self.trials[k] = 0
         self.done[k] = False
+        self.copy[k] = copy
+
+    def retire(self, k: int) -> None:
+        """Slot ``k`` has nothing left to search: pad semantics, an
+        always-hit step in the group's next launch and idle after."""
+        self.done[k] = True
+        self.t_arr[k] = (0xFFFFFFFF, 0xFFFFFFFF)
+
+
+def _copy_base(start: int, nth: int, lanes: int) -> int:
+    """Where the copy of an object on the ``nth`` lane after its own
+    (counted round the ``lanes``, so 1 to ``lanes - 1``) begins its
+    search: the object's own start plus ``nth`` shares of the nonce
+    space, 2**62 apart on four chips.  An object at network difficulty
+    needs 1e7 to 1e9 trials and its own range begins at ``start``, so
+    ranges 2**64 / ``lanes`` apart never meet; if they ever did, a
+    range would be searched twice and nothing would be wrong."""
+    return (start + nth * ((1 << 64) // lanes)) & _MASK64
 
 
 def _pow2_at_least(n: int, cap: int) -> int:
@@ -948,7 +985,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     :func:`worth_speculating` says so.  Every
     returned nonce is host re-verified.  Per-object ``trials`` credit
     the grid steps the object's own search really ran (a search leaves
-    its launch at its first hit), for every mode;
+    its launch at its first hit; an object searched in several slots,
+    below, the sum over them up to the win), for every mode;
     ``stats["executed_trials"]`` (optional dict) estimates total device
     hashing including straggler and pad waste — the two diverge
     exactly where packing removes waste.
@@ -977,23 +1015,38 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     missing.
 
     ``devices`` places the solve: the launch groups are dealt over
-    them in turn, a group's arrays and launches stay on its device (an
-    object's whole nonce range is searched on one chip), each device
-    has its own ``depth`` launches in flight and its own round-robin
-    over its groups, and a freed slot takes what ``feed`` brings on
-    the chip that freed it.  A queue is laid out as at least
+    them in turn, a group's arrays and launches stay on its device,
+    each device has its own ``depth`` launches in flight and its own
+    round-robin over its groups, and a freed slot takes what ``feed``
+    brings on the chip that freed it.  A queue is laid out as at least
     :data:`MIN_BATCH_GROUPS` groups a device, so that no device needs
     a launch dispatched ahead of an unread one to stay busy.  Without
     ``devices`` there is one lane, on JAX's default device.
+
+    An object's own nonce range is searched on one chip.  A chip of a
+    ``batched`` solve that has run out (no live slot, nothing from
+    ``feed``) takes a COPY of a straggler, one object a turn: from the
+    lane with the most unresolved objects the one that the fewest
+    lanes search (the hardest of those), into a done slot of a group of
+    its own, at a base no other holder searches (:func:`_copy_base`).
+    The search is memoryless, so a trial there is worth as much as one
+    in the object's own range.  The first slot to hit resolves the
+    object (``on_solved`` once, the nonce re-verified as any other);
+    every other slot of it becomes a pad slot, and what its launches
+    in flight still search is computed and not needed.  One a turn and
+    only with nothing live, because four chips that copy greedily are
+    four copies of one queue (PERF.md section 6, PR 42).
 
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
     proven miss-free for item ``i`` (safe resume point — speculative
     dispatch-ahead never moves a checkpoint before its slab is
-    harvested); on ``should_stop`` what is in flight is harvested
-    first, and an answer found there is returned; ``stall_timeout > 0``
-    bounds each harvest's blocking device wait.
+    harvested, and a copy's slot never moves one: it proves nothing
+    about the range a resumed search would skip); on ``should_stop``
+    what is in flight is harvested first, and an answer found there is
+    returned; ``stall_timeout > 0`` bounds each harvest's blocking
+    device wait.
     """
     import numpy as np
 
@@ -1076,9 +1129,21 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     lanes = [groups[k::len(devices)] for k in range(len(devices))]
     rr = [0] * len(lanes)
     results: list = [None] * n
-    executed = {"trials": 0, "launches": 0}
+    executed = {"trials": 0, "launches": 0, "copies": 0}
+    #: where each object's own range begins
+    starts = list(start_nonces) if start_nonces else [0] * n
+    #: unresolved item -> {lane: (group, slot)} of the slots that search
+    #: it: its own first, then its copies, at most one a lane
+    held = {i: {j % len(lanes): (g, k)}
+            for j, g in enumerate(groups)
+            for k, i in enumerate(g.idx) if i is not None}
+    #: whether a lane that has run out has another lane to copy from
+    may_copy = mode == "batched" and len(lanes) > 1
 
-    def take_in(g) -> int:
+    def load(lane) -> int:
+        return sum(g.live() for g in lanes[lane])
+
+    def take_in(g, lane) -> int:
         """Give the done slots of ``g``, whose launches have all been
         read, objects that have arrived since ``feed`` was last asked;
         how many it took."""
@@ -1087,10 +1152,35 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         for k, (initial_hash, target, start) in zip(free, arrived):
             items.append((initial_hash, target))
             results.append(None)
+            starts.append(start)
+            held[len(items) - 1] = {lane: (g, k)}
             g.refill(k, len(items) - 1, initial_hash, target, start)
         if arrived:
             REFILLS.labels(kind=kind).inc(len(arrived))
         return len(arrived)
+
+    def take_copy(lane):
+        """``lane`` has no live slot and ``feed`` had nothing for it:
+        a done slot of one of its groups whose launches have all been
+        read takes a copy of ONE object, from the lane with the most
+        unresolved objects the one that the fewest lanes search, of
+        those the hardest.  No live slot means ``lane`` holds none of
+        them yet.  The group to launch, or None."""
+        g = next((g for g in lanes[lane] if not g.unread), None)
+        if g is None or not held:
+            return None
+        of_lane = [[i for i, where in held.items() if other in where]
+                   for other in range(len(lanes))]
+        i = min(max(of_lane, key=len),
+                key=lambda i: (len(held[i]), items[i][1] & _MASK64))
+        own = next(iter(held[i]))
+        k = g.done.index(True)
+        g.refill(k, i, *items[i],
+                 _copy_base(starts[i], (lane - own) % len(lanes),
+                            len(lanes)), copy=True)
+        held[i][lane] = (g, k)
+        executed["copies"] += 1
+        return g
 
     def speculate(mine):
         # THE speculation rule, for every mode: with no fresh group
@@ -1113,7 +1203,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
 
     def next_launch(lane):
         mine = lanes[lane]
-        cand, refilled = None, 0
+        cand, refilled, copied = None, 0, 0
         # round-robin over the device's groups without an in-flight
         # slab: one with a done slot takes in what has arrived, an
         # unfinished one is launched
@@ -1122,13 +1212,17 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             if g.unread:
                 continue
             if feed is not None:
-                refilled = take_in(g)
+                refilled = take_in(g, lane)
             if not g.finished:
                 cand = g
                 rr[lane] = (rr[lane] + off + 1) % len(mine)
                 break
         speculative = cand is None
-        if speculative:
+        if speculative and may_copy and not load(lane):
+            # run out: search on for a chip that has not
+            cand, speculative = take_copy(lane), False
+            copied = int(cand is not None)
+        elif speculative:
             # decided in a call that has returned before the kernel is
             # called: none of it lies under a kernel's trace
             cand = speculate(mine)
@@ -1146,7 +1240,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         ih_words = cand.device_words()
         with trace("pow.launch", program=tele_prog, chunks=chunks,
                    live=live, speculative=speculative,
-                   refilled=refilled, device=lane) as span:
+                   refilled=refilled, copied=copied,
+                   device=lane) as span:
             # the kernels are called from this frame, not through a
             # helper: on the chip the first call of a process (trace
             # and lowering of pallas_search) took 2.5 times as long
@@ -1196,11 +1291,14 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         g.unread -= 1
         before, needed = executed["trials"], 0
         for k in range(g.width):
-            if g.done[k]:
-                # solved/pad slots still executed one always-hit step
-                executed["trials"] += step_trials
-                continue
             step1 = int(rows_out[k, 0])
+            if g.done[k]:
+                # a pad or solved slot's one always-hit step; or what a
+                # slot searched in this launch after another launch, or
+                # another slot of its object, had resolved it: computed,
+                # needed by nobody, and no second result
+                executed["trials"] += (step1 or chunks) * step_trials
+                continue
             i = g.idx[k]
             if step1:
                 g.trials[k] += step1 * step_trials
@@ -1208,22 +1306,28 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                 nonce = _checked_nonce(
                     (int(rows_out[k, 1]) << 32) | int(rows_out[k, 2]),
                     items[i][0], g.targets[k])
-                results[i] = (nonce, g.trials[k])
                 # this launch searched on from end_bases[k] - slab_trials;
                 # the nonce lies in the step that reported it
                 needed += min(
                     (nonce - end_bases[k] + slab_trials + 1) & _MASK64,
                     step1 * step_trials)
-                g.done[k] = True
-                # pad semantics: always-hit next launch, then idle
-                g.t_arr[k] = (0xFFFFFFFF, 0xFFFFFFFF)
+                # first hit wins: every slot that searches the object,
+                # on whichever lane, is retired with this one
+                slots = held.pop(i).values()
+                results[i] = (nonce, sum(h.trials[s] for h, s in slots))
+                for h, s in slots:
+                    h.retire(s)
+                    if h.copy[s]:
+                        COPIES.labels(
+                            kind=kind, outcome="won" if h is g
+                            else "cancelled").inc()
                 if on_solved is not None:
                     on_solved(i, results[i])
             else:
                 g.trials[k] += slab_trials
                 executed["trials"] += slab_trials
                 needed += slab_trials
-                if progress is not None:
+                if progress is not None and not g.copy[k]:
                     # this slab proved [prev, end_bases[k]) miss-free:
                     # a resumed search may safely start there
                     progress(i, end_bases[k])
@@ -1243,7 +1347,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         if not all(g.finished for g in groups):
             return False
         return feed is None or not any(
-            take_in(g) for g in groups if not g.unread)
+            take_in(g, j % len(lanes)) for j, g in enumerate(groups)
+            if not g.unread)
 
     driver = _PipelineDriver(depth=depth, lanes=len(lanes),
                              should_stop=should_stop,
@@ -1252,8 +1357,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                              devices=[getattr(d, "id", k)
                                       for k, d in enumerate(devices)])
     try:
-        driver.run(next_launch, harvest, done=done,
-                   load=lambda lane: sum(g.live() for g in lanes[lane]))
+        driver.run(next_launch, harvest, done=done, load=load)
     except PowInterrupted:
         if any(r is None for r in results):
             raise
@@ -1261,7 +1365,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         stats.update(
             mode=mode, pack=pack, width=width, chunks=chunks,
             groups=len(groups), devices=len(lanes),
-            launches=executed["launches"],
+            launches=executed["launches"], copies=executed["copies"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),
             wall_seconds=driver.wall_seconds,

@@ -3,7 +3,10 @@
 scratch copy of the benchmark at test difficulty, with the dispatcher
 told that it has FOUR accelerator chips (four of the suite's virtual
 devices).  The queue streams through the pipeline placed over them and
-a lone object takes the nonce-range partition.
+a lone object takes the nonce-range partition.  A second cell on the
+same configuration sends bursts (``pod4_burst_64``'s generator) whose
+chips run out unevenly: the ones that have take nonce-range copies of
+the stragglers (ISSUE 42).
 
 The entries and the readers are held in
 ``tests/benchmarks/test_pod4_queue_1k.py``.  This file is outside that
@@ -13,6 +16,8 @@ each, and each times a one-second window.
 """
 
 import asyncio
+import collections
+import functools
 import json
 import pathlib
 import shutil
@@ -32,6 +37,7 @@ from test_pod4_queue_1k import (BY_DEVICE, CONFIG,  # noqa: E402
 from test_queue_1k import one_chip  # noqa: E402,F401  (the fixture)
 
 REHEARSAL = "rehearse_pod4"
+BURST = "rehearse_pod4_burst"
 
 
 @pytest.fixture(scope="module")
@@ -51,6 +57,12 @@ def tree(tmp_path_factory):
         "report": 6, "body_bytes": [[1.0, 40, 300]],
         "warm_verify_batches": [], "warm_quiet_sweeps": 1,
         "warm_max_sweeps": 4}))
+    # bursts of twelve, a third of them ten times as long as the rest
+    (bdir / "traffic" / (BURST + "_mix.json")).write_text(json.dumps({
+        "generator": "closed_loop", "send": "message", "sweep": 12,
+        "body_bytes": [[0.7, 40, 300], [0.3, 2000, 4000]],
+        "warm_verify_batches": [], "warm_quiet_sweeps": 1,
+        "warm_max_sweeps": 4}))
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
     spec["configs"].append({
         "name": REHEARSAL + "_cfg", "source": "test", "reduced": [],
@@ -59,9 +71,12 @@ def tree(tmp_path_factory):
     spec["workloads"].append({
         "name": REHEARSAL, "config": REHEARSAL + "_cfg",
         "traffic": REHEARSAL + "_mix", "chips": 4, "why": "test"})
+    spec["workloads"].append({
+        "name": BURST, "config": REHEARSAL + "_cfg",
+        "traffic": BURST + "_mix", "chips": 4, "why": "test"})
     for metric in spec["per_layer"]:
         if metric["name"] in NEW_LAYERS:
-            metric["workloads"].append(REHEARSAL)
+            metric["workloads"] += [REHEARSAL, BURST]
     (root / "BENCHMARK.json").write_text(json.dumps(spec))
     return root
 
@@ -126,3 +141,51 @@ def test_the_cell_streams_over_four_devices_and_is_correct(tree,
     assert all(grown.values()), grown
     assert sum(grown.values()) \
         == counters.delta("pow_pipeline_launches_total")[("batch",)]
+
+
+def test_a_burst_whose_chips_run_out_unevenly_is_searched_in_copies(
+        tree, four_chips, monkeypatch):
+    """Bursts of twelve messages with a few long ones: the chips whose
+    objects solved first take copies of the stragglers; every object
+    is resolved once, the cell is ``correct`` and a copy is no refill."""
+    from pybitmessage_tpu.core.jaxsetup import setup_jax
+    from pybitmessage_tpu.pow.dispatcher import PowDispatcher
+    setup_jax()
+    solve_batch = PowDispatcher.solve_batch
+    solves = []
+
+    @functools.wraps(solve_batch)
+    def counting(self, items, **kwargs):
+        on_solved = kwargs.get("on_solved")
+        if on_solved is not None:
+            seen = collections.Counter()
+            solves.append(seen)
+
+            def once(i, result):
+                seen[i] += 1
+                on_solved(i, result)
+            kwargs["on_solved"] = once
+        return solve_batch(self, items, **kwargs)
+
+    monkeypatch.setattr(PowDispatcher, "solve_batch", counting)
+    lines = []
+    result = asyncio.run(harness.run_cell(
+        harness.load(tree, BURST), 2**31 + 42, 1.0, True,
+        lines.append, t_start=time.monotonic()))
+    assert result["correct"] is True, lines
+    assert result["failed"] == 0 and result["attempted"] >= 12
+    verdict = result["window"].verdict
+    assert {k: v["value"] for k, v in verdict["compared"].items()} \
+        == {"invalid_nonces": 0, "undelivered": 0, "off_tier": 0}
+    assert solves and all(n == 1 for seen in solves
+                          for n in seen.values())
+    counters = result["window"].counters
+    copies = counters.delta("pow_pipeline_copies_total")
+    assert sum(copies.values()) > 0, copies
+    assert set(copies) <= {("batch", "won"), ("batch", "cancelled")}
+    metrics = {k: v["value"] for k, v in result["metrics"].items()}
+    # an ack and a message a send, each through a freed slot or the
+    # solve's start: copies are not among the refills
+    assert metrics["slot_refills_per_msg.pod4"] <= 2.0
+    assert metrics["off_device_solves"] == 0
+    assert 0 < metrics["useful_trial_share.pod4"] <= 100

@@ -191,22 +191,34 @@ CASES = {
 }
 
 
-def _foreseen(script, shares, turns, width, slab, step_trials):
+#: launches that went out ahead of an unread one of their group and
+#: were read all the same: for each launch of the case, how many had
+#: been read when it was dispatched (every other case: all before it)
+SENT_AFTER = {"batched group with one live is not": [0, 0, 1]}
+
+
+def _foreseen(script, shares, turns, width, slab, step_trials,
+              sent_after=None):
     """What reading ``script``'s launches in order does, object by
     object: trials credited, ends of miss-free slabs reported, trials
-    executed (a solved or pad slot still runs one always-hit step).
+    executed (a slot that was solved or pad when its launch went out
+    runs one always-hit step; one that was resolved while the launch
+    was in flight ran what the launch reports, credited to nobody).
     ``shares`` are the items of each group, ``turns`` the group of each
     launch."""
     n = sum(len(share) for share in shares)
     credit, reported, executed = [0] * n, [], 0
     live = set(range(n))
+    live_after = [set(live)]        # after 0, 1, ... launches read
     turn = [0] * len(shares)
-    for step, g in zip(script, turns):
+    for k, (step, g) in enumerate(zip(script, turns)):
         turn[g] += 1
         executed += (width - len(shares[g])) * step_trials      # pad
+        sent_live = live_after[sent_after[k] if sent_after else k]
         for i in shares[g]:
             if i not in live:
-                executed += step_trials
+                executed += ((_of(step, i) or slab // step_trials)
+                             if i in sent_live else 1) * step_trials
             elif _of(step, i):
                 credit[i] += _of(step, i) * step_trials
                 executed += _of(step, i) * step_trials
@@ -215,6 +227,7 @@ def _foreseen(script, shares, turns, width, slab, step_trials):
                 credit[i] += slab
                 executed += slab
                 reported.append((i, turn[g] * slab))
+        live_after.append(set(live))
     return credit, reported, executed
 
 
@@ -257,7 +270,8 @@ def test_each_mode_launches_what_its_targets_call_for(case, monkeypatch):
     # a search really ran
     read = launches - abandoned
     credit, checkpoints, executed = _foreseen(
-        script[:read], shares, turns[:read], width, slab, step_trials)
+        script[:read], shares, turns[:read], width, slab, step_trials,
+        SENT_AFTER.get(case))
     assert reported == checkpoints
     assert results == list(zip(winners, credit))
     assert grown["pow_pipeline_executed_trials_total"] == executed

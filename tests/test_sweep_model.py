@@ -4,7 +4,9 @@ The numbers are the driver's, on the shape those runs launched (128
 chunks of four tiles): ``pod4_burst_64`` from PR 39's line (parent
 side: 6f56bca), ``burst_send_64`` from PR 38's (change side, the same
 commit).  A model that stops reading them has lost a mechanism, or the
-code it mirrors has gained one.
+code it mirrors has gained one.  ``NEWER`` holds it to the shape of
+PR 40 (1,024 steps of one tile) without the copies of PR 42 and with
+them.
 """
 
 import os
@@ -22,10 +24,63 @@ LEDGER = {
 }
 
 
+#: (cell, copy rule): sent_msgs_per_s, pow_wait_ms, useful_trial_share
+#: at the shape of PR 40: the driver's lines of PR 41 (the middle of
+#: its two sides), and with the copies my chip runs of PR 42 (PERF.md
+#: section 6) until the ledger has that PR's line
+NEWER = {
+    ("pod4_burst_64", "none"): (30.876, 538.2, 96.53),  # ledger, PR 41
+    ("burst_send_64", "none"): (10.283, 2051.9, 99.12),  # ledger, PR 41
+    # my chip runs, PR 42: the median of three untraced runs, the mean
+    # of two traced ones
+    ("pod4_burst_64", "one"): (36.630, 542.3, 93.31),
+    ("burst_send_64", "one"): (10.283, 2051.9, 99.12),   # one lane
+}
+
+
 @pytest.fixture(scope="module")
 def readings():
-    return {cell: sweep_model.read(cell, "old", sweeps=30, seeds=(1, 2))
+    return {cell: sweep_model.read(cell, "old", sweeps=30, seeds=(1, 2),
+                                   copies="none")
             for cell in LEDGER}
+
+
+@pytest.fixture(scope="module")
+def rules():
+    """``pod4_burst_64`` at today's shape under each copy rule."""
+    return {rule: sweep_model.read("pod4_burst_64", "new", sweeps=30,
+                                   seeds=(1, 2), copies=rule)
+            for rule in sweep_model.COPY_RULES}
+
+
+@pytest.mark.parametrize("cell,rule", sorted(NEWER))
+def test_the_model_reads_todays_shape_within_a_tenth(cell, rule, rules):
+    got = rules[rule] if cell == "pod4_burst_64" else sweep_model.read(
+        cell, "new", sweeps=30, seeds=(1, 2), copies=rule)
+    for name, want in zip(("sent_msgs_per_s", "pow_wait_ms",
+                           "useful_trial_share"), NEWER[cell, rule]):
+        assert got[name] == pytest.approx(want, rel=0.10), name
+
+
+def test_why_the_rule_is_one_object_a_turn(rules):
+    """ISSUE 42's table: one object a starved turn turns the tail into
+    search; greedy copies turn four chips into four copies of one
+    queue and lose to no copies at all."""
+    rate = {rule: r["sent_msgs_per_s"] for rule, r in rules.items()}
+    assert rate["one"] > rate["half"] > rate["none"] > rate["all"]
+    assert rate["one"] > 1.10 * rate["none"]
+    assert rules["none"]["copies_per_sweep"] == 0
+    assert 17 < rules["none"]["device_idle_share"] < 25
+    assert rules["one"]["device_idle_share"] < 8
+    # what the copies cost: the losers' launches in flight at the win
+    assert 85 < rules["one"]["useful_trial_share"] \
+        < rules["none"]["useful_trial_share"]
+    assert rules["all"]["useful_trial_share"] < 75
+    assert 6 < rules["one"]["copies_per_sweep"] < 20
+    assert 40 < rules["one"]["copies_won_share"] < 70
+    with pytest.raises(ValueError):
+        sweep_model.simulate(4, sweep_model.SHAPES["new"], sweeps=1,
+                             copies="some")
 
 
 @pytest.mark.parametrize("cell", list(LEDGER))

@@ -7,7 +7,9 @@ groups of 64 slots, two a chip), ``_PipelineDriver.run`` (depth 2, the
 device with the fewest launches in flight and then the least to do
 asked first, a device's groups in turn, ``take_in`` before a group's
 launch once its launches are all read, ``worth_speculating`` when every
-unfinished group of a device has a launch unread), results visible
+unfinished group of a device has a launch unread, a nonce-range copy
+of another chip's object when the device has nothing live: one object a
+turn, first hit wins, the other slots cancelled), results visible
 only when a launch is harvested, and the kernel's grid (an object
 leaves at its hit, a solved or pad slot after one step, every further
 grid step of theirs skipped).  Around it: one pipeline thread that
@@ -15,13 +17,16 @@ pays for every launch and harvest, one crypto thread that seals one
 message at a time, and a closed loop of sweeps.
 
 What it is for: sizing a change to this path before it is written
-(ISSUE 40 sized the kernel's step with it; the sweep's tail, nonce-range
-copies for a chip that has run out, is next).  Two hand reckonings of
+(ISSUE 40 sized the kernel's step with it, ISSUE 42 the nonce-range
+copies for a chip that has run out: ``--copies none,one,half,all`` are
+the four rules it weighed, and why the rule is one object a turn).  Two
+hand reckonings of
 ``pod4_burst_64`` were off by 1.6 times and by a whole PR (PERF.md
 section 6, PR 38 and PR 39).  ``tests/test_sweep_model.py`` holds it to
 the ledger's two burst cells.  No cell runs it.
 
     python3 tools/sweep_model.py [--shape old,new,16k,grid] [--sweeps 40]
+                                 [--copies none,one,half,all]
 """
 
 from __future__ import annotations
@@ -65,6 +70,11 @@ START_MS = 3.0
 ACK_TRIALS, TRIALS_PER_BYTE, MSG_OVERHEAD = 1.08e7, 6273.0, 450
 BODY_BYTES = ((0.60, 200, 800), (0.35, 800, 3000), (0.05, 3000, 8000))
 SPECULATE_BELOW = 1.0 / 32
+#: what a lane with nothing live and nothing from the queue takes from
+#: the lane with the most unresolved objects, a turn: nothing (the tree
+#: until PR 41), one object (``solve_batch_pipelined``'s rule since
+#: PR 42), half of that lane's objects, all of them
+COPY_RULES = ("none", "one", "half", "all")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,10 +107,11 @@ SHAPES = {"old": Shape(65536, 128, 1, 290.55),      # PR 24-39
 
 
 class _Slot:
-    __slots__ = ("req", "need", "mean", "done")
+    __slots__ = ("req", "need", "mean", "done", "copy")
 
     def __init__(self):
         self.req, self.need, self.mean, self.done = None, 0.0, 1.0, True
+        self.copy = False
 
 
 class _Group:
@@ -114,9 +125,12 @@ class _Group:
 
 def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
              launch_ms: float = LAUNCH_MS, harvest_ms: float = HARVEST_MS,
-             sends: int = 64) -> dict:
+             sends: int = 64, copies: str = "one") -> dict:
     """``sweeps`` closed-loop sweeps of ``sends`` messages, acks on, on
-    ``chips`` chips: what the benchmark's readers would read."""
+    ``chips`` chips: what the benchmark's readers would read.
+    ``copies`` is one of :data:`COPY_RULES`."""
+    if copies not in COPY_RULES:
+        raise ValueError("copies is one of %s" % (COPY_RULES,))
     rng = random.Random(seed)
     groups = [_Group(k % chips) for k in range(GROUPS_PER_CHIP * chips)]
     lanes = [[g for g in groups if g.chip == k] for k in range(chips)]
@@ -126,7 +140,9 @@ def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
     busy = [0.0] * chips
     arrivals: list = []       # (t, kind, send, mean) not yet fed, by time
     waits, stat = [], dict(computed=0.0, needed=0.0, launches=0, live=0,
-                           launch_ms=0.0, speculated=0)
+                           launch_ms=0.0, speculated=0, copies=0, won=0)
+    #: id of an unresolved request -> the slots that search it
+    held: dict = {}
     t = 0.0
     crypto_free = 0.0
     ended = 0
@@ -151,10 +167,34 @@ def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
             if not arrivals or arrivals[0][0] > t:
                 break
             if s.done:
-                s.req = arrivals.pop(0)
-                s.mean = s.req[3]
-                s.need = rng.expovariate(1.0 / s.mean)
-                s.done = False
+                search(s, arrivals.pop(0), copy=False)
+
+    def search(s, req, copy):
+        # the search is memoryless: a copy's disjoint range needs a
+        # draw of its own
+        s.req, s.mean, s.copy, s.done = req, req[3], copy, False
+        s.need = rng.expovariate(1.0 / s.mean)
+        held.setdefault(id(req), []).append(s)
+
+    def take_copies(lane):
+        """The copy rule of a lane with nothing live: the group that
+        took them, or None."""
+        g = next((g for g in lanes[lane] if not g.unread), None)
+        of_lane = [{id(s.req): s.req for h in mine for s in h.slots
+                    if not s.done} for mine in lanes]
+        donor = max(of_lane, key=len)
+        if g is None or not donor:
+            return None
+        # the object the fewest lanes search first, of those the hardest
+        order = sorted(donor.values(), key=lambda r: (
+            sum(id(r) in objs for objs in of_lane), -r[3]))
+        take = {"one": 1, "half": -(-len(order) // 2),
+                "all": len(order)}[copies]
+        free = [s for s in g.slots if s.done]
+        for s, req in zip(free, order[:take]):
+            search(s, req, copy=True)
+            stat["copies"] += 1
+        return g
 
     def speculate(mine):
         for g in mine:
@@ -180,7 +220,12 @@ def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
                 cand = g
                 turn[lane] = (turn[lane] + off + 1) % len(mine)
                 break
-        if cand is None:
+        if cand is None and copies != "none" and chips > 1 \
+                and not any(g.live() for g in mine):
+            cand = take_copies(lane)
+            if cand is None:
+                return False
+        elif cand is None:
             cand = speculate(mine)
             if cand is None:
                 return False
@@ -223,7 +268,10 @@ def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
                 stat["needed"] += shape.slab
                 continue
             stat["needed"] += need
-            s.done = True
+            # first hit wins: every slot of the object is retired
+            for other in held.pop(id(req)):
+                other.done = True
+            stat["won"] += s.copy
             waits.append(t + BACK_MS - req[0])
             if req[1] == "ack":
                 crypto_free = max(crypto_free, t + BACK_MS) + SEAL_MS
@@ -262,6 +310,8 @@ def simulate(chips: int, shape: Shape, sweeps: int = 40, seed: int = 1,
         "launch_ms": stat["launch_ms"] / stat["launches"],
         "speculated_launch_share": 100.0 * stat["speculated"]
         / stat["launches"],
+        "copies_per_sweep": stat["copies"] / sweeps,
+        "copies_won_share": 100.0 * stat["won"] / max(stat["copies"], 1),
     }
 
 
@@ -272,23 +322,27 @@ CELLS = {"pod4_burst_64": dict(chips=4),
 
 
 def read(cell: str, shape: str = "old", sweeps: int = 40,
-         seeds=(1, 2, 3)) -> dict:
+         seeds=(1, 2, 3), copies: str = "one") -> dict:
     """The model's reading of ``cell``, the mean over ``seeds``."""
     runs = [simulate(shape=SHAPES[shape], sweeps=sweeps, seed=s,
-                     **CELLS[cell]) for s in seeds]
+                     copies=copies, **CELLS[cell]) for s in seeds]
     return {k: sum(r[k] for r in runs) / len(runs) for k in runs[0]}
 
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--shape", default="old,new")
+    ap.add_argument("--shape", default="new")
     ap.add_argument("--sweeps", type=int, default=40)
+    ap.add_argument("--copies", default="none,one",
+                    help="of %s" % ",".join(COPY_RULES))
     args = ap.parse_args(argv)
     for cell in CELLS:
         for shape in args.shape.split(","):
-            row = read(cell, shape, args.sweeps)
-            print(json.dumps({"cell": cell, "shape": shape,
-                              **{k: round(v, 2) for k, v in row.items()}}))
+            for copies in args.copies.split(","):
+                row = read(cell, shape, args.sweeps, copies=copies)
+                print(json.dumps({
+                    "cell": cell, "shape": shape, "copies": copies,
+                    **{k: round(v, 2) for k, v in row.items()}}))
     return 0
 
 
