@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import argparse
 import asyncio
-import functools
+import collections
 import hashlib
 import json
 import os
@@ -502,48 +502,14 @@ def _valid(item, result) -> bool:
     return hashlib_trial(result[0].to_bytes(8, "big"), ih) <= target
 
 
-def compile_side_by_side(rep: Report, programs) -> None:
-    """Lower and compile ``[(label, jitted fn, example args)]``, each on
-    its own thread.  The pod path has four Mosaic programs of minutes
-    each (two meshes x single/batch) and a four-chip host is charged
-    four times over while they compile one after another; a compiled
-    program is found again by the solve that calls it."""
-    import threading
-    took: dict[str, float] = {}
-    errors: dict[str, BaseException] = {}
-
-    def work(label, fn, args):
-        t0 = time.monotonic()
-        try:
-            fn.lower(*args).compile()
-        except BaseException as exc:     # reported below, by name
-            errors[label] = exc
-        took[label] = time.monotonic() - t0
-
-    threads = [threading.Thread(target=work, args=p, daemon=True)
-               for p in programs]
-    t0 = time.monotonic()
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    for label, _, _ in programs:
-        rep.check(label not in errors, "compiled %s in %.1fs %s"
-                  % (label, took[label], errors.get(label, "")))
-    rep.say("four programs compiled side by side: %.1fs wall"
-            % (time.monotonic() - t0))
-
-
 def run_pod(rep: Report) -> None:
     """Through PowDispatcher on every chip: default-difficulty objects
-    one at a time, each nonce range partitioned over the chips, and a
-    64-object queue, its launch groups placed over them; and the same
-    objects on one device to compare with."""
+    one at a time, each object's nonce space shared out over the
+    pipeline's lanes (a chip each, first hit wins), and a 64-object
+    queue, its launch groups placed over them; and the same objects on
+    one device to compare with.  No ``shard_map`` program is lowered."""
     import jax
 
-    from pybitmessage_tpu.ops.sha512_pallas import LANE_COLS
-    from pybitmessage_tpu.parallel import (make_mesh, pallas_sharded_solve,
-                                           pow_pallas_sharded)
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
     from pybitmessage_tpu.pow.pipeline import solve_batch_pipelined
 
@@ -564,51 +530,41 @@ def run_pod(rep: Report) -> None:
         rep.say("%s: %.1fs wall" % (label, dt))
         return out, dt
 
-    # the partitioned search's two programs (pod mesh and one-device
-    # mesh) at the shape the product launches by default
-    import jax.numpy as jnp
-    mesh1 = make_mesh(1)
-    impl = pow_pallas_sharded.default_impl()
-    u32 = functools.partial(jnp.zeros, dtype=jnp.uint32)
-    kw = pallas_sharded_solve.__kwdefaults__
-    programs = []
-    for mesh in (d._mesh(ndev, 1), mesh1):
-        fn = pow_pallas_sharded._get_fn(
-            mesh, "single", kw["rows"], kw["chunks_per_call"],
-            kw["unroll"], impl, False, kw["variant"])
-        programs.append(("single on %d device(s)" % mesh.devices.size, fn,
-                         (u32((8, 2)), u32((2,)), u32((2,)))))
-    compile_side_by_side(rep, programs)
+    def alone(item):
+        # one chip's lone object, as ``single_send`` solves it
+        return solve_batch_pipelined([item])[0]
 
+    # a lone object is the pipeline's too: the rung of one chip, given
+    # every chip; lane k searches from the object's start plus k shares
+    # of the nonce space, and the first harvest with a hit wins
+    wins, share = "pow_pipeline_lone_wins_total", (1 << 64) // ndev
     warm = _default_item(b"pod warm", 1016)
     timed("first solve, single, %d devices" % ndev, lambda: d(*warm))
-    rep.check(d.last_backend == "tpu-pallas-sharded",
+    rep.check(d.last_backend == "tpu-pallas",
               "single solve backend %r" % d.last_backend)
-    timed("first solve, single, 1 device",
-          lambda: pallas_sharded_solve(*warm, mesh1))
-
-    # one device's share of a pod launch, from the shape the product
-    # launches by default: device d of a launch searches
-    # [base + d*slab, base + (d+1)*slab)
-    shape = pallas_sharded_solve.__kwdefaults__
-    slab = (shape["rows"] * LANE_COLS * shape["chunks_per_call"]
-            * shape["unroll"])
+    timed("first solve, single, 1 device", lambda: alone(warm))
+    before = _family(wins)
     res_n, dt_n = timed("single x%d, %d devices" % (len(singles), ndev),
                         lambda: [d(*it) for it in singles])
+    won = {k[0]: int(v - before.get(k, 0))
+           for k, v in sorted(_family(wins).items())
+           if v != before.get(k, 0)}
     res_1, dt_1 = timed("single x%d, 1 device" % len(singles),
-                        lambda: [pallas_sharded_solve(*it, mesh1)
-                                 for it in singles])
+                        lambda: [alone(it) for it in singles])
     rep.check(all(_valid(it, r) for it, r in zip(singles, res_n))
               and all(_valid(it, r) for it, r in zip(singles, res_1)),
-              "single nonces valid by hashlib on both meshes")
-    winners = sorted({(r[0] % (ndev * slab)) // slab for r in res_n})
-    rep.check(len(winners) > 1,
-              "winners came from more than one device index: %s"
-              % winners)
-    for label, res, dt in (("%d devices" % ndev, res_n, dt_n),
-                           ("1 device", res_1, dt_1)):
-        rep.say("smoke timing, single, %s: %.0f trials/s"
-                % (label, sum(r[1] for r in res) / dt))
+              "single nonces valid by hashlib on %d devices and on one"
+              % ndev)
+    rep.check(sum(won.values()) == len(singles)
+              and won == dict(collections.Counter(
+                  "%d" % (r[0] // share) for r in res_n)),
+              "every single solve was laid out over the %d lanes and "
+              "credited the lane whose share its nonce lies in: %s"
+              % (ndev, won))
+    rep.check(len(won) > 1,
+              "winners came from more than one lane: %s" % sorted(won))
+    rep.say("smoke timing, single x%d: %d lanes %.2fs wall, 1 device "
+            "%.2fs wall" % (len(singles), ndev, dt_n, dt_1))
 
     # a queue is the pipeline's on any number of chips: its launch
     # groups dealt over them, an object's own range on one chip and a

@@ -1,15 +1,16 @@
 """Pod-sharded PoW built on the production Pallas kernel.
 
-What the node calls here is :func:`pallas_sharded_solve`: an object
-that is alone on a host of several chips has its nonce range
-partitioned over all of them (``PowDispatcher._solve_on_device``).  A
-QUEUE on such a host is not this module's any more: it goes through
-``pow/pipeline.py``, whose launch groups are dealt over the chips, an
-object's own nonce range on one chip and copies of a straggler's on
-the chips that have run out (docs/pow_pipeline.md, "A solve placed
-over several chips").  :func:`pallas_sharded_solve_batch`, the
-2D (objects x nonce-range) loop, is left with no caller in the node
-(``tools/tpu_doctor.py`` and the tests call it; ROADMAP D10).
+Nothing in the node calls this module any more (ROADMAP D10).  On a
+host of several chips everything goes through ``pow/pipeline.py``: a
+queue's launch groups are dealt over the chips, an object's own nonce
+range on one chip and copies of a straggler's on the chips that have
+run out, and since PR 43 an object that is alone has its nonce space
+shared out over the driver's lanes (docs/pow_pipeline.md, "One object
+on several chips"), where it came to :func:`pallas_sharded_solve`
+before.  That and :func:`pallas_sharded_solve_batch`, the 2D (objects
+x nonce-range) loop, are left to ``tools/tpu_doctor.py``,
+``__graft_entry__.py``, ``bench.py`` and the tests until a
+``simplicity`` PR takes them out.
 
 The per-chip slab is the SAME Mosaic kernel the single-chip tier runs
 (``ops/sha512_pallas.py``): a ``pl.pallas_call`` per device under

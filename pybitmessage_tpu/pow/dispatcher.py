@@ -45,11 +45,7 @@ import time
 from typing import Callable, NamedTuple
 
 # imported with the dispatcher and not at a rung's first call: that
-# call is a solve somebody waits for, and ``parallel.pow_pallas_sharded``
-# binds the Mosaic kernels by name for its ``shard_map`` bodies, which
-# run under a trace, so it takes them as they are when the node starts
-# and not as whatever may have wrapped them since (a wrapper that keeps
-# a launch's outputs would keep tracers there)
+# call is a solve somebody waits for
 from .. import parallel
 from ..observability import REGISTRY, trace
 from ..ops.pow_search import PowInterrupted
@@ -125,16 +121,13 @@ _XLA_SHARDED_BATCH = _Rung(
     "tpu-batch", "tpu", (), True, "tpu-batch", "ladder",
     "batched TPU PoW failed; falling back to per-object solves")
 # one object: the ``tpu`` rung holds the topology probe, the Mosaic
-# rung of that topology (its own breaker inside this one) and the XLA
-# search, whose failures are this rung's
+# rung (the pipeline with a batch of one, over every chip; its own
+# breaker inside this one) and the XLA search, whose failures are this
+# rung's
 _TPU = _Rung(
     None, "tpu", (), True, "tpu", None,
     "TPU PoW failed; falling through to C++ (breaker open, half-open "
     "probe after cooldown)")
-_PALLAS_SHARDED = _Rung(
-    "tpu-pallas-sharded", "tpu-pallas", ("tpu",), False,
-    "tpu-pallas", "tpu-xla",
-    "sharded Pallas PoW failed; using sharded XLA search")
 _PALLAS = _Rung(
     "tpu-pallas", "tpu-pallas", ("tpu",), False, "tpu-pallas", "tpu-xla",
     "Pallas PoW failed; using XLA search")
@@ -187,11 +180,12 @@ class PowDispatcher:
     On an accelerator a queue goes through
     ``pow.pipeline.solve_batch_pipelined`` however many chips there
     are: its launch groups are dealt over them, an object's whole nonce
-    range on one chip.  On one chip a lone object goes the same way;
-    with several, a lone object's nonce range is partitioned across
-    the whole mesh (``pallas_sharded_solve``, then ``sharded_solve``).
-    Several devices that are no accelerator (a CPU mesh) take a queue
-    as one XLA search on a 2D (objects x nonce-range) mesh.
+    range on one chip.  A lone object goes the same way: on one chip a
+    batch of one, on several its nonce space shared out over the
+    pipeline's lanes, a share a chip, first hit wins.  Several devices
+    that are no accelerator (a CPU mesh) take a queue as one XLA search
+    on a 2D (objects x nonce-range) mesh, and a lone object as one
+    partitioned over the mesh (``sharded_solve``).
 
     Timing attributes (also exported through the metrics registry):
 
@@ -271,6 +265,13 @@ class PowDispatcher:
                 "ladder")
             _note_fallback("tpu", "ladder")
             return 0, False
+
+    @staticmethod
+    def _placement(ndev: int):
+        """The devices the pipeline places a solve over: every chip
+        where there are several, else None (JAX's default device)."""
+        import jax
+        return jax.devices()[:ndev] if ndev > 1 else None
 
     def _record_recovery(self) -> None:
         """A solve completed after a slab stall: export how long the
@@ -590,14 +591,12 @@ class PowDispatcher:
                 should_stop=should_stop, **self._xla_kwargs())
 
         def pipeline_batch():
-            import jax
-
             from .pipeline import solve_batch_pipelined
             return solve_batch_pipelined(
                 items, should_stop=should_stop, start_nonces=starts,
                 progress=progress, stall_timeout=self.stall_timeout,
                 on_solved=on_solved, feed=feed, expect=expect,
-                devices=jax.devices()[:ndev] if ndev > 1 else None)
+                devices=self._placement(ndev))
 
         if on_accel:
             yield _PIPELINE_BATCH, pipeline_batch
@@ -629,18 +628,12 @@ class PowDispatcher:
 
     def _solve_on_device(self, item, start_nonce, should_stop, progress):
         """The body of one object's ``tpu`` rung: on an accelerator the
-        Mosaic rung of this topology — on several chips the search
-        sharded by nonce range over all of them (an object that is
-        alone has nobody to share the chips with), on one chip the
-        pipeline with a batch of one — then the XLA search."""
+        Mosaic rung — the pipeline with a batch of one, given every
+        chip where there are several (an object that is alone has
+        nobody to share them with: each searches a share of its nonce
+        space) — then the XLA search."""
         initial_hash, target = item
         ndev = self._device_count()
-
-        def pallas_sharded():
-            return parallel.pallas_sharded_solve(
-                initial_hash, target, self._mesh(ndev, 1),
-                start_nonce=start_nonce, should_stop=should_stop,
-                progress=progress)
 
         def pipeline_one():
             from .pipeline import solve_batch_pipelined
@@ -649,12 +642,11 @@ class PowDispatcher:
                 start_nonces=[start_nonce],
                 progress=(None if progress is None
                           else lambda _i, nxt: progress(nxt)),
-                stall_timeout=self.stall_timeout)[0]
+                stall_timeout=self.stall_timeout,
+                devices=self._placement(ndev))[0]
 
         if self._on_accelerator():
-            result = (self._run_rung(_PALLAS_SHARDED, pallas_sharded)
-                      if ndev > 1 else
-                      self._run_rung(_PALLAS, pipeline_one))
+            result = self._run_rung(_PALLAS, pipeline_one)
             if result is not None:
                 return result
         if ndev > 1:

@@ -18,7 +18,9 @@ Four plan modes, one loop:
 ``slab``         one object alone at network difficulty (a send of
                  ``single_send``): ``pallas_search`` at 128 x 512 x 5,
                  a second slab in flight only for an object hard
-                 enough to be unlikely to hit in the first;
+                 enough to be unlikely to hit in the first; on several
+                 chips every chip searches a share of the nonce space
+                 of its own in shorter slabs, and the first hit wins;
 ``batched``      a queue of objects (``chan_storm_256``): the
                  per-object grid ``pallas_batch_search``, 64 objects a
                  launch at 1,024 steps of one tile of 64 rows;
@@ -43,7 +45,10 @@ object's own nonce range with them, and each chip has its own launches
 in flight.  A chip that has run out takes a nonce-range copy of
 another chip's unresolved object, one a turn; the first slot to hit
 resolves the object and every other slot of it is cancelled
-(docs/pow_pipeline.md, "A solve placed over several chips").
+(docs/pow_pipeline.md, "A solve placed over several chips").  An
+object that is alone there (mode ``slab``) is laid out over every chip
+from its first launch on, the same way: one slot a chip, each at its
+own :func:`_copy_base`.
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -184,6 +189,11 @@ COPIES = REGISTRY.counter(
     "copy's slot is retired: its own slot found the nonce (won) or "
     "another slot of the object did (cancelled); not in "
     "pow_pipeline_refills_total", ("kind", "outcome"))
+LONE_WINS = REGISTRY.counter(
+    "pow_pipeline_lone_wins_total",
+    "Solves of one object laid out over several devices' lanes, by the "
+    "lane whose launch found the nonce (0: the lane of the object's own "
+    "range, which a resumed search goes on from)", ("lane",))
 SLOTS = REGISTRY.counter(
     "pow_pipeline_slots_total",
     "Slots of the launches dispatched: those that searched (live) and "
@@ -402,6 +412,20 @@ PACKED_GROUPS_MAX = 64
 #: a single object expected to finish inside this many full-tile grid
 #: steps takes one small launch at a time (mode ``single-sync``)
 SYNC_SINGLE_STEPS = 8
+#: grid steps of ``pallas_search`` that the lanes of a lone object on
+#: several chips launch TOGETHER, shared out evenly: 64 a chip on four
+#: (5.2e6 trials, 18 ms).  A lane's launch runs on to its OWN hit or its
+#: end after another lane has won, and the next solve's launch on that
+#: chip queues behind it, so a launch must not outlast the host's work
+#: between two solves by much (7-8 ms in ``single_send``); all lanes
+#: together it still holds a network-default object's expected work
+#: (1.1-1.6e7 trials) in one launch each, and an object so hard that
+#: it needs many has its next launch dispatched ahead.  Measured on
+#: four v5e chips, 200 lone solves at ``single_send``'s difficulties,
+#: pairs of solves a second by steps a lane: 32: 18.05, 64: 18.66,
+#: 128: 15.45, 512: 10.26; one chip at 512: 9.06 (PERF.md section 6,
+#: PR 43)
+LONE_LANES_CHUNKS = 256
 #: launch groups a ``batched`` solve of at most one launch's objects
 #: is laid out as: with two, the round-robin always finds a group with
 #: no unread launch, so the device stays busy without speculation, as
@@ -427,18 +451,22 @@ class BatchPlan:
 
 
 def plan_batch(items, *, rows: int = DEFAULT_ROWS,
-               unroll: int = 1, expect: int = 0) -> BatchPlan:
+               unroll: int = 1, expect: int = 0,
+               lanes: int = 1) -> BatchPlan:
     """Choose the kernel and its geometry from the batch's size and
     difficulty — the only place that does.  ``expect`` is the number of
     objects the solve is to be laid out for, where more are announced
     than are there: the mode is then that of a queue of ``expect``
     objects (one object with announced company is a queue, not a lone
-    object), read from the targets that are there.
+    object), read from the targets that are there.  ``lanes`` is the
+    number of devices the solve may be placed over.
 
     One object alone searches whole slabs of ``pallas_search`` (mode
-    ``slab``), or, when it is expected to finish inside
-    ``SYNC_SINGLE_STEPS`` grid steps, takes one small launch at a time
-    (``single-sync``).  For a queue the pack factor is sized so one
+    ``slab``; on several ``lanes`` each searches a share of the nonce
+    space in slabs of ``LONE_LANES_CHUNKS / lanes`` steps), or, when it
+    is expected to finish inside ``SYNC_SINGLE_STEPS`` grid steps, takes
+    one small launch at a time (``single-sync``).  For a queue the pack
+    factor is sized so one
     launch covers roughly every object's expected work: tiny (storm)
     objects pack 16 per tile and network-default objects keep whole
     tiles (pack=1 -> the per-object batch kernel).  Objects are
@@ -457,8 +485,12 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS,
         # 512 is the largest power of two a v5e compiles for
         # pallas_search (1024 asks for 1.01M of its 1.00M of SMEM;
         # tests/test_tpu_compile.py), and the grid leaves at its first
-        # hit, so a long slab costs a short solve nothing
-        return BatchPlan("slab", 1, DEFAULT_CHUNKS, [0])
+        # hit, so a long slab costs a short solve nothing; on several
+        # lanes it costs the next solve the losers' run to their own
+        # hits, so their slabs are short
+        chunks = DEFAULT_CHUNKS if lanes == 1 else max(
+            LONE_LANES_CHUNKS // lanes, 1)
+        return BatchPlan("slab", 1, chunks, [0])
     order = sorted(range(there), key=lambda i: exp[i])
     med = sorted(exp)[there // 2]
     for p in PACK_CHOICES:
@@ -1037,6 +1069,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     only with nothing live, because four chips that copy greedily are
     four copies of one queue (PERF.md section 6, PR 42).
 
+    ONE object on several ``devices`` (mode ``slab``) is laid out the
+    same way from the start: a group of one slot on every device, lane
+    ``k``'s at :func:`_copy_base` ``(start, k, lanes)``, all launched in
+    the first turn.  The first harvest with a hit resolves the object
+    and the solve returns; what the other lanes' launches still search
+    is abandoned unread.  The speculation rule counts the unread
+    launches of every lane: none is dispatched ahead while those in
+    flight are likely to end the object.  ``progress`` and a resumed
+    ``start_nonces`` are lane 0's, the object's own range.
+
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
@@ -1061,7 +1103,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     if plan is None:
         with trace("pow.plan", objects=n, expect=expect) as span:
             plan = plan_batch(items, rows=rows, unroll=unroll,
-                              expect=expect)
+                              expect=expect, lanes=len(devices))
             span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
     mode, pack, chunks = plan.mode, plan.pack, plan.chunks
@@ -1069,6 +1111,10 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     pallas = impl == "pallas"
     if mode != "batched":
         feed = None             # only a queue of whole tiles takes in
+    if mode == "single-sync":
+        devices = devices[:1]   # one small launch at a time: one lane
+    #: one object shared out over the lanes, a slot of it on each
+    lone = mode == "slab" and len(devices) > 1
 
     # the launch geometry of each mode, and the jitted program it
     # launches with the static-shape key that decides compile-vs-cache
@@ -1117,26 +1163,34 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         per = -(-n // count)
         shares = [plan.order[s:s + per]
                   for s in range(0, per * count, per)]
+    elif lone:
+        shares = [plan.order] * len(devices)
     else:
         shares = [plan.order[s:s + width] for s in range(0, n, width)]
+    #: where each object's own range begins
+    starts = list(start_nonces) if start_nonces else [0] * n
     with trace("pow.groups", objects=n, width=width,
                devices=len(devices)):
         groups = [_LaunchGroup(items, share, width, starts=start_nonces,
                                unbatched=unbatched,
                                device=devices[j % len(devices)])
                   for j, share in enumerate(shares)]
+        if lone:
+            for k, g in enumerate(groups[1:], 1):
+                g.bases[0] = _copy_base(starts[0], k, len(devices))
+                g.copy[0] = True
     #: the groups of each device, and where its round-robin stands
     lanes = [groups[k::len(devices)] for k in range(len(devices))]
     rr = [0] * len(lanes)
     results: list = [None] * n
     executed = {"trials": 0, "launches": 0, "copies": 0}
-    #: where each object's own range begins
-    starts = list(start_nonces) if start_nonces else [0] * n
     #: unresolved item -> {lane: (group, slot)} of the slots that search
     #: it: its own first, then its copies, at most one a lane
-    held = {i: {j % len(lanes): (g, k)}
-            for j, g in enumerate(groups)
-            for k, i in enumerate(g.idx) if i is not None}
+    held: dict = {}
+    for j, g in enumerate(groups):
+        for k, i in enumerate(g.idx):
+            if i is not None:
+                held.setdefault(i, {})[j % len(lanes)] = (g, k)
     #: whether a lane that has run out has another lane to copy from
     may_copy = mode == "batched" and len(lanes) > 1
 
@@ -1192,7 +1246,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         for g in mine:
             if g.finished:
                 continue
-            ahead = worth_speculating(slab_trials * g.unread,
+            # a lone object is searched by every lane's unread launches
+            unread = sum(h.unread for h in groups) if lone else g.unread
+            ahead = worth_speculating(slab_trials * unread,
                                       g.live_targets())
             SPECULATION.labels(
                 kind=kind,
@@ -1317,10 +1373,13 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                 results[i] = (nonce, sum(h.trials[s] for h, s in slots))
                 for h, s in slots:
                     h.retire(s)
-                    if h.copy[s]:
+                    if h.copy[s] and not lone:
                         COPIES.labels(
                             kind=kind, outcome="won" if h is g
                             else "cancelled").inc()
+                if lone:
+                    # bounded by the host's device count
+                    LONE_WINS.labels(lane="%d" % _lane).inc()  # bmlint: allow(metric-labels)
                 if on_solved is not None:
                     on_solved(i, results[i])
             else:

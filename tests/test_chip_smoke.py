@@ -174,12 +174,16 @@ def test_cache_dir_defaults_to_a_fixed_path_in_the_checkout(monkeypatch):
 
 
 def test_pod_path_rehearsal_on_virtual_devices(monkeypatch, capsys):
-    """--chips 4's phase on the virtual CPU devices: the dispatcher's
-    partitioned search and the pipeline placed over the devices, with
-    their XLA stand-ins at a tiny tile."""
-    from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
+    """--chips 4's phase on the virtual CPU devices: a lone object laid
+    out over the pipeline's lanes and a queue placed over the devices,
+    with their XLA stand-ins at a tiny tile; the ``shard_map``
+    partition is not called."""
+    from pybitmessage_tpu import parallel
     from pybitmessage_tpu.pow import pipeline
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
+
+    def never(*_a, **_kw):
+        raise AssertionError("the smoke called the shard_map partition")
 
     monkeypatch.setattr(chip_smoke, "NETWORK_NTPB", 1)
     monkeypatch.setattr(chip_smoke, "NETWORK_EXTRA", 1)
@@ -188,20 +192,23 @@ def test_pod_path_rehearsal_on_virtual_devices(monkeypatch, capsys):
     monkeypatch.setattr(chip_smoke, "POD_SINGLE_OBJECTS", 4)
     monkeypatch.setattr(PowDispatcher, "_on_accelerator",
                         lambda self: True)
-    for key, value in (("rows", 8), ("chunks_per_call", 4),
-                       ("unroll", 1)):
-        monkeypatch.setitem(pod.pallas_sharded_solve.__kwdefaults__,
-                            key, value)
+    monkeypatch.setattr(parallel, "pallas_sharded_solve", never)
+    monkeypatch.setattr(parallel, "make_mesh", never)
     monkeypatch.setitem(pipeline.solve_batch_pipelined.__kwdefaults__,
                         "rows", 8)
     # a queue of easy objects planned as one at network difficulty is:
-    # whole tiles, no packing
+    # whole tiles, no packing; and a lone one in slabs, a short one a
+    # lane
     monkeypatch.setattr(pipeline, "PACK_CHOICES", ())
     monkeypatch.setattr(pipeline, "DEFAULT_BATCH_CHUNKS", 4)
+    monkeypatch.setattr(pipeline, "SYNC_SINGLE_STEPS", 0)
+    monkeypatch.setattr(pipeline, "DEFAULT_CHUNKS", 16)
+    monkeypatch.setattr(pipeline, "LONE_LANES_CHUNKS", 16)
     rep = chip_smoke.Report()
     chip_smoke.run_pod(rep)
     out = capsys.readouterr().out
     assert rep.failures == [], out
-    assert "winners came from more than one device index" in out
+    assert "single solve backend 'tpu-pallas'" in out
+    assert "winners came from more than one lane" in out
     assert "'tpu-pallas-batch'" in out
     assert "every device took launches of the batch" in out

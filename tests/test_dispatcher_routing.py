@@ -24,27 +24,38 @@ def on_accelerator(monkeypatch):
                         lambda self: True)
 
 
-def test_multidev_solve_prefers_pallas_sharded(monkeypatch,
-                                               on_accelerator):
+def test_multidev_solve_takes_the_pipeline_with_every_chip(
+        monkeypatch, on_accelerator):
+    """A lone object on an accelerator of several chips is the
+    pipeline's, given the devices; the rung is the one chip's."""
+    import jax
+
     import pybitmessage_tpu.parallel as par
+    from pybitmessage_tpu.pow import pipeline
 
     calls = {}
 
-    def fake_sharded(ih, target, mesh, **kw):
-        calls["mesh_devices"] = mesh.devices.size
-        return 1234, 999
+    def fake_pipeline(items, *, devices=None, **kw):
+        calls["devices"] = devices
+        calls["start_nonces"] = kw["start_nonces"]
+        return [(1234, 999)]
 
-    monkeypatch.setattr(par, "pallas_sharded_solve", fake_sharded)
+    def never(*a, **k):
+        raise AssertionError("the shard_map partition was called")
+
+    monkeypatch.setattr(pipeline, "solve_batch_pipelined", fake_pipeline)
+    monkeypatch.setattr(par, "pallas_sharded_solve", never)
     d = PowDispatcher(use_native=False)
     nonce, trials = d.solve(IH, 2**60)
-    assert d.last_backend == "tpu-pallas-sharded"
+    assert d.last_backend == "tpu-pallas"
     assert (nonce, trials) == (1234, 999)
-    assert calls["mesh_devices"] == 8
+    assert calls["devices"] == jax.devices()
+    assert len(calls["devices"]) == 8 and calls["start_nonces"] == [0]
 
 
 def test_multidev_solve_falls_back_and_latches(monkeypatch,
                                                on_accelerator):
-    import pybitmessage_tpu.parallel as par
+    from pybitmessage_tpu.pow import pipeline
 
     attempts = {"n": 0}
 
@@ -52,7 +63,7 @@ def test_multidev_solve_falls_back_and_latches(monkeypatch,
         attempts["n"] += 1
         raise RuntimeError("mosaic compile failed")
 
-    monkeypatch.setattr(par, "pallas_sharded_solve", broken)
+    monkeypatch.setattr(pipeline, "solve_batch_pipelined", broken)
     d = PowDispatcher(use_native=False)
     nonce, _ = d.solve(IH, 2**60)          # falls through to XLA sharded
     assert d.last_backend == "tpu-sharded"

@@ -3,7 +3,7 @@
 scratch copy of the benchmark at test difficulty, with the dispatcher
 told that it has FOUR accelerator chips (four of the suite's virtual
 devices).  The queue streams through the pipeline placed over them and
-a lone object takes the nonce-range partition.  A second cell on the
+a lone object is laid out over its lanes (ISSUE 43).  A second cell on the
 same configuration sends bursts (``pod4_burst_64``'s generator) whose
 chips run out unevenly: the ones that have take nonce-range copies of
 the stragglers (ISSUE 42).
@@ -85,13 +85,11 @@ def tree(tmp_path_factory):
 def four_chips(one_chip, monkeypatch):  # noqa: F811
     """``test_queue_1k``'s stand-in for the chip, told that it has four
     of them: the suite's first four virtual devices."""
-    from pybitmessage_tpu.parallel import pow_pallas_sharded as pod
+    from pybitmessage_tpu.pow import pipeline
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
     monkeypatch.setattr(PowDispatcher, "_device_count", lambda self: 4)
-    for key, value in (("rows", 8), ("chunks_per_call", 4),
-                       ("unroll", 1)):
-        monkeypatch.setitem(pod.pallas_sharded_solve.__kwdefaults__,
-                            key, value)
+    # a lone object's four lanes launch one grid step each at this tile
+    monkeypatch.setattr(pipeline, "LONE_LANES_CHUNKS", 4)
 
 
 def test_the_cell_streams_over_four_devices_and_is_correct(tree,
@@ -109,19 +107,19 @@ def test_the_cell_streams_over_four_devices_and_is_correct(tree,
     verdict = result["window"].verdict
     assert {k: v["value"] for k, v in verdict["compared"].items()} \
         == {"invalid_nonces": 0, "undelivered": 0, "off_tier": 0}
-    # the queue on the pipeline, a lone object on the partition
+    # the queue on the pipeline, and a lone object too
     assert set(verdict["attempts_by_backend"]) <= {
-        "tpu-pallas-batch", "tpu-pallas-sharded"}
+        "tpu-pallas-batch", "tpu-pallas"}
     assert "tpu-pallas-batch" in verdict["attempts_by_backend"]
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     # every new metric but the two that need a device's planes
     assert set(NEW_LAYERS) - set(metrics) == {
         "kernel_mhash_per_s.pod4", "chip_busy_share_min"}
     assert metrics["off_device_solves"] == 0
-    # the partition's program is no shape the warm-up runs: an object
+    # a lone object's slab is no shape the warm-up need run: an object
     # left alone for the first time inside the window compiles it
     # there (PERF.md section 7, row 13)
-    if "tpu-pallas-sharded" not in verdict["attempts_by_backend"]:
+    if "tpu-pallas" not in verdict["attempts_by_backend"]:
         assert metrics["compiles_in_window"] == 0
     assert 0 < metrics["chip_launch_share_max"] < 100
     assert 0 < metrics["live_slot_share.pod4"] <= 100

@@ -1117,7 +1117,7 @@ def test_a_device_that_has_run_out_is_not_waited_for(monkeypatch):
 
 
 @pytest.mark.parametrize("ndev, accel, queue, lone", [
-    (4, True, ["tpu-pallas-batch", "tpu-batch"], "tpu-pallas-sharded"),
+    (4, True, ["tpu-pallas-batch", "tpu-batch"], "tpu-pallas"),
     (4, False, ["tpu-batch"], "tpu-sharded"),
     (1, True, ["tpu-pallas-batch"], "tpu-pallas"),
     (1, False, [], "tpu")])
@@ -1125,7 +1125,8 @@ def test_the_rungs_a_topology_admits(ndev, accel, queue, lone,
                                      monkeypatch):
     """A queue is offered the pipeline on an accelerator however many
     chips it has, with the devices where there are several; a lone
-    object on several chips still takes the nonce-range partition."""
+    object goes the same way, on the rung of one chip (PR 43), and the
+    XLA partition is left for several devices that are no accelerator."""
     import jax
 
     import pybitmessage_tpu.parallel as par
@@ -1149,13 +1150,16 @@ def test_the_rungs_a_topology_admits(ndev, accel, queue, lone,
         assert rungs[0][1]() == [(7, 1)] * 3
         assert seen["devices"] == (jax.devices()[:4] if ndev > 1
                                    else None)
-    # a lone object: the partition on several chips, the pipeline on one
-    for name in ("pallas_sharded_solve", "sharded_solve"):
-        monkeypatch.setattr(
-            par, name, lambda ih, target, mesh, **kw: (11, 1))
+    # a lone object: the pipeline on an accelerator, with its devices
+    monkeypatch.setattr(
+        par, "sharded_solve", lambda ih, target, mesh, **kw: (11, 1))
+    monkeypatch.setattr(par, "pallas_sharded_solve", None)
     from pybitmessage_tpu.ops import pow_search
     monkeypatch.setattr(pow_search, "solve",
                         lambda ih, target, **kw: (11, 1))
     (result,) = d.solve_batch([items[0]])
     assert d.last_backend == lone
     assert result == ((7, 1) if lone == "tpu-pallas" else (11, 1))
+    if accel:
+        assert seen["devices"] == (jax.devices()[:4] if ndev > 1
+                                   else None)

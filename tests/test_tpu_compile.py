@@ -121,6 +121,22 @@ def test_the_shape_the_pipeline_launches_compiles(one_chip):
     assert _has_kernel(compiled)
 
 
+@pytest.mark.parametrize("lanes", [2, 4, 8])
+def test_the_slab_a_lone_object_s_lanes_launch_compiles(one_chip, lanes):
+    """``plan_batch`` hands each lane of a lone object on several chips
+    ``pallas_search`` at ``LONE_LANES_CHUNKS / lanes`` steps (64 on the
+    four chips of a v5e host; PR 43): a shape of its own to lower."""
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+    from pybitmessage_tpu.pow.pipeline import plan_batch
+    plan = plan_batch([(b"\x00" * 64, 2 ** 64 // 10 ** 7)], lanes=lanes)
+    assert (plan.mode, plan.chunks) == ("slab", 256 // lanes)
+    compiled = sp.pallas_search.lower(
+        _u32((8, 2), one_chip), _u32((2,), one_chip),
+        _u32((2,), one_chip), rows=sp.DEFAULT_ROWS, chunks=plan.chunks,
+        unroll=sp.DEFAULT_UNROLL).compile()
+    assert _has_kernel(compiled)
+
+
 def test_pallas_search_has_no_larger_shape_on_a_v5e(one_chip):
     """Twice ``DEFAULT_CHUNKS`` is what PR 24's autotuner asked for at
     a node's second single solve: the chip's compiler refuses it (its
