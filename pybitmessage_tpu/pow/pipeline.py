@@ -194,6 +194,11 @@ LONE_WINS = REGISTRY.counter(
     "Solves of one object laid out over several devices' lanes, by the "
     "lane whose launch found the nonce (0: the lane of the object's own "
     "range, which a resumed search goes on from)", ("lane",))
+LONE_HEAD = REGISTRY.histogram(
+    "pow_pipeline_lone_head_seconds",
+    "From the entry of a solve of one object (mode slab) to the return "
+    "of the last lane's first launch: what the host does before every "
+    "device it was given searches; observed once a solve", ("lanes",))
 SLOTS = REGISTRY.counter(
     "pow_pipeline_slots_total",
     "Slots of the launches dispatched: those that searched (live) and "
@@ -424,7 +429,9 @@ SYNC_SINGLE_STEPS = 8
 #: four v5e chips, 200 lone solves at ``single_send``'s difficulties,
 #: pairs of solves a second by steps a lane: 32: 18.05, 64: 18.66,
 #: 128: 15.45, 512: 10.26; one chip at 512: 9.06 (PERF.md section 6,
-#: PR 43)
+#: PR 43).  Again with a round of four launches at 2.7 ms of the host
+#: where it was 3.8 (``tools/lone_lanes_bench.py``, PR 44): 64: 20.58,
+#: 32: 20.26, 16: 18.21
 LONE_LANES_CHUNKS = 256
 #: launch groups a ``batched`` solve of at most one launch's objects
 #: is laid out as: with two, the round-robin always finds a group with
@@ -516,6 +523,11 @@ _KIND = {"slab": "slab", "batched": "batch", "packed": "packed",
 #: launched once: a program is lowered and compiled anew for each
 #: device it runs on
 _TRACED_SHAPES: set = set()
+#: lanes -> the guard workers of solves that are over, for the next
+#: solve on as many lanes to take: a lone object's solve lasts a dozen
+#: milliseconds, and starting five threads for it and waking them to
+#: end them cost it half of one (PERF.md section 6, PR 44)
+_IDLE_GUARDS: dict = {}
 
 
 class _PipelineDriver:
@@ -563,12 +575,16 @@ class _PipelineDriver:
         #: batch to the next ladder tier
         self.stall_timeout = max(stall_timeout or 0.0, 0.0)
         #: reusable guard workers: with several devices one a device
-        #: and one for a first launch beside their fetches, with one
-        #: device one worker for both (no fetch is out while the loop
-        #: launches) — the guarded path must not pay a thread spawn per
-        #: harvest; only a stall abandons them (the wedged thread keeps
-        #: the old executor, a fresh one takes over).  None: one device
-        #: and no watchdog, everything runs in place
+        #: and one for a first launch beside their fetches, and as many
+        #: again, since the workers outlive the solve (``_IDLE_GUARDS``)
+        #: and the fetch of a launch that was abandoned unread holds
+        #: its worker until that launch ends on the device, which may
+        #: be after the next solve has begun; with one device one
+        #: worker for both (no fetch is out while the loop launches) —
+        #: the guarded path must not pay a thread spawn per harvest, nor
+        #: per solve; only a stall abandons them (the wedged thread
+        #: keeps the old executor, a fresh one takes over).  None: one
+        #: device and no watchdog, everything runs in place
         self._guard_pool = None
         #: lane -> (fetch of that device's oldest launch, its deadline)
         self._fetching: dict = {}
@@ -597,9 +613,12 @@ class _PipelineDriver:
         import concurrent.futures as cf
         import contextvars
         if self._guard_pool is None:
-            self._guard_pool = cf.ThreadPoolExecutor(
-                self.lanes + (self.lanes > 1),
-                thread_name_prefix="bmtpu-pow-slab-guard")
+            try:
+                self._guard_pool = _IDLE_GUARDS[self.lanes].pop()
+            except (KeyError, IndexError):
+                self._guard_pool = cf.ThreadPoolExecutor(
+                    2 * self.lanes + 1 if self.lanes > 1 else 1,
+                    thread_name_prefix="bmtpu-pow-slab-guard")
         return self._guard_pool.submit(contextvars.copy_context().run,
                                        fn, *args)
 
@@ -617,16 +636,21 @@ class _PipelineDriver:
         return SlabStallError(
             "pow.slab exceeded %.1fs stall deadline" % timeout)
 
-    def _drop_guards(self) -> None:
-        """Leave the workers behind, a wedged one with its executor."""
+    def _drop_guards(self, wedged: bool = True) -> None:
+        """Leave the workers behind: a ``wedged`` one with its
+        executor, those of a solve that is over for the next solve."""
         for fut, _deadline in self._fetching.values():
             # consume whatever a worker eventually produces so its late
             # exception is not reported as never-retrieved
             fut.add_done_callback(lambda f: f.exception())
         self._fetching.clear()
-        if self._guard_pool is not None:
-            self._guard_pool.shutdown(wait=False)
-            self._guard_pool = None
+        pool, self._guard_pool = self._guard_pool, None
+        if pool is None:
+            return
+        if wedged:
+            pool.shutdown(wait=False)
+        else:
+            _IDLE_GUARDS.setdefault(self.lanes, []).append(pool)
 
     def _first_in(self, queues):
         """Take the launch that comes in first among each device's
@@ -765,7 +789,8 @@ class _PipelineDriver:
                 harvest(tag, host)
         finally:
             PIPELINE_DEPTH.set(0)
-            self._drop_guards()
+            # after a stall the workers are gone already
+            self._drop_guards(wedged=False)
             t_end = time.monotonic()
             left = [ln.leave(t_end) for ln in self._lanes]
             self.lane_seconds = {state: sum(s[state] for s in left)
@@ -858,6 +883,23 @@ def _split64(value: int):
     return (value >> 32) & 0xFFFFFFFF, value & 0xFFFFFFFF
 
 
+@functools.lru_cache(maxsize=256)
+def _pair_on_device(value: int, device):
+    """``value`` as the pair the kernels take, put on ``device`` (None:
+    JAX's default) once and kept there.  For the base of a lone
+    object's launch: an object begins at nonce 0 unless it is resumed,
+    so lane ``k``'s ``n``-th launch begins where it began for the last
+    object, and of a launch's three operands the base is the one that
+    need not cross again.  A transfer costs the host 0.11 to 0.28 ms
+    on a v5e however it is made (PERF.md section 6, PR 44); a base that
+    is not here yet (a resumed object, an object many launches long)
+    costs that once."""
+    import numpy as np
+
+    return jax.device_put(np.array(_split64(value), dtype=np.uint32),
+                          device)
+
+
 def _hash_words(initial_hash: bytes):
     """The eight 64-bit words of an initial hash as ``(hi, lo)`` pairs."""
     return [_split64(int.from_bytes(initial_hash[j:j + 8], "big"))
@@ -876,14 +918,16 @@ class _LaunchGroup:
     """Host state for one launch-wide slab group (``width`` slots, of
     which those not given an object are pad).  A group lives on one
     device: ``device`` is where its arrays are put and so where its
-    launches run (None: wherever JAX puts an array by default)."""
+    launches run (None: wherever JAX puts an array by default).  Its
+    words go there when it is laid out, unless ``words_on_device`` is
+    False: a lone object's ride its launches."""
 
     __slots__ = ("idx", "words", "ih_words", "stale", "targets", "t_arr",
                  "bases", "trials", "done", "copy", "unread", "width",
                  "unbatched", "device")
 
     def __init__(self, items, idx, width, starts=None, unbatched=False,
-                 device=None):
+                 device=None, words_on_device=True):
         import numpy as np
 
         self.device = device
@@ -899,9 +943,10 @@ class _LaunchGroup:
         self.unbatched = unbatched
         #: the device copy of ``words`` is behind the host's
         self.stale = True
-        if idx:
+        if idx and words_on_device:
             # a group of pad slots alone is never launched: its words
-            # go to the device with its first refill
+            # go to the device with its first refill; a lone object's
+            # cross in its launches (``words_on_device`` False)
             self.device_words()
         self.t_arr = np.array([_split64(t) for t in self.targets],
                               dtype=np.uint32)
@@ -1072,7 +1117,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     ONE object on several ``devices`` (mode ``slab``) is laid out the
     same way from the start: a group of one slot on every device, lane
     ``k``'s at :func:`_copy_base` ``(start, k, lanes)``, all launched in
-    the first turn.  The first harvest with a hit resolves the object
+    the first turn.  A lone object's lay-out, on one lane or several,
+    is host arrays: a lane's operands cross in its launches, the words
+    and the target riding the jit call and the base on its device
+    already (:func:`_pair_on_device`), the same call in every round.
+    The first harvest with a hit resolves the object
     and the solve returns; what the other lanes' launches still search
     is abandoned unread.  The speculation rule counts the unread
     launches of every lane: none is dispatched ahead while those in
@@ -1092,6 +1141,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     """
     import numpy as np
 
+    t_entry = time.monotonic()
     items = list(items)
     n = len(items)
     if n == 0:
@@ -1171,9 +1221,12 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     starts = list(start_nonces) if start_nonces else [0] * n
     with trace("pow.groups", objects=n, width=width,
                devices=len(devices)):
+        # a lone object's lay-out is host arrays: nothing of it crosses
+        # to a device before its lanes' launches do
         groups = [_LaunchGroup(items, share, width, starts=start_nonces,
                                unbatched=unbatched,
-                               device=devices[j % len(devices)])
+                               device=devices[j % len(devices)],
+                               words_on_device=mode != "slab")
                   for j, share in enumerate(shares)]
         if lone:
             for k, g in enumerate(groups[1:], 1):
@@ -1183,7 +1236,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     lanes = [groups[k::len(devices)] for k in range(len(devices))]
     rr = [0] * len(lanes)
     results: list = [None] * n
-    executed = {"trials": 0, "launches": 0, "copies": 0}
+    executed = {"trials": 0, "copies": 0}
+    #: lane -> when its first launch had returned
+    head: dict = {}
     #: unresolved item -> {lane: (group, slot)} of the slots that search
     #: it: its own first, then its copies, at most one a lane
     held: dict = {}
@@ -1293,7 +1348,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             # them (docs/observability.md semantics)
             PACK_SIZE.observe(live)
             PACK_OCCUPANCY.set(live / cand.width)
-        ih_words = cand.device_words()
+        if unbatched:
+            # a lone object's operands cross in the launch itself: its
+            # words and its target, new with every solve, ride the jit
+            # call as numpy, and the base, on the lane's device
+            # already, says which device that is.  Nothing else moves,
+            # in the first round or a later one
+            ih_words = cand.words[0]
+            base = _pair_on_device(cand.bases[0], cand.device)
+        else:
+            ih_words = cand.device_words()
         with trace("pow.launch", program=tele_prog, chunks=chunks,
                    live=live, speculative=speculative,
                    refilled=refilled, copied=copied,
@@ -1304,16 +1368,15 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             # from one frame further down (PERF.md section 6, PR 29)
             bases = np.array([_split64(b) for b in cand.bases],
                              dtype=np.uint32)
-            if not pallas:
+            if unbatched:
+                out = sha512_pallas.pallas_search(
+                    ih_words, base, cand.t_arr[0], rows=rows,
+                    chunks=chunks, unroll=unroll, interpret=interpret)
+            elif not pallas:
                 out = _packed_search_xla(
                     ih_words, jax.device_put(bases, cand.device),
                     jax.device_put(cand.t_arr, cand.device),
                     lanes=step_trials, chunks=chunks)
-            elif mode == "slab":
-                # numpy arguments: the transfers ride the jit call
-                out = sha512_pallas.pallas_search(
-                    ih_words, bases[0], cand.t_arr[0], rows=rows,
-                    chunks=chunks, unroll=unroll, interpret=interpret)
             elif mode == "batched":
                 out = sha512_pallas.pallas_batch_search(
                     ih_words, jax.device_put(bases, cand.device),
@@ -1326,7 +1389,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                     chunks=chunks, pack=pack, unroll=unroll,
                     interpret=interpret)
         cand.unread += 1
-        executed["launches"] += 1
+        head.setdefault(lane, span.end)
         for k in range(cand.width):
             if not cand.done[k]:
                 cand.bases[k] = (cand.bases[k] + slab_trials) & _MASK64
@@ -1420,11 +1483,15 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     except PowInterrupted:
         if any(r is None for r in results):
             raise
+    if mode == "slab" and len(head) == len(lanes):
+        # bounded by the host's device count
+        LONE_HEAD.labels(lanes="%d" % len(lanes)).observe(  # bmlint: allow(metric-labels)
+            max(head.values()) - t_entry)
     if stats is not None:
         stats.update(
             mode=mode, pack=pack, width=width, chunks=chunks,
             groups=len(groups), devices=len(lanes),
-            launches=executed["launches"], copies=executed["copies"],
+            launches=driver.slabs, copies=executed["copies"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),
             wall_seconds=driver.wall_seconds,

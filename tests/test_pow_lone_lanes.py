@@ -11,7 +11,11 @@ an XLA program of real hashes where ``pallas_search`` is: that the
 nonce is one the plain reference accepts, that the shares tie to the
 whole, that a checkpoint is the own lane's and a resumed search skips
 nothing, that the watchdog and the speculation rule hold on lanes as
-they do on one chip.
+they do on one chip; and (ISSUE 44) what the host does at the solve's
+head: nothing crosses to a device under ``pow.groups``, a lane's words
+and target ride its launch as numpy, its base is on its device from the
+last object that began there, every lane is launched, in lane order,
+before a fetch is out, and ``pow_pipeline_lone_head_seconds`` times it once a solve.
 """
 
 import hashlib
@@ -61,6 +65,12 @@ def _family(name: str) -> dict:
             for values, child in REGISTRY.get(name).children()}
 
 
+def _observed(name: str) -> dict:
+    """Observations of each child of a histogram."""
+    return {values: child.snapshot()[2]
+            for values, child in REGISTRY.get(name).children()}
+
+
 def _grown(name: str, before: dict) -> dict:
     return {k: v - before.get(k, 0) for k, v in _family(name).items()
             if v != before.get(k, 0)}
@@ -76,7 +86,8 @@ def devices():
 
 class Slab:
     """Stands where ``pallas_search`` is: an XLA program of real hashes
-    with its output contract, on the device its hash words are on.
+    with its output contract, on the device its base is on (the one
+    operand of a lone object's launch that is on a device before it).
     Keeps every launch: ``(device, base, trials)``."""
 
     def __init__(self, monkeypatch):
@@ -88,7 +99,7 @@ class Slab:
 
         def search(ih_words, base, target, rows, chunks, unroll,
                    interpret):
-            (device,) = ih_words.devices()
+            (device,) = base.devices()
             b = np.asarray(base)
             self.launches.append((device, (int(b[0]) << 32) | int(b[1]),
                                   rows * 128 * chunks * unroll))
@@ -232,18 +243,22 @@ def test_one_lane_is_the_lone_object_s_solve_of_one_chip(devices,
 # -- (3) a checkpoint is the own lane's, and a resume skips nothing ------
 
 
+@pytest.mark.parametrize("lanes", [1, LANES])
 def test_progress_is_the_own_lane_s_and_a_resumed_search_skips_nothing(
-        devices, monkeypatch):
+        lanes, devices, monkeypatch):
+    devices = devices[:lanes]
     slab = Slab(monkeypatch)
     item = _item("resume", 3 * EXPECTED)
-    start, seen = 777, []
+    start, seen, solved = 777, [], []
 
     def progress(i, nxt):
         seen.append((i, nxt))
 
     with pytest.raises(PowInterrupted):
         _solve(item, devices, start_nonces=[start], progress=progress,
+               on_solved=lambda i, result: solved.append((i, result)),
                should_stop=lambda: len(seen) >= 2)
+    assert not solved
     # the own lane's frontier only: lane 0's slabs read miss-free, in
     # order; nothing of a share 2**62 away
     assert seen and all(i == 0 for i, _n in seen)
@@ -260,12 +275,18 @@ def test_progress_is_the_own_lane_s_and_a_resumed_search_skips_nothing(
     # others at their shares of the new start
     del slab.launches[:]
     seen.clear()
-    (nonce, _trials), _stats = _solve(
-        item, devices, start_nonces=[frontier[-1]], progress=progress)
+    (nonce, trials), _stats = _solve(
+        item, devices, start_nonces=[frontier[-1]], progress=progress,
+        on_solved=lambda i, result: solved.append((i, result)))
+    # the nonce is one hashlib accepts, and the object left once
+    check = hashlib.sha512(hashlib.sha512(
+        nonce.to_bytes(8, "big") + item[0]).digest()).digest()
+    assert int.from_bytes(check[:8], "big") <= item[1]
     assert reference.trial_value(nonce.to_bytes(8, "big"), item[0]) \
         <= item[1]
+    assert solved == [(0, (nonce, trials))]
     for k, dev in enumerate(devices):
-        assert slab.of(dev)[0][0] == _copy_base(frontier[-1], k, LANES)
+        assert slab.of(dev)[0][0] == _copy_base(frontier[-1], k, lanes)
     assert all(n > frontier[-1] and (n - frontier[-1]) % SLAB == 0
                for _i, n in seen)
 
@@ -296,7 +317,7 @@ def test_a_launch_that_never_comes_in_on_one_of_four_trips_the_watchdog(
 
     def wedged(ih_words, base, target, **kw):
         out = search(ih_words, base, target, **kw)
-        if ih_words.devices() == {devices[2]}:
+        if base.devices() == {devices[2]}:
             return _NeverIn(release), out[1]
         return out
 
@@ -341,7 +362,7 @@ class Misses:
 
     def __call__(self, ih_words, base, target, rows, chunks, unroll,
                  interpret):
-        (device,) = ih_words.devices()
+        (device,) = base.devices()
         self.launches.append(device)
         found = np.zeros(chunks, np.int32)
         nonce = np.zeros((chunks, 2), np.uint32)
@@ -387,3 +408,145 @@ def test_no_launch_is_dispatched_ahead_of_an_unread_one_that_may_end_the_object(
         mine = [what for _t, dev, what in events if dev == k]
         assert mine[0] == "launch"
         assert all(a != b for a, b in zip(mine, mine[1:])), (k, mine)
+
+
+# -- (6) the head of the solve: what crosses to the devices, and when ----
+
+
+class Crossings:
+    """Stands where ``jax.device_put`` is and keeps every call:
+    ``(when, device, value)``; and where the driver hands a call to a
+    guard worker, ``(when, what)``.  The bases that earlier solves left
+    on the devices are forgotten first."""
+
+    def __init__(self, monkeypatch):
+        import jax
+        self.puts, self.submits = [], []
+        put, submit = jax.device_put, pipeline._PipelineDriver._submit
+
+        def device_put(x, device=None, **kw):
+            self.puts.append((time.monotonic(), device, np.asarray(x)))
+            return put(x, device, **kw)
+
+        def _submit(driver, fn, *args):
+            self.submits.append((time.monotonic(),
+                                 getattr(fn, "__name__", "fetch")))
+            return submit(driver, fn, *args)
+
+        monkeypatch.setattr(jax, "device_put", device_put)
+        monkeypatch.setattr(pipeline._PipelineDriver, "_submit", _submit)
+        pipeline._pair_on_device.cache_clear()
+
+
+class Operands(Misses):
+    """``Misses`` that keeps each launch's operands as they came (what
+    rides the call as numpy is a view of the group's arrays: a copy of
+    it) and when the call returned: ``(when, device, words, base,
+    target)``."""
+
+    def __call__(self, ih_words, base, target, **shape):
+        out = super().__call__(ih_words, base, target, **shape)
+        self.calls = getattr(self, "calls", [])
+        assert isinstance(ih_words, np.ndarray) \
+            and isinstance(target, np.ndarray)
+        (device,) = base.devices()
+        self.calls.append((time.monotonic(), device, ih_words.copy(),
+                           base, target.copy()))
+        return out
+
+
+def _pair(value):
+    return list(pipeline._split64(value & MASK))
+
+
+@pytest.mark.parametrize("lanes", [1, LANES])
+def test_nothing_crosses_before_the_launches_and_a_known_base_never_again(
+        lanes, devices, monkeypatch):
+    """A lane's operands cross in its launch: words and target as numpy
+    riding the call, the base from the device it is on already, put
+    there at most once a lane a round and never under ``pow.groups``;
+    the next object, which begins where this one did, puts nothing."""
+    devices = devices[:lanes]
+    kernel = Operands(2 * lanes, monkeypatch)       # two rounds miss
+    crossed = Crossings(monkeypatch)
+    # easy enough that no launch is dispatched ahead of an unread one
+    item, start = _item("crossings", 2 * 10 ** 5), 4242
+    TRACER.clear()
+    _solve(item, devices, start_nonces=[start])
+    (groups,) = TRACER.recent(50, name="pow.groups")
+    assert len(kernel.calls) == 3 * lanes
+    assert not [t for t, _d, _x in crossed.puts
+                if groups.start <= t <= groups.end]
+    # lane by lane: one pair a launch, the launch's own base, to the launch's device,
+    # after the lane's launch before it had returned
+    assert len(crossed.puts) == len(kernel.calls)
+    for k, lane_device in enumerate(devices):
+        puts = [(t, x) for t, device, x in crossed.puts
+                if device == lane_device]
+        calls = [call for call in kernel.calls if call[1] == lane_device]
+        assert len(puts) == len(calls) == 3
+        last = groups.end
+        for rnd, ((t_put, value), call) in enumerate(zip(puts, calls)):
+            t_call, _device, words, base, target = call
+            assert last <= t_put <= t_call
+            assert value.tolist() == _pair(
+                _copy_base(start, k, lanes) + rnd * SLAB) \
+                == np.asarray(base).tolist()
+            assert base.devices() == {lane_device}
+            # what is new with every solve rides the call
+            assert words.shape == (8, 2)
+            assert target.tolist() == _pair(item[1])
+            last = t_call
+    # every round launches the words it launched first
+    assert all(call[2].tolist() == kernel.calls[0][2].tolist()
+               for call in kernel.calls)
+    # the same ranges again, another object: nothing crosses but in
+    # the launches themselves
+    del crossed.puts[:], kernel.calls[:], kernel.launches[:]
+    _solve(_item("crossings, the next object", 2 * 10 ** 5), devices,
+           start_nonces=[start])
+    assert len(kernel.calls) == 3 * lanes and not crossed.puts
+
+
+@pytest.mark.parametrize("lanes", [1, LANES])
+def test_every_lane_is_launched_in_lane_order_before_a_fetch_is_out(
+        lanes, devices, monkeypatch):
+    """The first turn: every lane's launch, one after the other in
+    lane order, each at its share of the nonce space; the fetches are
+    handed to the guard workers when the last of them has returned,
+    and nothing else is."""
+    devices = devices[:lanes]
+    kernel = Operands(lanes, monkeypatch)           # one round misses
+    item, start = _item("head", 2 * 10 ** 5), 99
+    _solve(item, devices, start_nonces=[start])     # the shape is known
+    crossed = Crossings(monkeypatch)
+    del kernel.calls[:], kernel.launches[:]
+    head = _observed("pow_pipeline_lone_head_seconds")
+    t_in = time.monotonic()
+    _solve(item, devices, start_nonces=[start])
+    wall = time.monotonic() - t_in
+    first = kernel.calls[:lanes]
+    assert [call[1] for call in first] == devices
+    assert [np.asarray(call[3]).tolist() for call in first] \
+        == [_pair(_copy_base(start, k, lanes)) for k in range(lanes)]
+    out = first[-1][0]
+    assert crossed.submits and all(
+        what == "default_fetch" and t >= out
+        for t, what in crossed.submits)
+    # timed once, under the lane count, from the solve's entry on
+    now = _observed("pow_pipeline_lone_head_seconds")
+    assert {k: n - head.get(k, 0) for k, n in now.items()
+            if n != head.get(k, 0)} == {("%d" % lanes,): 1}
+    assert 0 < out - t_in <= wall
+
+
+def test_a_queue_s_solve_observes_no_lone_head(devices):
+    head = _observed("pow_pipeline_lone_head_seconds")
+    items = [_item("queue %d" % i, 2000) for i in range(3)]
+    stats = {}
+    results = solve_batch_pipelined(items, rows=ROWS, impl="xla",
+                                    devices=devices, stats=stats,
+                                    stall_timeout=30.0)
+    assert stats["mode"] != "slab" and len(results) == 3
+    assert _observed("pow_pipeline_lone_head_seconds") == head
+
