@@ -39,6 +39,7 @@ import asyncio
 import logging
 import os
 import threading
+import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Iterable, Sequence
 
@@ -62,6 +63,16 @@ EARLY_CANCELS = REGISTRY.counter(
     "Queued trial-decrypt attempts skipped because another key "
     "already matched (first-match early-cancel)")
 
+BUSY_SECONDS = REGISTRY.counter(
+    "cryptopool_busy_seconds_total",
+    "Seconds the pool's worker threads spent inside jobs handed to "
+    "run(): over wall time, the share of one thread the pool keeps busy",
+    ("pool",))
+QUEUE_WAIT = REGISTRY.histogram(
+    "cryptopool_queue_wait_seconds",
+    "Time a job handed to run() waited for a worker thread, submit to "
+    "start", ("pool",))
+
 #: default worker count — crypto is CPU-bound, so more threads than
 #: cores only adds contention; capped small because the event loop and
 #: the PoW executor share the same cores
@@ -80,9 +91,14 @@ class CryptoPool:
     """
 
     def __init__(self, size: int | None = None, *,
-                 decrypt_fn=None, verify_fn=None, batch=None):
+                 decrypt_fn=None, verify_fn=None, batch=None,
+                 name: str = "processor"):
         #: 0 = inline synchronous execution (the pre-pool path)
         self.size = DEFAULT_POOL_SIZE if size is None else size
+        #: whose pool this is: the ``pool`` label of its two series
+        self.name = name
+        self._busy = BUSY_SECONDS.labels(pool=name)
+        self._queue_wait = QUEUE_WAIT.labels(pool=name)
         self._exec: ThreadPoolExecutor | None = None
         self._lock = threading.Lock()
         self._decrypt = decrypt_fn
@@ -133,7 +149,17 @@ class CryptoPool:
         if self.size == 0:
             return fn(*args)
         return await asyncio.get_running_loop().run_in_executor(
-            self._executor(), fn, *args)
+            self._executor(), self._timed, time.perf_counter(), fn, *args)
+
+    def _timed(self, submitted: float, fn, *args):
+        """``fn(*args)`` on a worker thread, its wait for the thread and
+        its length counted (a job that raises is counted too)."""
+        started = time.perf_counter()
+        self._queue_wait.observe(started - submitted)
+        try:
+            return fn(*args)
+        finally:
+            self._busy.inc(time.perf_counter() - started)
 
     # -- signatures ----------------------------------------------------------
 

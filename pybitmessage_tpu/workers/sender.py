@@ -68,6 +68,15 @@ POW_WAIT_SECONDS = REGISTRY.histogram(
     "worker_pow_wait_seconds",
     "End-to-end PoW wait in the send pipeline: coalescing queue + "
     "solve + host verify")
+ADMIT_SECONDS = REGISTRY.histogram(
+    "sender_admit_seconds",
+    "One admission pass of a sweep over the sent table: the read of "
+    "the queued rows and the filter against the sends in flight",
+    ("kind",))
+ADMIT_ROWS = REGISTRY.counter(
+    "sender_admit_rows_total",
+    "Sent-table rows an admission pass read, admitted or not",
+    ("kind",))
 OBJECTS_PUBLISHED = REGISTRY.counter(
     "worker_objects_published_total",
     "Locally generated objects entered into the inventory",
@@ -111,8 +120,9 @@ class SendWorker:
     #: sweep began, not 20 ms: the sweep's solve then starts as late as
     #: at the parent and the device idles 6.95 % against 1.08 (traced,
     #: PR 33: PERF.md section 6).  Members have to leave one by one for
-    #: a solve to begin with the first.
-    crypto = CryptoPool(1)
+    #: a solve to begin with the first.  How much of its time the one
+    #: thread works is ``cryptopool_busy_seconds_total{pool="sender"}``.
+    crypto = CryptoPool(1, name="sender")
 
     def __init__(self, *, keystore: KeyStore, store: MessageStore,
                  inventory, pool, solver: Callable,
@@ -304,9 +314,12 @@ class SendWorker:
         # so one row more than can be in flight is read: whatever the
         # in-flight rows leave of that is ``room`` rows to admit and one
         # that says the outbox goes on
-        rows = [m for m in self.store.sent_by_status(
-                    *statuses, limit=MAX_IN_FLIGHT + 1)
-                if m.ackdata not in self._in_flight]
+        t0 = time.perf_counter()
+        read = self.store.sent_by_status(*statuses,
+                                         limit=MAX_IN_FLIGHT + 1)
+        rows = [m for m in read if m.ackdata not in self._in_flight]
+        ADMIT_SECONDS.labels(kind=kind).observe(time.perf_counter() - t0)
+        ADMIT_ROWS.labels(kind=kind).inc(len(read))
         msgs = rows[:room]
         if not msgs:
             return
