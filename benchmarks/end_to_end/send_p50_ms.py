@@ -1,4 +1,4 @@
-"""Median send latency of the window's messages (see ``_latency``)."""
+"""Median send latency of the window's sends (see ``_latency``)."""
 
 from benchmarks.end_to_end._latency import latency_ms
 

@@ -51,7 +51,7 @@ import time
 
 from benchmarks.generators.closed_loop import (FAILED_STATES, SENT_STATES,
                                                Generator as ClosedLoop,
-                                               Sent, sweep_sizes)
+                                               Sent, changed, sweep_sizes)
 
 #: seconds between two passes over the sent table
 POLL_SECONDS = 0.1
@@ -64,7 +64,6 @@ LOST_AFTER = 300.0
 #: sweep of the outbox has solved ends its first after 100-110 s and
 #: needs 7.6 minutes a run (PERF.md, PR 32), more than a run is given.
 STALLED_AFTER = 60.0
-CHANGED = "SELECT ackdata, status FROM sent WHERE lastactiontime >= ?"
 
 
 class Generator:
@@ -115,8 +114,8 @@ class Generator:
         a failed state, or have been outstanding too long."""
         read_from, self._read_from = self._read_from, int(time.time())
         out = []
-        for handle, status in dep.sender.db.query(CHANGED, (read_from,)):
-            rec = self._outstanding.get(bytes(handle))
+        for handle, status in changed(dep.sender, read_from):
+            rec = self._outstanding.get(handle)
             if rec is None:
                 continue
             rec.status = status

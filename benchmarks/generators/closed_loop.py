@@ -18,6 +18,14 @@ Parameters (a traffic file under ``traffic/``):
 
 Every seed sends the same set of sizes, in another order: the sizes of
 a sweep are the mix's quantiles, shuffled by the seed.
+
+Every send is timed by itself (``t_submit`` before its call,
+``t_done`` from the poll that first sees its final status).  A sweep of
+one polls its one handle every 2 ms.  A sweep of more polls every 20 ms
+with ONE pass over the sent table (``changed``: the rows whose status
+was stamped since the pass before), and every pending send the pass
+finds published gets that pass's time: watching the head of the line
+alone would give each send the time of the slowest before it.
 """
 
 from __future__ import annotations
@@ -33,6 +41,10 @@ SENT_STATES = {"message": ("msgsent", "ackreceived",
 FAILED_STATES = ("badkey", "toodifficult", "notfound")
 #: seconds one sweep may take before the run gives up
 SWEEP_TIMEOUT = 300.0
+#: seconds between two polls: of the one handle of a sweep of one, and
+#: between two passes over a sweep of more
+POLL_ONE, POLL_SWEEP = 0.002, 0.02
+CHANGED = "SELECT ackdata, status FROM sent WHERE lastactiontime >= ?"
 
 
 @dataclass
@@ -43,6 +55,15 @@ class Sent:
     t_submit: float
     t_done: float | None = None
     status: str = ""
+
+
+def changed(node, read_from: int) -> list[tuple[bytes, str]]:
+    """One pass: ``(handle, status)`` of the sent rows stamped in the
+    wall-clock second ``read_from`` or later.  ``lastactiontime`` is in
+    whole seconds, so a caller reads from the second its last pass
+    began in and meets a row of that second again."""
+    return [(bytes(handle), status) for handle, status
+            in node.db.query(CHANGED, (read_from,))]
 
 
 def sweep_sizes(mix, n: int) -> list[int]:
@@ -71,6 +92,8 @@ class Generator:
         self.sweep_size = int(params["sweep"])
         self._sizes = sweep_sizes(params["body_bytes"], self.sweep_size)
         self._n = 0
+        #: wall-clock second the last pass began in (0: none yet)
+        self._read_from = 0
 
     def _bodies(self) -> list[str]:
         sizes = list(self._sizes)
@@ -99,27 +122,49 @@ class Generator:
                     handle = await node.send_broadcast(
                         dep.from_address, subject, body, ttl=ttl)
                 sent.append(Sent(subject, body, handle, t))
-        done_states = SENT_STATES[self.kind]
-        # a single send is timed, so it is polled closely; a sweep
-        # resolves as a whole, so one pending send is watched
-        poll = 0.002 if self.sweep_size == 1 else 0.02
-        deadline = time.monotonic() + SWEEP_TIMEOUT
-        pending = list(sent)
         with prof.TraceAnnotation("bench.wait_published"):
-            while pending:
-                head = pending[0]
-                head.status = node.message_status(head.handle)
-                if head.status in done_states:
-                    head.t_done = time.monotonic()
-                    pending.pop(0)
-                    continue
-                if head.status in FAILED_STATES:
-                    pending.pop(0)
-                    continue
-                if time.monotonic() > deadline:
-                    break
-                await asyncio.sleep(poll)
+            if self.sweep_size == 1:
+                await self._wait_one(node, sent[0])
+            else:
+                await self._wait_each(node, sent)
         return sent
+
+    async def _wait_one(self, node, rec: Sent) -> None:
+        """A sweep of one: its handle's status every ``POLL_ONE``."""
+        deadline = time.monotonic() + SWEEP_TIMEOUT
+        while True:
+            rec.status = node.message_status(rec.handle)
+            if rec.status in SENT_STATES[self.kind]:
+                rec.t_done = time.monotonic()
+                return
+            if (rec.status in FAILED_STATES
+                    or time.monotonic() > deadline):
+                return
+            await asyncio.sleep(POLL_ONE)
+
+    async def _wait_each(self, node, sent: list[Sent]) -> None:
+        """A sweep of more: one pass every ``POLL_SWEEP`` stamps every
+        send it first sees published; a failed one leaves unstamped."""
+        deadline = time.monotonic() + SWEEP_TIMEOUT
+        pending = {bytes(rec.handle): rec for rec in sent}
+        while pending and time.monotonic() <= deadline:
+            read_from, self._read_from = self._read_from, int(time.time())
+            # a second further back than the last pass began in: the
+            # program stamps a row before it writes it, and a write
+            # that waits for the table's lock across a second's end
+            # and a pass would never be read
+            for handle, status in changed(node, read_from - 1):
+                rec = pending.get(handle)
+                if rec is None:
+                    continue
+                rec.status = status
+                if status in SENT_STATES[self.kind]:
+                    rec.t_done = time.monotonic()
+                elif status not in FAILED_STATES:
+                    continue
+                del pending[handle]
+            if pending:
+                await asyncio.sleep(POLL_SWEEP)
 
     async def warm_receive_shapes(self, dep) -> None:
         """Once, after the first warm-up sweep: what the window will
@@ -145,12 +190,13 @@ async def send_alone(gen, dep) -> None:
     """``warm_lone_sends`` sends of ``gen``'s kind, each submitted when
     the one before is published, so that each of its objects asks for
     its proof of work with nobody beside it.  A sweep's last straggler
-    can be such an object, laid out for nobody, and the solver ladder
-    gives it another program than a queue's: on several chips the
-    nonce-range partition, which takes tens of seconds to trace and
-    lower and which no whole sweep need ever run.  Left to chance its
-    first use falls inside the measured window in most runs
-    (PERF.md, PR 38); asked for here it is set-up."""
+    can be such an object, laid out for nobody, and the pipeline gives
+    it another shape than a queue's: ``pallas_search`` on every chip
+    (plan mode ``slab`` on lanes), which no whole sweep need ever
+    launch.  Left to chance its first use, and with it its tracing
+    and lowering, falls inside the measured window in most runs
+    (PERF.md, PR 38; 24 s for the partition's program then, seconds
+    for the pipeline's since PR 43); asked for here it is set-up."""
     lone = Generator(dict(gen.params, sweep=1), gen.rng)
     for k in range(int(gen.params.get("warm_lone_sends") or 0)):
         sent = await lone.sweep(dep, "lone%d" % k)

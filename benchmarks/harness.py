@@ -29,6 +29,7 @@ from pathlib import Path
 
 from . import check, probes, tracereduce
 from .deployments import build
+from .end_to_end._latency import latencies_ms
 
 #: where a traced run keeps its profile, inside the checkout
 OUT_DIR = ".bench_out"
@@ -111,6 +112,13 @@ def read_metrics(bench: Bench, group: str, folder: str,
         if value is not None:
             out[m["name"]] = {"value": value, "unit": m["unit"]}
     return out
+
+
+def compared_lines(compared: dict) -> list[str]:
+    """Each number ``check.verify`` compared, beside its limit."""
+    return ["compared: %s = %d (limit %d)" % (name, row["value"],
+                                              row["limit"])
+            for name, row in compared.items()]
 
 
 def device_block() -> dict:
@@ -249,9 +257,8 @@ async def run_cell(bench: Bench, seed: int, seconds: float, trace: bool,
     window = Window(bench, t1 - t0, setup_s, sent, counters, launches,
                     verdict, notes={"lowerings": low1[0] - low0[0],
                            "backend_compiles": low1[1] - low0[1]})
-    for name, row in verdict["compared"].items():
-        say("compared: %s = %d (limit %d)" % (name, row["value"],
-                                              row["limit"]))
+    for line in compared_lines(verdict["compared"]):
+        say(line)
     say("window %.3fs: %d sent, %d published, %d objects, worst "
         "value/target %.4f, backends %s, lowerings in window %d, "
         "tuner %s" % (window.seconds, len(sent), len(window.published),
@@ -259,6 +266,13 @@ async def run_cell(bench: Bench, seed: int, seconds: float, trace: bool,
                       verdict["worst_value_over_target"],
                       verdict["attempts_by_backend"],
                       window.notes["lowerings"], _tuner_state()))
+    times = latencies_ms(window)
+    if times:
+        # 1,000 over the mean is the rate of a sweep of one
+        say("window's send latency over %d send(s): mean %.3f ms, "
+            "least %.3f, most %.3f" % (len(times), sum(times)
+                                       / len(times), min(times),
+                                       max(times)))
     say("shapes launched in the window: %s" % json.dumps(log.shapes()))
     say("persistent compile cache events: %s"
         % json.dumps(dict(sorted(watch.cache_events.items()))))
@@ -287,5 +301,7 @@ async def run_cell(bench: Bench, seed: int, seconds: float, trace: bool,
         result["metrics"] = read_metrics(bench, "end_to_end",
                                          "end_to_end", window)
     result["device"] = device
+    # each number compared beside its limit, last in the line
+    result["compared"] = verdict["compared"]
     result["window"] = window       # for callers; dropped before printing
     return result

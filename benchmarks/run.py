@@ -95,6 +95,9 @@ def main(argv=None) -> int:
         t_start=T_START))
     result.pop("window")
     print(json.dumps(result), flush=True)
+    # and as the last lines of standard error
+    for line in harness.compared_lines(result["compared"]):
+        print(line, file=sys.stderr, flush=True)
     return 0
 
 

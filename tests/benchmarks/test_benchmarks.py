@@ -136,6 +136,13 @@ def test_cell_added_as_files_runs_and_is_correct(tree, cell):
                                      "memory_peak_bytes", "window_s"}
     assert any(l.startswith("compared: invalid_nonces = 0 (limit 0)")
                for l in result["lines"])
+    # and in the line, each number beside its limit, as the last key
+    # (``window`` is dropped before the line is printed)
+    printed = [k for k in result if k not in ("window", "lines")]
+    assert printed[-2:] == ["device", "compared"]
+    assert result["compared"] == {
+        name: {"value": 0, "limit": 0}
+        for name in ("invalid_nonces", "undelivered", "off_tier")}
     window = result["window"]
     assert window.verdict["objects"] == (
         2 if cell == "rehearse_pair" else 1) * result["attempted"]

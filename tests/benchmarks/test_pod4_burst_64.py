@@ -79,10 +79,12 @@ def test_the_cell_is_the_one_the_issue_names():
 
 def test_the_cell_reports_the_chip_metrics_and_those_of_every_cell():
     bench = harness.load(REPO, CELL)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(NEW_LAYERS) | EVERY_CELL
+    assert set(NEW_LAYERS) | EVERY_CELL \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red: a
+    # closed-loop cell times every send by itself (ISSUE 47)
     assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+        >= {"sent_msgs_per_s", "setup_s", "send_p50_ms", "send_p90_ms"}
     # only the lists grew: the entries are pod4_queue_1k's, the cell
     # after it
     spec = json.loads((REPO / "BENCHMARK.json").read_text())

@@ -121,11 +121,15 @@ def test_a_layer_metric_lists_the_cell_and_four_chip_cells_only(name):
 
 def test_the_cell_reports_the_metrics_that_list_no_cells():
     bench = harness.load(REPO, CELL)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(NEW_LAYERS) | {"off_device_solves", "compiles_in_window",
-                              "device_idle_share"}
-    assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+    assert set(NEW_LAYERS) | {"off_device_solves", "compiles_in_window",
+                              "device_idle_share"} \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red; its
+    # outbox is filled before the window, so submit-to-sent is a place
+    # in the queue and no latency (ISSUE 47)
+    ends = {m["name"] for m in bench.metrics("end_to_end")}
+    assert ends >= {"sent_msgs_per_s", "setup_s"}
+    assert not ends & {"send_p50_ms", "send_p90_ms"}
     # and no cell that was there reports a metric of this one
     for cell in ("queue_1k", "burst_send_64", "chan_storm_256",
                  "single_send"):

@@ -91,12 +91,13 @@ def test_the_cell_is_the_one_the_issue_names():
         "generator": "closed_loop", "send": "message", "sweep": 1,
         "body_bytes": [[1.0, 1000, 1000]], "warm_verify_batches": [],
         "warm_quiet_sweeps": 2, "warm_max_sweeps": 12}
-    # appended: the eighth cell, the third on four chips of at most
-    # half of all cells, rounded down
+    # appended behind the two four-chip cells that were there, found by
+    # name wherever later appends leave it; of all cells at most half,
+    # rounded down, are on four chips
     cells = bench.spec["workloads"]
-    assert cells[-1] == bench.cell and len(cells) == 8
+    assert [c for c in cells if c["name"] == CELL] == [bench.cell]
     four = [c["name"] for c in cells if c["chips"] == 4]
-    assert four == ["pod4_queue_1k", "pod4_burst_64", CELL]
+    assert four[:3] == ["pod4_queue_1k", "pod4_burst_64", CELL]
     assert len(four) <= len(cells) // 2
 
 
@@ -124,7 +125,7 @@ def test_the_configuration_is_sender_default_on_four_chips():
     assert set(cfg["assumed"]) == {"body_bytes",
                                    "first_hit_at_the_harvest"}
     assert "config 5" in cfg["source"] and "config 1" in cfg["source"]
-    entry = bench.spec["configs"][-1]
+    (entry,) = [c for c in bench.spec["configs"] if c["name"] == CONFIG]
     assert entry == {
         "name": CONFIG, "source": entry["source"],
         "file": "benchmarks/configs/%s.json" % CONFIG,
@@ -135,7 +136,6 @@ def test_the_configuration_is_sender_default_on_four_chips():
         "object PoW at network default); PyBitmessage defaults.py "
         "1000/1000; TTL 4 d")
     assert all(0 < len(entry[k]) <= 200 for k in ("source", "why"))
-    assert len(bench.spec["configs"]) == 5
     # no other configuration's file
     assert [c["file"] for c in bench.spec["configs"]].count(
         entry["file"]) == 1
@@ -143,8 +143,11 @@ def test_the_configuration_is_sender_default_on_four_chips():
 
 def test_the_twelve_entries_are_appended_in_the_issue_s_order():
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
-    assert [m["name"] for m in spec["per_layer"][-len(LONE4):]] \
-        == list(LONE4)
+    # found by name, in the issue's order and side by side, wherever
+    # later appends leave them
+    names = [m["name"] for m in spec["per_layer"]]
+    first = names.index(list(LONE4)[0])
+    assert names[first:first + len(LONE4)] == list(LONE4)
     assert len({m["name"] for m in spec["per_layer"]}) \
         == len(spec["per_layer"])
     assert len((REPO / "BENCHMARK.json").read_bytes()) <= 64 * 1024
@@ -173,12 +176,16 @@ def test_a_lone4_metric_lists_the_cell_alone(name):
 
 def test_the_cell_reports_the_lone4_metrics_and_those_of_every_cell():
     bench = harness.load(REPO, CELL)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(LONE4) | EVERY_CELL
+    assert set(LONE4) | EVERY_CELL \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red: a
+    # closed-loop cell times every send by itself (ISSUE 47)
     assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
-    # and no cell that was there reports a metric of this one
-    for cell in bench.spec["workloads"][:-1]:
+        >= {"sent_msgs_per_s", "setup_s", "send_p50_ms", "send_p90_ms"}
+    # and no other cell reports a metric of this one
+    for cell in bench.spec["workloads"]:
+        if cell["name"] == CELL:
+            continue
         theirs = {m["name"] for m in
                   harness.load(REPO, cell["name"]).metrics("per_layer")}
         assert not theirs & set(LONE4), cell["name"]

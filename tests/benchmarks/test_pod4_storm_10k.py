@@ -250,10 +250,14 @@ def test_a_storm4_metric_lists_the_cell_alone(name):
 
 def test_the_cell_reports_the_storm4_metrics_and_those_of_every_cell():
     bench = harness.load(REPO, CELL)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(STORM4) | EVERY_CELL
-    assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+    assert set(STORM4) | EVERY_CELL \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red; its
+    # outbox is filled before the window, so submit-to-sent is a place
+    # in the queue and no latency (ISSUE 47)
+    ends = {m["name"] for m in bench.metrics("end_to_end")}
+    assert ends >= {"sent_msgs_per_s", "setup_s"}
+    assert not ends & {"send_p50_ms", "send_p90_ms"}
     # and no other cell reports a metric of this one
     for cell in bench.spec["workloads"]:
         if cell["name"] == CELL:

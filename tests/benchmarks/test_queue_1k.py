@@ -112,11 +112,19 @@ def test_a_layer_metric_lists_the_two_cells(name):
 @pytest.mark.parametrize("cell", CELLS)
 def test_a_new_cell_reports_the_metrics_that_list_no_cells(cell):
     bench = harness.load(REPO, cell)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(NEW_LAYERS) | {"off_device_solves", "compiles_in_window",
-                              "device_idle_share"}
-    assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+    assert set(NEW_LAYERS) | {"off_device_solves", "compiles_in_window",
+                              "device_idle_share"} \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red; the
+    # closed-loop cell times every send by itself, and the backlog's
+    # outbox is filled before the window, so submit-to-sent there is a
+    # place in the queue and no latency (ISSUE 47)
+    ends = {m["name"] for m in bench.metrics("end_to_end")}
+    assert ends >= {"sent_msgs_per_s", "setup_s"}
+    assert (ends >= {"send_p50_ms", "send_p90_ms"}) \
+        == (bench.traffic["generator"] == "closed_loop")
+    assert (not ends & {"send_p50_ms", "send_p90_ms"}) \
+        == (bench.traffic["generator"] == "backlog")
 
 
 # -- the rehearsal ------------------------------------------------------

@@ -23,7 +23,8 @@ REPO = pathlib.Path(__file__).resolve().parents[2]
 if str(REPO) not in sys.path:
     sys.path.insert(0, str(REPO))
 
-from benchmarks import check, controls, harness, probes  # noqa: E402
+from benchmarks import (check, controls, harness, probes,  # noqa: E402
+                        stats)
 
 CELL = "single_send"
 NEW_LAYERS = {
@@ -65,8 +66,10 @@ def test_the_cell_is_the_deployment_the_issue_names():
     assert (bench.traffic["sweep"], bench.traffic["send"],
             bench.traffic["body_bytes"]) == (1, "message",
                                              [[1.0, 1000, 1000]])
+    # at least these, so that a later append turns nothing red: a
+    # closed-loop cell times every send by itself (ISSUE 47)
     assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+        >= {"sent_msgs_per_s", "setup_s", "send_p50_ms", "send_p90_ms"}
 
 
 @pytest.mark.parametrize("name", sorted(NEW_LAYERS))
@@ -83,7 +86,7 @@ def test_a_layer_metric_of_the_cell_is_entered_as_the_issue_says(name):
 def test_the_cell_reports_the_metrics_that_list_no_cells():
     names = {m["name"] for m in harness.load(REPO, CELL)
              .metrics("per_layer")}
-    assert names == set(NEW_LAYERS) | {
+    assert names >= set(NEW_LAYERS) | {
         "off_device_solves", "compiles_in_window", "device_idle_share"}
 
 
@@ -154,10 +157,19 @@ def test_the_cell_runs_and_is_correct_with_two_objects_a_send(tree):
 def test_untraced_the_cell_reports_its_end_to_end_metrics(tree):
     result = _run(tree, trace=False, seed=2**31 + 28)
     assert result["correct"] is True, result["lines"]
-    assert set(result["metrics"]) == {"sent_msgs_per_s", "setup_s"}
+    assert set(result["metrics"]) >= {"sent_msgs_per_s", "setup_s",
+                                      "send_p50_ms", "send_p90_ms"}
     window = result["window"]
     assert result["metrics"]["sent_msgs_per_s"]["value"] \
         == pytest.approx(len(window.published) / window.seconds)
+    # one send at a time: each is timed on its own handle, and 1,000
+    # over the mean of those times is the rate but for the harness's
+    # own gap between two sends
+    times = [(s.t_done - s.t_submit) * 1e3 for s in window.published]
+    for name, q in (("send_p50_ms", 50), ("send_p90_ms", 90)):
+        assert result["metrics"][name] == {
+            "value": stats.percentile(times, q), "unit": "ms"}
+    assert sum(times) / 1e3 <= window.seconds
 
 
 def test_the_control_is_not_correct_in_this_cell(tree):

@@ -98,10 +98,14 @@ def test_the_configuration_states_config_4s_size_and_cuts_nothing():
 
 def test_the_cell_reports_the_stream_metrics_that_read_no_ack():
     bench = harness.load(REPO, CELL)
-    assert {m["name"] for m in bench.metrics("per_layer")} \
-        == set(STREAM_LAYERS) | EVERY_CELL
-    assert {m["name"] for m in bench.metrics("end_to_end")} \
-        == {"sent_msgs_per_s", "setup_s"}
+    assert set(STREAM_LAYERS) | EVERY_CELL \
+        <= {m["name"] for m in bench.metrics("per_layer")}
+    # at least these, so that a later append turns nothing red; its
+    # outbox is filled before the window, so submit-to-sent is a place
+    # in the queue and no latency (ISSUE 47)
+    ends = {m["name"] for m in bench.metrics("end_to_end")}
+    assert ends >= {"sent_msgs_per_s", "setup_s"}
+    assert not ends & {"send_p50_ms", "send_p90_ms"}
     spec = json.loads((REPO / "BENCHMARK.json").read_text())
     listed = {m["name"]: m["workloads"] for m in spec["per_layer"]
               if "workloads" in m}
