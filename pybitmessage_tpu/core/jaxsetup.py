@@ -2,14 +2,23 @@
 
 Every entry point that reaches a device (``__main__.main``, bench.py,
 chip_smoke.py, tools/tpu_doctor.py) calls :func:`setup_jax` before its
-first device use.  The production Mosaic programs take minutes to
-compile (docs/pow_pipeline.md), so a process without a persistent
-compile cache re-pays them at every start.
+first device use.  A start pays for a device program in three parts:
+the trace of its Python, the lowering of the result, and the backend's
+compile.  JAX's persistent compile cache, placed here, keeps the
+third (seconds for a SHA-512 search kernel since PR 26, half a minute
+and more for the secp256k1 and ``pow_verify`` programs); its key is
+the lowered module, so by itself it saves nothing of the first two.
+For the search kernels those are 4-8 s a shape, and
+``core/programcache.py`` keeps them too: the lowered program is
+written under ``<cache dir>/programs/`` by a machine's first start and
+loaded by every later one.  A warm start then pays, for a kernel, a
+parse of the stored module and a cache read.
 
 Placement rule: when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads
 it itself and nothing is set in code; otherwise the cache lives at the
 fixed path ``<checkout>/.jax_cache`` (git-ignored).  The path is part
 of the cache key, so it is never a temp name, a pid or a time.
+:func:`cache_dir` is that rule, for the persisted programs too.
 
 It is also where the program first touches the backend, so that the
 wall of that initialisation is the program's own number
@@ -33,6 +42,12 @@ DEFAULT_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
 _backend_timed = False
 
 
+def cache_dir() -> str:
+    """The directory of the placement rule above: the compile cache,
+    and under it the persisted programs (``core/programcache.py``)."""
+    return os.environ.get(CACHE_ENV) or str(DEFAULT_CACHE_DIR)
+
+
 def setup_jax() -> str:
     """Place the persistent compile cache, listen to JAX's compile
     events and initialise the backend; returns the cache directory in
@@ -44,12 +59,10 @@ def setup_jax() -> str:
 
     from ..observability.devicetelemetry import install_compile_listener
     install_compile_listener()
-    cache_dir = os.environ.get(CACHE_ENV)
-    if not cache_dir:
-        cache_dir = str(DEFAULT_CACHE_DIR)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    if not os.environ.get(CACHE_ENV):
+        jax.config.update("jax_compilation_cache_dir", cache_dir())
     _time_backend_init(jax)
-    return cache_dir
+    return cache_dir()
 
 
 def _time_backend_init(jax) -> None:

@@ -146,7 +146,8 @@ COMPILES = REGISTRY.counter(
 CACHE_HITS = REGISTRY.counter(
     "device_program_cache_hits_total",
     "Backend compiles of a named device program served from the "
-    "persistent compile cache (still traced and lowered)",
+    "persistent compile cache (lowered first: traced live, or parsed "
+    "from its persisted program, program_cache_total)",
     ("program",))
 COMPILE_SECONDS = REGISTRY.histogram(
     "device_program_compile_seconds",

@@ -15,7 +15,16 @@ takes the first hit.
 
 This module holds kernels only: the three jitted entry points
 (``pallas_search``, ``pallas_batch_search``, ``pallas_packed_search``),
-their shape constants and their ``register_program`` lines.  Which of
+their shape constants and their ``register_program`` lines.  The
+entries are ``persisted_jit``s (``core/programcache.py``): tracing one
+walks 160 unrolled rounds of Python and lowering the result to Mosaic
+takes as long again, 4-8 s a shape together, so on an accelerator a
+shape's lowered program is exported by a machine's first start, kept
+beside the compile cache and loaded by every later start, which runs
+the same Mosaic bytes without walking this file (an ``interpret=True``
+call, the ``cpu`` backend and a call under a trace go to the jitted
+function as before; an edit of this file, ``sha512_jax.py`` or
+``u64.py`` is another program).  Which of
 them serves an input, at which shape, and the host loop that launches
 it live one layer up, in ``pow/pipeline.py`` (``plan_batch``,
 ``_PipelineDriver``), which also places a queue over the chips of a
@@ -29,16 +38,23 @@ from __future__ import annotations
 import functools
 import math
 import operator
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..core.programcache import persisted_jit
 from ..observability.devicetelemetry import (POW_FLOPS_PER_HASH,
                                              register_program)
 from .sha512_jax import _H0, _K
 from .u64 import U32
+
+#: the files the three programs are traced from: the key of a
+#: persisted program holds their digest (core/programcache.py)
+_SOURCES = tuple(str(Path(__file__).with_name(name)) for name in (
+    "sha512_pallas.py", "sha512_jax.py", "u64.py"))
 
 LANE_COLS = 128
 #: sublanes of one 32-bit vreg: the slice of a tile hashed at a time
@@ -497,9 +513,10 @@ def _packed_kernel(ih_hi_ref, ih_lo_ref, t_hi_ref, t_lo_ref,
                         flag_ref[grp, pack] = flag_ref[grp, pack] + 1
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "chunks", "pack",
-                                             "unroll", "interpret"),
-                   donate_argnums=(1, 2))
+@persisted_jit(sources=_SOURCES,
+               static_argnames=("rows", "chunks", "pack", "unroll",
+                                "interpret"),
+               donate_argnums=(1, 2))
 def pallas_packed_search(ih_words, bases, targets, rows: int = DEFAULT_ROWS,
                          chunks: int = 16, pack: int = 16,
                          unroll: int = 1, interpret: bool = False):
@@ -605,8 +622,8 @@ def _batch_search(ih_words, bases, targets, *, rows, chunks, interpret,
     )(ih_words, bases, targets)
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",
-                                             "unroll"))
+@persisted_jit(sources=_SOURCES,
+               static_argnames=("rows", "chunks", "interpret", "unroll"))
 def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
                         chunks: int = 128, interpret: bool = False,
                         unroll: int = 1):
@@ -635,7 +652,8 @@ def pallas_batch_search(ih_words, bases, targets, rows: int = 256,
 #: trials) a 64-wide launch runs 0.45 s warm.  Mosaic compiled the
 #: 64-wide grid in minutes until PR 26 (CHANGES.md PR 22 has those
 #: times) and in seconds since; core/jaxsetup.py places the persistent
-#: cache that keeps it a once-per-machine cost.
+#: cache that keeps the compile a once-per-machine cost, and
+#: core/programcache.py keeps the trace and the lowering one too.
 BATCH_OBJS = 64
 #: grid steps an object of the pod's batch launches
 #: (parallel/pow_pallas_sharded.py, four tiles to a step there); the
@@ -661,8 +679,8 @@ BATCH_UNROLL = 1
 BATCH_INNER = 64
 
 
-@functools.partial(jax.jit, static_argnames=("rows", "chunks", "interpret",
-                                             "unroll"))
+@persisted_jit(sources=_SOURCES,
+               static_argnames=("rows", "chunks", "interpret", "unroll"))
 def pallas_search(ih_words, base, target, rows: int = 256,
                   chunks: int = 16, interpret: bool = False,
                   unroll: int = 1):

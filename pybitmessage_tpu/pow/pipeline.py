@@ -1363,9 +1363,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                    refilled=refilled, copied=copied,
                    device=lane) as span:
             # the kernels are called from this frame, not through a
-            # helper: on the chip the first call of a process (trace
-            # and lowering of pallas_search) took 2.5 times as long
-            # from one frame further down (PERF.md section 6, PR 29)
+            # helper: on the chip a process's first call, when it
+            # traced and lowered pallas_search, took 2.5 times as long
+            # from one frame further down (PERF.md section 6, PR 29).
+            # Since PR 48 only a machine's FIRST start traces here: the
+            # export of core/programcache.py, under a frame with a
+            # chunk of its own, so it no longer matters how deep this
+            # one lies (tools/first_solve_faults.py drives it); a later
+            # start loads the program and traces nothing.  The pin
+            # still serves the calls that trace live (an export that
+            # failed, the XLA stand-in)
             bases = np.array([_split64(b) for b in cand.bases],
                              dtype=np.uint32)
             if unbatched:
