@@ -131,6 +131,14 @@ def _family(name: str) -> dict[tuple, float]:
     return {values: float(child.value) for values, child in fam.children()}
 
 
+def _grown_by_label(name: str, before: dict) -> dict[str, int]:
+    """What each series of a one-label counter family grew by since
+    ``before`` (``_family``'s answer then), by that label."""
+    return {k[0]: int(v - before.get(k, 0))
+            for k, v in sorted(_family(name).items())
+            if v != before.get(k, 0)}
+
+
 def _hist(name: str, *labels: str) -> tuple[float, int]:
     """(sum, count) of one histogram series."""
     from pybitmessage_tpu.observability import REGISTRY
@@ -510,6 +518,7 @@ def run_pod(rep: Report) -> None:
     one device to compare with.  No ``shard_map`` program is lowered."""
     import jax
 
+    from pybitmessage_tpu.pow import pipeline
     from pybitmessage_tpu.pow.dispatcher import PowDispatcher
     from pybitmessage_tpu.pow.pipeline import solve_batch_pipelined
 
@@ -538,17 +547,19 @@ def run_pod(rep: Report) -> None:
     # every chip; lane k searches from the object's start plus k shares
     # of the nonce space, and the first harvest with a hit wins
     wins, share = "pow_pipeline_lone_wins_total", (1 << 64) // ndev
+    lanes, lag = ("pow_pipeline_lone_lanes_total",
+                  "pow_pipeline_lone_cancel_lag_steps")
     warm = _default_item(b"pod warm", 1016)
     timed("first solve, single, %d devices" % ndev, lambda: d(*warm))
     rep.check(d.last_backend == "tpu-pallas",
               "single solve backend %r" % d.last_backend)
     timed("first solve, single, 1 device", lambda: alone(warm))
-    before = _family(wins)
+    before, left_before, lag_before = (_family(wins), _family(lanes),
+                                       _hist(lag))
     res_n, dt_n = timed("single x%d, %d devices" % (len(singles), ndev),
                         lambda: [d(*it) for it in singles])
-    won = {k[0]: int(v - before.get(k, 0))
-           for k, v in sorted(_family(wins).items())
-           if v != before.get(k, 0)}
+    left, won = _grown_by_label(lanes, left_before), _grown_by_label(
+        wins, before)
     res_1, dt_1 = timed("single x%d, 1 device" % len(singles),
                         lambda: [alone(it) for it in singles])
     rep.check(all(_valid(it, r) for it, r in zip(singles, res_n))
@@ -563,6 +574,20 @@ def run_pod(rep: Report) -> None:
               % (ndev, won))
     rep.check(len(won) > 1,
               "winners came from more than one lane: %s" % sorted(won))
+    if pipeline._one_program(pipeline.default_impl(), jax.devices()):
+        # the chips of an accelerator: each of those solves was ONE
+        # program over them (``ops/sha512_ici.py``), whose kernels stop
+        # at the winner's flag, and every lane said how it left
+        steps, late = (a - b for a, b in zip(_hist(lag), lag_before))
+        rep.check(left.get("won") == len(singles)
+                  and sum(left.values()) % ndev == 0,
+                  "every lane of every launch of the one program "
+                  "reported how it left: %s" % left)
+        rep.check(left.get("cancelled", 0) > 0
+                  and late == left.get("cancelled"),
+                  "losers left on the winner's flag over ICI, %.2f "
+                  "grid steps past its hit on average"
+                  % (steps / max(late, 1)))
     rep.say("smoke timing, single x%d: %d lanes %.2fs wall, 1 device "
             "%.2fs wall" % (len(singles), ndev, dt_n, dt_1))
 

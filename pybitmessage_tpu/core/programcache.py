@@ -159,28 +159,35 @@ def _roomy_frame():
     return scope["roomy"]
 
 
-def export_program(jitted, platform: str, avals, static: dict):
+def export_program(jitted, platform: str, avals, static: dict,
+                   in_shardings=None):
     """``jitted`` traced and lowered for ``platform`` at ``avals``
     ((shape, dtype) pairs): the trace and lowering a first launch
-    pays, kept as a ``jax.export.Exported``."""
+    pays, kept as a ``jax.export.Exported``.  ``in_shardings``, one an
+    argument, for a program over several devices."""
     import jax
-    specs = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in avals]
+    specs = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for (shape, dtype), sharding in zip(
+                 avals, in_shardings or [None] * len(avals))]
     export = jax.export.export(jitted, platforms=(platform,))
     return _roomy_frame()(lambda: export(*specs, **static))
 
 
-def _fits(exported, platform: str, avals) -> bool:
+def _fits(exported, platform: str, avals, devices: int = 1) -> bool:
     return (tuple(exported.platforms) == (platform,)
+            and exported.nr_devices == devices
             and [(a.shape, a.dtype) for a in exported.in_avals]
             == [(tuple(shape), dtype) for shape, dtype in avals])
 
 
-def fetch(path: Path, jitted, platform: str, avals, static: dict):
+def fetch(path: Path, jitted, platform: str, avals, static: dict,
+          in_shardings=None):
     """``(outcome, exported)``: the program at ``path`` if it is whole
     and fits, else a new export written there (``error`` where it
     could not be written).  What the export itself raises is the
     caller's."""
     import jax
+    devices = _device_count(in_shardings)
     outcome = "miss"
     try:
         blob = path.read_bytes()
@@ -189,13 +196,14 @@ def fetch(path: Path, jitted, platform: str, avals, static: dict):
     if blob is not None:
         try:
             exported = jax.export.deserialize(bytearray(blob))
-            if _fits(exported, platform, avals):
+            if _fits(exported, platform, avals, devices):
                 return "hit", exported
         except Exception as exc:    # counted by the caller, as ``stale``
             logger.warning("persisted program %s does not deserialise "
                            "(%r)", path, exc)
         outcome = "stale"
-    exported = export_program(jitted, platform, avals, static)
+    exported = export_program(jitted, platform, avals, static,
+                              in_shardings)
     try:
         write_whole(path, exported.serialize())
     except OSError as exc:
@@ -205,12 +213,25 @@ def fetch(path: Path, jitted, platform: str, avals, static: dict):
     return outcome, exported
 
 
-def persisted_jit(*, sources, static_argnames, **jit_kwargs):
+def _device_count(in_shardings) -> int:
+    return len(in_shardings[0].mesh.devices.flat) if in_shardings else 1
+
+
+def persisted_jit(*, sources, static_argnames, shardings=None,
+                  **jit_kwargs):
     """``jax.jit`` for a kernel's entry point whose lowered program is
     kept beside the compile cache (module docstring).  ``sources`` are
     the files the program is traced from.  The entry keeps the
     function's signature, ``__wrapped__`` (the function itself) and
-    the jitted function's ``lower`` and ``trace``."""
+    the jitted function's ``lower`` and ``trace``.
+
+    ONE program over several devices (a ``shard_map`` inside) gives
+    its ``shardings``: ``(in_shardings, out_shardings)``, the first
+    one an argument, ``NamedSharding``s of one mesh.  The export is
+    made for as many devices as that mesh has, their number is in the
+    program's key, and the loaded program is called under them (the
+    jitted function itself takes its from the ``shard_map``: a jit
+    with ``in_shardings`` takes no static argument by name)."""
     import jax
 
     def decorate(fun):
@@ -234,7 +255,7 @@ def persisted_jit(*, sources, static_argnames, **jit_kwargs):
                     else accelerator()
                 run = None if platform is None else _program(
                     jitted, name, platform, static, key[1], sources,
-                    jit_kwargs)
+                    jit_kwargs, shardings)
                 loaded[key] = run
                 return run
 
@@ -263,21 +284,24 @@ def persisted_jit(*, sources, static_argnames, **jit_kwargs):
 
 
 def _program(jitted, name: str, platform: str, static: dict, avals,
-             sources, jit_kwargs):
+             sources, jit_kwargs, shardings=None):
     """The jit of ``jitted``'s persisted program at ``avals``, loaded
     or exported now; None where it has to be traced live."""
     import jax
     program = DEVICE_TELEMETRY.program_of(name) or name
+    in_shardings, out_shardings = shardings or (None, None)
+    keyed = static if in_shardings is None else dict(
+        static, devices=_device_count(in_shardings))
     with trace("program.load", program=program) as span:
         try:
             path = Path(cache_dir()) / PROGRAMS_DIR / (
-                name + "-" + program_key(name, static, avals,
+                name + "-" + program_key(name, keyed, avals,
                                          environment(),
                                          source_digest(sources))
                 + SUFFIX)
             t0 = time.monotonic()
             outcome, exported = fetch(path, jitted, platform, avals,
-                                      static)
+                                      static, in_shardings)
             if outcome == "hit":
                 LOAD_SECONDS.labels(program=program).observe(
                     time.monotonic() - t0)
@@ -295,4 +319,7 @@ def _program(jitted, name: str, platform: str, static: dict, avals,
         return exported.call(*arrays)
     # JAX's compile events and the module carry the entry's name
     call.__name__ = call.__qualname__ = name
+    if shardings:
+        jit_kwargs = dict(jit_kwargs, in_shardings=in_shardings,
+                          out_shardings=out_shardings)
     return jax.jit(call, **jit_kwargs)

@@ -61,6 +61,10 @@ every registration must have a row here):
   (``ops/pow_search.pow_verify_batch``).
 ``pallas_slab`` — Mosaic single-object slab kernel
   (``ops/sha512_pallas.pallas_search`` under ``solve``).
+``ici_slab`` — the single-object slab kernel as ONE program over
+  several chips, the first hit stopping the others over ICI
+  (``ops/sha512_ici.ici_search``; the pipeline's lone object on the
+  chips of an accelerator).
 ``batch_search`` — per-object batch kernel
   (``ops/sha512_pallas.pallas_batch_search``; also the pipeline's
   batched mode).

@@ -20,7 +20,12 @@ Four plan modes, one loop:
                  a second slab in flight only for an object hard
                  enough to be unlikely to hit in the first; on several
                  chips every chip searches a share of the nonce space
-                 of its own in shorter slabs, and the first hit wins;
+                 of its own and the first hit wins: as ONE program over
+                 the chips, whose kernels stop at the winner's flag
+                 (``sha512_ici.ici_search``, 512 steps a chip; its XLA
+                 equivalent under ``impl="xla"``); the Mosaic kernels'
+                 stand-ins on the tests' virtual CPU devices alone
+                 keep a launch a chip in shorter slabs;
 ``batched``      a queue of objects (``chan_storm_256``): the
                  per-object grid ``pallas_batch_search``, 64 objects a
                  launch at 1,024 steps of one tile of 64 rows;
@@ -48,7 +53,9 @@ resolves the object and every other slot of it is cancelled
 (docs/pow_pipeline.md, "A solve placed over several chips").  An
 object that is alone there (mode ``slab``) is laid out over every chip
 from its first launch on, the same way: one slot a chip, each at its
-own :func:`_copy_base`.
+own :func:`_copy_base`; where the plan says ``one_program``
+(:func:`_one_program`) its slots are ONE group and a launch ONE program
+over the devices.
 
 ``chunks`` is a static argument of every Mosaic kernel, so each value
 is a program of its own to trace, lower and compile, and one the chip
@@ -58,13 +65,16 @@ chip (PERF.md section 6, PR 27); :class:`SlabAutotuner` sizes the XLA
 tier's slabs only (``ops.pow_search.solve``), where a shape is cheap.
 
 The kernels that the benchmark's launch log and the tests replace
-(``pallas_search``, ``pallas_batch_search``) are looked up on
-``ops.sha512_pallas`` at every launch, never bound here by name.
+(``pallas_search``, ``pallas_batch_search``, ``sha512_ici.ici_search``)
+are looked up on their modules at every launch, never bound here by
+name.
 
 On hosts without an accelerator (the CI virtual CPU mesh) the Mosaic
 kernels are replaced by an XLA equivalent with the identical
-(objects, 3)-row output contract (``impl="xla"``), so planning, the
-driver and the metrics are exercised without a TPU — the same pattern
+(objects, 3)-row output contract (``impl="xla"``;
+:func:`_ici_search_xla` with ``ici_search``'s row a device for a lone
+object on several), so planning, the driver and the metrics are
+exercised without a TPU — the same pattern
 ``parallel/pow_pallas_sharded.py`` uses.
 """
 
@@ -89,7 +99,7 @@ from ..observability.flightrec import record as _flight
 from ..ops.pow_search import PowInterrupted
 from ..resilience.chaos import inject
 from ..resilience.watchdog import STALLS, SlabStallError
-from ..ops import sha512_pallas
+from ..ops import sha512_ici, sha512_pallas
 from ..ops.sha512_jax import double_sha512_trial
 from ..ops.sha512_pallas import (BATCH_OBJS, BATCH_ROWS, BATCH_UNROLL,
                                  DEFAULT_CHUNKS,
@@ -194,6 +204,30 @@ LONE_WINS = REGISTRY.counter(
     "Solves of one object laid out over several devices' lanes, by the "
     "lane whose launch found the nonce (0: the lane of the object's own "
     "range, which a resumed search goes on from)", ("lane",))
+#: how a lane of a lone object's ONE program over several chips left
+#: its launch (``ops/sha512_ici.py``)
+LONE_OUTCOMES = ("won", "cancelled", "own_hit", "ran_out")
+#: a row's ``why`` as the outcome of a lane whose hit was not taken
+_LONE_LEFT = {sha512_ici.OWN_HIT: "own_hit",
+              sha512_ici.CANCELLED: "cancelled",
+              sha512_ici.RAN_OUT: "ran_out"}
+LONE_LANES = REGISTRY.counter(
+    "pow_pipeline_lone_lanes_total",
+    "Lanes of the harvested launches of one object searched by several "
+    "devices as ONE program whose kernels stop at the first hit, by how "
+    "the lane left its launch: its hit is the one the host took (won), "
+    "it read the flag the winner had raised (cancelled), it hit in the "
+    "winner's step or before the flag reached it (own_hit), it ran "
+    "every step (ran_out)", ("outcome",))
+# every outcome exists at 0 from the start: "never" then reads 0, not
+# "no such series"
+for _outcome in LONE_OUTCOMES:
+    LONE_LANES.labels(outcome=_outcome)
+LONE_CANCEL_LAG = REGISTRY.histogram(
+    "pow_pipeline_lone_cancel_lag_steps",
+    "Grid steps a cancelled lane of such a launch ran past the step of "
+    "the winner's hit, a lane an observation",
+    buckets=(0.0,) + DEFAULT_SIZE_BUCKETS)
 LONE_HEAD = REGISTRY.histogram(
     "pow_pipeline_lone_head_seconds",
     "From the entry of a solve of one object (mode slab) to the return "
@@ -209,13 +243,18 @@ NEEDED_TRIALS = REGISTRY.counter(
     "Trials of harvested launches that a search needed: a slot that "
     "missed, its whole slab (a copy's too: it proved a range empty); a "
     "slot that hit, up to its winning nonce; a solved or pad slot, or "
-    "one whose object another slot had resolved by then, none",
+    "one whose object another slot had resolved by then, none; of a "
+    "lone object's ONE program over several devices (kind slab), the "
+    "winner's lane up to its nonce and every other lane up to the "
+    "winner's step",
     ("kind",))
 EXECUTED_TRIALS = REGISTRY.counter(
     "pow_pipeline_executed_trials_total",
     "Trials the device computed in harvested launches, counted by the "
     "grid steps each really ran (abandoned launches are not read "
-    "back, so their trials are not in here)", ("kind",))
+    "back, so their trials are not in here; every lane of a lone "
+    "object's ONE program over several devices reports its steps, the "
+    "losers' too)", ("kind",))
 
 
 class SlabAutotuner:
@@ -381,9 +420,38 @@ def _packed_search_xla(ih_words, bases, targets, lanes: int, chunks: int):
     return jax.vmap(one)(ih_words, bases, targets)
 
 
+@functools.partial(jax.jit, static_argnames=("lanes", "chunks", "lag"))
+def _ici_search_xla(operands, lanes: int, chunks: int, lag: int = 1):
+    """Same output contract as ``sha512_ici.ici_search`` in pure XLA.
+
+    ``operands`` is that entry's: a row a device (the words, that
+    device's base, the target); each row scans ``chunks`` chunks of
+    ``lanes`` consecutive nonces as :func:`_packed_search_xla` does.
+    The rows whose hit lies in the earliest step hit (``OWN_HIT``),
+    every other leaves ``lag`` steps past it (``CANCELLED``: on the
+    chip a loser reads the flag at the next step it begins, PERF.md
+    section 6, PR 49: 0.94 steps), and with no hit at all every row
+    runs out.  Returns (devices, ``sha512_ici.ROW_WORDS``) uint32.
+    """
+    found = _packed_search_xla(
+        operands[:, :16].reshape(-1, 8, 2), operands[:, 16:18],
+        operands[:, 18:20], lanes=lanes, chunks=chunks)
+    hit = jnp.where(found[:, 0] > 0, found[:, 0], chunks + 1)
+    first = hit.min()
+    own = (hit == first) & (first <= chunks)
+    ran = jnp.where(own, hit, jnp.minimum(first + lag, chunks))
+    why = jnp.where(own, sha512_ici.OWN_HIT, jnp.where(
+        first <= chunks, sha512_ici.CANCELLED, sha512_ici.RAN_OUT))
+    zero = jnp.zeros_like(hit)
+    return jnp.stack(
+        [jnp.where(own, hit, 0), jnp.where(own, found[:, 1], 0),
+         jnp.where(own, found[:, 2], 0), ran, why, zero, zero, zero],
+        axis=1).astype(U32)
+
+
 register_program("packed_search_xla", flops_per_item=POW_FLOPS_PER_HASH,
                  module="pow/pipeline.py",
-                 jit_names=("_packed_search_xla",))
+                 jit_names=("_packed_search_xla", "_ici_search_xla"))
 
 
 # ---------------------------------------------------------------------------
@@ -418,20 +486,26 @@ PACKED_GROUPS_MAX = 64
 #: steps takes one small launch at a time (mode ``single-sync``)
 SYNC_SINGLE_STEPS = 8
 #: grid steps of ``pallas_search`` that the lanes of a lone object on
-#: several chips launch TOGETHER, shared out evenly: 64 a chip on four
-#: (5.2e6 trials, 18 ms).  A lane's launch runs on to its OWN hit or its
-#: end after another lane has won, and the next solve's launch on that
-#: chip queues behind it, so a launch must not outlast the host's work
-#: between two solves by much (7-8 ms in ``single_send``); all lanes
-#: together it still holds a network-default object's expected work
-#: (1.1-1.6e7 trials) in one launch each, and an object so hard that
-#: it needs many has its next launch dispatched ahead.  Measured on
-#: four v5e chips, 200 lone solves at ``single_send``'s difficulties,
-#: pairs of solves a second by steps a lane: 32: 18.05, 64: 18.66,
-#: 128: 15.45, 512: 10.26; one chip at 512: 9.06 (PERF.md section 6,
-#: PR 43).  Again with a round of four launches at 2.7 ms of the host
-#: where it was 3.8 (``tools/lone_lanes_bench.py``, PR 44): 64: 20.58,
-#: 32: 20.26, 16: 18.21
+#: several chips launch TOGETHER where each lane is a launch of its own
+#: (the lay-out of ``impl="pallas"`` on virtual CPU devices, that is
+#: the tests'; everybody's until PR 49),
+#: shared out evenly: 64 a chip on four (5.2e6 trials, 18 ms).  There a
+#: lane's launch runs on to its OWN hit or its end after another lane
+#: has won, and the next solve's launch on that chip queues behind it,
+#: so a launch must not outlast the host's work between two solves by
+#: much (7-8 ms in ``single_send``); all lanes together it still holds a
+#: network-default object's expected work (1.1-1.6e7 trials) in one
+#: launch each, and an object so hard that it needs many has its next
+#: launch dispatched ahead.  Measured on four v5e chips, 200 lone
+#: solves at ``single_send``'s difficulties, pairs of solves a second
+#: by steps a lane: 32: 18.05, 64: 18.66, 128: 15.45, 512: 10.26; one
+#: chip at 512: 9.06 (PERF.md section 6, PR 43).  Again with a round of
+#: four launches at 2.7 ms of the host where it was 3.8
+#: (``tools/lone_lanes_bench.py``, PR 44): 64: 20.58, 32: 20.26, 16:
+#: 18.21.  Everywhere else the lanes are ONE program that stops at the
+#: first hit (``ops/sha512_ici.py``), and a launch is as long as on one
+#: chip (:func:`plan_batch`); this lay-out and its constant go with the
+#: tests that hold it (ROADMAP D10)
 LONE_LANES_CHUNKS = 256
 #: launch groups a ``batched`` solve of at most one launch's objects
 #: is laid out as: with two, the round-robin always finds a group with
@@ -444,13 +518,17 @@ class BatchPlan:
     """Which kernel at which static shape serves one batch (see
     :func:`plan_batch`)."""
 
-    __slots__ = ("mode", "pack", "chunks", "order")
+    __slots__ = ("mode", "pack", "chunks", "order", "one_program")
 
-    def __init__(self, mode: str, pack: int, chunks: int, order):
+    def __init__(self, mode: str, pack: int, chunks: int, order,
+                 one_program: bool = False):
         self.mode = mode        # "slab" | "batched" | "packed" | "single-sync"
         self.pack = pack        # objects per tile (packed mode)
         self.chunks = chunks    # grid steps per launch
         self.order = order      # item indices, difficulty-sorted
+        #: a lone object's lanes are ONE program over the devices that
+        #: stops at the first hit, not a launch a lane (mode "slab")
+        self.one_program = one_program
 
     def __repr__(self):  # pragma: no cover - debug aid
         return ("BatchPlan(mode=%r, pack=%d, chunks=%d, n=%d)"
@@ -459,18 +537,22 @@ class BatchPlan:
 
 def plan_batch(items, *, rows: int = DEFAULT_ROWS,
                unroll: int = 1, expect: int = 0,
-               lanes: int = 1) -> BatchPlan:
+               lanes: int = 1, one_program: bool = False) -> BatchPlan:
     """Choose the kernel and its geometry from the batch's size and
     difficulty — the only place that does.  ``expect`` is the number of
     objects the solve is to be laid out for, where more are announced
     than are there: the mode is then that of a queue of ``expect``
     objects (one object with announced company is a queue, not a lone
     object), read from the targets that are there.  ``lanes`` is the
-    number of devices the solve may be placed over.
+    number of devices the solve may be placed over, ``one_program``
+    whether a lone object's lanes are launched as one program whose
+    kernels stop at the first hit (:func:`_one_program`).
 
     One object alone searches whole slabs of ``pallas_search`` (mode
     ``slab``; on several ``lanes`` each searches a share of the nonce
-    space in slabs of ``LONE_LANES_CHUNKS / lanes`` steps), or, when it
+    space: in slabs of ``LONE_LANES_CHUNKS / lanes`` steps where a lane
+    is a launch of its own, in whole slabs where they are
+    ``one_program``), or, when it
     is expected to finish inside ``SYNC_SINGLE_STEPS`` grid steps, takes
     one small launch at a time (``single-sync``).  For a queue the pack
     factor is sized so one
@@ -493,11 +575,12 @@ def plan_batch(items, *, rows: int = DEFAULT_ROWS,
         # pallas_search (1024 asks for 1.01M of its 1.00M of SMEM;
         # tests/test_tpu_compile.py), and the grid leaves at its first
         # hit, so a long slab costs a short solve nothing; on several
-        # lanes it costs the next solve the losers' run to their own
-        # hits, so their slabs are short
-        chunks = DEFAULT_CHUNKS if lanes == 1 else max(
+        # lanes that nothing stops it costs the next solve the losers'
+        # run to their own hits, so their slabs are short
+        chunks = DEFAULT_CHUNKS if lanes == 1 or one_program else max(
             LONE_LANES_CHUNKS // lanes, 1)
-        return BatchPlan("slab", 1, chunks, [0])
+        return BatchPlan("slab", 1, chunks, [0],
+                         one_program=one_program and lanes > 1)
     order = sorted(range(there), key=lambda i: exp[i])
     med = sorted(exp)[there // 2]
     for p in PACK_CHOICES:
@@ -551,7 +634,10 @@ class _PipelineDriver:
     While ``run`` is under way every lane is in one of
     :data:`LANE_STATES` (:class:`_Lane`); ``devices`` are the JAX ids
     of the lanes' devices, which name their planes in a profiler trace
-    (the lanes' indices where none are given).
+    (the lanes' indices where none are given).  More ``devices`` than
+    ``lanes`` are shared out evenly: a lane's launches are then ONE
+    program over its devices (a lone object's, ``ops/sha512_ici.py``),
+    and every one of them is in the lane's state.
     """
 
     def __init__(self, *, depth: int = 2, lanes: int = 1,
@@ -724,8 +810,9 @@ class _PipelineDriver:
     def run(self, next_launch, harvest, done=None, load=None) -> None:
         queues = [deque() for _ in range(self.lanes)]
         t_start = time.monotonic()
-        self._lanes = [_Lane(k, self.devices[k], load, t_start)
-                       for k in range(self.lanes)]
+        per = len(self.devices) // self.lanes
+        self._lanes = [_Lane(k, self.devices[k * per:(k + 1) * per], load,
+                             t_start) for k in range(self.lanes)]
         try:
             while True:
                 inflight = sum(map(len, queues))
@@ -773,8 +860,9 @@ class _PipelineDriver:
                         room.remove(lane)
                     inflight += 1
                     LAUNCHES.labels(kind=self.kind).inc()
-                    # bounded by the host's device count
-                    DEVICE_LAUNCHES.labels(device="%d" % lane).inc()  # bmlint: allow(metric-labels)
+                    for k in range(lane * per, (lane + 1) * per):
+                        # bounded by the host's device count
+                        DEVICE_LAUNCHES.labels(device="%d" % k).inc()  # bmlint: allow(metric-labels)
                     PIPELINE_DEPTH.set(inflight)
                     _flight("slab_launch", n=self.slabs,
                             inflight=inflight)
@@ -803,7 +891,8 @@ class _PipelineDriver:
         """The share of the latest ``run``'s lane time with a launch in
         flight."""
         return self.lane_seconds["inflight"] / (
-            self.lanes * self.wall_seconds) if self.wall_seconds else 0.0
+            len(self.devices) * self.wall_seconds) \
+            if self.wall_seconds else 0.0
 
 
 class _Lane:
@@ -826,15 +915,20 @@ class _Lane:
     opened (``inflight`` is their absence inside a solve); every
     state's seconds are credited to
     ``pow_pipeline_lane_seconds_total`` on the host's clock when the
-    lane leaves it, so one ``run`` credits ``lanes`` times its wall
-    time whatever ends it.
+    lane leaves it, so one ``run`` credits its devices times its wall
+    time whatever ends it.  A lane whose launches are one program over
+    several devices opens an interval and credits the seconds for each
+    of them.
     """
 
-    __slots__ = ("lane", "device", "load", "state", "since", "seconds",
+    __slots__ = ("lane", "devices", "load", "state", "since", "seconds",
                  "_interval")
 
-    def __init__(self, lane: int, device: int, load, now: float):
-        self.lane, self.device, self.load = lane, device, load
+    def __init__(self, lane: int, devices, load, now: float):
+        #: the ids of the devices a launch of the lane runs on: one, or
+        #: all that one program spans
+        self.lane, self.devices, self.load = lane, tuple(devices), load
+        #: the open interval of each device (None: none is open)
         self.state, self.since, self._interval = None, now, None
         self.seconds = dict.fromkeys(LANE_STATES, 0.0)
         self.enter("turn")
@@ -845,24 +939,29 @@ class _Lane:
         if state == self.state:
             return
         if self._interval is not None:
-            end = self._interval.close().end
+            end = [iv.close().end for iv in self._interval][0]
             now = end if now is None else now
             self._interval = None
         if state in ("turn", "starved"):
-            attrs = dict(device=self.device, lane=self.lane,
+            attrs = dict(lane=self.lane,
                          live=self.load(self.lane) if self.load else 0)
-            self._interval = interval("pow.lane.turn", **attrs) \
-                if state == "turn" else interval("pow.lane.starved", **attrs)
-            start = self._interval.open().start
+            self._interval = tuple(
+                interval("pow.lane.turn", device=device, **attrs)
+                if state == "turn"
+                else interval("pow.lane.starved", device=device, **attrs)
+                for device in self.devices)
+            start = [iv.open().start for iv in self._interval][0]
             now = start if now is None else now
         elif now is None:
             now = time.monotonic()
         if self.state is not None:
-            self.seconds[self.state] += now - self.since
-            # bounded by the host's device count
-            LANE_SECONDS.labels(  # bmlint: allow(metric-labels)
-                device="%d" % self.device, state=self.state).inc(
-                    now - self.since)
+            self.seconds[self.state] += (now - self.since) \
+                * len(self.devices)
+            for device in self.devices:
+                # bounded by the host's device count
+                LANE_SECONDS.labels(  # bmlint: allow(metric-labels)
+                    device="%d" % device, state=self.state).inc(
+                        now - self.since)
             self.since = now
         self.state = state
 
@@ -1025,6 +1124,18 @@ def _pow2_at_least(n: int, cap: int) -> int:
     return min(p, cap)
 
 
+def _one_program(impl: str, devices) -> bool:
+    """Whether several ``devices`` search a lone object as ONE program
+    whose parts stop at the first hit (``sha512_ici.ici_search``, or
+    its XLA equivalent :func:`_ici_search_xla`).  They do, but for the
+    Mosaic kernel on the tests' virtual CPU devices, which keep a
+    launch a lane: the TPU interpreter cannot read a semaphore on the
+    ``cpu`` backend, so that program cannot run there."""
+    return len(devices) > 1 and (
+        impl != "pallas"
+        or getattr(devices[0], "platform", "cpu") != "cpu")
+
+
 def _slab_rows(out, found):
     """``pallas_search``'s output (a hit flag and a nonce a grid step)
     as the one ``[hit_step + 1, nonce_hi, nonce_lo]`` row the other
@@ -1128,6 +1239,24 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     flight are likely to end the object.  ``progress`` and a resumed
     ``start_nonces`` are lane 0's, the object's own range.
 
+    Where the plan says ``one_program`` (:func:`_one_program`: the
+    chips of an accelerator, and the XLA equivalent anywhere) those
+    lanes are ONE program, ``sha512_ici.ici_search``: one group whose slots
+    are the lanes' ranges, one driver lane that spans the devices, the
+    operands one array riding the call, a launch as long as one chip's
+    (512 steps each).  Its kernels read a flag at every grid step that
+    the chip whose step hits raises on the others over ICI, so a solve
+    is one launch, one fetch and one harvest, nothing runs on after
+    the win, and every lane's row says how many steps it ran and why
+    it left: ``executed_trials`` and ``pow_pipeline_executed_trials_total``
+    count every lane's steps, the needed trials the winner's up to its
+    nonce and the others' up to the winner's step,
+    ``pow_pipeline_lone_lanes_total`` how each lane left and
+    ``pow_pipeline_lone_cancel_lag_steps`` the steps a cancelled lane
+    ran past the winner's.  Of two lanes that hit in one step the host
+    takes the first; an object that outlasts a launch has its next one
+    dispatched ahead by the same rule.
+
     Resilience hooks (docs/resilience.md): ``start_nonces`` resumes
     each object from a checkpointed offset; ``progress(i, next)`` is
     invoked at every harvest with the end of the slab range just
@@ -1153,7 +1282,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     if plan is None:
         with trace("pow.plan", objects=n, expect=expect) as span:
             plan = plan_batch(items, rows=rows, unroll=unroll,
-                              expect=expect, lanes=len(devices))
+                              expect=expect, lanes=len(devices),
+                              one_program=_one_program(impl, devices))
             span.attrs.update(mode=plan.mode, chunks=plan.chunks)
     PIPELINE_MODE.labels(mode=plan.mode).inc()
     mode, pack, chunks = plan.mode, plan.pack, plan.chunks
@@ -1163,8 +1293,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         feed = None             # only a queue of whole tiles takes in
     if mode == "single-sync":
         devices = devices[:1]   # one small launch at a time: one lane
-    #: one object shared out over the lanes, a slot of it on each
+    #: one object shared out over the devices, a slot of it on each
     lone = mode == "slab" and len(devices) > 1
+    #: its slots are ONE group and a launch ONE program over the
+    #: devices, which stops every slot at the first hit
+    spanned = lone and plan.one_program
 
     # the launch geometry of each mode, and the jitted program it
     # launches with the static-shape key that decides compile-vs-cache
@@ -1187,7 +1320,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     step_trials = (rows // pack) * LANE_COLS * unroll
     slab_trials = step_trials * chunks          # per object per launch
     if not pallas:
-        tele_prog, tele_key = "packed_search_xla", (step_trials, chunks)
+        tele_prog, tele_key = "packed_search_xla", (
+            step_trials, chunks) + ((len(devices),) if spanned else ())
+    elif spanned:
+        tele_prog, tele_key = "ici_slab", (rows, chunks, unroll,
+                                           interpret, len(devices))
     elif mode == "slab":
         tele_prog, tele_key = "pallas_slab", (rows, chunks, unroll,
                                               interpret)
@@ -1200,7 +1337,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     # the packed Mosaic kernel donates its base/target input buffers,
     # which is why they are made anew for every launch
     donated = pallas and mode in ("packed", "single-sync")
-    unbatched = pallas and mode == "slab"
+    unbatched = pallas and mode == "slab" and not spanned
 
     # what each group starts with: ``width`` objects of the plan's
     # order; or, for a queue that would fill fewer groups than
@@ -1213,6 +1350,9 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         per = -(-n // count)
         shares = [plan.order[s:s + per]
                   for s in range(0, per * count, per)]
+    elif spanned:
+        width = len(devices)
+        shares = [plan.order * width]
     elif lone:
         shares = [plan.order] * len(devices)
     else:
@@ -1229,11 +1369,16 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
                                words_on_device=mode != "slab")
                   for j, share in enumerate(shares)]
         if lone:
-            for k, g in enumerate(groups[1:], 1):
-                g.bases[0] = _copy_base(starts[0], k, len(devices))
-                g.copy[0] = True
-    #: the groups of each device, and where its round-robin stands
-    lanes = [groups[k::len(devices)] for k in range(len(devices))]
+            # slot ``k`` of the object: a group's one, or the one
+            # group's ``k``-th
+            for k in range(1, len(devices)):
+                g, slot = (groups[0], k) if spanned else (groups[k], 0)
+                g.bases[slot] = _copy_base(starts[0], k, len(devices))
+                g.copy[slot] = True
+    #: the groups of each lane (a device; or all of them, where a launch
+    #: is one program over them), and where its round-robin stands
+    lanes = [groups] if spanned else [
+        groups[k::len(devices)] for k in range(len(devices))]
     rr = [0] * len(lanes)
     results: list = [None] * n
     executed = {"trials": 0, "copies": 0}
@@ -1245,7 +1390,8 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
     for j, g in enumerate(groups):
         for k, i in enumerate(g.idx):
             if i is not None:
-                held.setdefault(i, {})[j % len(lanes)] = (g, k)
+                held.setdefault(i, {})[k if spanned
+                                       else j % len(lanes)] = (g, k)
     #: whether a lane that has run out has another lane to copy from
     may_copy = mode == "batched" and len(lanes) > 1
 
@@ -1302,9 +1448,11 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             if g.finished:
                 continue
             # a lone object is searched by every lane's unread launches
-            unread = sum(h.unread for h in groups) if lone else g.unread
+            unread = sum(h.unread * h.width for h in groups) if lone \
+                else g.unread
             ahead = worth_speculating(slab_trials * unread,
-                                      g.live_targets())
+                                      g.targets[:1] if spanned
+                                      else g.live_targets())
             SPECULATION.labels(
                 kind=kind,
                 decision="launched" if ahead else "withheld").inc()
@@ -1356,7 +1504,7 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             # in the first round or a later one
             ih_words = cand.words[0]
             base = _pair_on_device(cand.bases[0], cand.device)
-        else:
+        elif not spanned:       # whose operands are one array, below
             ih_words = cand.device_words()
         with trace("pow.launch", program=tele_prog, chunks=chunks,
                    live=live, speculative=speculative,
@@ -1375,7 +1523,21 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             # failed, the XLA stand-in)
             bases = np.array([_split64(b) for b in cand.bases],
                              dtype=np.uint32)
-            if unbatched:
+            if spanned:
+                # ONE array, a row a device (the words, that device's
+                # base, the target), rides the call as numpy: a
+                # transfer a device
+                operands = np.concatenate(
+                    [cand.words.reshape(cand.width, 16), bases,
+                     cand.t_arr], axis=1)
+                if pallas:
+                    out = sha512_ici.ici_search(
+                        operands, devices, rows=rows, chunks=chunks,
+                        unroll=unroll, interpret=interpret)
+                else:
+                    out = _ici_search_xla(operands, lanes=step_trials,
+                                          chunks=chunks)
+            elif unbatched:
                 out = sha512_pallas.pallas_search(
                     ih_words, base, cand.t_arr[0], rows=rows,
                     chunks=chunks, unroll=unroll, interpret=interpret)
@@ -1411,12 +1573,65 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
         with trace("pow.harvest", device=tag[1]) as span:
             _harvest(tag, host, span.start)
 
+    def _harvest_spanned(g, rows_out, end_bases) -> int:
+        """A launch of the one program over the devices: every lane's
+        row says how many steps it ran and why it left, so what each
+        computed is known, the losers' too.  The trials it needed."""
+        ran = [int(r[sha512_ici.STEPS]) for r in rows_out]
+        executed["trials"] += sum(ran) * step_trials
+        hits = [k for k in range(g.width) if rows_out[k, sha512_ici.HIT]]
+        # two lanes that hit in one step both report: the first step's,
+        # then the first lane's
+        win = min(hits, key=lambda k: int(rows_out[k, sha512_ici.HIT]),
+                  default=None)
+        step1 = int(rows_out[win, sha512_ici.HIT]) if hits else 0
+        # a launch dispatched ahead and read after the one before it
+        # had hit: computed, needed by nobody, and no second result
+        late = g.finished
+        for k, n in enumerate(ran):
+            outcome = "won" if k == win and not late else _LONE_LEFT.get(
+                int(rows_out[k, sha512_ici.WHY]), "ran_out")
+            LONE_LANES.labels(outcome=outcome).inc()
+            if outcome == "cancelled":
+                LONE_CANCEL_LAG.observe(max(n - step1, 0))
+            if not late:
+                g.trials[k] += n * step_trials
+        if late:
+            return 0
+        i = g.idx[0]
+        if not hits:
+            # nobody raised the flag: every lane ran its whole slab
+            if progress is not None:
+                progress(i, end_bases[0])
+            return sum(ran) * step_trials
+        nonce = _checked_nonce(
+            (int(rows_out[win, sha512_ici.NONCE_HI]) << 32)
+            | int(rows_out[win, sha512_ici.NONCE_LO]),
+            items[i][0], g.targets[win])
+        del held[i]
+        results[i] = (nonce, sum(g.trials))
+        for k in range(g.width):
+            g.retire(k)
+        # bounded by the host's device count
+        LONE_WINS.labels(lane="%d" % win).inc()  # bmlint: allow(metric-labels)
+        if on_solved is not None:
+            on_solved(i, results[i])
+        # the winner needed its steps up to the nonce, every other lane
+        # what it searched while the object was unresolved
+        return min((nonce - end_bases[win] + slab_trials + 1) & _MASK64,
+                   step1 * step_trials) + sum(
+                       min(n, step1) * step_trials
+                       for k, n in enumerate(ran) if k != win)
+
     def _harvest(tag, host, t_h):
         g, _lane, t0, t1, end_bases, out = tag
         rows_out = _slab_rows(out, host) if unbatched else host
         g.unread -= 1
-        before, needed = executed["trials"], 0
-        for k in range(g.width):
+        before = executed["trials"]
+        # the one program's lanes report for themselves; else slot by
+        # slot
+        needed = _harvest_spanned(g, rows_out, end_bases) if spanned else 0
+        for k in () if spanned else range(g.width):
             step1 = int(rows_out[k, 0])
             if g.done[k]:
                 # a pad or solved slot's one always-hit step; or what a
@@ -1492,12 +1707,12 @@ def solve_batch_pipelined(items, *, rows: int = DEFAULT_ROWS,
             raise
     if mode == "slab" and len(head) == len(lanes):
         # bounded by the host's device count
-        LONE_HEAD.labels(lanes="%d" % len(lanes)).observe(  # bmlint: allow(metric-labels)
+        LONE_HEAD.labels(lanes="%d" % len(devices)).observe(  # bmlint: allow(metric-labels)
             max(head.values()) - t_entry)
     if stats is not None:
         stats.update(
             mode=mode, pack=pack, width=width, chunks=chunks,
-            groups=len(groups), devices=len(lanes),
+            groups=len(groups), devices=len(devices),
             launches=driver.slabs, copies=executed["copies"],
             executed_trials=executed["trials"],
             credited_trials=sum(r[1] for r in results),

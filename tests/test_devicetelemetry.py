@@ -70,7 +70,7 @@ def test_catalog_registration_lockstep():
     _import_launch_modules()
     catalog = set(re.findall(r"^``([a-z_][a-z0-9_.]*)``",
                              devicetelemetry.__doc__, re.MULTILINE))
-    assert len(catalog) == 12
+    assert len(catalog) == 13
     registered = set(DEVICE_TELEMETRY.programs())
     assert catalog == registered, (
         "catalog rows and register_program() calls drifted: "

@@ -979,8 +979,8 @@ def test_metric_naming_runtime_complement():
         if isinstance(fam, Counter):
             assert fam.name.endswith("_total"), fam.name
         elif isinstance(fam, Histogram):
-            assert fam.name.endswith(("_seconds", "_size", "_bytes")), \
-                fam.name
+            assert fam.name.endswith(("_seconds", "_size", "_bytes",
+                                      "_steps")), fam.name
         elif isinstance(fam, Gauge):
             assert not fam.name.endswith("_total"), fam.name
 

@@ -60,17 +60,33 @@ def tree(tmp_path_factory):
     return root
 
 
+@pytest.mark.parametrize("one_program", [False, True],
+                         ids=["a_launch_a_lane", "one_program"])
 def test_every_send_is_two_lone_objects_on_the_pipeline_s_lanes(
-        tree, four_chips, monkeypatch):  # noqa: F811
+        one_program, tree, four_chips, monkeypatch):  # noqa: F811
+    """As the virtual devices get it (the Mosaic kernels' stand-ins, a
+    launch a lane), and as an accelerator's chips do since ISSUE 49:
+    the lanes ONE program that stops at the first hit, its XLA
+    equivalent standing where ``ici_search`` is."""
     from pybitmessage_tpu import parallel
     from pybitmessage_tpu.core.jaxsetup import setup_jax
     from pybitmessage_tpu.observability import TRACER
+    from pybitmessage_tpu.ops import sha512_ici
+    from pybitmessage_tpu.pow import pipeline
 
     def never(*_a, **_kw):
         raise AssertionError("the node called the shard_map partition")
 
     monkeypatch.setattr(parallel, "pallas_sharded_solve", never)
     monkeypatch.setattr(parallel, "pallas_sharded_solve_batch", never)
+    if one_program:
+        def ici_search(operands, devices, rows, chunks, unroll, interpret):
+            return pipeline._ici_search_xla(
+                operands, lanes=rows * 128 * unroll, chunks=chunks)
+
+        monkeypatch.setattr(sha512_ici, "ici_search", ici_search)
+        monkeypatch.setattr(pipeline, "_one_program",
+                            lambda impl, devices: len(devices) > 1)
     setup_jax()
     TRACER.clear()
     lines = []
@@ -91,16 +107,39 @@ def test_every_send_is_two_lone_objects_on_the_pipeline_s_lanes(
     metrics = {k: v["value"] for k, v in result["metrics"].items()}
     lone4 = {m["name"] for m in bench.metrics("per_layer")
              if m["name"].endswith(".lone4")}
-    assert len(lone4) == 12
-    # every new metric but the two that need a device's planes
-    assert lone4 - set(metrics) == {"kernel_mhash_per_s.lone4",
-                                    "chip_busy_share_min.lone4"}
+    assert len(lone4) == 15
+    # every metric of the cell but the two that need a device's planes
+    # and, a launch a lane, the two of the first-hit flag (ISSUE 49);
+    # under the one program the launch log sees no slab, so the share
+    # that reads it is left out unless a tiny ack went lane by lane
+    absent = lone4 - set(metrics)
+    if one_program:
+        assert {"kernel_mhash_per_s.lone4", "chip_busy_share_min.lone4"} \
+            <= absent <= {"kernel_mhash_per_s.lone4",
+                          "chip_busy_share_min.lone4",
+                          "useful_trial_share.lone4"}
+        assert 0 <= metrics["cancel_lag_steps.lone4"] <= 1
+        assert 0 <= metrics["cancelled_lane_share.lone4"] <= 100
+        lanes = window.counters.delta("pow_pipeline_lone_lanes_total")
+        # four rows a harvested launch, a winner a solve (at this
+        # tile most launches run out)
+        assert sum(lanes.values()) % 4 == 0
+        assert lanes[("won",)] == sum(window.counters.delta(
+            "pow_pipeline_lone_wins_total").values()) > 0
+    else:
+        assert absent == {"kernel_mhash_per_s.lone4",
+                          "chip_busy_share_min.lone4",
+                          "cancel_lag_steps.lone4",
+                          "cancelled_lane_share.lone4"}
+        assert 0 < metrics["useful_trial_share.lone4"] <= 100
+    # the kernel's rate under the one program needs a device's planes
+    assert "kernel_mhash_per_s.ici4" not in metrics
+    assert 0 < metrics["executed_useful_share.lone4"] <= 100
     assert metrics["solves_per_msg.lone4"] == 2.0
     assert metrics["off_device_solves"] == 0
     assert metrics["compiles_in_window"] == 0
     assert metrics["program_lowerings_in_window.lone4"] == 0
     assert 0 <= metrics["partition_win_share.lone4"] <= 100
-    assert 0 < metrics["useful_trial_share.lone4"] <= 100
     assert metrics["pow_wait_ms.lone4"] > 0
     assert metrics["pipeline_host_ms_per_launch.lone4"] > 0
     assert metrics["sender_host_ms_per_msg.lone4"] > 0
@@ -124,7 +163,13 @@ def test_every_send_is_two_lone_objects_on_the_pipeline_s_lanes(
     launched = counters.delta("pow_pipeline_device_launches_total")
     assert set(launched) == {("0",), ("1",), ("2",), ("3",)}, launched
     # the launch log saw every launch, abandoned ones too, through the
-    # entries kernels.json names
-    assert {r["program"] for r in window.launches} <= {"slab", "packed"}
-    assert len(window.launches) \
-        == sum(counters.delta("pow_pipeline_launches_total").values())
+    # entries kernels.json names; the one program is none of them
+    assert {r["program"] for r in window.launches} <= (
+        {"packed"} if one_program else {"slab", "packed"})
+    if one_program:
+        programs = {s.attrs["program"] for s in TRACER.recent(
+            8 * sends, name="pow.launch")}
+        assert "ici_slab" in programs and "pallas_slab" not in programs
+    else:
+        assert len(window.launches) == sum(
+            counters.delta("pow_pipeline_launches_total").values())

@@ -478,6 +478,108 @@ def test_the_cache_directory_is_the_one_setup_jax_places(monkeypatch,
     assert jaxsetup.cache_dir() == str(jaxsetup.DEFAULT_CACHE_DIR)
 
 
+# -- ONE program over several devices (ISSUE 49) -------------------------
+
+
+class Spanning(Store):
+    """A ``shard_map`` over four devices under the store: each device
+    adds its place in the mesh and ``step``, and all gather the rows."""
+
+    def process(self):
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        mesh = Mesh(np.array(jax.devices()[:4]), ("nonce",))
+
+        def add_place(x, step: int = 1, interpret: bool = True):
+            self.traced += 1
+
+            def one(row):
+                place = jax.lax.axis_index("nonce").astype(jnp.uint32)
+                return jax.lax.all_gather(row + place + jnp.uint32(step),
+                                          "nonce", tiled=True)
+            return jax.shard_map(one, mesh=mesh, in_specs=P("nonce", None),
+                                 out_specs=P(), check_vma=False)(x)
+        pc.source_digest.cache_clear()
+        return pc.persisted_jit(
+            sources=(str(self.source),),
+            static_argnames=("step", "interpret"),
+            shardings=((NamedSharding(mesh, P("nonce", None)),),
+                       NamedSharding(mesh, P())))(add_place)
+
+    @staticmethod
+    def counted():
+        fam = REGISTRY.get("program_cache_total")
+        return {values[1]: child.value for values, child in fam.children()
+                if values[0] == "add_place"}
+
+
+ROWS4 = np.arange(4 * 8, dtype=np.uint32).reshape(4, 8)
+PLACED = ROWS4 + np.arange(4, dtype=np.uint32)[:, None]
+
+
+def test_a_program_over_four_devices_is_exported_once_and_loaded_after(
+        tmp_path, monkeypatch):
+    store = Spanning(tmp_path, monkeypatch)
+    before = store.counted()
+    first = store.process()
+    out = first(ROWS4, step=2, interpret=False)
+    np.testing.assert_array_equal(out, PLACED + 2)
+    # the same answer on every device: the host reads one
+    assert len(out.devices()) == 4 and out.is_fully_replicated
+    (name,) = store.files()
+    assert re.fullmatch(r"add_place-[0-9a-f]{40}\.jaxexport", name)
+    stored = jax.export.deserialize(bytearray(
+        (store.dir / "programs" / name).read_bytes()))
+    assert stored.nr_devices == 4
+    later = store.process()
+    np.testing.assert_array_equal(later(ROWS4, step=2, interpret=False),
+                                  PLACED + 2)
+    assert store.traced == 1, "a loaded program traced its function"
+    assert _grown(before, store.counted()) == {"miss": 1, "hit": 1}
+    assert store.files() == [name]
+
+
+def test_the_device_count_is_in_a_spanning_program_s_key():
+    static = {"rows": 128, "chunks": 512}
+    avals = [((4, 20), U32)]
+    ENV = ("0.9.0", "0.9.0", "tpu", "libtpu 0.0.34", "TPU v5 lite")
+    one = pc.program_key("ici_search", static, avals, ENV, "digest")
+    four = pc.program_key("ici_search", dict(static, devices=4), avals,
+                          ENV, "digest")
+    eight = pc.program_key("ici_search", dict(static, devices=8), avals,
+                           ENV, "digest")
+    assert len({one, four, eight}) == 3
+
+
+def test_a_file_made_for_another_number_of_devices_is_stale(
+        tmp_path, monkeypatch):
+    """The key holds the device count, so only a file put there by hand
+    can disagree: it is replaced, never called."""
+    store = Spanning(tmp_path, monkeypatch)
+    store.process()(ROWS4, step=2, interpret=False)
+    (name,) = store.files()
+    single = jax.export.export(jax.jit(lambda x: x + jnp.uint32(1)),
+                               platforms=("cpu",))(
+        jax.ShapeDtypeStruct((4, 8), U32))
+    (store.dir / "programs" / name).write_bytes(single.serialize())
+    before = store.counted()
+    np.testing.assert_array_equal(
+        store.process()(ROWS4, step=2, interpret=False), PLACED + 2)
+    assert _grown(before, store.counted()) == {"stale": 1}
+    assert store.traced == 2
+
+
+def test_the_search_programs_keys_do_not_hold_a_device_count(store):
+    """A program of one device is keyed as it was before there were
+    others: the three search programs' files stay where every machine's
+    caches have them."""
+    store.process()(X, step=1, interpret=False)
+    (name,) = store.files()
+    static = {"step": 1, "interpret": False}
+    assert name == "add_step-%s.jaxexport" % pc.program_key(
+        "add_step", static, [(X.shape, X.dtype)], pc.environment(),
+        pc.source_digest((str(store.source),)))
+
+
 @pytest.mark.slow
 def test_the_search_kernel_exported_for_the_cpu_answers_as_the_live_call():
     """``pallas_search`` in interpret mode (minutes on the CPU), one

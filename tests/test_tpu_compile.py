@@ -137,6 +137,32 @@ def test_the_slab_a_lone_object_s_lanes_launch_compiles(one_chip, lanes):
     assert _has_kernel(compiled)
 
 
+def test_the_one_program_of_a_lone_object_compiles_for_four_chips(topo):
+    """``ici_search`` (ISSUE 49): the slab kernel at its production
+    shape as ONE program over the four chips, with the semaphore each
+    grid step reads, the remote signals of a hit and of leaving, and
+    the barrier of its ``collective_id``; the rows gathered on every
+    chip.  What the chip's compiler refuses of those, it refuses
+    here."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from pybitmessage_tpu.ops import sha512_ici as ici
+    from pybitmessage_tpu.ops import sha512_pallas as sp
+    from pybitmessage_tpu.pow.pipeline import plan_batch
+    plan = plan_batch([(b"", 2 ** 64 // 10 ** 7)], lanes=4,
+                      one_program=True)
+    assert plan.chunks == sp.DEFAULT_CHUNKS
+    entry = ici._entry(tuple(topo.devices))
+    mesh = ici.make_mesh(devices=topo.devices, axis=ici.AXIS)
+    compiled = entry.lower(
+        _u32((4, ici.OPERAND_WORDS),
+             NamedSharding(mesh, P(ici.AXIS, None))),
+        rows=sp.DEFAULT_ROWS, chunks=plan.chunks,
+        unroll=sp.DEFAULT_UNROLL).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "all-gather" in text
+
+
 def test_pallas_search_has_no_larger_shape_on_a_v5e(one_chip):
     """Twice ``DEFAULT_CHUNKS`` is what PR 24's autotuner asked for at
     a node's second single solve: the chip's compiler refuses it (its

@@ -31,7 +31,7 @@ import re
 from ..core import FileCtx, Finding, call_name, str_const
 
 _SNAKE = re.compile(r"^[a-z][a-z0-9_]*$")
-_HISTOGRAM_UNITS = ("_seconds", "_size", "_bytes")
+_HISTOGRAM_UNITS = ("_seconds", "_size", "_bytes", "_steps")
 _FACTORIES = ("counter", "gauge", "histogram")
 _CONSTRUCTORS = ("Counter", "Gauge", "Histogram")
 _PEERISH = frozenset({"peer", "peers", "addr", "address", "host",
@@ -87,7 +87,7 @@ class MetricsChecker:
         if kind == "histogram" and \
                 not mname.endswith(_HISTOGRAM_UNITS):
             problems.append("histogram needs a unit suffix "
-                            "(_seconds/_bytes/_size)")
+                            "(_seconds/_bytes/_size/_steps)")
         if kind == "gauge" and mname.endswith("_total"):
             problems.append("gauge must not end _total")
         for ln in self._label_names(node):

@@ -21,9 +21,26 @@ beside ``pipeline.LONE_LANES_CHUNKS``.
 ``jax.device_put`` for every lane), a launch at a time on an idle
 device.
 
+``--ici``: the precheck of the first-hit flag (ROADMAP S16; PERF.md
+section 6, PR 49): ``ops/sha512_ici.ici_search`` ALONE on the chips of
+this host, no pipeline around it, the same solves with the same sleep
+between, one launch of 512 steps a chip a solve.  Three numbers decide:
+what ONE dispatch over the chips costs the host (``dispatch``), how
+long after the winner's hit the last chip is out (``lag_steps`` a
+cancelled chip ran past the winner's step, in milliseconds by the
+measured time of a step; ``over_search`` is everything a solve's wall
+holds beyond the winner's own steps), and the pairs of solves a second
+beside the lay-out of a launch a lane in the same call (``--chunks
+64``: the tool's own plan holds those runs to that lay-out, which an
+accelerator's chips no longer get from the pipeline's; a last run has
+the one program under the pipeline, as the node launches it).
+
     chiprun --chips 4 -- python3 tools/lone_lanes_bench.py --split \
         --chunks 64,32,128 --micro
+    chiprun --chips 4 -- python3 tools/lone_lanes_bench.py --ici \
+        --chunks 64
     JAX_PLATFORMS=cpu python tools/lone_lanes_bench.py --tiny --split
+    JAX_PLATFORMS=cpu python tools/lone_lanes_bench.py --tiny --ici
 
 Times are the host's clock; ``--tiny`` (the CPU's virtual devices, the
 XLA stand-in at eight rows) rehearses the script and measures nothing.
@@ -40,6 +57,9 @@ from pathlib import Path
 
 #: the cell's two difficulties: an ack's and a message's expected trials
 EXPECTED = (1.08e7, 1.56e7)
+#: ``--ici``: a flag that never comes would hang a chip for the call's
+#: whole time limit; after this long the tool leaves with what it knows
+ICI_PATIENCE_S = 300.0
 
 
 def _ms(values) -> dict:
@@ -69,9 +89,12 @@ class Clocked:
         return out
 
 
-def solves(args, devices, chunks, split):
+def solves(args, devices, chunks, split, one_program=False):
     """``args.solves`` lone solves at ``chunks`` steps a launch; the
-    rates, and with ``split`` the head's parts."""
+    rates, and with ``split`` the head's parts.  The plan is the
+    tool's: a launch a lane (the lay-out ``LONE_LANES_CHUNKS`` is sized
+    for), or with ``one_program`` the lanes as the one program that an
+    accelerator's chips get from the pipeline's own plan."""
     import jax
     from pybitmessage_tpu.observability import REGISTRY, TRACER
     from pybitmessage_tpu.ops import sha512_pallas
@@ -83,7 +106,9 @@ def solves(args, devices, chunks, split):
     expected = (3e4, 5e4) if args.tiny else EXPECTED
     objs = [(hashlib.sha512(b"lone lanes bench %d" % i).digest(),
              int(2 ** 64 / expected[i % 2])) for i in range(args.solves)]
-    plan = pipeline.BatchPlan("slab", 1, chunks, [0])
+    one_program = one_program and lanes > 1
+    plan = pipeline.BatchPlan("slab", 1, chunks, [0],
+                              one_program=one_program)
 
     def solve(item, stats=None):
         return pipeline.solve_batch_pipelined(
@@ -145,6 +170,7 @@ def solves(args, devices, chunks, split):
     wall = time.monotonic() - t_start
     sha512_pallas.pallas_search, jax.device_put = kernel.orig, put.orig
     rec = dict(chunks=chunks, lanes=lanes, solves=len(objs),
+               lay_out="one program" if one_program else "a launch a lane",
                wall_s=round(wall, 3),
                pairs_per_s=round(len(objs) / 2 / wall, 3),
                launches_per_solve=round(launches / len(objs), 3),
@@ -159,6 +185,124 @@ def solves(args, devices, chunks, split):
                 "/".join(values): child.snapshot()[:0:-1]
                 for values, child in head.children()}
     return rec
+
+
+def ici(args, devices):
+    """``args.solves`` lone solves through ``ici_search`` alone: a
+    launch, a fetch, the hashlib check; a second launch only if every
+    chip ran out."""
+    import numpy as np
+    from pybitmessage_tpu.ops import sha512_ici
+    from pybitmessage_tpu.pow.dispatcher import host_trial
+    from pybitmessage_tpu.pow.pipeline import (_copy_base, _hash_words,
+                                               _split64)
+
+    lanes = len(devices)
+    shape = dict(rows=8 if args.tiny else 128,
+                 chunks=4 if args.tiny else 512, unroll=5)
+    step_trials = shape["rows"] * 128 * shape["unroll"]
+    expected = (3e4, 5e4) if args.tiny else EXPECTED
+    objs = [(hashlib.sha512(b"lone lanes bench %d" % i).digest(),
+             int(2 ** 64 / expected[i % 2])) for i in range(args.solves)]
+
+    def operands(item, starts):
+        words = [w for pair in _hash_words(item[0]) for w in pair]
+        return np.array([words + list(_split64(starts[k]))
+                         + list(_split64(item[1])) for k in range(lanes)],
+                        dtype=np.uint32)
+
+    def launch(item, starts):
+        t0 = time.perf_counter()
+        out = sha512_ici.ici_search(operands(item, starts), devices,
+                                    **shape)
+        t1 = time.perf_counter()
+        rows = np.asarray(out)
+        return rows, t1 - t0, time.perf_counter() - t1
+
+    def begin():
+        return [_copy_base(0, k, lanes) for k in range(lanes)]
+
+    import threading
+    patience = threading.Timer(ICI_PATIENCE_S, lambda: (
+        print(json.dumps({"ici": "no answer in %.0f s"
+                          % ICI_PATIENCE_S}), flush=True),
+        os._exit(70)))
+    patience.daemon = True
+    patience.start()
+    t0 = time.perf_counter()
+    launch(objs[0], begin())        # the program is loaded or exported
+    first_s = time.perf_counter() - t0
+    # a step's time: launches nobody can win, every chip runs out
+    nobody = (objs[0][0], 0)
+    full = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        rows, _d, _f = launch(nobody, begin())
+        full.append(time.perf_counter() - t0)
+        assert all(int(r[sha512_ici.WHY]) == sha512_ici.RAN_OUT
+                   and int(r[sha512_ici.STEPS]) == shape["chunks"]
+                   for r in rows), rows
+    step_s = min(full) / shape["chunks"]
+    time.sleep(0.3)
+    parts = {k: [] for k in ("solve", "dispatch", "fetch", "over_search",
+                             "lag")}
+    why = dict.fromkeys(("won", "own_hit", "cancelled", "ran_out"), 0)
+    lag_steps, launches, executed, needed = [], 0, 0, 0
+    t_start = time.monotonic()
+    for item in objs:
+        t_in = time.perf_counter()
+        starts, dispatch, fetch = begin(), 0.0, 0.0
+        while True:
+            rows, d, f = launch(item, starts)
+            launches += 1
+            dispatch, fetch = dispatch + d, fetch + f
+            ran = [int(r[sha512_ici.STEPS]) for r in rows]
+            executed += sum(ran) * step_trials
+            hits = [k for k in range(lanes) if rows[k][sha512_ici.HIT]]
+            if hits:
+                break
+            needed += sum(ran) * step_trials
+            why["ran_out"] += lanes
+            starts = [s + n * step_trials for s, n in zip(starts, ran)]
+        t_out = time.perf_counter()
+        win = min(hits, key=lambda k: int(rows[k][sha512_ici.HIT]))
+        hit = int(rows[win][sha512_ici.HIT])
+        nonce = (int(rows[win][sha512_ici.NONCE_HI]) << 32) \
+            | int(rows[win][sha512_ici.NONCE_LO])
+        assert host_trial(nonce, item[0]) <= item[1]
+        assert (nonce - starts[win]) // step_trials == hit - 1
+        needed += sum(min(n, hit) for n in ran) * step_trials
+        for k in range(lanes):
+            code = int(rows[k][sha512_ici.WHY])
+            if k == win:
+                why["won"] += 1
+            elif code == sha512_ici.OWN_HIT:
+                why["own_hit"] += 1
+            elif code == sha512_ici.CANCELLED:
+                why["cancelled"] += 1
+                lag_steps.append(max(ran[k] - hit, 0))
+            else:
+                why["ran_out"] += 1
+        parts["solve"].append(t_out - t_in)
+        parts["dispatch"].append(dispatch)
+        parts["fetch"].append(fetch)
+        parts["over_search"].append(t_out - t_in - hit * step_s)
+        late = [max(ran[k] - hit, 0) for k in range(lanes) if k != win]
+        parts["lag"].append(max(late) * step_s)
+        time.sleep(args.gap_ms / 1e3)
+    wall = time.monotonic() - t_start
+    patience.cancel()
+    return dict(
+        ici=True, lanes=lanes, solves=len(objs), chunks=shape["chunks"],
+        first_launch_s=round(first_s, 3), step_ms=round(step_s * 1e3, 5),
+        wall_s=round(wall, 3), pairs_per_s=round(len(objs) / 2 / wall, 3),
+        launches_per_solve=round(launches / len(objs), 3), lanes_by=why,
+        cancel_lag_steps={
+            "mean": round(statistics.fmean(lag_steps), 4),
+            "max": max(lag_steps), "n": len(lag_steps)} if lag_steps
+        else {},
+        executed_useful_share=round(100.0 * needed / executed, 3),
+        ms={k: _ms(v) for k, v in parts.items()})
 
 
 def micro(args, devices):
@@ -254,8 +398,9 @@ def _xla_where_the_kernel_is() -> None:
     """``--tiny``: an XLA program of real hashes with ``pallas_search``'s
     output contract in its place, as the tests have it."""
     import jax
-    from pybitmessage_tpu.ops import sha512_pallas
+    from pybitmessage_tpu.ops import sha512_ici, sha512_pallas
     from pybitmessage_tpu.parallel.pow_pallas_sharded import _xla_slab
+    from pybitmessage_tpu.pow import pipeline
 
     slab = jax.jit(_xla_slab, static_argnames=("rows", "chunks"))
 
@@ -266,6 +411,15 @@ def _xla_where_the_kernel_is() -> None:
 
     sha512_pallas.pallas_search = search
 
+    def ici_search(operands, devices, rows, chunks, unroll,
+                   interpret=False):
+        # the entry's XLA equivalent: a grid step is ``unroll`` tiles
+        return pipeline._ici_search_xla(
+            operands, lanes=rows * sha512_pallas.LANE_COLS * unroll,
+            chunks=chunks)
+
+    sha512_ici.ici_search = ici_search
+
 
 def main() -> None:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
@@ -274,6 +428,7 @@ def main() -> None:
     ap.add_argument("--split", action="store_true")
     ap.add_argument("--chunks", default="")
     ap.add_argument("--micro", action="store_true")
+    ap.add_argument("--ici", action="store_true")
     ap.add_argument("--micro-rounds", type=int, default=200)
     ap.add_argument("--lanes", type=int, default=0,
                     help="devices to lay the object over (0: all)")
@@ -304,7 +459,17 @@ def main() -> None:
         out["runs"].append(solves(args, placed, own, True))
         print(json.dumps(out["runs"][-1]), flush=True)
     for chunks in [int(c) for c in args.chunks.split(",") if c]:
+        # beside ``--ici``, what that is compared with: a launch a lane
         out["runs"].append(solves(args, placed, chunks, False))
+        print(json.dumps(out["runs"][-1]), flush=True)
+    if args.ici:
+        out["runs"].append(ici(args, devices))
+        print(json.dumps(out["runs"][-1]), flush=True)
+        # and the one program where the node has it: under the pipeline
+        out["runs"].append(solves(args, placed, pipeline.plan_batch(
+            [(b"", int(2 ** 64 / EXPECTED[0]))], lanes=len(devices),
+            one_program=True).chunks if not args.tiny else 4, False,
+            one_program=True))
         print(json.dumps(out["runs"][-1]), flush=True)
     if args.micro:
         out["micro"] = micro(args, devices)

@@ -137,6 +137,21 @@ def _probe_pallas_slab():
     _probe_pipeline("slab")
 
 
+def _probe_ici_slab():
+    """One always-hit launch of a lone object's ONE program over every
+    chip (``ops/sha512_ici.py``).  On one chip, or on the ``cpu``
+    backend, the Mosaic program is never launched and there is none to
+    probe."""
+    import jax
+    from pybitmessage_tpu.pow import pipeline
+    devices = jax.devices()
+    if not pipeline._one_program("pallas", devices):
+        return
+    pipeline.solve_batch_pipelined(
+        [(_IH, _ALWAYS)], rows=8, impl="pallas", devices=devices,
+        plan=pipeline.BatchPlan("slab", 1, 1, [0], one_program=True))
+
+
 def _probe_batch_search():
     _probe_pipeline("batched")
 
@@ -200,6 +215,7 @@ _PROBES = {
     "pow_slab": _probe_pow_slab,
     "pow_verify": _probe_pow_verify,
     "pallas_slab": _probe_pallas_slab,
+    "ici_slab": _probe_ici_slab,
     "batch_search": _probe_batch_search,
     "packed_search": _probe_packed_search,
     "packed_search_xla": _probe_packed_search_xla,
